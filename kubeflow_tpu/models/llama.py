@@ -72,16 +72,17 @@ class LlamaConfig:
     # "xla" reference einsum, "flash" the fused Pallas flash-decode
     # kernel (ops/flash_decode.py — online softmax over KV blocks, int8
     # dequant fused at the block load, GQA regrouped in-kernel), "auto"
-    # the selection policy (flash on TPU, xla elsewhere; KTPU_DECODE_ATTN
-    # env overrides the default). Orthogonal to attention_impl, which
+    # the selection policy (flash where it compiles — a TPU target and a
+    # head_dim the kernel tiles — xla elsewhere; KTPU_DECODE_ATTN env
+    # overrides the default). Orthogonal to attention_impl, which
     # governs the TRAINING/prefill full-sequence attention.
     decode_attention_impl: str = "auto"
     # serving PREFILL chunk attention (ISSUE 20): "xla" the reference
     # mha einsum, "flash" the fused Pallas chunked-prefill kernel
     # (ops/flash_prefill.py — online softmax over KV blocks, q_offset
     # causal masking, int8 dequant fused at the block load), "auto" the
-    # selection policy (flash on TPU, xla elsewhere; KTPU_PREFILL_ATTN
-    # env overrides the default). Governs the serving prefill_inner/
+    # selection policy (the decode rule; KTPU_PREFILL_ATTN env overrides
+    # the default). Governs the serving prefill_inner/
     # prefill_continue_inner bodies — TRAINING attention stays on
     # attention_impl.
     prefill_attention_impl: str = "auto"
@@ -504,20 +505,32 @@ def prefill_inner(layers: Params, x: jax.Array, positions: jax.Array,
     return jax.lax.scan(body, x, xs)
 
 
-def lm_head(params: Params, x: jax.Array, cfg: LlamaConfig) -> jax.Array:
+def lm_head(params: Params, x: jax.Array, cfg: LlamaConfig,
+            rows: jax.Array | None = None) -> jax.Array:
     """final_norm + lm_head projection — the serving tail every prefill/
-    decode wrapper (and the LAST pipeline stage) shares."""
+    decode wrapper (and the LAST pipeline stage) shares.
+
+    `rows` [B] int32 projects ONLY position rows[b] of each sequence:
+    x [B, S, D] -> logits [B, vocab]. A prefill samples one token per
+    prompt, and the all-position f32 logits of a 16 x 1024 wave at vocab
+    128256 are 7.8 GiB — more than a 16 GB chip has beside the model.
+    Out-of-range rows clamp (the dynamic_index_in_dim rule)."""
+    if rows is not None:
+        x = jnp.take_along_axis(x, rows[:, None, None], axis=1,
+                                mode="clip")[:, 0]
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return quant.matmul_f32_out(x, params["lm_head"], cfg.dtype)
 
 
 def prefill(params: Params, tokens: jax.Array, cfg: LlamaConfig,
-            lora: Params | None = None, ids: jax.Array | None = None):
+            lora: Params | None = None, ids: jax.Array | None = None,
+            logit_rows: jax.Array | None = None):
     """Forward a (right-padded) prompt, returning logits and per-layer KV.
 
-    tokens: [B, S] → (logits [B, S, vocab] fp32, k, v [L, B, S, kv, hd]).
-    Pad positions produce garbage KV past the true length — callers track
-    lengths and decode masks them out.
+    tokens: [B, S] → (logits [B, S, vocab] fp32, k, v [L, B, S, kv, hd]);
+    with `logit_rows` [B], logits are [B, vocab] of those positions only
+    (lm_head's `rows`). Pad positions produce garbage KV past the true
+    length — callers track lengths and decode masks them out.
 
     `lora`/`ids`: optional multi-adapter batch (serving/llm.py
     `adapters=`): lora = {target: {"a": [L, A, d_in, r], "b": [L, A, r,
@@ -529,28 +542,30 @@ def prefill(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     x = params["embed"].astype(cfg.dtype)[tokens]
     x, (ks, vs) = prefill_inner(params["layers"], x, positions, cfg,
                                 lora, ids)
-    return lm_head(params, x, cfg), ks, vs
+    return lm_head(params, x, cfg, logit_rows), ks, vs
 
 
 def prefill_continue(params: Params, tail_tokens: jax.Array,
                      k_prefix: jax.Array, v_prefix: jax.Array,
                      cfg: LlamaConfig, lora: Params | None = None,
-                     ids: jax.Array | None = None):
+                     ids: jax.Array | None = None,
+                     logit_rows: jax.Array | None = None):
     """Continuation prefill: forward only the TAIL of a prompt whose prefix
     KV is already computed (prefix caching — serving/llm.py).
 
     tail_tokens: [B, T] (right-padded); k_prefix/v_prefix: [L, B, P, kv, hd]
     from a previous prefill of the shared prefix. Returns
-    (logits [B, T, vocab] fp32, k_tail, v_tail [L, B, T, kv, hd]).
-    The tail attends causally over prefix+tail (q_offset = P); pad tail
-    positions produce garbage KV the caller masks by true lengths.
+    (logits [B, T, vocab] fp32, k_tail, v_tail [L, B, T, kv, hd]) — or
+    logits [B, vocab] of tail positions `logit_rows` [B] only (lm_head's
+    `rows`). The tail attends causally over prefix+tail (q_offset = P);
+    pad tail positions produce garbage KV the caller masks by true lengths.
     """
     positions = k_prefix.shape[2] + jnp.arange(tail_tokens.shape[1])
     x = params["embed"].astype(cfg.dtype)[tail_tokens]
     x, (ks, vs) = prefill_continue_inner(params["layers"], x, k_prefix,
                                          v_prefix, positions, cfg,
                                          lora, ids)
-    return lm_head(params, x, cfg), ks, vs
+    return lm_head(params, x, cfg, logit_rows), ks, vs
 
 
 def prefill_continue_inner(layers: Params, x: jax.Array,
@@ -654,7 +669,9 @@ def resolve_decode_attn(cfg: LlamaConfig) -> str:
     engine's compiled program menu covers exactly the selected impl."""
     from kubeflow_tpu.ops import flash_decode
 
-    return flash_decode.resolve_impl(cfg.decode_attention_impl)
+    return flash_decode.resolve_impl(cfg.decode_attention_impl,
+                                     head_dim=cfg.head_dim,
+                                     n_kv_heads=cfg.n_kv_heads)
 
 
 def resolve_prefill_attn(cfg: LlamaConfig) -> str:
@@ -663,7 +680,9 @@ def resolve_prefill_attn(cfg: LlamaConfig) -> str:
     trace, the prefill twin of resolve_decode_attn."""
     from kubeflow_tpu.ops import flash_prefill
 
-    return flash_prefill.resolve_impl(cfg.prefill_attention_impl)
+    return flash_prefill.resolve_impl(cfg.prefill_attention_impl,
+                                      head_dim=cfg.head_dim,
+                                      n_kv_heads=cfg.n_kv_heads)
 
 
 def prefill_attention(cfg: LlamaConfig, q: jax.Array, k: jax.Array,

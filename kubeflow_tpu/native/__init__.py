@@ -6,17 +6,21 @@ Triton's C++ serving core, MLMD's C++ metadata store, NCCL/MPI rendezvous
 C++ libraries with flat C ABIs, bound via ctypes (no pybind11 in the image).
 
 Libraries are compiled on demand from ``native/src/*.cpp`` with the system
-g++ into ``native/build/`` and cached by source mtime; environments without
-a toolchain raise ``NativeUnavailable`` and callers fall back to their pure-
-Python implementations (same contract, slower queue/scheduling paths).
+g++ into the git-ignored ``native/build/``, keyed by the source's content
+(a checkout or a copy sets mtimes arbitrarily, so a binary is never trusted
+for being newer than its source); environments without a toolchain raise
+``NativeUnavailable`` and callers fall back to their pure-Python
+implementations (same contract, slower queue/scheduling paths).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import shutil
 import subprocess
+import tempfile
 import threading
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -37,7 +41,8 @@ def _compiler() -> str | None:
 
 
 def build(name: str, force: bool = False) -> str:
-    """Compile native/src/<name>.cpp → native/build/lib<name>.so; returns path."""
+    """Compile native/src/<name>.cpp → native/build/lib<name>-<sha>.so, where
+    <sha> is a digest of the source; returns the path."""
     src = os.path.join(SRC_DIR, f"{name}.cpp")
     if not os.path.exists(src):
         raise NativeUnavailable(f"no native source {src}")
@@ -52,15 +57,19 @@ def build(name: str, force: bool = False) -> str:
         raise NativeUnavailable(
             f"KTPU_NATIVE_SANITIZE={san!r} (want thread|address|undefined)")
     suffix = f".{san[0]}san.so" if san else ".so"
-    out = os.path.join(BUILD_DIR, f"lib{name}{suffix}")
-    if not force and os.path.exists(out) and \
-            os.path.getmtime(out) >= os.path.getmtime(src):
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    out = os.path.join(BUILD_DIR, f"lib{name}-{digest}{suffix}")
+    if not force and os.path.exists(out):
         return out
     cxx = _compiler()
     if cxx is None:
         raise NativeUnavailable("no C++ compiler on PATH")
     os.makedirs(BUILD_DIR, exist_ok=True)
-    tmp = out + ".tmp"
+    # a private temp name: with the binaries untracked, the workers of a
+    # multi-process job all build on a fresh checkout at the same moment
+    fd, tmp = tempfile.mkstemp(dir=BUILD_DIR, suffix=".tmp")
+    os.close(fd)
     if san:
         cmd = [cxx, "-O1", "-g", f"-fsanitize={san}", "-std=c++17",
                "-shared", "-fPIC", "-pthread", src, "-o", tmp]
@@ -69,8 +78,10 @@ def build(name: str, force: bool = False) -> str:
                src, "-o", tmp]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
+        os.unlink(tmp)
         raise NativeUnavailable(
             f"native build failed: {' '.join(cmd)}\n{proc.stderr[-2000:]}")
+    os.chmod(tmp, 0o755)  # mkstemp creates 0600
     os.replace(tmp, out)  # atomic: concurrent builders race benignly
     return out
 
@@ -81,6 +92,12 @@ def library(name: str) -> ctypes.CDLL:
         if name not in _cache:
             _cache[name] = ctypes.CDLL(build(name))
         return _cache[name]
+
+
+def loaded() -> list[str]:
+    """Names of the native libraries this process has loaded so far."""
+    with _lock:
+        return sorted(_cache)
 
 
 def available(name: str) -> bool:

@@ -5,7 +5,9 @@ Two implementations behind one API:
   - ``impl="xla"``: blockwise ``lax.scan`` — pure XLA, differentiable,
     O(S·block) memory, runs anywhere (CPU tests included).
   - ``impl="pallas"``: Mosaic kernel (ops/flash_pallas.py) for the TPU hot
-    path; falls back to xla when Pallas/TPU is unavailable.
+    path. ``impl="auto"`` takes it wherever the kernel accepts the call
+    and the blockwise path elsewhere; on a TPU target a refusal is logged
+    once with its reason, and ``TRACED_BODIES`` names every body traced.
 
 The reference platform has no attention code at all (compute is delegated to
 user containers, SURVEY.md L7); this is one of the framework's native-compute
@@ -14,16 +16,29 @@ components replacing what CUDA users get from flash-attn kernels.
 
 from __future__ import annotations
 
+import functools
+import logging
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from kubeflow_tpu.ops import flash_pallas, pallas_compat
 from kubeflow_tpu.ops.attention import repeat_kv
-from kubeflow_tpu.parallel.mesh import manual_axis_names as _manual_axis_names
+from kubeflow_tpu.parallel.mesh import (get_active_mesh,
+                                        manual_axis_names as _manual_axis_names,
+                                        mesh_shape)
 
 NEG_INF = -1e30
+
+_log = logging.getLogger(__name__)
+
+#: the bodies flash_attention has traced in this process: "pallas" and/or
+#: "xla" (blockwise). A caller that must know the kernel is what compiled
+#: (chip_smoke.py) reads it after the step has been traced.
+TRACED_BODIES: set[str] = set()
+_refusals_logged: set[str] = set()
 
 
 def _pallas_island(q, k, v, segment_ids, call):
@@ -40,16 +55,12 @@ def _pallas_island(q, k, v, segment_ids, call):
     axes already manual/local or trivial); raises NotImplementedError when
     the kernel cannot run sharded (indivisible shapes, auto seq sharding) so
     the caller falls back to the partitionable blockwise-XLA path."""
-    from kubeflow_tpu.parallel.mesh import get_active_mesh, mesh_shape
-
     mesh = get_active_mesh()
     if mesh is None:
         return None
     # target-platform gate BEFORE any shard_map construction: aborting a
     # trace mid-shard_map (kernel raising NotImplementedError inside the
     # body) can leave partial state behind — decide early instead
-    from kubeflow_tpu.ops import flash_pallas
-
     if not flash_pallas.FORCE_INTERPRET and \
             mesh.devices.flat[0].platform != "tpu":
         raise NotImplementedError(
@@ -200,22 +211,30 @@ def flash_attention(
 
     if impl in ("auto", "pallas"):
         try:
-            import functools
-
-            from kubeflow_tpu.ops.flash_pallas import pallas_flash_attention
-
             call = functools.partial(
-                pallas_flash_attention, causal=causal, scale=scale,
-                q_offset=q_offset,
+                flash_pallas.pallas_flash_attention, causal=causal,
+                scale=scale, q_offset=q_offset,
                 block_kv=None if block_kv is None else max(block_kv, 128))
+            out = None
             if isinstance(q_offset, int) and q_offset == 0:
                 out = _pallas_island(q, k, v, segment_ids, call)
-                if out is not None:
-                    return out
-            return call(q, k, v, segment_ids=segment_ids)
-        except (ImportError, NotImplementedError):
+            if out is None:
+                out = call(q, k, v, segment_ids=segment_ids)
+            TRACED_BODIES.add("pallas")
+            return out
+        except NotImplementedError as e:
             if impl == "pallas":
                 raise
+            # off-TPU the blockwise path IS the platform's body; on a TPU
+            # target a refusal means the chip trains on the slow path, and
+            # must say why
+            if pallas_compat.target_platform() == "tpu" \
+                    and str(e) not in _refusals_logged:
+                _refusals_logged.add(str(e))
+                _log.warning("flash_attention: Pallas kernel refused on a "
+                             "TPU target (%s); tracing the blockwise XLA "
+                             "body", e)
+    TRACED_BODIES.add("xla")
     block = min(block_kv or 512, k.shape[1])
     return _blockwise_attn(q, k, v, causal=causal, scale=scale,
                            q_offset=q_offset, block_kv=block,

@@ -17,7 +17,7 @@ softmax, weighted sum — in VMEM:
     (the whole point: the cache's HBM footprint is its int8 bytes).
     The kv-head grid axis indexes the slab through a metadata-only
     `[B, span, kv*hd]` reshape, so no transpose of the payload is ever
-    staged; only the tiny scale arrays are transposed to `[B, kv, span]`
+    staged; only the tiny scale arrays are transposed to `[B, kv, 1, span]`
     (4/hd of the payload bytes).
   - **One body for decode and verify.** q is `[slots, S_v, heads, hd]`:
     S_v=1 is `decode_step`, S_v>1 is the speculative `verify_step`
@@ -57,6 +57,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kubeflow_tpu.ops import pallas_compat
+
 NEG_INF = -1e30
 
 # Tests on the CPU backend set this to exercise the kernel via the Pallas
@@ -76,26 +78,16 @@ DEFAULT_BLOCK_KV = 256
 IMPL_ENV = "KTPU_DECODE_ATTN"
 
 
-def _target_platform() -> str:
-    from kubeflow_tpu.ops.pallas_compat import target_platform
-
-    return target_platform()
-
-
-def resolve_impl(configured: str = "auto") -> str:
-    """Selection policy (ISSUE 15): kernels default ON for TPU, OFF
-    (xla) elsewhere. Explicit config ("xla"/"flash") > KTPU_DECODE_ATTN
-    env > platform default. Static — resolved at trace time, so each
-    engine's compiled menu covers exactly one impl."""
-    if configured in ("xla", "flash"):
-        return configured
-    env = os.environ.get(IMPL_ENV, "").strip().lower()
-    if env in ("xla", "flash"):
-        return env
-    try:
-        return "flash" if _target_platform() == "tpu" else "xla"
-    except Exception:
-        return "xla"
+def resolve_impl(configured: str = "auto", *, head_dim: int,
+                 n_kv_heads: int) -> str:
+    """Selection policy: explicit config ("xla"/"flash") >
+    KTPU_DECODE_ATTN env > flash where it compiles (TPU target, KV
+    layout the kernel tiles), xla elsewhere — see
+    pallas_compat.resolve_flash_impl. Static — resolved at trace time,
+    so each engine's compiled menu covers exactly one impl."""
+    return pallas_compat.resolve_flash_impl(
+        configured, os.environ.get(IMPL_ENV), head_dim=head_dim,
+        n_kv_heads=n_kv_heads)
 
 
 def _resolve_interpret(interpret):
@@ -105,21 +97,11 @@ def _resolve_interpret(interpret):
         return True
     # non-TPU target: interpreter mode — the differential tests' CPU
     # fast lane (and the bench's CPU A/B smoke) run the SAME kernel body
-    return _target_platform() != "tpu"
+    return pallas_compat.target_platform() != "tpu"
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
-
-
-def _out_shape(shape, dtype, *xs):
-    """ShapeDtypeStruct carrying the union of the inputs' varying-manual
-    axes — makes the kernel legal inside a check_vma=True shard_map
-    region (a pipeline stage body); see ops/pallas_compat."""
-    from kubeflow_tpu.ops import pallas_compat
-
-    return pallas_compat.sds_with_vma(shape, dtype,
-                                      pallas_compat.collect_vma(*xs))
 
 
 def _decode_kernel(len_ref, *refs, s_v, block_kv, t_real, scale,
@@ -161,7 +143,7 @@ def _decode_kernel(len_ref, *refs, s_v, block_kv, t_real, scale,
         if quantized:
             # per-token k scale on the score column — the einsum path's
             # `att * k_scales` order (scale BEFORE 1/sqrt(hd))
-            s = s * ks_ref[0, 0][None, :]
+            s = s * ks_ref[0, 0]
         s = s * scale
         k_pos = k_start + jax.lax.broadcasted_iota(
             jnp.int32, (rows, block_kv), 1)
@@ -183,7 +165,7 @@ def _decode_kernel(len_ref, *refs, s_v, block_kv, t_real, scale,
             # fold the per-token v scale into p so the int8 payload
             # feeds the dot un-materialized (the einsum path's
             # probs_s = probs * v_scales trick)
-            pv = (p * vs_ref[0, 0][None, :]).astype(q.dtype)
+            pv = (p * vs_ref[0, 0]).astype(q.dtype)
         else:
             pv = p.astype(q.dtype)
         acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
@@ -284,24 +266,27 @@ def flash_decode_attention(q, k, v, lengths, *, k_scale=None, v_scale=None,
             (1, block_kv, hd),
             lambda b_, h, j, len_ref, tbl_ref: (tbl_ref[b_, j], 0, h))
         sc_spec = pl.BlockSpec(
-            (1, 1, block_kv),
-            lambda b_, h, j, len_ref, tbl_ref: (tbl_ref[b_, j], h, 0))
+            (1, 1, 1, block_kv),
+            lambda b_, h, j, len_ref, tbl_ref: (tbl_ref[b_, j], h, 0, 0))
     else:
         k3 = k.reshape(b, t_pad, nkv * hd)
         v3 = v.reshape(b, t_pad, nkv * hd)
         kv_spec = pl.BlockSpec((1, block_kv, hd),
                                lambda b_, h, j, *_: (b_, j, h))
-        sc_spec = pl.BlockSpec((1, 1, block_kv),
-                               lambda b_, h, j, *_: (b_, h, j))
+        sc_spec = pl.BlockSpec((1, 1, 1, block_kv),
+                               lambda b_, h, j, *_: (b_, h, 0, j))
 
     extra_specs, extra_args = [], []
     if quantized:
-        # scales ARE transposed (slab [B, kv, T] / pool [N, kv, bt] —
-        # lane-major per head): 4/hd of the payload bytes, the price of
-        # a tiling-legal scale block
+        # scales ARE transposed, lane-major per head with a unit
+        # sublane axis (slab [B, kv, 1, T] / pool [N, kv, 1, bt]): the
+        # (1, block_kv) block tail then equals the array's second-minor
+        # dimension, which is what Mosaic's tiling rule asks of a block
+        # that is not a multiple of 8 sublanes. 4/hd of the payload bytes.
         extra_specs = [sc_spec, sc_spec]
-        extra_args = [jnp.swapaxes(k_scale, -2, -1).astype(jnp.float32),
-                      jnp.swapaxes(v_scale, -2, -1).astype(jnp.float32)]
+        extra_args = [
+            jnp.swapaxes(sc, -2, -1).astype(jnp.float32)[:, :, None, :]
+            for sc in (k_scale, v_scale)]
 
     prefetch = [jnp.asarray(lengths, jnp.int32)]
     if paged:
@@ -327,15 +312,14 @@ def flash_decode_attention(q, k, v, lengths, *, k_scale=None, v_scale=None,
     kernel = functools.partial(
         _decode_kernel, s_v=s_v, block_kv=block_kv, t_real=t, scale=scale,
         quantized=quantized, paged=paged)
-    from kubeflow_tpu.ops.pallas_compat import tpu_compiler_params
-
     itemsize = jnp.dtype(k.dtype).itemsize
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=_out_shape((b, nkv, r_pad, hd), q.dtype, q, k, v),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        out_shape=pallas_compat.sds_with_vma(
+            (b, nkv, r_pad, hd), q.dtype, q, k, v),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * nh * s_v * t_pad * hd,
             bytes_accessed=2 * b * t_pad * nkv * hd * itemsize,

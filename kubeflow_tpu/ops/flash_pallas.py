@@ -30,6 +30,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kubeflow_tpu.ops import pallas_compat
+from kubeflow_tpu.ops.pallas_compat import sds_with_vma as _sds
+
 NEG_INF = -1e30
 
 # Tests on the CPU backend set this to exercise the kernels via the Pallas
@@ -37,10 +40,9 @@ NEG_INF = -1e30
 FORCE_INTERPRET = False
 
 
-def _compiler_params(dimension_semantics):
-    from kubeflow_tpu.ops.pallas_compat import tpu_compiler_params
-
-    return tpu_compiler_params(dimension_semantics)
+# every kernel here walks (batch*heads, outer blocks, sequential blocks)
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _round_up(x: int, m: int) -> int:
@@ -53,21 +55,6 @@ def default_blocks(sq: int, sk: int) -> tuple[int, int]:
     bigger tiles amortize the grid; at seq 2048 the small blocks win (the
     r2 sweep). ONE source of truth — the ring body mirrors these."""
     return (512 if sq >= 4096 else 256, 1024 if sk >= 4096 else 512)
-
-
-def _out_vma(*xs):
-    """Varying-manual-axes annotation for pallas out_shapes: the union of
-    the inputs' vma (None on jax versions without vma tracking); see
-    ops/pallas_compat.collect_vma."""
-    from kubeflow_tpu.ops.pallas_compat import collect_vma
-
-    return collect_vma(*xs)
-
-
-def _sds(shape, dtype, vma):
-    from kubeflow_tpu.ops.pallas_compat import sds_with_vma
-
-    return sds_with_vma(shape, dtype, vma)
 
 
 # ---------------------------------------------------------------------------
@@ -203,15 +190,14 @@ def _fwd(q, k, v, seg_q, seg_k, causal, scale, q_offset, interpret, block_q,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_kv=block_kv, sk=sk, segmented=segmented)
-    vma = _out_vma(q, k, v)
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            _sds((bh, sq_p, d), q.dtype, vma),
-            _sds((bh, n_q, 1, block_q), jnp.float32, vma),
+            _sds((bh, sq_p, d), q.dtype, q, k, v),
+            _sds((bh, n_q, 1, block_q), jnp.float32, q, k, v),
         ],
-        compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_COMPILER_PARAMS,
         cost_estimate=pl.CostEstimate(
             flops=4 * bh * sq_p * sk_p * d,
             bytes_accessed=2 * bh * (sq_p + 2 * sk_p) * d * q.dtype.itemsize,
@@ -383,7 +369,6 @@ def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
         ]
         seg_args = [seg_q3, seg_k3]
 
-    vma = _out_vma(q, k, v, do)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_kv=block_kv, sk=sk,
@@ -392,9 +377,9 @@ def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
         in_specs=[q_spec, kv_spec_dq, kv_spec_dq, q_spec, row_spec, row_spec,
                   *seg_specs_dq],
         out_specs=q_spec,
-        out_shape=_sds((bh, sq_p, d), q.dtype, vma),
+        out_shape=_sds((bh, sq_p, d), q.dtype, q, k, v, do),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, do, lse3, delta3, *seg_args)
 
@@ -418,11 +403,11 @@ def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
         in_specs=[q_spec_kv, kv_spec, kv_spec, q_spec_kv, row_spec_kv,
                   row_spec_kv, *seg_specs_kv],
         out_specs=[kv_spec, kv_spec],
-        out_shape=[_sds((bh, sk_p, d), k.dtype, vma),
-                   _sds((bh, sk_p, d), v.dtype, vma)],
+        out_shape=[_sds((bh, sk_p, d), k.dtype, q, k, v, do),
+                   _sds((bh, sk_p, d), v.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
                         pltpu.VMEM((block_kv, d), jnp.float32)],
-        compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, do, lse3, delta3, *seg_args)
 
@@ -504,19 +489,12 @@ def pallas_flash_attention(q, k, v, *, causal=True, scale=None,
     """
     if interpret is None:
         # auto mode: compiled when the COMPILE TARGET is a TPU; off-TPU only
-        # when the interpreter was opted into globally, else fall back to
-        # the blockwise-XLA path. The target is the active mesh's platform
-        # when one is set (it may be a PJRT *topology* — AOT-compiling for
-        # v5e from a CPU-pinned process must still pick the kernel), and
-        # the process default backend otherwise.
+        # when the interpreter was opted into globally, else refuse (the
+        # caller takes the blockwise-XLA path)
         if FORCE_INTERPRET:
             interpret = True
         else:
-            from kubeflow_tpu.parallel.mesh import get_active_mesh
-
-            mesh = get_active_mesh()
-            platform = (mesh.devices.flat[0].platform if mesh is not None
-                        else jax.default_backend())
+            platform = pallas_compat.target_platform()
             if platform != "tpu":
                 raise NotImplementedError(
                     f"pallas flash kernel: target platform {platform!r}")

@@ -1,54 +1,24 @@
-"""Shared jax-version compatibility probes for the Pallas kernel modules
-(flash_pallas, quant_matmul, flash_decode) — ONE guarded implementation
-instead of three divergent copies, because the failure mode of a stale
-copy is every kernel call dying at trace time.
-
-The repo's floor is "whatever jax the container bakes": the kernels must
-run (interpret OR compiled) on both the 0.4.x line (TPUCompilerParams,
-no jax.typeof/vma) and the current line (CompilerParams, vma-checked
-shard_map regions).
+"""What the Pallas kernel modules (flash_pallas, quant_matmul,
+flash_decode, flash_prefill) share: the compile-target probe their
+selection policies use, the tiling rule of the serving KV layout, and the
+vma annotation that makes a pallas_call legal inside a shard_map region.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
+
+from kubeflow_tpu.parallel.mesh import (get_active_mesh, manual_axis_names,
+                                        mesh_shape)
 
 
-def tpu_compiler_params(dimension_semantics):
-    """CompilerParams for a pallas_call, or None (pallas_call accepts
-    None) when this jax exposes neither spelling — CompilerParams was
-    TPUCompilerParams before jax 0.5."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        return None
-    try:
-        return cls(dimension_semantics=dimension_semantics)
-    except TypeError:  # field-name drift — let Mosaic autodetect
-        return cls()
-
-
-def collect_vma(*xs):
-    """Union of the inputs' varying-manual-axes, or None on jax versions
-    without vma tracking (no jax.typeof — those versions don't check vma
-    either). Inside a check_vma=True shard_map (e.g. a pipeline stage
-    body) a pallas_call output without vma is rejected; annotating with
-    the inputs' axes makes the kernels legal in any manual region."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return None
-    vma = frozenset()
-    for x in xs:
-        vma |= getattr(typeof(x), "vma", frozenset())
-    return vma
-
-
-def sds_with_vma(shape, dtype, vma):
-    """ShapeDtypeStruct carrying the vma annotation when this jax
-    supports one (see collect_vma)."""
-    if vma is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
+def sds_with_vma(shape, dtype, *xs):
+    """ShapeDtypeStruct for a pallas out_shape carrying the union of the
+    inputs' varying-manual-axes. Inside a check_vma=True shard_map (e.g.
+    a pipeline stage body) a pallas_call output without vma is rejected;
+    annotating with the inputs' axes makes the kernels legal in any
+    manual region."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in xs))
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
@@ -58,9 +28,59 @@ def target_platform() -> str:
     process must still pick the kernel path), else the process default
     backend. The ONE platform probe every kernel-selection policy uses,
     so the policies cannot diverge on the AOT/mesh scenario."""
-    from kubeflow_tpu.parallel.mesh import get_active_mesh
-
     mesh = get_active_mesh()
     if mesh is not None:
         return mesh.devices.flat[0].platform
     return jax.default_backend()
+
+
+def gspmd_partitioned() -> bool:
+    """True when the current trace runs under an active mesh that still
+    has an automatic (non-manual) axis of size > 1: XLA will partition
+    the program, and a Mosaic custom call has no partitioning rule
+    ("Mosaic kernels cannot be automatically partitioned"). Kernels that
+    are not wrapped in their own shard_map island must take the XLA
+    lowering there."""
+    mesh = get_active_mesh()
+    if mesh is None:
+        return False
+    manual = manual_axis_names(mesh)
+    return any(size > 1 and name not in manual
+               for name, size in mesh_shape(mesh).items())
+
+
+def flash_kv_refusal(head_dim: int, n_kv_heads: int) -> str | None:
+    """Why the compiled serving flash kernels (flash_decode,
+    flash_prefill) cannot tile a KV layout, or None when they can. Both
+    read head h's keys as the `(1, block_kv, head_dim)` block at lane
+    offset `h * head_dim` of the `[B, T, kv_heads * head_dim]` view, and
+    Mosaic needs a block's last dimension to be a multiple of 128 lanes
+    or the whole array dimension. Interpret mode has no such rule."""
+    if head_dim % 128 == 0 or n_kv_heads == 1:
+        return None
+    return (f"head_dim {head_dim} is not a multiple of 128 lanes (with "
+            f"{n_kv_heads} kv heads folded into the lane dimension the "
+            "per-head KV block cannot be tiled by Mosaic)")
+
+
+def resolve_flash_impl(configured: str, env_value: str | None, *,
+                       head_dim: int, n_kv_heads: int) -> str:
+    """The one selection policy of both serving attention kernels:
+    explicit config ("xla"/"flash") > env override > "flash" exactly
+    where it compiles (a TPU target and a KV layout the kernel tiles),
+    "xla" elsewhere. An explicit "flash" (config or env) at a layout the
+    TPU compiler refuses raises with the reason instead of failing at
+    the first prefill. Static: engines resolve once at construction."""
+    explicit = configured if configured in ("xla", "flash") else None
+    if explicit is None:
+        env_value = (env_value or "").strip().lower()
+        explicit = env_value if env_value in ("xla", "flash") else None
+    if explicit == "xla":
+        return "xla"
+    on_tpu = target_platform() == "tpu"
+    refusal = flash_kv_refusal(head_dim, n_kv_heads) if on_tpu else None
+    if explicit == "flash":
+        if refusal:
+            raise ValueError(f"flash attention kernel refused: {refusal}")
+        return "flash"
+    return "flash" if on_tpu and refusal is None else "xla"

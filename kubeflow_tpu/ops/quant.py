@@ -15,10 +15,14 @@ A quantized weight is a dict leaf {"q": int8 [..., in, out],
 
 from __future__ import annotations
 
+import math
+import os
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+
+from kubeflow_tpu.ops import pallas_compat
 
 
 def quantize_int8(w: jax.Array) -> dict[str, jax.Array]:
@@ -52,51 +56,36 @@ QUANT_MATMUL_ENV = "KTPU_QUANT_MATMUL"
 
 
 def resolve_quant_matmul_impl() -> str:
-    """"pallas" | "xla" — which lowering decode-shaped int8 matmuls take
-    (the ISSUE 15 selection policy): USE_PALLAS_DEQUANT (programmatic
-    force-on) > KTPU_QUANT_MATMUL env > platform default (pallas on
-    TPU, xla elsewhere). The platform probe is the same mesh-aware
-    `pallas_compat.target_platform` the flash-decode policy uses, so
-    the two kernel defaults can never diverge on the AOT-for-TPU-from-
-    CPU scenario."""
-    import os
-
+    """"pallas" | "xla" — which lowering decode-shaped int8 matmuls take:
+    USE_PALLAS_DEQUANT (programmatic force-on) > KTPU_QUANT_MATMUL env >
+    xla wherever XLA will partition the program (an active GSPMD mesh:
+    the Mosaic custom call has no partitioning rule, the attention
+    kernels' boundary) > platform default (pallas on TPU, xla
+    elsewhere). The probes are the mesh-aware ones in ops/pallas_compat
+    that the flash kernels use, so the kernel defaults cannot diverge on
+    the AOT-for-TPU-from-CPU scenario."""
     if USE_PALLAS_DEQUANT:
         return "pallas"
     env = os.environ.get(QUANT_MATMUL_ENV, "").strip().lower()
     if env in ("xla", "pallas"):
         return env
-    try:
-        from kubeflow_tpu.ops.pallas_compat import target_platform
-
-        return "pallas" if target_platform() == "tpu" else "xla"
-    except Exception:
+    if pallas_compat.gspmd_partitioned():
         return "xla"
+    return "pallas" if pallas_compat.target_platform() == "tpu" else "xla"
 
 
 def _pallas_dequant_wanted(x, q) -> bool:
     from kubeflow_tpu.ops import quant_matmul
 
-    if not (quant_matmul.FORCE_INTERPRET
-            or resolve_quant_matmul_impl() == "pallas"):
-        return False
-    if q.ndim != 2:
-        return False
-    m = 1
-    for v in x.shape[:-1]:
-        m *= v
-    if not quant_matmul.kernel_applicable(m, *q.shape):
+    if q.ndim != 2 or not quant_matmul.kernel_applicable(
+            math.prod(x.shape[:-1]), *q.shape):
         return False
     if quant_matmul.FORCE_INTERPRET:
         return True
-    try:   # selected but the compile TARGET isn't a TPU (explicit env
-        # on a CPU box): compiled Mosaic can't lower — fall back
-        # silently rather than crash every quantized matmul
-        from kubeflow_tpu.ops.pallas_compat import target_platform
-
-        return target_platform() == "tpu"
-    except Exception:
-        return False
+    # selected but the compile TARGET isn't a TPU (the env set on a CPU
+    # box): compiled Mosaic cannot lower there — the XLA expression
+    return (resolve_quant_matmul_impl() == "pallas"
+            and pallas_compat.target_platform() == "tpu")
 
 
 def matmul(x: jax.Array, wt: Any, dtype) -> jax.Array:

@@ -106,16 +106,11 @@ def _dequant_matmul_2d(x, q, s, *, out_dtype, interpret=False):
         out_specs=pl.BlockSpec((m_pad, block_o), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m_pad, o), out_dtype),
         scratch_shapes=[pltpu.VMEM((m_pad, block_o), jnp.float32)],
-        compiler_params=_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, q, s.reshape(1, o))
     return out[:m]
-
-
-def _compiler_params(dimension_semantics):
-    from kubeflow_tpu.ops.pallas_compat import tpu_compiler_params
-
-    return tpu_compiler_params(dimension_semantics)
 
 
 def dequant_matmul(x: jax.Array, q: jax.Array, s: jax.Array,

@@ -44,9 +44,8 @@ AXIS = "stage"
 
 #: env override for the serving stage-pipeline schedule (ISSUE 20):
 #: "overlapped"/"1" or "sync"/"0". An EXPLICIT engine arg wins over the
-#: env; the env wins over the default ("sync" — jax 0.4.37 boxes carry
-#: pre-existing shard_map failures, so overlap is opt-in like
-#: KTPU_DECODE_ATTN was before its TPU default flipped).
+#: env; the env wins over the default ("sync": overlap stays opt-in until
+#: a chip run has timed it).
 SCHEDULE_ENV = "KTPU_STAGE_OVERLAP"
 
 
@@ -558,10 +557,8 @@ def collective_matmul(x_shard: jax.Array, w_shard: jax.Array, *,
     chunks through a closure); production use inside shard_map leaves
     them None and gets ppermute receive-from-next semantics.
     """
-    size = axis_size if axis_size is not None else jax.lax.psum(
-        jnp.ones((), jnp.int32), axis_name)
-    if axis_size is not None:
-        size = int(axis_size)
+    size = (int(axis_size) if axis_size is not None
+            else jax.lax.axis_size(axis_name))
     idx = axis_index if axis_index is not None else jax.lax.axis_index(
         axis_name)
     if shift is None:
@@ -582,33 +579,3 @@ def collective_matmul(x_shard: jax.Array, w_shard: jax.Array, *,
         if j != size - 1:
             cur = shift(cur)
     return out
-
-
-_SHARD_MAP_OK: bool | None = None
-
-
-def shard_map_overlap_supported() -> bool:
-    """Cached runtime probe: can this jax build run a trivial
-    shard_map + ppermute? jax 0.4.37 on some hosts fails inside
-    shard_map tracing (pre-existing, tracked in ROADMAP), so every
-    collective-matmul path/test that actually engages shard_map gates on
-    this instead of crashing the suite."""
-    global _SHARD_MAP_OK
-    if _SHARD_MAP_OK is not None:
-        return _SHARD_MAP_OK
-    try:
-        from jax.experimental.shard_map import shard_map
-
-        devs = jax.devices()[:1]
-        mesh = Mesh(devs, ("probe",))
-
-        def body(x):
-            return jax.lax.ppermute(x, "probe", [(0, 0)])
-
-        fn = shard_map(body, mesh=mesh, in_specs=P("probe"),
-                       out_specs=P("probe"))
-        jax.jit(fn)(jnp.zeros((len(devs), 2), jnp.float32))
-        _SHARD_MAP_OK = True
-    except Exception:
-        _SHARD_MAP_OK = False
-    return _SHARD_MAP_OK

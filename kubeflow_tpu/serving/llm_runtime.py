@@ -65,8 +65,6 @@ class LLMModel(Model):
                  logprobs_topk: int = 0,
                  sample_k_max: int = 64,
                  pipeline_decode: bool = True,
-                 compile_cache: str | None = None,
-                 compile_cache_min_secs: float | None = None,
                  supervised: bool = True,
                  supervisor: dict[str, Any] | None = None,
                  sse_keepalive_s: float = 15.0,
@@ -135,13 +133,6 @@ class LLMModel(Model):
         self._logprobs_topk = logprobs_topk
         self._sample_k_max = sample_k_max
         self._pipeline_decode = pipeline_decode
-        # config.compile_cache: persistent XLA compilation cache dir (the
-        # Knative cold-start lever beyond in-process warmup): a restarted
-        # predictor reloads its whole program menu from disk instead of
-        # recompiling — at 8B dims that is ~37-90s of warmup down to
-        # seconds on a warm cache
-        self._compile_cache = compile_cache
-        self._compile_cache_min_secs = compile_cache_min_secs
         # config.supervised (default ON — the unified-dataplane contract):
         # the engine sits behind serving/agent.EngineSupervisor, so every
         # HTTP/gRPC/predict submission is journaled and a mid-stream
@@ -269,28 +260,14 @@ class LLMModel(Model):
 
     def load(self) -> None:
         from kubeflow_tpu.models import llama
+        from kubeflow_tpu.runtime.compile_cache import ensure_compile_cache
         from kubeflow_tpu.serving.llm import LLMEngine
 
-        if self._compile_cache:
-            import jax
-
-            # keyed by HLO + compile flags, so correctness is unaffected;
-            # process-global (jax has one cache), which is the right scope
-            # for a predictor pod. reset_cache(): jax binds the cache
-            # instance lazily to the dir at first use — a dir configured
-            # after that would silently never be written
-            jax.config.update("jax_compilation_cache_dir",
-                              self._compile_cache)
-            if self._compile_cache_min_secs is not None:
-                # optional threshold override; left alone by default so an
-                # operator's env/flag policy survives this predictor
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs",
-                    self._compile_cache_min_secs)
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-
-            _cc.reset_cache()
+        # the cold-start lever beyond in-process warmup: a restarted
+        # predictor reloads its program menu from the persistent compile
+        # cache instead of recompiling (placed from outside — see
+        # runtime/compile_cache.py)
+        ensure_compile_cache()
         mesh = None
         if self._mesh:
             # tensor-parallel predictor: config.mesh {tensor: N, ...}

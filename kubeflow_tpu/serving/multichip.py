@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from kubeflow_tpu.kvcache import RadixKVCache, StagePartitionedKVCache
 from kubeflow_tpu.models import llama
 from kubeflow_tpu.obs.trace import TRACER
+from kubeflow_tpu.parallel.mesh import active_mesh
 from kubeflow_tpu.parallel.pipeline import (InferenceStagePlan, StageClock,
                                             resolve_schedule,
                                             split_stage_params, wavefront)
@@ -214,6 +215,22 @@ class StageShardedEngine(LLMEngine):
 
     # -- per-stage compiled programs ------------------------------------------
 
+    def _stage_jit(self, s: int, run, **jit_kw):
+        """jit one stage program; its body traces with the stage's
+        sub-mesh ambient (see llm._under_engine_mesh), so a
+        tensor-sharded stage keeps the int8 matmul partitionable while a
+        one-device stage takes the kernels like the single-program
+        engine."""
+        submesh = self._plan.submeshes[s]
+        if submesh is None:
+            return jax.jit(run, **jit_kw)
+
+        def traced(*args):
+            with active_mesh(submesh):
+                return run(*args)
+
+        return jax.jit(traced, **jit_kw)
+
     def _stage_prefill_prog(self, s: int, bucket: int, width: int):
         key = ("prefill", s, bucket, width)
         if key not in self._stage_progs:
@@ -233,18 +250,16 @@ class StageShardedEngine(LLMEngine):
                     cache_slab = self._cache_write(
                         cache_slab, slots[i], 0, bucket, ks[:, i], vs[:, i])
                 if last:
-                    logits = llama.lm_head(slab, x, self.cfg)
-                    lasts = [jax.lax.dynamic_index_in_dim(
-                        logits[i], prompt_lens[i] - 1, keepdims=False)
-                        for i in range(width)]
-                    return cache_slab, jnp.stack(lasts)
+                    return cache_slab, llama.lm_head(
+                        slab, x, self.cfg, prompt_lens - 1)
                 return cache_slab, x
 
             if first:
-                fn = jax.jit(lambda slab, c, wave: run(slab, c, wave, None),
-                             donate_argnums=(1,))
+                fn = self._stage_jit(
+                    s, lambda slab, c, wave: run(slab, c, wave, None),
+                    donate_argnums=(1,))
             else:
-                fn = jax.jit(run, donate_argnums=(1,))
+                fn = self._stage_jit(s, run, donate_argnums=(1,))
             self._stage_progs[key] = fn
         return self._stage_progs[key]
 
@@ -271,19 +286,17 @@ class StageShardedEngine(LLMEngine):
                     cache_slab = self._cache_write(
                         cache_slab, slots[i], p, t, ks[:, i], vs[:, i])
                 if last:
-                    logits = llama.lm_head(slab, x, self.cfg)
-                    lasts = [jax.lax.dynamic_index_in_dim(
-                        logits[i], prompt_lens[i] - p - 1, keepdims=False)
-                        for i in range(width)]
-                    return cache_slab, jnp.stack(lasts)
+                    return cache_slab, llama.lm_head(
+                        slab, x, self.cfg, prompt_lens - p - 1)
                 return cache_slab, x
 
             if first:
-                fn = jax.jit(lambda slab, c, wave, kp, vp:
-                             run(slab, c, wave, kp, vp, None),
-                             donate_argnums=(1,))
+                fn = self._stage_jit(
+                    s, lambda slab, c, wave, kp, vp:
+                    run(slab, c, wave, kp, vp, None),
+                    donate_argnums=(1,))
             else:
-                fn = jax.jit(run, donate_argnums=(1,))
+                fn = self._stage_jit(s, run, donate_argnums=(1,))
             self._stage_progs[key] = fn
         return self._stage_progs[key]
 
@@ -315,7 +328,8 @@ class StageShardedEngine(LLMEngine):
                                                     self.cfg)[:, 0]
                 return new_cache, x
 
-            self._stage_progs[key] = jax.jit(run, donate_argnums=(1,))
+            self._stage_progs[key] = self._stage_jit(
+                s, run, donate_argnums=(1,))
         return self._stage_progs[key]
 
     def _tail_prefill_prog(self, cols: int, width: int):

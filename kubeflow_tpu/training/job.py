@@ -65,8 +65,10 @@ def config_from_env(env: dict[str, str]) -> tuple[TrainerConfig, int]:
 def train_target(env: dict[str, str], cancel: threading.Event) -> None:
     """Train a registered model from env-provided config (see module doc)."""
     from kubeflow_tpu.hpo.observations import report_metric
+    from kubeflow_tpu.runtime.compile_cache import ensure_compile_cache
     from kubeflow_tpu.training import data as data_lib
 
+    ensure_compile_cache()
     cfg, num_steps = config_from_env(env)
     metrics = MetricsWriter(env.get("KTPU_METRICS_FILE"))
     trial = env.get("KTPU_TRIAL_NAME")

@@ -11,24 +11,27 @@ from __future__ import annotations
 
 import jax
 
-# bf16 peak FLOP/s per chip. (v5e's oft-quoted 394 TOPS is int8; bf16 is 197.)
+# bf16 peak FLOP/s per chip, keyed by the exact `device_kind` JAX reports.
+# (v5e's oft-quoted 394 TOPS is int8; bf16 is 197.)
 PEAK_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,  # v5e
-    "TPU v5e": 197e12,
     "TPU v5p": 459e12,
     "TPU v6 lite": 918e12,  # trillium
-    "cpu": 1e11,  # nominal, so CPU tests produce finite MFU
 }
 
 
 def device_peak_flops(device: jax.Device | None = None) -> float:
+    """Peak of the device a utilization is reported against. A device
+    that is not in the table is an error: a utilization over a made-up
+    peak (a CPU's, or a near-miss of the name) is not a measurement."""
     dev = device if device is not None else jax.devices()[0]
-    kind = getattr(dev, "device_kind", "cpu")
-    for name, peak in PEAK_FLOPS.items():
-        if name.lower() in str(kind).lower():
-            return peak
-    return PEAK_FLOPS["cpu"]
+    try:
+        return PEAK_FLOPS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s known for device_kind {dev.device_kind!r} "
+            f"(known: {sorted(PEAK_FLOPS)})") from None
 
 
 def compiled_flops(compiled) -> float | None:

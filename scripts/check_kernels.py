@@ -17,6 +17,10 @@ module under `kubeflow_tpu/ops/` that calls `pallas_call`:
    convention every kernel here follows).
 3. The module is referenced by name from at least one `tests/test_*.py`
    — a kernel no parity test imports is, by construction, untested.
+4. `tests/test_kernels_lower_tpu.py` declares, in `PALLAS_CALL_SITES`,
+   exactly as many call sites for the module as its source has — the
+   interpreter accepts block shapes Mosaic refuses, so every call site
+   is also lowered for TPU (compiled, from the CPU fast lane) there.
 
 Run: `python scripts/check_kernels.py` — exit 0 clean, 1 with findings
 (one per line). The fast lane runs it via tests/test_dataplane_lint.py.
@@ -31,6 +35,7 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OPS = os.path.join(REPO, "kubeflow_tpu", "ops")
 TESTS = os.path.join(REPO, "tests")
+LOWERING_TEST = "test_kernels_lower_tpu.py"
 
 
 class _PallasCallVisitor(ast.NodeVisitor):
@@ -52,22 +57,40 @@ class _PallasCallVisitor(ast.NodeVisitor):
 
 
 def _test_references(tests_root: str) -> str:
-    """Concatenated source of every tests/test_*.py (module-name
-    reference check is textual: any import or attribute spelling
-    counts)."""
+    """Concatenated source of every tests/test_*.py but the TPU-lowering
+    test, which proves no numbers (module-name reference check is
+    textual: any import or attribute spelling counts)."""
     chunks = []
     if os.path.isdir(tests_root):
         for fn in sorted(os.listdir(tests_root)):
-            if fn.startswith("test_") and fn.endswith(".py"):
+            if (fn.startswith("test_") and fn.endswith(".py")
+                    and fn != LOWERING_TEST):
                 with open(os.path.join(tests_root, fn),
                           encoding="utf-8") as f:
                     chunks.append(f.read())
     return "\n".join(chunks)
 
 
+def _lowered_call_sites(tests_root: str) -> dict[str, int]:
+    """The PALLAS_CALL_SITES literal of the TPU-lowering test ({} when
+    the file or the table is missing — every kernel then has a finding)."""
+    path = os.path.join(tests_root, LOWERING_TEST)
+    if not os.path.exists(path):
+        return {}
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read(), filename=path)
+    for node in tree.body:
+        if (isinstance(node, ast.Assign) and len(node.targets) == 1
+                and isinstance(node.targets[0], ast.Name)
+                and node.targets[0].id == "PALLAS_CALL_SITES"):
+            return dict(ast.literal_eval(node.value))
+    return {}
+
+
 def check(ops_root: str = OPS, tests_root: str = TESTS) -> list[str]:
     findings: list[str] = []
     test_src = _test_references(tests_root)
+    lowered = _lowered_call_sites(tests_root)
     for fn in sorted(os.listdir(ops_root)):
         if not fn.endswith(".py"):
             continue
@@ -103,6 +126,12 @@ def check(ops_root: str = OPS, tests_root: str = TESTS) -> list[str]:
                 f"{rel}: kernel module not referenced by any "
                 "tests/test_*.py — land it WITH its interpret-mode "
                 "parity test")
+        if v.calls and lowered.get(module) != len(v.calls):
+            findings.append(
+                f"{rel}: {len(v.calls)} pallas_call site(s) but "
+                f"tests/{LOWERING_TEST} PALLAS_CALL_SITES declares "
+                f"{lowered.get(module, 0)} — lower every call site for "
+                "TPU there (interpret=False, lowering_platforms=('tpu',))")
     return findings
 
 

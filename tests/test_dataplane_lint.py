@@ -11,6 +11,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -247,7 +249,8 @@ def test_lint_allows_pool_buffer_construction_inside_kvcache(tmp_path):
 # -- kernel-path lint (ISSUE 15 satellite: scripts/check_kernels.py) ----------
 # An untestable-on-CPU Pallas kernel must never land: every ops module
 # calling pallas_call must pass interpret= at each call site, expose the
-# FORCE_INTERPRET seam, and be referenced from a parity test.
+# FORCE_INTERPRET seam, be referenced from a parity test, and have each
+# call site lowered for TPU in tests/test_kernels_lower_tpu.py.
 
 
 def _load_kernel_lint():
@@ -274,13 +277,15 @@ def test_kernel_lint_runs_as_a_script():
     assert "check_kernels: ok" in out.stdout
 
 
-def _kernel_tree(tmp_path, src, test_src=""):
+def _kernel_tree(tmp_path, src, test_src="",
+                 lowering_src="PALLAS_CALL_SITES = {'rogue_kernel': 1}\n"):
     ops = tmp_path / "kubeflow_tpu" / "ops"
     ops.mkdir(parents=True)
     (ops / "rogue_kernel.py").write_text(src)
     tests = tmp_path / "tests"
     tests.mkdir()
     (tests / "test_rogue.py").write_text(test_src)
+    (tests / "test_kernels_lower_tpu.py").write_text(lowering_src)
     return str(ops), str(tests)
 
 
@@ -326,6 +331,28 @@ def test_kernel_lint_flags_untested_kernel_module(tmp_path):
     findings = lint.check(ops_root=ops, tests_root=tests)
     assert len(findings) == 1
     assert "not referenced" in findings[0]
+
+
+@pytest.mark.parametrize("lowering_src", [
+    "",                                              # no table at all
+    "PALLAS_CALL_SITES = {'other_kernel': 1}\n",     # module missing
+    "PALLAS_CALL_SITES = {'rogue_kernel': 2}\n",     # stale count
+])
+def test_kernel_lint_flags_call_site_not_lowered_for_tpu(tmp_path,
+                                                        lowering_src):
+    lint = _load_kernel_lint()
+    ops, tests = _kernel_tree(
+        tmp_path,
+        "from jax.experimental import pallas as pl\n"
+        "FORCE_INTERPRET = False\n"
+        "def op(x, interpret=False):\n"
+        "    return pl.pallas_call(lambda i, o: None, out_shape=x,\n"
+        "                          interpret=interpret)(x)\n",
+        "from kubeflow_tpu.ops import rogue_kernel\n",
+        lowering_src=lowering_src)
+    findings = lint.check(ops_root=ops, tests_root=tests)
+    assert len(findings) == 1
+    assert "PALLAS_CALL_SITES" in findings[0]
 
 
 def test_kernel_lint_ignores_pallas_free_modules(tmp_path):

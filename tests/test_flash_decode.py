@@ -135,21 +135,40 @@ def test_rows_mask_independent_slots():
 
 
 def test_selection_policy(monkeypatch):
+    from kubeflow_tpu.ops import pallas_compat
+
+    toy = dict(head_dim=8, n_kv_heads=4)
     monkeypatch.delenv(flash_decode.IMPL_ENV, raising=False)
     # auto on this CPU box resolves xla
-    assert flash_decode.resolve_impl("auto") == "xla"
+    assert flash_decode.resolve_impl("auto", **toy) == "xla"
     # env overrides the platform default...
     monkeypatch.setenv(flash_decode.IMPL_ENV, "flash")
-    assert flash_decode.resolve_impl("auto") == "flash"
+    assert flash_decode.resolve_impl("auto", **toy) == "flash"
     # ...but an explicit config wins over the env (bench A/B pins impls)
-    assert flash_decode.resolve_impl("xla") == "xla"
-    assert flash_decode.resolve_impl("flash") == "flash"
+    assert flash_decode.resolve_impl("xla", **toy) == "xla"
+    assert flash_decode.resolve_impl("flash", **toy) == "flash"
     monkeypatch.setenv(flash_decode.IMPL_ENV, "xla")
-    assert flash_decode.resolve_impl("flash") == "flash"
+    assert flash_decode.resolve_impl("flash", **toy) == "flash"
     with pytest.raises(ValueError):
         llama.LlamaConfig.tiny().__class__(
             **{**dataclasses.asdict(llama.LlamaConfig.tiny()),
                "decode_attention_impl": "mosaic"})
+    # on a TPU target the policy follows what Mosaic can tile: auto
+    # takes the kernel at head_dim 128 only, and an explicit flash at a
+    # head_dim the compiler refuses raises with the reason
+    monkeypatch.delenv(flash_decode.IMPL_ENV)
+    monkeypatch.setattr(pallas_compat, "target_platform", lambda: "tpu")
+    assert flash_decode.resolve_impl(
+        "auto", head_dim=128, n_kv_heads=8) == "flash"
+    assert flash_decode.resolve_impl(
+        "auto", head_dim=64, n_kv_heads=8) == "xla"
+    assert flash_decode.resolve_impl(
+        "auto", head_dim=64, n_kv_heads=1) == "flash"   # lane dim == hd
+    with pytest.raises(ValueError, match="head_dim 64"):
+        flash_decode.resolve_impl("flash", head_dim=64, n_kv_heads=8)
+    monkeypatch.setenv(flash_decode.IMPL_ENV, "flash")
+    with pytest.raises(ValueError, match="head_dim 32"):
+        flash_decode.resolve_impl("auto", head_dim=32, n_kv_heads=4)
 
 
 def test_quant_matmul_selection_policy(monkeypatch):

@@ -180,15 +180,16 @@ def test_q_offset_must_be_static_and_nonnegative():
 # -- selection policy ---------------------------------------------------------
 
 def test_resolve_impl_policy(monkeypatch):
+    toy = dict(head_dim=8, n_kv_heads=4)
     monkeypatch.delenv(flash_prefill.IMPL_ENV, raising=False)
-    assert flash_prefill.resolve_impl("xla") == "xla"
-    assert flash_prefill.resolve_impl("flash") == "flash"
-    assert flash_prefill.resolve_impl("auto") == "xla"   # CPU default
+    assert flash_prefill.resolve_impl("xla", **toy) == "xla"
+    assert flash_prefill.resolve_impl("flash", **toy) == "flash"
+    assert flash_prefill.resolve_impl("auto", **toy) == "xla"  # CPU default
     monkeypatch.setenv(flash_prefill.IMPL_ENV, "flash")
-    assert flash_prefill.resolve_impl("auto") == "flash"
-    assert flash_prefill.resolve_impl("xla") == "xla"    # explicit wins
+    assert flash_prefill.resolve_impl("auto", **toy) == "flash"
+    assert flash_prefill.resolve_impl("xla", **toy) == "xla"  # explicit wins
     monkeypatch.setenv(flash_prefill.IMPL_ENV, "xla")
-    assert flash_prefill.resolve_impl("auto") == "xla"
+    assert flash_prefill.resolve_impl("auto", **toy) == "xla"
 
 
 def test_config_validates_impl():

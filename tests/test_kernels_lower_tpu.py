@@ -1,0 +1,160 @@
+"""Every Pallas entry point lowers for TPU — compiled (interpret=False),
+at the shapes chip_smoke.py serves and trains at — from this CPU process.
+
+Interpret-mode parity (test_flash_decode, test_flash_prefill,
+test_quant_matmul, test_attention) says a kernel computes the right
+numbers; it says nothing about whether Mosaic accepts its block shapes.
+r14/r17/r20 shipped int8-KV kernels that were byte-exact in the
+interpreter and that the TPU lowering refused ("the last two dimensions of
+your block shape are divisible by 8 and 128 … or equal to the respective
+dimensions of the overall array"). That check lives in JAX's own
+Pallas→Mosaic lowering, so `lowering_platforms=("tpu",)` raises it here in
+milliseconds, with no libtpu and no chip. What only the Mosaic compiler
+itself can refuse is covered by the slow-lane topology compiles in
+test_contract_serving.py.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from kubeflow_tpu.models import llama
+from kubeflow_tpu.ops import (flash_decode, flash_pallas, flash_prefill,
+                              pallas_compat, quant_matmul)
+
+#: pallas_call sites per ops module that this file lowers for TPU.
+#: scripts/check_kernels.py requires the counts to match the source, so a
+#: new kernel (or a new call site) cannot land without a case here.
+PALLAS_CALL_SITES = {
+    "flash_decode": 1,
+    "flash_prefill": 1,
+    "quant_matmul": 1,
+    "flash_pallas": 3,
+}
+
+# chip_smoke.py's serving shapes: Llama-3-8B heads, 16 slots x 2048
+SLOTS, SPAN, HEADS, KV_HEADS, HEAD_DIM = 16, 2048, 32, 8, 128
+BLOCK_TOKENS = 128   # paged pool block = gcd of the 128/512/1024 buckets
+
+
+def sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def mosaic_calls(fn, *args) -> int:
+    """Lower fn for TPU from abstract args; count its Mosaic custom calls."""
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    return lowered.as_text().count("tpu_custom_call")
+
+
+def kv_operands(batch, tokens, quantized, paged):
+    """(k, scale, tables) abstract operands in the slab or pool layout."""
+    dtype = jnp.int8 if quantized else jnp.bfloat16
+    if paged:
+        n_pool = batch * tokens // BLOCK_TOKENS + 1
+        k = sds((n_pool, BLOCK_TOKENS, KV_HEADS, HEAD_DIM), dtype)
+        scale = sds((n_pool, BLOCK_TOKENS, KV_HEADS), jnp.float32)
+        tables = sds((batch, tokens // BLOCK_TOKENS), jnp.int32)
+    else:
+        k = sds((batch, tokens, KV_HEADS, HEAD_DIM), dtype)
+        scale = sds((batch, tokens, KV_HEADS), jnp.float32)
+        tables = None
+    return k, (scale if quantized else None), tables
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("s_v", [1, 4, 7])   # decode; verify k=3; k=6
+def test_flash_decode_lowers(s_v, quantized, paged):
+    k, scale, tables = kv_operands(SLOTS, SPAN, quantized, paged)
+
+    def fn(q, k, v, lengths, ks, vs, tables):
+        return flash_decode.flash_decode_attention(
+            q, k, v, lengths, k_scale=ks, v_scale=vs, tables=tables,
+            interpret=False)
+
+    assert mosaic_calls(
+        fn, sds((SLOTS, s_v, HEADS, HEAD_DIM), jnp.bfloat16), k, k,
+        sds((SLOTS,), jnp.int32), scale, scale, tables) == 1
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("q_offset", [0, 1024])
+def test_flash_prefill_lowers(q_offset, quantized, paged):
+    width, chunk = 4, 512
+    k, scale, tables = kv_operands(width, q_offset + chunk, quantized,
+                                   paged)
+
+    def fn(q, k, v, ks, vs, tables):
+        return flash_prefill.flash_prefill_attention(
+            q, k, v, q_offset=q_offset, k_scale=ks, v_scale=vs,
+            tables=tables, interpret=False)
+
+    assert mosaic_calls(
+        fn, sds((width, chunk, HEADS, HEAD_DIM), jnp.bfloat16), k, k,
+        scale, scale, tables) == 1
+
+
+@pytest.mark.parametrize("rows,d_in,d_out", [
+    (16, 4096, 4096),       # wq / wo, one decode step of 16 slots
+    (112, 4096, 1024),      # wk / wv, a k=6 verify round (16 x 7 rows)
+    (16, 4096, 14336),      # w_gate / w_up
+    (16, 14336, 4096),      # w_down
+    (112, 4096, 128256),    # lm_head at the Llama-3 vocabulary
+])
+def test_quant_matmul_lowers(rows, d_in, d_out):
+    assert quant_matmul.kernel_applicable(rows, d_in, d_out)
+    assert mosaic_calls(
+        lambda x, q, s: quant_matmul._dequant_matmul_2d(
+            x, q, s, out_dtype=jnp.dtype(jnp.bfloat16), interpret=False),
+        sds((rows, d_in), jnp.bfloat16), sds((d_in, d_out), jnp.int8),
+        sds((d_out,), jnp.float32)) == 1
+
+
+def test_flash_pallas_forward_and_backward_lower():
+    # chip_smoke.py's train proxy: batch 6, seq 2048, 16 heads of 128
+    q = sds((6, 2048, 16, 128), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_pallas.pallas_flash_attention(q, k, v, causal=True,
+                                                   interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    assert mosaic_calls(fwd, q, q, q) == 1
+    # value_and_grad: the forward kernel, then the dq and the dk/dv kernels
+    assert mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 3
+
+
+def test_unsupported_head_dim_is_refused_at_engine_construction(
+        monkeypatch):
+    """head_dim 32 (examples/llm-inference-service.yaml) cannot be tiled
+    by the serving flash kernels. On a TPU target `auto` must resolve to
+    xla at construction — visibly, in what metrics()/healthz report — and
+    an explicit `flash` must raise there with the reason, not die in the
+    compiler at the first prefill."""
+    from kubeflow_tpu.serving.llm import LLMEngine
+
+    monkeypatch.setattr(pallas_compat, "target_platform", lambda: "tpu")
+    cfg = llama.LlamaConfig(vocab_size=128, d_model=128, n_layers=1,
+                            n_heads=4, n_kv_heads=2, d_ff=128,
+                            max_seq_len=64, remat=False)
+    assert cfg.head_dim == 32
+    params = llama.init(jax.random.key(0), cfg)
+    kw = dict(n_slots=2, max_len=32, buckets=(8,))
+    with pytest.raises(ValueError, match="head_dim 32"):
+        LLMEngine(params, cfg, decode_attention_impl="flash", **kw)
+    with pytest.raises(ValueError, match="head_dim 32"):
+        LLMEngine(params, cfg, prefill_attention_impl="flash", **kw)
+    eng = LLMEngine(params, cfg, **kw)
+    assert eng.cfg.decode_attention_impl == "xla"
+    assert eng.cfg.prefill_attention_impl == "xla"
+    # and at a head_dim the kernels tile, auto takes them
+    wide = llama.LlamaConfig(vocab_size=128, d_model=256, n_layers=1,
+                             n_heads=2, n_kv_heads=2, d_ff=128,
+                             max_seq_len=64, remat=False)
+    eng = LLMEngine(llama.init(jax.random.key(0), wide), wide, **kw)
+    assert eng.cfg.decode_attention_impl == "flash"
+    assert eng.cfg.prefill_attention_impl == "flash"

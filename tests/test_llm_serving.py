@@ -781,45 +781,6 @@ def test_chunked_prefill_hits_prefix_store(tiny):
     assert engine.metrics()["prefix_hits"] > hits0
 
 
-def test_compile_cache_config_cold_start_lever(tiny, tmp_path):
-    """config.compile_cache points jax's persistent compilation cache at
-    a predictor-owned dir: the program menu lands there at first load, so
-    a restarted pod warms from disk instead of recompiling."""
-    import jax
-
-    from kubeflow_tpu.serving.llm_runtime import LLMModel
-
-    _, cfg = tiny
-    prev = jax.config.jax_compilation_cache_dir
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    cache_dir = str(tmp_path / "compile-cache")
-    m = LLMModel("llm-cc", model={k: getattr(cfg, k) for k in
-                                  ("vocab_size", "d_model", "n_layers",
-                                   "n_heads", "n_kv_heads", "d_ff",
-                                   "max_seq_len", "attention_impl",
-                                   "remat")},
-                 n_slots=1, max_len=32, buckets=(8,), seed=0,
-                 compile_cache=cache_dir,
-                 compile_cache_min_secs=0.0)   # timing-independent assert
-    try:
-        m.load()
-        out = m.predict({"prompt_tokens": [1, 2, 3], "max_new_tokens": 2})
-        assert len(out["output_tokens"]) == 2
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-        import os
-
-        assert os.path.isdir(cache_dir) and os.listdir(cache_dir)
-    finally:
-        m.unload()
-        jax.config.update("jax_compilation_cache_dir", prev)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          prev_min)
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-
-        _cc.reset_cache()   # rebind to the restored dir for later tests
-
-
 @pytest.mark.slow
 def test_usage_cached_tokens_and_healthz_cache_section(tiny):
     """kvcache counters end-to-end over HTTP: the OpenAI usage object
