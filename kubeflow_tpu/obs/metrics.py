@@ -55,10 +55,6 @@ TPOT_SECONDS = REGISTRY.histogram(
 QUEUE_WAIT_SECONDS = REGISTRY.histogram(
     "serving_queue_wait_seconds", "Submit to prefill dispatch",
     ["component"], buckets=QUEUE_WAIT_BUCKETS)
-PHASE_SECONDS = REGISTRY.histogram(
-    "serving_phase_seconds",
-    "Per-request phase walls (prefill/handoff/decode)",
-    ["component", "phase"], buckets=QUEUE_WAIT_BUCKETS)
 INFLIGHT = REGISTRY.gauge(
     "serving_inflight", "Live requests per component", ["component"])
 
@@ -129,6 +125,20 @@ SCHED_ACTIVE = REGISTRY.gauge(
 SCHED_SHED = REGISTRY.counter(
     "scheduler_shed_total", "Requests shed by degraded-mode policy",
     ["engine"])
+
+# -- engine thread phases (scrape-hook fed from the engine's PhaseClock) ------
+ENGINE_PHASE_SECONDS = REGISTRY.counter(
+    "serving_engine_phase_seconds_total",
+    "Engine-thread wall per phase (obs.trace.PHASES partitions the "
+    "thread's timeline)", ["engine", "phase"])
+ENGINE_DEVICE_EMPTY_SECONDS = REGISTRY.counter(
+    "serving_engine_device_empty_seconds_total",
+    "Wall with nothing dispatched and unfetched: a floor under the "
+    "device's idle time", ["engine"])
+ENGINE_STALLS = REGISTRY.counter(
+    "serving_engine_stalls_total",
+    "Single non-idle phase occurrences of 500 ms or more",
+    ["engine", "phase"])
 
 # -- SLO burn (scrape-hook fed from SloBurnTracker) ---------------------------
 SLO_ATTAINMENT = REGISTRY.gauge(

@@ -280,7 +280,8 @@ class _Journaled:
     finish_reason: str | None = None
     chain: list[str] = dataclasses.field(default_factory=list)
     verify_prefix: list[int] | None = None
-    #: phase split (queue_wait_ms / prefill_ms / decode_ms) captured from
+    #: phase split (queue_wait_ms / prefill_ms / decode_ms, and `engine`:
+    #: what the engine thread did over the decode window) captured from
     #: the engine at completion — durations survive the engine's death,
     #: so request_timing() keeps reporting them after release/restart
     #: (for a replayed request they describe the LAST engine generation)
@@ -447,7 +448,14 @@ class EngineSupervisor:
             return True
         now = time.monotonic()   # step() may have sat in the compiler
         before = self._last_progress
+        # copying tokens into the journal is the engine thread's time
+        # too: the same phase as the engine's own token-recording loops
+        clock = self.phase_clock
+        if clock is not None:
+            clock.enter("replay")
         self._poll_outcomes(now)
+        if clock is not None:
+            clock.leave()
         self._no_progress_steps = (0 if self._last_progress > before
                                    else self._no_progress_steps + 1)
         if self._watchdog(now, None):
@@ -646,7 +654,7 @@ class EngineSupervisor:
                         tm = self.engine.request_timing(e.engine_rid)
                         e.phases = {k: tm.get(k) for k in
                                     ("queue_wait_ms", "prefill_ms",
-                                     "decode_ms")}
+                                     "decode_ms", "engine")}
                         e.cached = int(tm.get("cached_prefix_len") or 0)
                     except Exception:
                         pass   # phase detail is best-effort accounting
@@ -804,7 +812,8 @@ class EngineSupervisor:
                     "prefill_tokens": len(e.prompt) - cached,
                     "queue_wait_ms": phases.get("queue_wait_ms"),
                     "prefill_ms": phases.get("prefill_ms"),
-                    "decode_ms": phases.get("decode_ms")}
+                    "decode_ms": phases.get("decode_ms"),
+                    "engine": phases.get("engine")}
 
     def cached_tokens(self, rid: int) -> int:
         """Prefix-KV tokens the CURRENT engine reused for this request.
@@ -831,6 +840,12 @@ class EngineSupervisor:
             self._journal.pop(rid, None)
 
     # -- engine passthroughs --------------------------------------------------
+
+    @property
+    def phase_clock(self):
+        """The live engine's phase clock (None while it is down): the
+        driving loop enters `sched` / `idle` on it between steps."""
+        return getattr(self.engine, "phase_clock", None)
 
     @property
     def _adapter_idx(self):

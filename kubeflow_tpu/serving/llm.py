@@ -34,7 +34,7 @@ import numpy as np
 from kubeflow_tpu.kvcache import RadixKVCache
 from kubeflow_tpu.models import llama
 from kubeflow_tpu.obs import metrics as obs_metrics
-from kubeflow_tpu.obs.trace import TRACER, StepAggregator
+from kubeflow_tpu.obs.trace import TRACER, PhaseClock, StepAggregator
 from kubeflow_tpu.parallel.mesh import active_mesh
 from kubeflow_tpu.serving.scheduler import (DecodeAction, PrefillAction,
                                             PromptTooLong, make_scheduler)
@@ -173,6 +173,17 @@ def _under_engine_mesh(program):
         with active_mesh(self.mesh):
             return program(self, *args, **kw)
     return traced
+
+
+def named_program(name: str, fn, **static):
+    """`fn` with `static` bound, under a function name: jax calls the
+    compiled module `jit_<name>` — one name per KIND of engine program,
+    whatever its chunk, span or bucket, so a device trace's modules and
+    idle-gap labels are few (a bare functools.partial has no name and
+    compiles as `jit__unknown`)."""
+    prog = functools.partial(fn, **static)
+    prog.__name__ = prog.__qualname__ = name
+    return prog
 
 
 def pin_attention_impls(cfg: llama.LlamaConfig, *,
@@ -393,13 +404,10 @@ class LLMEngine:
         self.pipeline_decode = pipeline_decode
         self._pending: tuple | None = None
         self._inflight = np.zeros((n_slots,), np.int64)
-        # -- decode-step attribution counters (training/profiling.py's
-        # serving_decode_breakdown reads these): wall time the HOST spends
-        # dispatching decode programs vs fetching+replaying their outputs.
-        # Two perf_counter() calls per chunk — noise next to a dispatch.
-        self._perf = {"dispatch_s": 0.0, "fetch_replay_s": 0.0,
-                      "decode_chunks": 0, "decode_steps": 0,
-                      "active_uploads": 0}
+        # active-mask re-uploads (perf_counters() reports them beside
+        # the phase clock's decode buckets)
+        self._active_uploads = 0
+        self._perf_base: dict[str, Any] = {}
         # device-resident copy of the decode active mask: the mask only
         # changes at prefill/finish boundaries, so re-uploading it every
         # chunk paid a host->device transfer per chunk for identical
@@ -441,16 +449,20 @@ class LLMEngine:
         # is the TTFT/TPOT record the loadgen runner reads via
         # request_timing() BEFORE release())
         self._finish_t: dict[int, float] = {}
-        # -- observability (ISSUE 17): optional per-request trace ids and
-        # the hot-loop step AGGREGATOR (per-dispatch counter bumps only —
-        # the one decode span a request gets is emitted retrospectively
-        # at finish from timestamps already kept; check_observability.py
-        # lints that no span objects are minted on the step/_do_decode
-        # paths). _decode_mark snapshots the aggregator at first token so
-        # the finish span can report the request's decode-step window.
+        # -- observability: optional per-request trace ids and the engine
+        # thread's PHASE CLOCK (obs.trace.PhaseClock: every instant of a
+        # driven step lies in one named phase; per-dispatch counter bumps
+        # only — the one decode span a request gets is emitted
+        # retrospectively at finish; check_observability.py lints that
+        # no span objects are minted on the step/_do_decode paths).
+        # _phase_mark reads the clock at a request's first token,
+        # _phase_fin holds (usage `engine` object, decode-step window)
+        # from its finish until release().
         self._req_trace: dict[int, str] = {}
-        self._decode_agg = StepAggregator()
-        self._decode_mark: dict[int, tuple[int, int]] = {}
+        self.phase_clock = PhaseClock(self.role, self._stall_context)
+        self._phase_mark: dict[int, Any] = {}
+        self._phase_fin: dict[int, tuple[dict[str, Any], dict[str, int]]] \
+            = {}
         # queue-depth gauges are pull-model: refreshed from the scheduler
         # at scrape time (weakref-held, so a dropped engine unregisters
         # itself)
@@ -1152,8 +1164,8 @@ class LLMEngine:
         k = self.spec if k is None else k
         if (steps, span, k) not in self._spec_fns:
             self._spec_fns[steps, span, k] = jax.jit(
-                functools.partial(self._spec_decode, steps=steps, span=span,
-                                  k_spec=k),
+                named_program("decode_spec", self._spec_decode,
+                              steps=steps, span=span, k_spec=k),
                 donate_argnums=(1, 2, 3, 4, 5))
         return self._spec_fns[steps, span, k]
 
@@ -1162,7 +1174,8 @@ class LLMEngine:
         powers of two so a burst of any size maps onto a tiny program menu."""
         if (bucket, width) not in self._prefill_fns:
             self._prefill_fns[bucket, width] = jax.jit(
-                self._prefill, donate_argnums=(1, 2, 3, 4, 5))
+                named_program("prefill", self._prefill),
+                donate_argnums=(1, 2, 3, 4, 5))
         return self._prefill_fns[bucket, width]
 
     def _cont_fn(self, p: int, t: int, width: int):
@@ -1172,19 +1185,21 @@ class LLMEngine:
         only within the dispatch)."""
         if (p, t, width) not in self._cont_fns:
             self._cont_fns[p, t, width] = jax.jit(
-                self._prefill_cont, donate_argnums=(1, 2, 3, 4, 5))
+                named_program("prefill_cont", self._prefill_cont),
+                donate_argnums=(1, 2, 3, 4, 5))
         return self._cont_fns[p, t, width]
 
     def _extract_fn(self, p: int):
         if p not in self._extract_fns:
             self._extract_fns[p] = jax.jit(
-                functools.partial(self._extract_prefix, p=p))
+                named_program("extract_prefix", self._extract_prefix, p=p))
         return self._extract_fns[p]
 
     def _extract_raw_fn(self, p: int):
         if p not in self._extract_raw_fns:
             self._extract_raw_fns[p] = jax.jit(
-                functools.partial(self._extract_prefix_raw, p=p))
+                named_program("extract_prefix_raw",
+                              self._extract_prefix_raw, p=p))
         return self._extract_raw_fns[p]
 
     def _tail_bucket(self, tail_len: int) -> int | None:
@@ -1274,7 +1289,7 @@ class LLMEngine:
         span = self.max_len if span is None else span
         if (steps, span) not in self._decode_fns:
             self._decode_fns[steps, span] = jax.jit(
-                functools.partial(self._decode, steps=steps, span=span),
+                named_program("decode", self._decode, steps=steps, span=span),
                 donate_argnums=(1, 2, 3, 4, 5))
         return self._decode_fns[steps, span]
 
@@ -1287,8 +1302,8 @@ class LLMEngine:
         stage-sharded engine can supply its pipelined twin."""
         span = self.max_len if span is None else span
         return jax.jit(
-            functools.partial(self._decode, steps=steps, span=span,
-                              sample=False),
+            named_program("decode_nosample", self._decode, steps=steps,
+                          span=span, sample=False),
             donate_argnums=(1, 2, 3, 4, 5))
 
     def _span_menu(self) -> list[int]:
@@ -1540,6 +1555,7 @@ class LLMEngine:
                 self.scheduler.cancel(rid)
                 self._finish_reasons[rid] = "cancelled"
                 self._finish_t[rid] = now
+                self._close_phases(rid)
                 self._done.add(rid)
                 self._cancelled_count += 1
                 self._prompts.pop(rid, None)
@@ -1563,7 +1579,18 @@ class LLMEngine:
 
         Chunk boundary = here: pending cancellations and expired deadlines
         are applied first, so a freed slot is refillable by this very
-        step's prefill wave."""
+        step's prefill wave.
+
+        The phase clock runs from here to the return: every helper below
+        enters the phase it is (obs.trace.PHASES)."""
+        clock = self.phase_clock
+        clock.enter("sched")
+        try:
+            return self._step()
+        finally:
+            clock.leave()
+
+    def _step(self) -> bool:
         self._apply_cancellations()
         with self._submit_lock:
             action = self.scheduler.next()
@@ -1579,6 +1606,7 @@ class LLMEngine:
         # frees slots/completes requests, and the device-side prefill that
         # follows overwrites any junk the chunk wrote into reused slots
         self._drain_pending()
+        self.phase_clock.enter("sched")
         actions = [action]
         while len(actions) < self.n_slots:
             with self._submit_lock:
@@ -1618,6 +1646,8 @@ class LLMEngine:
         # (tail-only compute); everything else groups by bucket, one
         # batched program per group. All dispatches go out before any
         # token fetch.
+        clock = self.phase_clock
+        clock.enter("prefill_pack")
         chunked: list[PrefillAction] = []
         short: list[PrefillAction] = []
         for a in actions:  # one-pass, identity-safe partition
@@ -1653,6 +1683,8 @@ class LLMEngine:
         # hit bookkeeping + unpin AFTER every dispatch went out: the
         # committed accounting records only reuse that actually rode a
         # continuation program
+        if self.prefix_cache_enabled:
+            clock.enter("prefix_bank")
         for a, m, p, t in cont:
             self._prefix_hits += 1
             self._cached_prefix[a.req_id] = p
@@ -1671,8 +1703,11 @@ class LLMEngine:
             for wave, _ in dispatched:
                 for a in wave:
                     self._bank_prefix_blocks(a)
-        for wave, out in dispatched:
+        for n, (wave, out) in enumerate(dispatched, 1):
+            clock.enter("prefill_fetch")
             out_np = np.asarray(out)   # one fetch per wave [W, out_cols]
+            clock.fetched(outstanding=n < len(dispatched))
+            clock.enter("replay")
             for i, a in enumerate(wave):
                 # true length, not action.prompt_len: a chunked request's
                 # scheduler-visible length was clamped to the largest bucket
@@ -1719,6 +1754,7 @@ class LLMEngine:
         chain's programs compile lazily on the first long prompt — a
         cold start the docstring of warmup() points at. Returns the
         next-token device array [1]."""
+        self.phase_clock.enter("prefill_pack")
         prompt = self._prompts[action.req_id]
         n = len(prompt)
         slot = action.slot
@@ -1749,33 +1785,37 @@ class LLMEngine:
                 self._prefix_misses += 1
             self.kvcache.release(m)
         self._prefill_computed_tokens += n - done
+        clock = self.phase_clock
         if done == 0:
-            packed = self._pack_rows(1, big,
-                                     [(prompt[:big], slot, big) + tail])
+            packed = self._put(self._pack_rows(
+                1, big, [(prompt[:big], slot, big) + tail]))
+            fn = self._prefill_fn(big, 1)
+            clock.enter("prefill_dispatch")
             (self.cache, self.lengths, self.last_tokens, self.samp,
-             self.rng_key, out) = self._prefill_fn(big, 1)(
+             self.rng_key, out) = fn(
                 self.params, self.cache, self.lengths, self.last_tokens,
-                self.samp, self.rng_key, self._put(packed),
-                *self._extra())
+                self.samp, self.rng_key, packed, *self._extra())
             done = big
         plan = self._chunk_plan_from(n, done) or []
         for chunk_len, t in plan:
-            ek, ev = (pending if pending is not None
-                      else self._extract_fn(done)(self.cache, slot))
-            pending = None
+            clock.enter("prefill_pack")
             # the chain boundary is a continuation with the request's OWN
             # prefix (p == done), so the row layout comes from the same
             # helper the cont waves use
             row_toks = self._cont_row_tokens(
                 list(prompt[:done + chunk_len]), done, t)
-            packed = self._pack_rows(1, t + (done if self.spec else 0),
-                                     [(row_toks, slot,
-                                       done + chunk_len) + tail])
+            packed = self._put(self._pack_rows(
+                1, t + (done if self.spec else 0),
+                [(row_toks, slot, done + chunk_len) + tail]))
+            fn = self._cont_fn(done, t, 1)
+            clock.enter("prefill_dispatch")
+            ek, ev = (pending if pending is not None
+                      else self._extract_fn(done)(self.cache, slot))
+            pending = None
             (self.cache, self.lengths, self.last_tokens, self.samp,
-             self.rng_key, out) = self._cont_fn(done, t, 1)(
+             self.rng_key, out) = fn(
                 self.params, self.cache, self.lengths, self.last_tokens,
-                self.samp, self.rng_key, self._put(packed), ek, ev,
-                *self._extra())
+                self.samp, self.rng_key, packed, ek, ev, *self._extra())
             done += chunk_len
         return out
 
@@ -1936,6 +1976,7 @@ class LLMEngine:
         just before a /metrics render (see obs.metrics.add_scrape_hook;
         exceptions are swallowed by the hook runner, so a closed engine
         can't poison a scrape)."""
+        self.phase_clock.publish()
         s = self.scheduler.stats()
         obs_metrics.SCHED_QUEUED.set(s.queued, engine=self.role)
         obs_metrics.SCHED_ACTIVE.set(s.active, engine=self.role)
@@ -2012,7 +2053,8 @@ class LLMEngine:
         self._req_plen.pop(req_id, None)
         self._prefill_start_t.pop(req_id, None)
         self._req_trace.pop(req_id, None)
-        self._decode_mark.pop(req_id, None)
+        self._phase_mark.pop(req_id, None)
+        self._phase_fin.pop(req_id, None)
 
     def generate(self, prompt: Sequence[int],
                  max_new_tokens: int = 32,
@@ -2042,14 +2084,18 @@ class LLMEngine:
         interference attribution): queue_wait_ms (submit → the prefill
         leaving the queue), prefill_ms (queue exit → first token) and
         decode_ms (first token → finish), each None until its phase
-        boundary lands. Read BEFORE release() — release drops all of
-        it."""
+        boundary lands; and `engine`, what the ENGINE THREAD did over the
+        decode_ms window (obs.trace.PhaseClock.usage: per phase [ms,
+        count], device_empty_ms, the longest single occurrence since
+        submit), None until finish. Read BEFORE release() — release
+        drops all of it."""
         plen = self._req_plen.get(req_id)
         cached = self._cached_prefix.get(req_id, 0)
         sub = self._submit_t.get(req_id)
         pstart = self._prefill_start_t.get(req_id)
         first = self._first_token_t.get(req_id)
         fin = self._finish_t.get(req_id)
+        fin_phases = self._phase_fin.get(req_id)
 
         def ms(a, b):
             return (round((b - a) * 1e3, 3)
@@ -2068,6 +2114,7 @@ class LLMEngine:
             "queue_wait_ms": ms(sub, pstart),
             "prefill_ms": ms(pstart, first),
             "decode_ms": ms(first, fin),
+            "engine": fin_phases[0] if fin_phases else None,
         }
 
     def cached_tokens(self, req_id: int) -> int:
@@ -2265,6 +2312,7 @@ class LLMEngine:
         packed transfer + one dispatch, mirroring _dispatch_prefill_wave.
         pairs: list of (action, materialized (k, v) prefix); returns [W]
         device tokens."""
+        self.phase_clock.enter("prefill_pack")
         width = 1
         while width < len(pairs):
             width *= 2
@@ -2272,12 +2320,15 @@ class LLMEngine:
         rows = [(self._cont_row_tokens(self._prompts[a.req_id], p, t),
                  a.slot, a.prompt_len) + self._row_tail(a.req_id)
                 for a, _ in padded]
-        packed = self._pack_rows(width, t + (p if self.spec else 0), rows)
+        packed = self._put(self._pack_rows(
+            width, t + (p if self.spec else 0), rows))
         k_prefix, v_prefix = self._stack_prefix([e for _, e in padded])
+        fn = self._cont_fn(p, t, width)
+        self.phase_clock.enter("prefill_dispatch")
         (self.cache, self.lengths, self.last_tokens, self.samp,
-         self.rng_key, out) = self._cont_fn(p, t, width)(
+         self.rng_key, out) = fn(
             self.params, self.cache, self.lengths, self.last_tokens,
-            self.samp, self.rng_key, self._put(packed),
+            self.samp, self.rng_key, packed,
             k_prefix, v_prefix, *self._extra())
         return out
 
@@ -2315,6 +2366,7 @@ class LLMEngine:
         pipeline. The wave is padded up to a power-of-two width by
         repeating its last action (idempotent duplicate writes), keeping
         the compiled-program menu small."""
+        self.phase_clock.enter("prefill_pack")
         width = 1
         while width < len(wave):
             width *= 2
@@ -2324,11 +2376,13 @@ class LLMEngine:
                 + self._row_tail(a.req_id) for a in wave]
         self._prefill_computed_tokens += sum(
             len(self._prompts[a.req_id]) for a in wave)
-        packed = self._pack_rows(width, bucket, rows)
+        packed = self._put(self._pack_rows(width, bucket, rows))
+        fn = self._prefill_fn(bucket, width)
+        self.phase_clock.enter("prefill_dispatch")
         (self.cache, self.lengths, self.last_tokens, self.samp,
-         self.rng_key, out) = self._prefill_fn(bucket, width)(
+         self.rng_key, out) = fn(
             self.params, self.cache, self.lengths, self.last_tokens,
-            self.samp, self.rng_key, self._put(packed), *self._extra())
+            self.samp, self.rng_key, packed, *self._extra())
         return out
 
     def _do_decode(self) -> None:
@@ -2352,6 +2406,8 @@ class LLMEngine:
         surplus tokens are dropped host-side, and new arrivals wait at
         most one chunk for their prefill — decode_chunk bounds scheduling
         latency."""
+        clock = self.phase_clock
+        clock.enter("decode_plan")
         if self._pending is not None:
             # if the in-flight chunk's deliveries already satisfy every
             # active budget, OR the cache has no room for even one more
@@ -2466,19 +2522,15 @@ class LLMEngine:
                 span = self.max_len
             fn = self._decode_fn(k, span)
         self._spec_last_k = kd
-        t_dispatch = time.perf_counter()
+        active_dev = self._active_for(active)
+        clock.enter("decode_dispatch")
         (self.cache, self.lengths, self.last_tokens, self.samp,
          self.rng_key, out) = fn(
             self.params, self.cache, self.lengths, self.last_tokens,
-            self.samp, self.rng_key, self._active_for(active),
-            *self._extra())
-        self._perf["dispatch_s"] += time.perf_counter() - t_dispatch
-        self._perf["decode_chunks"] += 1
-        self._perf["decode_steps"] += k
-        # obs: aggregate counters only on this path (no span objects —
-        # scripts/check_observability.py lints that invariant)
-        self._decode_agg.note_step(int(active.sum()) * k * per_tok,
-                                   steps=k)
+            self.samp, self.rng_key, active_dev, *self._extra())
+        # obs: phases and aggregate counters only on this path (no span
+        # objects — scripts/check_observability.py lints that invariant)
+        clock.note_step(int(active.sum()) * k * per_tok, steps=k)
         rows_added = np.where(active, k * per_tok, 0)
         self._inflight += rows_added
         prev = self._pending
@@ -2518,18 +2570,25 @@ class LLMEngine:
                 or not np.array_equal(active, self._active_host)):
             self._active_host = active.copy()
             self._active_dev = self._put(active)
-            self._perf["active_uploads"] += 1
+            self._active_uploads += 1
         return self._active_dev
 
     def perf_counters(self, reset: bool = False) -> dict[str, Any]:
         """Decode host-side attribution counters (dispatch wall, fetch+
-        replay wall, chunk/step counts, active-mask uploads). The serving
-        profiler (training/profiling.serving_decode_breakdown) reads these
-        to fill the host buckets of the decode-step breakdown."""
-        out = dict(self._perf)
+        replay wall, chunk/step counts, active-mask uploads): a view of
+        the phase clock since the last reset. The serving profiler
+        (training/profiling.serving_decode_breakdown) reads these to fill
+        the host buckets of the decode-step breakdown."""
+        c = self.phase_clock
+        now = {"dispatch_s": c.ns["decode_dispatch"] / 1e9,
+               "fetch_replay_s": (c.ns["decode_fetch"]
+                                  + c.ns["replay"]) / 1e9,
+               "decode_chunks": c.counts["decode_dispatch"],
+               "decode_steps": c.steps,
+               "active_uploads": self._active_uploads}
+        out = {k: v - self._perf_base.get(k, 0) for k, v in now.items()}
         if reset:
-            for key in self._perf:
-                self._perf[key] = type(self._perf[key])(0)
+            self._perf_base = now
         return out
 
     def _observe_round_tokens(self, n: int) -> None:
@@ -2573,8 +2632,11 @@ class LLMEngine:
         captured request and is skipped — its rows are junk by contract,
         exactly like post-EOS surplus."""
         slot_req, steps, out, rows_added, kd = pending
-        t_replay = time.perf_counter()
+        clock = self.phase_clock
+        clock.enter("decode_fetch")
         out_np = np.asarray(out)   # one fetch per chunk
+        clock.fetched(outstanding=self._pending is not None)
+        clock.enter("replay")
         # in-flight rows for THIS chunk are now accounted by the replay's
         # own host_lengths advancement (junk/surplus rows stay counted in
         # neither — the next prefill into the slot resets both)
@@ -2625,7 +2687,6 @@ class LLMEngine:
         # host_lengths above; junk rows belong to freed slots whose state
         # the next prefill resets anyway
         self._inflight = np.maximum(self._inflight - rows_added, 0)
-        self._perf["fetch_replay_s"] += time.perf_counter() - t_replay
 
     def _record_token(self, req_id: int, slot: int, token: int,
                       lp: float = 0.0, top: dict[int, float] | None = None,
@@ -2635,8 +2696,7 @@ class LLMEngine:
             now = time.monotonic()
             self._first_token_t[req_id] = now
             self._ttft_window.append(now - self._submit_t[req_id])
-            if req_id in self._req_trace:
-                self._decode_mark[req_id] = self._decode_agg.snapshot()
+            self._phase_mark[req_id] = self.phase_clock.mark()
         res = self._results[req_id]
         res.append(token)
         self._logprobs[req_id].append(lp)
@@ -2671,6 +2731,7 @@ class LLMEngine:
             self._finish_reasons[req_id] = (
                 "stop" if (hit_eos or hit_stop) else "length")
             self._finish_t[req_id] = time.monotonic()
+            self._close_phases(req_id)
             self._done.add(req_id)
             self._prompts.pop(req_id, None)
             self._max_new.pop(req_id, None)
@@ -2680,6 +2741,28 @@ class LLMEngine:
             self._deadlines.pop(req_id, None)
             self._obs_finish(req_id)
         return freed
+
+    def _close_phases(self, req_id: int) -> None:
+        """At a request's finish instant: what the engine thread did
+        since its first token (request_timing()'s `engine`) and the
+        decode-step window of its decode span. Nothing for a request
+        that never got a token."""
+        first = self._phase_mark.pop(req_id, None)
+        if first is None:
+            return
+        clock = self.phase_clock
+        self._phase_fin[req_id] = (
+            clock.usage(first, self._submit_t.get(req_id)),
+            StepAggregator.window((first.steps, first.tokens),
+                                  clock.snapshot()))
+
+    def _stall_context(self) -> dict[str, Any]:
+        """What a stall line says beside the phase (PhaseClock calls
+        this on the engine thread, once per stall)."""
+        s = self.scheduler.stats()
+        return {"in_flight": ("decode_chunk" if self._pending is not None
+                              else "nothing"),
+                "queued": s.queued, "active": s.active}
 
     def _obs_finish(self, req_id: int) -> None:
         """Per-request telemetry, emitted ONCE at finish (never inside
@@ -2705,7 +2788,6 @@ class LLMEngine:
             obs_metrics.TPOT_SECONDS.observe((fin - first) / (n_tok - 1),
                                              component=self.role)
         trace = self._req_trace.pop(req_id, None)
-        mark = self._decode_mark.pop(req_id, None)
         if trace is None or not TRACER.sampled(trace):
             return
         tenant = self._req_tenant.get(req_id)
@@ -2719,9 +2801,8 @@ class LLMEngine:
         attrs: dict[str, Any] = {"n_tokens": n_tok,
                                  "finish_reason": reason,
                                  "tenant": tenant}
-        if mark is not None:
-            attrs.update(StepAggregator.window(
-                mark, self._decode_agg.snapshot()))
+        if req_id in self._phase_fin:
+            attrs.update(self._phase_fin[req_id][1])
         TRACER.record_span(f"{self.role}.decode", "decode", trace,
                            first, fin, **attrs)
 

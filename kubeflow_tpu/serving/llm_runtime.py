@@ -487,17 +487,35 @@ class LLMModel(Model):
         return llama.init(jax.random.key(self._seed), cfg)
 
     def _loop(self) -> None:
+        """The engine thread. It owns the engine's phase clock for as
+        long as it runs (`hold_open`): what is not inside `step()` is
+        `sched` (the sweep) or `idle` (the wait), so every instant of
+        this thread has a phase. A supervisor hands out its live
+        engine's clock, anew after a restart; a disaggregated pair has
+        none here (each role's clock runs inside its own steps)."""
+        def enter(phase: str, hold: bool = True) -> None:
+            clock = getattr(self._engine, "phase_clock", None)
+            if clock is not None:
+                clock.hold_open = hold
+                clock.enter(phase)
+                clock.leave()    # stops the clock once it is not held
+
         try:
             while not self._stop.is_set():
                 progressed = self._engine.step()
+                enter("sched")
                 self._sweep_abandoned()
                 if not progressed:
                     # idle: sleep until a submit wakes us
+                    enter("idle")
                     self._wake.wait(timeout=0.02)
                     self._wake.clear()
+                    enter("sched")
         except BaseException as e:  # surface to waiting predict() calls
             self._loop_error = e
             raise
+        finally:
+            enter("sched", hold=False)
 
     def _sweep_abandoned(self) -> None:
         for rid in list(self._abandoned):
@@ -681,17 +699,22 @@ class LLMModel(Model):
         return self._stream_from(rid, on_finish, hold, info)
 
     def _timing_fields(self, rid: int) -> dict[str, Any]:
-        """The request's phase split for the usage object (read BEFORE
-        release). Missing phases report as None — the engine fills them
-        as the boundaries land."""
+        """{"timing": the request's phase split for the usage object,
+        "submit_s": its submit instant (time.monotonic)}; read BEFORE
+        release. Missing phases report as None — the engine fills them
+        as the boundaries land. `engine` is what the engine thread did
+        over the decode_ms window. `submit_s` is not for the usage
+        object: the HTTP layer times its own two spans against it
+        (pre_submit_ms, first_write_lag_ms)."""
         try:
             tm = self._engine.request_timing(rid)
         except Exception:
-            return {}
-        return {k: tm.get(k) for k in
-                ("queue_wait_ms", "prefill_ms", "handoff_ms",
-                 "decode_ms")
-                if k != "handoff_ms" or "handoff_ms" in tm}
+            return {"timing": {}}
+        return {"timing": {k: tm.get(k) for k in
+                           ("queue_wait_ms", "prefill_ms", "handoff_ms",
+                            "decode_ms", "engine")
+                           if k != "handoff_ms" or "handoff_ms" in tm},
+                "submit_s": tm.get("submit_s")}
 
     def _slo_record(self, rid: int, reason: str) -> None:
         """Feed one finished request into the burn tracker (read BEFORE
@@ -782,7 +805,7 @@ class LLMModel(Model):
             if cached is not None:
                 info["cached_tokens"] = cached
             if self._usage_timing:
-                info["timing"] = self._timing_fields(rid)
+                info.update(self._timing_fields(rid))
         if on_finish is not None:
             on_finish(reason)
         self._slo_record(rid, reason)
@@ -825,7 +848,7 @@ class LLMModel(Model):
             # the phase split rides the usage object only when the
             # operator turned it on (the r10 cached_tokens precedent:
             # the default usage shape stays byte-unchanged)
-            result["timing"] = self._timing_fields(rid)
+            result.update(self._timing_fields(rid))
         if self._logprobs_topk:
             result["top_logprobs"] = self._engine.result_top_logprobs(rid)
         self._slo_record(rid, reason)

@@ -44,7 +44,6 @@ follow-on work, and the single-program engine keeps serving them.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Any
 
@@ -58,7 +57,7 @@ from kubeflow_tpu.parallel.mesh import active_mesh
 from kubeflow_tpu.parallel.pipeline import (InferenceStagePlan, StageClock,
                                             resolve_schedule,
                                             split_stage_params, wavefront)
-from kubeflow_tpu.serving.llm import LLMEngine
+from kubeflow_tpu.serving.llm import LLMEngine, named_program
 
 
 class StageShardedEngine(LLMEngine):
@@ -534,7 +533,8 @@ class StageShardedEngine(LLMEngine):
 
     def _extract_fn(self, p: int):
         if p not in self._extract_fns:
-            prog = jax.jit(functools.partial(self._extract_prefix, p=p))
+            prog = jax.jit(named_program(
+                "extract_prefix", self._extract_prefix, p=p))
 
             def driver(cache, slot):
                 ks, vs = [], []
@@ -549,8 +549,8 @@ class StageShardedEngine(LLMEngine):
 
     def _extract_raw_fn(self, p: int):
         if p not in self._extract_raw_fns:
-            prog = jax.jit(functools.partial(self._extract_prefix_raw,
-                                             p=p))
+            prog = jax.jit(named_program(
+                "extract_prefix_raw", self._extract_prefix_raw, p=p))
 
             def driver(cache, slot):
                 return [prog(cache["stages"][s], slot)

@@ -288,6 +288,7 @@ class PagedLLMEngine(LLMEngine):
             self._pool.deref(displaced)
 
     def _dispatch_prefill_cont_wave(self, p: int, t: int, pairs):
+        self.phase_clock.enter("prefill_pack")
         nb = p // self._bt
         for a, entry in pairs:
             self._splice_shared(a.slot, entry.ids[:nb])
@@ -299,6 +300,7 @@ class PagedLLMEngine(LLMEngine):
         prefix blocks into the slot table FIRST (the base method's own
         match — deterministic, nothing mutates the trie in between —
         then materializes the same chain and skips the prefix write)."""
+        self.phase_clock.enter("prefill_pack")
         prompt = self._prompts[action.req_id]
         n = len(prompt)
         bt = self._bt
@@ -428,18 +430,19 @@ class PagedLLMEngine(LLMEngine):
         held = {a.slot for a in self._held}
         return [-1 if s in held else r for s, r in enumerate(slot_req)]
 
-    def step(self) -> bool:
+    def _step(self) -> bool:
         if self._held:
             # held retry first: finished chunks free blocks, so drain
             # the pipeline, then re-run admission before the scheduler
             # hands out anything new
             self._apply_cancellations()
             self._drain_pending()
+            self.phase_clock.enter("sched")
             ready = self._admit_prefills([])
             if ready:
                 self._run_prefill_actions(ready)
                 return True
-        return super().step()
+        return super()._step()
 
     # -- release / deferred frees --------------------------------------------
 

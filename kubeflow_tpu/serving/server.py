@@ -126,6 +126,7 @@ class ModelServer:
                     self._send(500, {"error": str(e)})
 
             def do_POST(self):
+                t_enter = time.monotonic()   # usage.pre_submit_ms starts
                 try:
                     length = int(self.headers.get("Content-Length", 0))
                     raw = self.rfile.read(length)
@@ -147,10 +148,11 @@ class ModelServer:
                         trace = (self.headers.get(TRACE_HEADER)
                                  or new_trace_id())
                         if body.get("stream"):
-                            return server._stream_completion(self, body,
-                                                             chat, trace)
+                            return server._stream_completion(
+                                self, body, chat, trace, t_enter)
                         return self._send(
-                            *server._completion(body, chat, trace))
+                            *server._completion(body, chat, trace,
+                                                t_enter))
                     self._send(*server._handle_post(self.path, raw))
                 except Exception as e:
                     self._send(500, {"error": str(e)})
@@ -577,7 +579,9 @@ class ModelServer:
         return choice
 
     def _completion(self, body: dict[str, Any], chat: bool = False,
-                    trace: str | None = None) -> tuple[int, dict[str, Any]]:
+                    trace: str | None = None,
+                    t_enter: float | None = None
+                    ) -> tuple[int, dict[str, Any]]:
         t0 = time.perf_counter()
         t_mono = time.monotonic()
         try:
@@ -643,12 +647,9 @@ class ModelServer:
         # only when the model runs usage_timing (shape unchanged
         # otherwise — the cached_tokens precedent). One request, one
         # split: n/best_of clones report the first returned choice's.
-        timing = next((r["timing"] for r in results if r.get("timing")),
-                      None)
-        if timing:
-            for k, v in timing.items():
-                if v is not None:
-                    usage[k] = v
+        timed = next((r for r in results if r.get("timing")), None)
+        if timed:
+            self._usage_timing(usage, timed, t_enter)
         return 200, {
             "object": "chat.completion" if chat else "text_completion",
             "model": m.name, "choices": choices,
@@ -658,9 +659,39 @@ class ModelServer:
             # (the field OpenAI clients read for billing/limits)
             "usage": usage}
 
+    @staticmethod
+    def _usage_timing(usage: dict[str, Any], timed: dict[str, Any],
+                      t_enter: float | None,
+                      t_first_write: float | None = None) -> None:
+        """A `usage_timing` model's phase split into the usage object,
+        and the server thread's own two spans on the same clock:
+        `pre_submit_ms` (handler entry -> the submit instant that
+        queue_wait_ms starts from) and, streaming only,
+        `first_write_lag_ms` (the engine's first token, i.e. the end of
+        prefill_ms, -> the first SSE chunk written). With the client's
+        send-to-first-token they split the HTTP overhead into the
+        server's two halves and, by subtraction, the router's relay."""
+        timing = timed["timing"]
+        for k, v in timing.items():
+            if v is not None:
+                usage[k] = v
+        sub = timed.get("submit_s")
+        if sub is None:
+            return
+        if t_enter is not None:
+            usage["pre_submit_ms"] = round((sub - t_enter) * 1e3, 3)
+        if (t_first_write is not None
+                and timing.get("queue_wait_ms") is not None
+                and timing.get("prefill_ms") is not None):
+            first_token_s = sub + (timing["queue_wait_ms"]
+                                   + timing["prefill_ms"]) / 1e3
+            usage["first_write_lag_ms"] = round(
+                (t_first_write - first_token_s) * 1e3, 3)
+
     def _stream_completion(self, handler, body: dict[str, Any],
                            chat: bool = False,
-                           trace: str | None = None) -> None:
+                           trace: str | None = None,
+                           t_enter: float | None = None) -> None:
         """Server-sent events: one `data: {...}` chunk per token carrying
         the incremental TEXT delta (multi-byte sequences decode across
         chunk boundaries), a final chunk with finish_reason, then
@@ -698,6 +729,7 @@ class ModelServer:
         first = [True]
         want_lp = payload.get("want_logprobs")
         n_sent = 0
+        t_first_write = None
 
         def chunk_of(text: str, token_id: int | None = None,
                      reason: str | None = None,
@@ -751,6 +783,8 @@ class ModelServer:
                         decoder.push(tok), token_id=int(tok),
                         logprob=(float(lp) if want_lp else None)))
                     handler.wfile.flush()
+                    if t_first_write is None:
+                        t_first_write = time.monotonic()
             except (BrokenPipeError, ConnectionResetError, OSError):
                 # the SOCKET died, not the engine: this must reach the
                 # disconnect path below — the generic handler would "write"
@@ -775,9 +809,9 @@ class ModelServer:
                     usage["cached_tokens"] = stream_info["cached_tokens"]
                     usage["prompt_tokens_details"] = {
                         "cached_tokens": stream_info["cached_tokens"]}
-                for k, v in (stream_info.get("timing") or {}).items():
-                    if v is not None:   # usage_timing models only
-                        usage[k] = v
+                if stream_info.get("timing"):   # usage_timing models only
+                    self._usage_timing(usage, stream_info, t_enter,
+                                       t_first_write)
                 if reason == "cancelled":
                     # same type as the buffered path: a COUNT of
                     # cancelled returned choices (a stream has one)

@@ -335,16 +335,29 @@ class Trainer:
                                 start_step + self.config.profile_start_step,
                                 self.config.profile_num_steps)
         pending = None
+        data_wait = 0.0   # since the last logged step
+
+        def next_batch():
+            # under a profiler capture the wait shows on this thread's
+            # host line, beside the steps' `train_step` annotations
+            nonlocal data_wait
+            t = time.perf_counter()
+            with jax.profiler.TraceAnnotation("trainer.data_wait"):
+                out = self.shard_batch(next(data))
+            data_wait += time.perf_counter() - t
+            return out
+
         for i in range(num_steps):
-            batch = (pending if pending is not None
-                     else self.shard_batch(next(data)))
+            batch = pending if pending is not None else next_batch()
             pending = None
             if step_fn is None:
                 step_fn = self.compiled_step(state, batch)
             step = start_step + i + 1
             if prof is not None:
                 prof.maybe_start(step)
-            state, metrics = step_fn(state, batch)
+            with jax.profiler.StepTraceAnnotation("train_step",
+                                                  step_num=step):
+                state, metrics = step_fn(state, batch)
             # one-batch device prefetch: the next host->device transfer is
             # enqueued while this step runs, hiding it behind compute
             # (device_put/make_array are async dispatches). A data-iterator
@@ -353,7 +366,7 @@ class Trainer:
             data_err: BaseException | None = None
             if i + 1 < num_steps:
                 try:
-                    pending = self.shard_batch(next(data))
+                    pending = next_batch()
                 except BaseException as e:
                     data_err = e
             if prof is not None:
@@ -365,10 +378,14 @@ class Trainer:
                 metrics = jax.device_get(metrics)
                 now = time.perf_counter()
                 dt = (now - t_last) / steps_since_log
-                t_last = now
-                steps_since_log = 0
                 scalars = {k: float(v) for k, v in metrics.items()}
                 scalars["step_time_s"] = dt
+                # per step like step_time_s, and part of it: the host
+                # wall in next(data) + shard_batch
+                scalars["data_wait_s"] = data_wait / steps_since_log
+                t_last = now
+                steps_since_log = 0
+                data_wait = 0.0
                 if first_interval:
                     scalars["includes_compile"] = 1.0
                     first_interval = False

@@ -15,11 +15,16 @@ Rules (AST, no imports of the checked code):
    actually meet in one module).
 2. Decode hot paths never mint spans. Inside the engine step/decode/
    prefill driver functions (the per-token loop), `span(...)` /
-   `record_span(...)` calls are banned — the only sanctioned recorder
-   there is `StepAggregator.note_step`, with the ONE retrospective span
-   per request emitted at finish time (`_obs_finish`, off the hot path).
-   A live span per step would put an allocation + deque append + lock
-   in the tokens/sec denominator.
+   `record_span(...)` calls are banned. What those functions may do is
+   enter PHASES on the engine's `PhaseClock` (`clock.enter(...)`: two
+   clock reads and a profiler annotation per transition, nothing per
+   token) and bump its counters (`StepAggregator.note_step`), with the
+   ONE retrospective span per request emitted at finish time
+   (`_obs_finish`, off the hot path). A live span per step would put an
+   allocation + deque append + lock in the tokens/sec denominator. The
+   one span the clock itself records is a `stall` (a phase occurrence of
+   500 ms or more: rare by construction), inside `obs/trace.py`, which
+   is not a hot path file.
 
 Run: `python scripts/check_observability.py` — exit 0 clean, 1 with
 findings (one per line). The fast lane runs it via
@@ -48,9 +53,9 @@ _INSTRUMENT_METHODS = ("counter", "gauge", "histogram")
 #: helper defined INSIDE a hot function is hot too)
 HOT_PATHS = {
     os.path.join("kubeflow_tpu", "serving", "llm.py"):
-        ("step", "_do_decode", "_decode", "_decode_fn",
-         "_decode_nosample_fn", "_prefill", "_prefill_cont",
-         "_prefill_fn"),
+        ("step", "_step", "_do_decode", "_replay", "_run_prefill_actions",
+         "_decode", "_decode_fn", "_decode_nosample_fn", "_prefill",
+         "_prefill_cont", "_prefill_fn"),
     os.path.join("kubeflow_tpu", "serving", "multichip.py"):
         ("step", "_do_decode", "_decode_driver", "_decode_fn",
          "_decode_nosample_fn", "_prefill_fn"),
@@ -129,9 +134,10 @@ def check(pkg_root: str = PKG, repo_root: str = REPO) -> list[str]:
                     findings.append(
                         f"{rel}:{lineno}: {call}(...) inside hot "
                         f"function {'/'.join(stack)} — decode/prefill "
-                        "loops record through StepAggregator.note_step "
-                        "only; emit the retrospective span at finish "
-                        "time (_obs_finish)")
+                        "loops enter PhaseClock phases and record "
+                        "through StepAggregator.note_step only; emit "
+                        "the retrospective span at finish time "
+                        "(_obs_finish)")
     return findings
 
 

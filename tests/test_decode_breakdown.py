@@ -30,6 +30,23 @@ def engine():
     return eng
 
 
+def _assert_device_partition(bd):
+    """The STRUCTURE of the device buckets, not a ratio of CPU timings:
+    attention and sampling are differentials of separately timed runs
+    (full - nosample, nosample - weight read), each clamped at 0. Where
+    neither clamp fired the three sum to the device step to the
+    rounding; a clamp (timing noise on a loaded CPU: a stripped variant
+    that ran slower than the full program) can only push the sum above
+    it, never under."""
+    b = bd["buckets_ms"]
+    device_sum = (b["weight_read"] + b["attention_kv_update"]
+                  + b["sampling_penalties"])
+    slack = 1e-3    # four buckets rounded to 1e-4 ms each
+    assert device_sum >= bd["device_step_ms"] - slack
+    if b["attention_kv_update"] > 0 and b["sampling_penalties"] > 0:
+        assert device_sum == pytest.approx(bd["device_step_ms"], abs=slack)
+
+
 def test_breakdown_buckets_account_for_the_device_step(engine):
     engine.perf_counters(reset=True)
     baseline = engine.generate([1, 2, 3], 8)   # populate host counters
@@ -40,9 +57,7 @@ def test_breakdown_buckets_account_for_the_device_step(engine):
         assert b[name] is None or b[name] >= 0, (name, b)
     # the three device buckets are a PARTITION of the measured device
     # step (sampling and attention are differentials against it)
-    device_sum = (b["weight_read"] + b["attention_kv_update"]
-                  + b["sampling_penalties"])
-    assert device_sum == pytest.approx(bd["device_step_ms"], rel=0.02)
+    _assert_device_partition(bd)
     # host buckets came from the live counters populated above
     assert b["host_fetch_replay_per_step"] is not None
     assert bd["perf_counters"]["decode_steps"] > 0
@@ -68,9 +83,7 @@ def test_breakdown_attn_subattribution_unquantized(engine):
     assert "prefill_attn" in b
     assert b["prefill_attn"] is not None and b["prefill_attn"] >= 0
     # sub-attribution never perturbs the bucket PARTITION contract
-    device_sum = (b["weight_read"] + b["attention_kv_update"]
-                  + b["sampling_penalties"])
-    assert device_sum == pytest.approx(bd["device_step_ms"], rel=0.02)
+    _assert_device_partition(bd)
 
 
 def test_breakdown_attn_dequant_measured_on_int8_cache():

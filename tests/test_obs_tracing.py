@@ -13,11 +13,13 @@ from __future__ import annotations
 
 import gc
 import json
+import logging
 import re
 import time
 import urllib.error
 import urllib.request
 
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -27,9 +29,11 @@ from kubeflow_tpu.models import llama
 from kubeflow_tpu.obs import metrics as obs_metrics
 from kubeflow_tpu.obs.metrics import render_metrics
 from kubeflow_tpu.obs.slo import SloBurnTracker
-from kubeflow_tpu.obs.trace import (TRACE_HEADER, TRACER, NOOP_SPAN,
-                                    SpanSink, StepAggregator, Tracer,
-                                    new_trace_id)
+from kubeflow_tpu.obs.trace import (PHASES, STALL_NS, TRACE_HEADER, TRACER,
+                                    NOOP_SPAN, PhaseClock, SpanSink,
+                                    StepAggregator, Tracer, new_trace_id)
+from kubeflow_tpu.serving.agent import EngineSupervisor
+from kubeflow_tpu.serving.llm import LLMEngine
 from kubeflow_tpu.serving.llm_runtime import LLMModel
 from kubeflow_tpu.serving.model import ModelRepository, load_model
 from kubeflow_tpu.serving.router import OPEN, Router
@@ -112,6 +116,302 @@ def test_step_aggregator_window():
     agg.note_step(3)
     w = StepAggregator.window(before, agg.snapshot())
     assert w == {"decode_steps": 3, "decode_tokens": 11}
+
+
+def test_span_json_carries_unix_start():
+    """`start_unix_ns` lays a JSONL export over a profiler trace: the
+    span's monotonic start moved by the one anchor pair read at import."""
+    sink = SpanSink()
+    now_s, unix_ns = time.monotonic(), time.time_ns()
+    Tracer(sink=sink).record_span("a", "queue", "tid", now_s, now_s + 1.0)
+    rec = json.loads(sink.export_jsonl())
+    assert rec["start_s"] == now_s
+    assert abs(rec["start_unix_ns"] - unix_ns) < 50_000_000   # 50 ms
+
+
+# -- unit: the engine thread's phase clock ------------------------------------
+
+
+def test_phase_clock_partitions_the_timeline():
+    """Entering a phase ends the one before, so between the first enter
+    and any mark the phases' nanoseconds sum to the wall between them —
+    exactly, whatever the driver did meanwhile. Re-entering the open
+    phase is not a new occurrence."""
+    c = PhaseClock("unit")
+    c.hold_open = True
+    c.enter("sched")
+    t0 = c._t0
+    c.enter("sched")                  # same phase: no new occurrence
+    c.enter("decode_plan")
+    c.enter("decode_dispatch")
+    c.note_step(8, steps=4)           # the StepAggregator counts ride along
+    c.enter("decode_fetch")
+    time.sleep(0.01)
+    c.enter("replay")
+    c.leave()                         # held open: the clock keeps running
+    m = c.mark()
+    assert sum(m.ns) == m.at_ns - t0
+    by = dict(zip(PHASES, m.counts))
+    assert by["sched"] == by["decode_dispatch"] == by["replay"] == 1
+    assert dict(zip(PHASES, m.ns))["decode_fetch"] >= 10_000_000
+    assert (m.steps, m.tokens) == (4, 8)
+    # a driver that does not hold the thread: leave() stops the clock
+    c.hold_open = False
+    c.leave()
+    a = c.mark()
+    time.sleep(0.005)
+    assert c.mark().ns == a.ns
+
+
+def test_phase_clock_device_empty_overlay():
+    """`device_empty_ns` runs from a fetch that left nothing dispatched
+    and unfetched to the next program call; a fetch with work still in
+    flight starts nothing; a mark counts the open stretch."""
+    c = PhaseClock("unit")
+    c.enter("decode_fetch")
+    c.fetched(outstanding=True)
+    c.enter("replay")
+    time.sleep(0.005)
+    c.enter("decode_dispatch")
+    assert c.device_empty_ns == 0
+    c.enter("decode_fetch")
+    c.fetched(outstanding=False)
+    c.enter("replay")
+    time.sleep(0.01)
+    assert c.device_empty_ns == 0 and c.mark().device_empty_ns >= 10_000_000
+    c.enter("sched")                  # not a program call: still empty
+    c.enter("prefill_dispatch")
+    closed = c.device_empty_ns
+    assert closed >= 10_000_000
+    time.sleep(0.002)
+    assert c.mark().device_empty_ns == closed
+
+
+def test_phase_clock_longest_occurrence_of_a_window():
+    """`phase_max` is the longest single non-idle occurrence that
+    overlapped submit -> finish: an older, longer one that ended before
+    the submit does not count, `idle` never does, the open phase does."""
+    c = PhaseClock("unit")
+    c.enter("prefill_fetch")
+    time.sleep(0.03)
+    c.enter("idle")
+    time.sleep(0.04)                  # longest of all, but idle
+    submit_s = time.monotonic()
+    c.enter("decode_fetch")
+    time.sleep(0.01)
+    c.enter("replay")
+    ns, phase = c.longest_since(submit_s, time.monotonic_ns())
+    assert phase == "decode_fetch" and 10_000_000 <= ns < 30_000_000
+    # from before the prefill fetch the older, longer occurrence wins
+    ns, phase = c.longest_since(submit_s - 1.0, time.monotonic_ns())
+    assert phase == "prefill_fetch" and ns >= 30_000_000
+    time.sleep(0.02)                  # the open `replay` outgrows both
+    assert c.longest_since(submit_s, time.monotonic_ns())[1] == "replay"
+
+
+# -- the clock inside a toy engine --------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def toy_engine():
+    cfg = llama.LlamaConfig.tiny()
+    eng = LLMEngine(llama.init(jax.random.key(0), cfg), cfg, n_slots=2,
+                    max_len=64, buckets=(16,), decode_chunk=2)
+    eng.warmup()
+    eng.phase_clock.hold_open = True   # this test module drives the thread
+    yield eng
+    eng.close()
+
+
+def _run(eng, *rids):
+    while not all(eng.is_done(r) for r in rids):
+        assert eng.step()
+
+
+def test_engine_usage_phases_sum_to_decode_ms(toy_engine):
+    """Sink B: over a finished request the phases partition the window
+    `decode_ms` covers (first token -> finish), prefill waves of OTHER
+    requests included, and every name is one of PHASES."""
+    eng = toy_engine
+    a = eng.submit([1, 2, 3, 4, 5], 24)
+    for _ in range(3):
+        eng.step()
+    b = eng.submit([9, 8, 7], 6)       # its prefill lands inside a's decode
+    _run(eng, a, b)
+    tm = eng.request_timing(a)
+    engine = tm["engine"]
+    assert set(engine) == {"phases", "device_empty_ms", "phase_max_ms",
+                           "phase_max"}
+    assert set(engine["phases"]) <= set(PHASES)
+    assert "idle" not in engine["phases"]
+    total = sum(ms for ms, _ in engine["phases"].values())
+    assert total == pytest.approx(tm["decode_ms"], rel=0.01)
+    # b's prefill wave ran inside a's window and is named as such
+    assert engine["phases"]["prefill_dispatch"][1] >= 1
+    assert engine["phases"]["prefill_fetch"][0] > 0
+    assert engine["phases"]["decode_dispatch"][1] >= 24 // 2 - 1
+    assert engine["phase_max"] in PHASES and engine["phase_max_ms"] > 0
+    assert 0 <= engine["device_empty_ms"] <= tm["decode_ms"]
+    # a request that never got a token has no window to report
+    c = eng.submit([1, 2, 3], 4)
+    eng.cancel(c)
+    eng.step()                         # applied at the chunk boundary
+    assert eng.is_done(c)
+    assert eng.request_timing(c)["engine"] is None
+    for r in (a, b, c):
+        eng.release(r)
+    assert not eng._phase_mark and not eng._phase_fin
+
+
+def test_device_empty_only_when_nothing_is_in_flight(toy_engine, monkeypatch):
+    """The overlay stands still while a decode chunk is dispatched and
+    unfetched (pipelined decode keeps one in flight), and grows across a
+    sleep planted between a fetch and the next program call."""
+    eng = toy_engine
+    clock = eng.phase_clock
+    a = eng.submit([1, 2, 3, 4], 40)
+    while eng._pending is None:
+        eng.step()
+    before = clock.mark().device_empty_ns
+    for _ in range(5):
+        eng.step()
+        assert eng._pending is not None
+    assert clock.mark().device_empty_ns == before
+    # the next prefill burst drains the chunk (a fetch that leaves nothing
+    # in flight), then admits (the sleep), then dispatches
+    admit = eng._admit_prefills
+    monkeypatch.setattr(eng, "_admit_prefills",
+                        lambda acts: (time.sleep(0.05), admit(acts))[1])
+    b = eng.submit([5, 6, 7], 2)
+    eng.step()
+    assert clock.mark().device_empty_ns - before >= 50_000_000
+    monkeypatch.undo()
+    _run(eng, a, b)
+    assert eng.request_timing(a)["engine"]["device_empty_ms"] >= 50
+    eng.release(a)
+    eng.release(b)
+
+
+def test_stall_is_logged_counted_spanned_and_named_in_usage(
+        toy_engine, monkeypatch, caplog):
+    """Sink C's stall rule: one non-idle phase occurrence of 500 ms or
+    more gives one WARNING, one `serving_engine_stalls_total` increment
+    and a `stall` span (no trace id needed), and the usage of a request
+    alive across it names the phase."""
+    eng = toy_engine
+    a = eng.submit([1, 2, 3, 4], 12)
+    while eng._pending is None:
+        eng.step()
+    n_before = obs_metrics.ENGINE_STALLS.value(engine="engine",
+                                               phase="sched")
+    spans_before = len([s for s in TRACER.sink.spans()
+                        if s.kind == "stall"])
+    admit = eng._admit_prefills
+    monkeypatch.setattr(eng, "_admit_prefills",
+                        lambda acts: (time.sleep(0.6), admit(acts))[1])
+    b = eng.submit([5, 6, 7], 2)
+    with caplog.at_level(logging.WARNING, logger="kubeflow_tpu.obs.trace"):
+        eng.step()
+        monkeypatch.undo()
+        _run(eng, a, b)
+    warned = [r for r in caplog.records
+              if "engine stall" in r.getMessage()
+              and "phase sched" in r.getMessage()]
+    assert len(warned) == 1
+    assert "queued=" in warned[0].getMessage()
+    assert "active=" in warned[0].getMessage()
+    assert obs_metrics.ENGINE_STALLS.value(
+        engine="engine", phase="sched") == n_before + 1
+    stalls = [s for s in TRACER.sink.spans() if s.kind == "stall"]
+    assert len(stalls) == spans_before + 1
+    assert stalls[-1].attrs["phase"] == "sched"
+    assert stalls[-1].duration_ms() >= STALL_NS / 1e6
+    engine = eng.request_timing(a)["engine"]
+    assert engine["phase_max"] == "sched" and engine["phase_max_ms"] >= 600
+    # the stall lay inside a's decode window, so its phase carries it
+    assert engine["phases"]["sched"][0] >= 600
+    eng.release(a)
+    eng.release(b)
+
+
+def test_perf_counters_is_a_view_of_the_clock(toy_engine):
+    """`self._perf` is gone: the decode host counters the serving
+    profiler reads are the phase clock's, since the last reset."""
+    eng = toy_engine
+    assert not hasattr(eng, "_perf")
+    eng.perf_counters(reset=True)
+    zero = eng.perf_counters()
+    assert set(zero) == {"dispatch_s", "fetch_replay_s", "decode_chunks",
+                         "decode_steps", "active_uploads"}
+    assert zero["decode_chunks"] == zero["decode_steps"] == 0
+    assert zero["dispatch_s"] == 0
+    eng.generate([1, 2, 3], 8)
+    pc = eng.perf_counters()
+    assert pc["decode_chunks"] >= 3 and pc["decode_steps"] >= 7
+    assert pc["dispatch_s"] > 0 and pc["fetch_replay_s"] > 0
+    assert (pc["decode_chunks"] <= pc["decode_steps"]
+            <= pc["decode_chunks"] * eng.decode_chunk)
+
+
+def test_metrics_render_the_phase_series(toy_engine):
+    """Sink C, pull model: a scrape adds what closed since the last one
+    to the two cumulative series; scraping twice adds nothing twice."""
+    eng = toy_engine
+    eng.generate([4, 5, 6], 6)
+    text = render_metrics()
+    assert "# TYPE serving_engine_phase_seconds_total counter" in text
+    assert "# TYPE serving_engine_device_empty_seconds_total counter" \
+        in text
+    for phase in PHASES:
+        assert f'serving_engine_phase_seconds_total{{engine="engine",' \
+            f'phase="{phase}"}}' in text
+    series = 'serving_engine_phase_seconds_total{engine="engine",' \
+        'phase="decode_dispatch"}'
+    first = _metric_value(text, series)
+    assert first > 0
+    assert _metric_value(
+        text, 'serving_engine_device_empty_seconds_total'
+        '{engine="engine"}') > 0
+    assert _metric_value(render_metrics(), series) == first
+    assert "serving_phase_seconds" not in text    # the dead histogram
+
+
+def test_engine_programs_have_one_name_per_kind(toy_engine):
+    """A device trace names a module `jit_<function name>`: one name per
+    KIND of program, no chunk, span or bucket in it (a bare partial
+    compiled as `jit__unknown`)."""
+    eng = toy_engine
+    assert eng._decode_fn(2).__name__ == "decode"
+    assert eng._decode_fn(1, 32).__name__ == "decode"
+    assert eng._decode_nosample_fn(1).__name__ == "decode_nosample"
+    assert eng._prefill_fn(16, 1).__name__ == "prefill"
+    assert eng._cont_fn(16, 16, 1).__name__ == "prefill_cont"
+    assert eng._extract_fn(16).__name__ == "extract_prefix"
+    assert eng._extract_raw_fn(16).__name__ == "extract_prefix_raw"
+    assert eng._spec_fn(1, 32, 2).__name__ == "decode_spec"
+
+
+def test_supervisor_journal_carries_engine_phases():
+    """The engine's rid is released at completion; `engine` survives in
+    the journal's phases like the three durations beside it."""
+    cfg = llama.LlamaConfig.tiny()
+    params = llama.init(jax.random.key(0), cfg)
+    sup = EngineSupervisor(
+        lambda: LLMEngine(params, cfg, n_slots=2, max_len=32,
+                          buckets=(8,), decode_chunk=2))
+    try:
+        rid = sup.submit([1, 2, 3], 6)
+        sup.run_until_idle()
+        tm = sup.request_timing(rid)
+        assert tm["decode_ms"] is not None
+        assert set(tm["engine"]["phases"]) <= set(PHASES)
+        assert tm["engine"]["phases"]["decode_dispatch"][1] >= 2
+        # the supervisor's own journal poll is the engine thread's time too
+        assert sup.phase_clock is sup.engine.phase_clock
+        assert sup.phase_clock.counts["replay"] > 0
+    finally:
+        sup.close()
+    assert sup.phase_clock is None      # no live engine, no clock
 
 
 # -- unit: SLO burn -----------------------------------------------------------
@@ -213,8 +513,7 @@ def _crash_now(seed: int = 1):
         faults=(FaultSpec("backend_crash", 1, (0.0, 0.0)),)), name="now")
 
 
-@pytest.fixture(scope="module")
-def llm_server():
+def _llm_server(**model_kw):
     cfg = llama.LlamaConfig(vocab_size=128, d_model=32, n_layers=2,
                             n_heads=4, n_kv_heads=2, d_ff=64,
                             max_seq_len=64, attention_impl="xla",
@@ -230,13 +529,39 @@ def llm_server():
                              "backoff_base_s": 0.3,
                              "backoff_cap_s": 0.6,
                              "rewarm": False},
-                 sse_keepalive_s=0.05)
+                 sse_keepalive_s=0.05, **model_kw)
     repo = ModelRepository()
     repo.register(m)
     server = ModelServer(repo).start()
     yield m, server
     server.stop()
     m.unload()
+
+
+@pytest.fixture(scope="module")
+def llm_server():
+    yield from _llm_server()
+
+
+@pytest.fixture(scope="module")
+def timed_server():
+    """The same server with `usage_timing` on: Sink B's channel."""
+    yield from _llm_server(usage_timing=True)
+
+
+def _completion_usage(port: int, stream: bool) -> dict:
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/openai/v1/completions",
+        data=json.dumps({"model": "llm", "prompt": PROMPT,
+                         "max_tokens": MAX_TOKENS, "temperature": 0.0,
+                         "stream": stream}).encode(),
+        headers={"Content-Type": "application/json"}, method="POST")
+    with urllib.request.urlopen(req, timeout=120) as r:
+        raw = r.read().decode()
+    if not stream:
+        return json.loads(raw)["usage"]
+    chunks = [ln[6:] for ln in raw.splitlines() if ln.startswith("data: {")]
+    return json.loads(chunks[-1])["usage"]
 
 
 def _post_completion(port: int, trace_id: str, timeout=120.0) -> dict:
@@ -368,6 +693,8 @@ def test_crash_replay_stays_under_one_trace_id(llm_server):
 
 def test_server_metrics_and_healthz_payloads(llm_server):
     m, server = llm_server
+    _post_completion(server.port, new_trace_id())   # alone, this is the
+    # first request the server sees
     with urllib.request.urlopen(
             f"http://127.0.0.1:{server.port}/metrics", timeout=10) as r:
         assert r.status == 200
@@ -376,7 +703,9 @@ def test_server_metrics_and_healthz_payloads(llm_server):
     assert "# TYPE serving_requests_total counter" in text
     assert 'serving_http_requests_total{model="llm",verb="completions"}' \
         in text
-    assert re.search(r'supervisor_restarts_total\{cause=', text)
+    # declared whether or not this worker ever counted a restart (the
+    # series itself exists only after one)
+    assert "# TYPE supervisor_restarts_total counter" in text
     with urllib.request.urlopen(
             f"http://127.0.0.1:{server.port}/healthz", timeout=10) as r:
         health = json.loads(r.read())
@@ -388,6 +717,57 @@ def test_server_metrics_and_healthz_payloads(llm_server):
     # the pre-obs JSON metrics view survives unchanged for callers
     mm = server._metrics()
     assert "request_count" in mm and "latency_sum_s" in mm
+
+
+@pytest.mark.parametrize("stream", [False, True])
+def test_usage_is_unchanged_with_usage_timing_off(llm_server, stream):
+    """Golden keys: without `usage_timing` nothing of the phase clock,
+    nor the server thread's two spans, reaches the usage object."""
+    _, server = llm_server
+    usage = _completion_usage(server.port, stream)
+    assert usage == {"prompt_tokens": len(PROMPT),
+                     "completion_tokens": MAX_TOKENS,
+                     "total_tokens": len(PROMPT) + MAX_TOKENS}
+
+
+@pytest.mark.parametrize("stream", [False, True])
+def test_usage_timing_carries_engine_and_server_spans(timed_server, stream):
+    """Sink B end to end, through the supervisor's journal: `engine`
+    (phases that sum to decode_ms), `pre_submit_ms` on both paths,
+    `first_write_lag_ms` on the streaming one only."""
+    _, server = timed_server
+    usage = _completion_usage(server.port, stream)
+    assert {"queue_wait_ms", "prefill_ms", "decode_ms", "engine",
+            "pre_submit_ms"} <= set(usage)
+    assert ("first_write_lag_ms" in usage) is stream
+    assert "submit_s" not in usage      # an instant, not for the client
+    engine = usage["engine"]
+    assert set(engine["phases"]) <= set(PHASES)
+    total = sum(ms for ms, _ in engine["phases"].values())
+    assert total == pytest.approx(usage["decode_ms"], rel=0.01)
+    assert engine["phases"]["decode_dispatch"][1] >= 1
+    assert 0 <= usage["pre_submit_ms"] < 5_000
+    if stream:
+        # the first chunk cannot be written before the token exists
+        assert 0 <= usage["first_write_lag_ms"] < 5_000
+
+
+def test_loop_driven_clock_names_every_instant(timed_server):
+    """`LLMModel._loop` holds the thread: with no work its time is
+    `idle` (plus the wake-ups' `sched`), and between two marks the
+    phases sum to the wall exactly."""
+    m, _ = timed_server
+    clock = m._engine.phase_clock
+    assert clock is not None and clock.hold_open
+    a = clock.mark()
+    time.sleep(0.2)
+    b = clock.mark()
+    # exact on the engine thread; a mark read from THIS thread may catch
+    # one transition halfway
+    assert sum(b.ns) - sum(a.ns) == pytest.approx(b.at_ns - a.at_ns,
+                                                  abs=5_000_000)
+    idle = PHASES.index("idle")
+    assert b.ns[idle] - a.ns[idle] >= 0.9 * (b.at_ns - a.at_ns)
 
 
 def test_router_metrics_and_healthz_payloads():

@@ -31,6 +31,30 @@ def test_mnist_loss_decreases():
     assert int(state["step"]) == 30
 
 
+def test_logged_record_splits_out_the_data_wait():
+    """Each logged step's record carries `data_wait_s` beside
+    `step_time_s`: the host's wall in next(data) + shard_batch, per step
+    of the interval, so a slow input pipeline shows as itself and not as
+    a slow step."""
+    import time
+
+    tr = make_trainer()
+    tr.metrics.echo = False
+    inner = data_lib.for_model("mnist_cnn", tr.model_cfg, 8)
+
+    def slow():
+        for batch in inner:
+            time.sleep(0.01)
+            yield batch
+
+    records = []
+    tr.train(slow(), 10, step_callback=lambda s, m: records.append(m))
+    assert len(records) == 2              # log_every=5
+    for rec in records:
+        # (the last step prefetches nothing: 4 waits over 5 steps)
+        assert 0.005 <= rec["data_wait_s"] <= rec["step_time_s"]
+
+
 def test_bf16_first_moment_halves_mu_state():
     """OptimizerConfig.mu_dtype='bfloat16': adam's first moment carries
     bf16 (half the HBM residency + step traffic) while params and the
