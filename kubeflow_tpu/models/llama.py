@@ -440,7 +440,7 @@ def _adapted(h, layer, t: str, lora_layer, ids, dtype):
     Multi-adapter batched serving: x@W once for the batch, plus the
     low-rank bypass gathered per row — S-LoRA's trick, XLA-shaped (the
     gather is tiny next to the W read decode is bound on)."""
-    y = quant.matmul(h, layer[t], dtype)
+    y = quant.matmul(h, layer[t], dtype, layer.get("layer_idx"))
     if lora_layer is None or t not in lora_layer:
         return y
     a = lora_layer[t]["a"][ids].astype(jnp.float32)  # [B, d_in, r]
@@ -476,6 +476,29 @@ def _wo(cfg: LlamaConfig, out, layer, lora_layer=None, ids=None):
     return _adapted(out, layer, "wo", lora_layer, ids, cfg.dtype)
 
 
+def _scan_layers(layers: Params):
+    """A layer slab as the serving scans take it: (xs, layer_of).
+
+    The quantized matmul leaves stay OUT of `xs`: the body closes over the
+    whole stacks [Ls, in, out] (loop invariants of the `while`) and
+    `layer_of(one iteration's slice of xs)` hands them to _adapted with
+    the layer's index "layer_idx", so the int8 matmul kernel reads its
+    layer in place (ops/quant.py matmul). Sliced by the scan, each would
+    reach the kernel, a custom call XLA fuses nothing into, as a copy made
+    on every step. Norms and an unquantized tree ride `xs` as ever; the
+    index is within the slab."""
+    stacked = {t: w for t, w in layers.items() if quant.is_quantized(w)}
+    n_layers = jax.tree.leaves(layers)[0].shape[0]
+    xs = ({t: w for t, w in layers.items() if t not in stacked},
+          jnp.arange(n_layers))
+
+    def layer_of(inp):
+        sliced, li = inp
+        return {**sliced, **stacked, "layer_idx": li}
+
+    return xs, layer_of
+
+
 def prefill_inner(layers: Params, x: jax.Array, positions: jax.Array,
                   cfg: LlamaConfig, lora: Params | None = None,
                   ids: jax.Array | None = None):
@@ -491,17 +514,19 @@ def prefill_inner(layers: Params, x: jax.Array, positions: jax.Array,
     # of an engine runs one prefill-attention impl — the mha einsum or
     # the fused Pallas chunked-prefill kernel (cfg.prefill_attention_impl)
     attn_impl = resolve_prefill_attn(cfg)
+    layer_xs, layer_of = _scan_layers(layers)
 
     def body(carry, inp):
         x = carry
-        layer, ll = inp if lora is not None else (inp, None)
+        lx, ll = inp if lora is not None else (inp, None)
+        layer = layer_of(lx)
         q, k, v = _project_qkv(cfg, layer, x, positions, ll, ids)
         out = prefill_attention(cfg, q, k, v, q_offset=0, impl=attn_impl)
         x = x + _wo(cfg, out.reshape(b, s, -1), layer, ll, ids)
         x = _serving_mlp(cfg, x, layer, ll, ids)
         return x, (k, v)
 
-    xs = (layers, lora) if lora is not None else layers
+    xs = (layer_xs, lora) if lora is not None else layer_xs
     return jax.lax.scan(body, x, xs)
 
 
@@ -581,13 +606,15 @@ def prefill_continue_inner(layers: Params, x: jax.Array,
     p = k_prefix.shape[2]
     # static impl resolution, like prefill_inner: one impl per trace
     attn_impl = resolve_prefill_attn(cfg)
+    layer_xs, layer_of = _scan_layers(layers)
 
     def body(carry, inp):
         x = carry
         if lora is not None:
-            layer, kp, vp, ll = inp
+            lx, kp, vp, ll = inp
         else:
-            (layer, kp, vp), ll = inp, None  # kp/vp: [B, P, kv, hd]
+            (lx, kp, vp), ll = inp, None  # kp/vp: [B, P, kv, hd]
+        layer = layer_of(lx)
         q, k_new, v_new = _project_qkv(cfg, layer, x, positions, ll, ids)
         k_full = jnp.concatenate([kp.astype(cfg.dtype), k_new], axis=1)
         v_full = jnp.concatenate([vp.astype(cfg.dtype), v_new], axis=1)
@@ -597,8 +624,8 @@ def prefill_continue_inner(layers: Params, x: jax.Array,
         x = _serving_mlp(cfg, x, layer, ll, ids)
         return x, (k_new, v_new)
 
-    xs = ((layers, k_prefix, v_prefix, lora)
-          if lora is not None else (layers, k_prefix, v_prefix))
+    xs = ((layer_xs, k_prefix, v_prefix, lora)
+          if lora is not None else (layer_xs, k_prefix, v_prefix))
     return jax.lax.scan(body, x, xs)
 
 
@@ -899,11 +926,9 @@ def verify_inner(layers: Params, x: jax.Array, cache: Params,
     # ~2 GiB of junk HBM write+read per step on the serving hot path.
     def body(carry, inp):
         x, cache_c = carry
-        ll = None
-        if lora is not None:
-            layer, li, ll = inp
-        else:
-            layer, li = inp
+        lx, ll = inp if lora is not None else (inp, None)
+        layer = layer_of(lx)
+        li = layer["layer_idx"]
         q, k_new, v_new = _project_qkv(cfg, layer, x, positions, ll, ids)
         if quantized:
             kq, ksc = quantize_kv(k_new)
@@ -942,10 +967,8 @@ def verify_inner(layers: Params, x: jax.Array, cache: Params,
         x = _serving_mlp(cfg, x, layer, ll, ids)
         return (x, cache_c), None
 
-    n_layers = jax.tree.leaves(layers)[0].shape[0]
-    layer_idx = jnp.arange(n_layers)
-    xs = ((layers, layer_idx, lora) if lora is not None
-          else (layers, layer_idx))
+    layer_xs, layer_of = _scan_layers(layers)
+    xs = (layer_xs, lora) if lora is not None else layer_xs
     (x, new_cache), _ = jax.lax.scan(body, (x, cache), xs)
     if paged:
         new_cache = dict(new_cache, tbl=tbl)   # tables pass through

@@ -15,6 +15,9 @@ A quantized weight is a dict leaf {"q": int8 [..., in, out],
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 import math
 import os
 from typing import Any
@@ -44,11 +47,11 @@ def is_quantized(wt: Any) -> bool:
 # flash-decode kernel: default "pallas" on TPU, "xla" elsewhere, env
 # override KTPU_QUANT_MATMUL=xla|pallas (the fleet kill-switch), and
 # USE_PALLAS_DEQUANT=True as the programmatic force-on the older tests
-# use. The r2 caveat stands in the record: +7% on a single-step decode
-# program but -17% on scan-of-steps chunk programs on THAT jax (the
-# custom call defeated cross-iteration weight prefetch) — which is why
-# every record now carries the serving_kernels A/B (bench.py, schema 9)
-# so the default is re-litigated per hardware record, not folklore.
+# use. Inside the serving scans the kernel is handed the whole stacked
+# leaf and the layer's index (_fused_or_leaf): a layer sliced out by the
+# scan reached the custom call as a copy made on every decode step, which
+# is what the r2 record's "-17% on scan-of-steps chunk programs" was
+# (ops/quant_matmul.py has the measured numbers).
 USE_PALLAS_DEQUANT: bool = False
 
 #: env override for the quant-matmul impl selection: "pallas" | "xla".
@@ -77,8 +80,8 @@ def resolve_quant_matmul_impl() -> str:
 def _pallas_dequant_wanted(x, q) -> bool:
     from kubeflow_tpu.ops import quant_matmul
 
-    if q.ndim != 2 or not quant_matmul.kernel_applicable(
-            math.prod(x.shape[:-1]), *q.shape):
+    if not quant_matmul.kernel_applicable(
+            math.prod(x.shape[:-1]), *q.shape[-2:]):
         return False
     if quant_matmul.FORCE_INTERPRET:
         return True
@@ -88,7 +91,57 @@ def _pallas_dequant_wanted(x, q) -> bool:
             and pallas_compat.target_platform() == "tpu")
 
 
-def matmul(x: jax.Array, wt: Any, dtype) -> jax.Array:
+# Trace-time census of the quantized matmul call sites by the path each
+# took ("stacked_kernel" | "kernel_2d" | "xla"), kept only while a caller
+# asks: the serving engine counts its warm-up menu and reports it as
+# metrics()["quant_matmul_sites"]. A site is traced once per program,
+# whatever the scans around it repeat.
+_sites: contextvars.ContextVar[collections.Counter | None] = (
+    contextvars.ContextVar("quant_matmul_sites", default=None))
+
+
+@contextlib.contextmanager
+def count_sites():
+    """Counter, by path, of the quantized matmul sites traced inside the
+    block (tracing runs in the caller's own context)."""
+    sites: collections.Counter = collections.Counter()
+    token = _sites.set(sites)
+    try:
+        yield sites
+    finally:
+        _sites.reset(token)
+
+
+def _fused_or_leaf(x, wt, layer, out_dtype):
+    """(kernel result, None) where the fused kernel takes this site, else
+    (None, the 2-D {"q", "s"} leaf) for the caller's XLA expression.
+
+    A rank-3 `q` is a whole stack [L, in, out] and comes with its `layer`
+    index: the kernel reads that layer in place from the stack
+    (quant_matmul._dequant_matmul_stacked). Outside the kernel's gate the
+    layer is indexed here, which is what a scan's own slicing of `xs`
+    gives: XLA fuses that slice into the dot's operand read."""
+    q, s = wt["q"], wt["s"]
+    stacked = q.ndim == 3
+    if stacked and layer is None:
+        raise ValueError("a stacked quantized weight needs its layer index")
+    sites = _sites.get()
+    if _pallas_dequant_wanted(x, q):
+        from kubeflow_tpu.ops import quant_matmul
+
+        if sites is not None:
+            sites["stacked_kernel" if stacked else "kernel_2d"] += 1
+        return quant_matmul.dequant_matmul(
+            x, q, s, out_dtype, layer=layer if stacked else None), None
+    if sites is not None:
+        sites["xla"] += 1
+    if stacked:
+        q = jax.lax.dynamic_index_in_dim(q, layer, axis=0, keepdims=False)
+        s = jax.lax.dynamic_index_in_dim(s, layer, axis=0, keepdims=False)
+    return None, {"q": q, "s": s}
+
+
+def matmul(x: jax.Array, wt: Any, dtype, layer=None) -> jax.Array:
     """x @ W for a raw or quantized weight leaf (x: [..., in]). The scale
     is applied in f32 and the PRODUCT cast to dtype — casting s itself to
     bf16 first would add a systematic per-channel bias on top of the
@@ -96,25 +149,23 @@ def matmul(x: jax.Array, wt: Any, dtype) -> jax.Array:
     quantized matmuls route through the fused Pallas kernel
     (ops/quant_matmul.py) when resolve_quant_matmul_impl() selects it —
     the TPU default since ISSUE 15; everything else (big prefill rows,
-    ragged blocks, non-TPU) takes this XLA lowering."""
+    ragged blocks, non-TPU) takes this XLA lowering. `layer` indexes a
+    stacked quantized leaf (_fused_or_leaf)."""
     if is_quantized(wt):
-        if _pallas_dequant_wanted(x, wt["q"]):
-            from kubeflow_tpu.ops import quant_matmul
-
-            return quant_matmul.dequant_matmul(x, wt["q"], wt["s"], dtype)
+        out, wt = _fused_or_leaf(x, wt, layer, dtype)
+        if out is not None:
+            return out
         return ((x @ wt["q"].astype(dtype)).astype(jnp.float32)
                 * wt["s"]).astype(dtype)
     return x @ wt.astype(dtype)
 
 
-def matmul_f32_out(x: jax.Array, wt: Any, dtype) -> jax.Array:
+def matmul_f32_out(x: jax.Array, wt: Any, dtype, layer=None) -> jax.Array:
     """Like matmul but accumulating to f32 (the lm-head contract)."""
     if is_quantized(wt):
-        if _pallas_dequant_wanted(x, wt["q"]):
-            from kubeflow_tpu.ops import quant_matmul
-
-            return quant_matmul.dequant_matmul(x, wt["q"], wt["s"],
-                                               jnp.float32)
+        out, wt = _fused_or_leaf(x, wt, layer, jnp.float32)
+        if out is not None:
+            return out
         out = jnp.einsum("...d,dv->...v", x, wt["q"].astype(dtype),
                          preferred_element_type=jnp.float32)
         return out * wt["s"]

@@ -12,16 +12,26 @@ accumulates f32 across d-blocks in VMEM scratch, and applies the
 per-output-channel scale on the last block — the weight's HBM footprint
 is its int8 bytes, full stop.
 
-MEASURED HISTORY (v5e, 8B geometry, r2 jax): +7% on a single-step
-decode program, but -17% on the engine's scan-of-steps chunk programs —
-inside the step scan the custom call blocked XLA's cross-iteration
-weight prefetch. ISSUE 15 promotes the kernel to the TPU default
-anyway, WITH teeth: every bench record carries a serving_kernels A/B on
-the same warmed engine (schema 9), so a regression on the current
-toolchain shows up as a committed per-bucket delta, and
-KTPU_QUANT_MATMUL=xla flips the fleet back without a code push.
-quant.matmul gates on resolve_quant_matmul_impl() (or FORCE_INTERPRET
-in tests); see ops/quant.py for the policy.
+TWO ENTRIES, ONE BODY. _dequant_matmul_2d takes a two-dimensional weight
+(the lm_head). _dequant_matmul_stacked takes a whole stack [L, d, o] with
+the layer's index as a prefetched scalar and picks the layer in the
+BlockSpec index maps of q and s: the serving bodies scan over layers whose
+weights are stacked, and nothing may be sliced outside the kernel. Sliced
+by the scan, a layer's weights reached the 2-D custom call as the output
+of a dynamic-slice; XLA fuses nothing into a custom call, so it
+materialised the slice: every decode step copied each layer's int8
+weights (read + write) and the kernel then read the copy. On the v5e at
+Mistral-7B widths, 8 layers, 16 decode rows (PERF.md, PR 24 and PR 26):
+the copies took 3.17 s of a traced 10 s beside 1.51 s in the kernel, a
+decode step 11.59 ms; read in place a step takes 6.17 ms, its seven layer
+matmuls 2.45 ms for 1.75 GB (2.13 ms at 819 GB/s: 88 % of the HBM roof),
+and the p95 gap between tokens fell from 118.3 to 60.5 ms end to end.
+That copy is the cause of what an earlier toolchain's record here called
+"-17% on the engine's scan-of-steps chunk programs ... the custom call
+blocked XLA's cross-iteration weight prefetch". KTPU_QUANT_MATMUL=xla
+still flips the fleet back without a code push; quant.matmul gates on
+resolve_quant_matmul_impl() (or FORCE_INTERPRET in tests); see
+ops/quant.py for the policy.
 
 Gating (quant.matmul decides): m ≤ MAX_ROWS (decode/verify shapes; big
 prefill batches are compute-bound and XLA's MXU path is fine), block
@@ -65,8 +75,10 @@ def kernel_applicable(m: int, d: int, o: int) -> bool:
             and _pick_block(o, (512, 384, 256, 128)) is not None)
 
 
-def _dequant_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, n_d: int,
-                    out_dtype):
+def _dequant_kernel(*refs, n_d: int, out_dtype):
+    # one body for both entries: the stacked entry's refs lead with the
+    # prefetched layer index, which only its index maps read
+    x_ref, q_ref, s_ref, o_ref, acc_ref = refs[-5:]
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -84,46 +96,79 @@ def _dequant_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, n_d: int,
         o_ref[...] = (acc_ref[...] * s_ref[...]).astype(out_dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
-def _dequant_matmul_2d(x, q, s, *, out_dtype, interpret=False):
-    """[m, d] bf16 @ int8 [d, o] (scale [o]) → [m, o] out_dtype."""
+def _pallas_dequant(x, q, s, layer, out_dtype, interpret):
+    """The one pallas_call of both entries. x [m, d] bf16, result [m, o].
+    `layer` None: q [d, o], s [1, o]. Else q [L, d, o] and s [L, 1, o] are
+    whole stacks and `layer` (s32[1]) is prefetched: the index maps of q
+    and s pick the layer, their leading block dimension is squeezed, and
+    x, the output, the grid, the blocks, the f32 accumulator and the body
+    are the same, tile for tile."""
     m, d = x.shape
-    o = q.shape[1]
+    o = q.shape[-1]
     block_d = _pick_block(d, (2048, 1024, 512, 256))
     block_o = _pick_block(o, (512, 384, 256, 128))
     m_pad = max(_MIN_M, m)
     if m_pad != m:
         x = jnp.pad(x, ((0, m_pad - m), (0, 0)))
     n_d, n_o = d // block_d, o // block_o
+    if layer is None:
+        prefetch, lead, at = (), (), lambda refs: ()
+    else:
+        prefetch, lead, at = (layer,), (None,), lambda refs: (refs[0][0],)
+    # an index map takes the grid indices, then the prefetched refs
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, n_d=n_d, out_dtype=out_dtype),
-        grid=(n_o, n_d),
-        in_specs=[
-            pl.BlockSpec((m_pad, block_d), lambda i, j: (0, j)),
-            pl.BlockSpec((block_d, block_o), lambda i, j: (j, i)),
-            pl.BlockSpec((1, block_o), lambda i, j: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((m_pad, block_o), lambda i, j: (0, i)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(n_o, n_d),
+            in_specs=[
+                pl.BlockSpec((m_pad, block_d), lambda i, j, *refs: (0, j)),
+                pl.BlockSpec(lead + (block_d, block_o),
+                             lambda i, j, *refs: at(refs) + (j, i)),
+                pl.BlockSpec(lead + (1, block_o),
+                             lambda i, j, *refs: at(refs) + (0, i)),
+            ],
+            out_specs=pl.BlockSpec((m_pad, block_o),
+                                   lambda i, j, *refs: (0, i)),
+            scratch_shapes=[pltpu.VMEM((m_pad, block_o), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((m_pad, o), out_dtype),
-        scratch_shapes=[pltpu.VMEM((m_pad, block_o), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x, q, s.reshape(1, o))
+    )(*prefetch, x, q, s)
     return out[:m]
 
 
+@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
+def _dequant_matmul_2d(x, q, s, *, out_dtype, interpret=False):
+    """[m, d] bf16 @ int8 [d, o] (scale [o]) → [m, o] out_dtype."""
+    return _pallas_dequant(x, q, s.reshape(1, -1), None, out_dtype,
+                           interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
+def _dequant_matmul_stacked(layer, x, q, s, *, out_dtype, interpret=False):
+    """[m, d] bf16 @ int8 q[layer] of a stack [L, d, o] (scales [L, o]) →
+    [m, o] out_dtype, bit-identical to _dequant_matmul_2d(x, q[l], s[l]).
+    The device trace names the custom call after this function."""
+    n_l, _, o = q.shape
+    return _pallas_dequant(x, q, s.reshape(n_l, 1, o), layer, out_dtype,
+                           interpret)
+
+
 def dequant_matmul(x: jax.Array, q: jax.Array, s: jax.Array,
-                   out_dtype) -> jax.Array:
+                   out_dtype, layer: jax.Array | None = None) -> jax.Array:
     """x [..., d] @ {q int8 [d, o], s f32 [o]} → [..., o] out_dtype,
-    f32 accumulation, scale applied once per output channel. Caller has
-    already checked kernel_applicable() on the flattened row count."""
+    f32 accumulation, scale applied once per output channel. With `layer`
+    (a scalar index), q [L, d, o] and s [L, o] are whole stacks and the
+    kernel reads that layer in place. Caller has already checked
+    kernel_applicable() on the flattened row count."""
     lead = x.shape[:-1]
-    d = x.shape[-1]
-    x2 = x.reshape(-1, d).astype(jnp.bfloat16)
-    interpret = False
-    if FORCE_INTERPRET:
-        interpret = True
-    out = _dequant_matmul_2d(x2, q, s, out_dtype=jnp.dtype(out_dtype),
-                             interpret=interpret)
-    return out.reshape(*lead, q.shape[1])
+    x2 = x.reshape(-1, x.shape[-1]).astype(jnp.bfloat16)
+    kw = dict(out_dtype=jnp.dtype(out_dtype), interpret=FORCE_INTERPRET)
+    if layer is None:
+        out = _dequant_matmul_2d(x2, q, s, **kw)
+    else:
+        out = _dequant_matmul_stacked(
+            jnp.asarray(layer, jnp.int32).reshape(1), x2, q, s, **kw)
+    return out.reshape(*lead, q.shape[-1])

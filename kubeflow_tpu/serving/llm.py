@@ -35,6 +35,7 @@ from kubeflow_tpu.kvcache import RadixKVCache
 from kubeflow_tpu.models import llama
 from kubeflow_tpu.obs import metrics as obs_metrics
 from kubeflow_tpu.obs.trace import TRACER, PhaseClock, StepAggregator
+from kubeflow_tpu.ops import quant
 from kubeflow_tpu.parallel.mesh import active_mesh
 from kubeflow_tpu.serving.scheduler import (DecodeAction, PrefillAction,
                                             PromptTooLong, make_scheduler)
@@ -415,6 +416,7 @@ class LLMEngine:
         self._active_host: np.ndarray | None = None
         self._active_dev = None
         self._warmed = False
+        self._quant_matmul_sites: dict[str, int] = {}
         self._max_new: dict[int, int] = {}
         self._finish_reasons: dict[int, str] = {}
 
@@ -1824,6 +1826,15 @@ class LLMEngine:
             pass
 
     def warmup(self) -> None:
+        """_warm_menu under the census of the quantized matmul sites it
+        traces (ops/quant.py count_sites): metrics() reports, as
+        "quant_matmul_sites", how many sites of the warmed programs run
+        the stacked int8 kernel, the 2-D one, or the XLA expression."""
+        with quant.count_sites() as sites:
+            self._warm_menu()
+        self._quant_matmul_sites = dict(sites)
+
+    def _warm_menu(self) -> None:
         """Execute every program in the menu once (each bucket × each
         power-of-two wave width, plus decode) so no request ever pays XLA
         compile time. Must run before serving traffic: a cold width means
@@ -2195,6 +2206,8 @@ class LLMEngine:
                # overrides to "paged" and adds the pool gauges)
                "kv_layout": self.kv_layout,
                "mesh": self.mesh_info()}
+        if self._quant_matmul_sites:
+            out["quant_matmul_sites"] = self._quant_matmul_sites
         out["prefill_tokens_computed"] = self._prefill_computed_tokens
         if self.prefix_cache_enabled and self.kvcache is not None:
             st = self.kvcache.stats()

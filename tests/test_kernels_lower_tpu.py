@@ -28,7 +28,7 @@ from kubeflow_tpu.ops import (flash_decode, flash_pallas, flash_prefill,
 PALLAS_CALL_SITES = {
     "flash_decode": 1,
     "flash_prefill": 1,
-    "quant_matmul": 1,
+    "quant_matmul": 1,   # one call site, two entries: both lowered below
     "flash_pallas": 3,
 }
 
@@ -110,6 +110,24 @@ def test_quant_matmul_lowers(rows, d_in, d_out):
             x, q, s, out_dtype=jnp.dtype(jnp.bfloat16), interpret=False),
         sds((rows, d_in), jnp.bfloat16), sds((d_in, d_out), jnp.int8),
         sds((d_out,), jnp.float32)) == 1
+
+
+@pytest.mark.parametrize("rows,d_in,d_out", [
+    (16, 4096, 4096),       # wq / wo of a stack of 8 layers
+    (112, 4096, 1024),      # wk / wv, a k=6 verify round
+    (16, 4096, 14336),      # w_gate / w_up
+    (16, 14336, 4096),      # w_down
+    (1, 4096, 4096),        # one row, padded to the sublane floor
+])
+def test_quant_matmul_stacked_lowers(rows, d_in, d_out):
+    assert quant_matmul.kernel_applicable(rows, d_in, d_out)
+    assert mosaic_calls(
+        lambda layer, x, q, s: quant_matmul._dequant_matmul_stacked(
+            layer, x, q, s, out_dtype=jnp.dtype(jnp.bfloat16),
+            interpret=False),
+        sds((1,), jnp.int32), sds((rows, d_in), jnp.bfloat16),
+        sds((8, d_in, d_out), jnp.int8),
+        sds((8, d_out), jnp.float32)) == 1
 
 
 def test_flash_pallas_forward_and_backward_lower():
