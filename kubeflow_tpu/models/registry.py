@@ -66,8 +66,9 @@ def _populate() -> None:
 
 
 def _do_populate() -> None:
-    from kubeflow_tpu.models import (bert, llama, lora, mnist_cnn,
-                                     moe_llama, nas_cnn, resnet, vit)
+    from kubeflow_tpu.models import (bert, kimi_linear, llama, lora,
+                                     mnist_cnn, moe_llama, nas_cnn, resnet,
+                                     vit)
 
     register("llama", ModelDef(llama.LlamaConfig, llama.init, llama.apply,
                                llama.loss_fn, llama.logical_axes))
@@ -77,6 +78,9 @@ def _do_populate() -> None:
     register("mixtral", ModelDef(moe_llama.MoELlamaConfig, moe_llama.init,
                                  moe_llama.apply, moe_llama.loss_fn,
                                  moe_llama.logical_axes))
+    register("kimi_linear", ModelDef(
+        kimi_linear.KimiLinearConfig, kimi_linear.init, kimi_linear.apply,
+        kimi_linear.loss_fn, kimi_linear.logical_axes))
     register("mnist_cnn", ModelDef(mnist_cnn.MnistConfig, mnist_cnn.init,
                                    mnist_cnn.apply, mnist_cnn.loss_fn,
                                    mnist_cnn.logical_axes))

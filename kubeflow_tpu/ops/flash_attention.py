@@ -137,7 +137,7 @@ def _blockwise_attn(q, k, v, *, causal: bool, scale: float, q_offset,
     kb = k.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(
         b, h, n_blocks, block_kv, d)
     vb = v.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(
-        b, h, n_blocks, block_kv, d)
+        b, h, n_blocks, block_kv, v.shape[3])
 
     q_pos = jnp.arange(sq) + q_offset  # [Sq]
     if segment_ids is not None:
@@ -176,7 +176,7 @@ def _blockwise_attn(q, k, v, *, causal: bool, scale: float, q_offset,
     # carries derived from q (not fresh zeros) so they inherit q's varying
     # manual axes — required when this runs inside a shard_map body (e.g.
     # a pipeline stage), harmless under plain jit
-    bhqd = jnp.zeros_like(qf, jnp.float32)  # [B,H,Sq,D]
+    bhqd = _zeros_like_q(qf, v.shape[3])  # [B,H,Sq,Dv]
     init = (
         bhqd,
         jnp.full_like(bhqd[..., 0], NEG_INF),
@@ -239,3 +239,13 @@ def flash_attention(
     return _blockwise_attn(q, k, v, causal=causal, scale=scale,
                            q_offset=q_offset, block_kv=block,
                            segment_ids=segment_ids)
+
+
+def _zeros_like_q(qf, dv: int):
+    """float32 zeros [B, H, Sq, dv] derived from q; v's head size may be
+    another than q's (latent attention: 192 beside 128). Below the entry
+    point so that no line above it moves."""
+    z = jnp.zeros_like(qf, jnp.float32)
+    if dv == qf.shape[-1]:
+        return z
+    return jnp.broadcast_to(z[..., :1], z.shape[:-1] + (dv,))

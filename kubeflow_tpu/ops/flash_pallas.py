@@ -11,9 +11,9 @@ Forward + backward are hand-written kernels wired through `jax.custom_vjp`:
     decomposition with delta = rowsum(dO ⊙ O) precomputed in XLA.
 
 Layout contract: BSHD in, GQA already expanded (flash_attention.py repeats KV
-heads before calling). Sequences are padded here to block multiples; padded
-keys are masked via `k_pos < sk`, padded query rows are sliced off (their
-dk/dv contributions vanish because dO rows are zero-padded).
+heads before calling); q and k share a head size, v and o may have another.
+Sequences are padded here to block multiples; padded keys are masked via `k_pos
+< sk`, padded query rows sliced off (dO rows zero-padded: no dk/dv from them).
 
 On non-TPU backends the kernels run only in interpreter mode (tests set
 FORCE_INTERPRET); otherwise NotImplementedError lets flash_attention.py fall
@@ -138,9 +138,9 @@ def _block_rows(seg, s_pad, block):
 
 def _fwd(q, k, v, seg_q, seg_k, causal, scale, q_offset, interpret, block_q,
          block_kv):
-    """q,k,v: [BH, S, D]; seg_q [BH, Sq] / seg_k [BH, Sk] or None."""
+    """q,k [BH, S, D], v [BH, S, Dv]; seg_q [BH, Sq], seg_k [BH, Sk] or None."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     sq_p = _round_up(sq, block_q)
     sk_p = _round_up(sk, block_kv)
     if sq_p != sq:
@@ -172,17 +172,17 @@ def _fwd(q, k, v, seg_q, seg_k, causal, scale, q_offset, interpret, block_q,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
             pl.BlockSpec((1, block_kv, d), lambda b, i, j, *_: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, i, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, block_kv, dv), lambda b, i, j, *_: (b, j, 0)),
             *seg_in_specs,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j, *_: (b, i, 0)),
             # lse is (BH, n_q, 1, block_q): the singleton sublane dim makes
             # the (1, block_q) block tail legal under the TPU tiling rule.
             pl.BlockSpec((1, 1, 1, block_q), lambda b, i, j, *_: (b, i, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -194,13 +194,13 @@ def _fwd(q, k, v, seg_q, seg_k, causal, scale, q_offset, interpret, block_q,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            _sds((bh, sq_p, d), q.dtype, q, k, v),
+            _sds((bh, sq_p, dv), q.dtype, q, k, v),
             _sds((bh, n_q, 1, block_q), jnp.float32, q, k, v),
         ],
         compiler_params=_COMPILER_PARAMS,
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * sq_p * sk_p * d,
-            bytes_accessed=2 * bh * (sq_p + 2 * sk_p) * d * q.dtype.itemsize,
+            flops=2 * bh * sq_p * sk_p * (d + dv),
+            bytes_accessed=_io_bytes(bh, sq_p, sk_p, d, dv, q.dtype.itemsize),
             transcendentals=bh * sq_p * sk_p,
         ),
         interpret=interpret,
@@ -332,7 +332,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
          block_q, block_kv):
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     sq_p = _round_up(sq, block_q)
     sk_p = _round_up(sk, block_kv)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -354,8 +354,8 @@ def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
         seg_q3 = _block_rows(seg_q, sq_p, block_q)
         seg_k3 = _block_rows(seg_k, sk_p, block_kv)
 
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kv_spec_dq = pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0))
+    q_spec, do_spec = _row_specs(block_q, (d, dv), lambda b, i, j: (b, i, 0))
+    k_sp, v_sp = _row_specs(block_kv, (d, dv), lambda b, i, j: (b, j, 0))
     row_spec = pl.BlockSpec((1, 1, 1, block_q),
                            lambda b, i, j: (b, i, 0, 0))
     seg_specs_dq, seg_args = [], []
@@ -374,7 +374,7 @@ def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
                           block_q=block_q, block_kv=block_kv, sk=sk,
                           segmented=segmented),
         grid=(bh, n_q, n_k),
-        in_specs=[q_spec, kv_spec_dq, kv_spec_dq, q_spec, row_spec, row_spec,
+        in_specs=[q_spec, k_sp, v_sp, do_spec, row_spec, row_spec,
                   *seg_specs_dq],
         out_specs=q_spec,
         out_shape=_sds((bh, sq_p, d), q.dtype, q, k, v, do),
@@ -383,8 +383,8 @@ def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
         interpret=interpret,
     )(q, k, v, do, lse3, delta3, *seg_args)
 
-    q_spec_kv = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, block_kv, d), lambda b, j, i: (b, j, 0))
+    q_kv, do_kv = _row_specs(block_q, (d, dv), lambda b, j, i: (b, i, 0))
+    k_spec, v_spec = _row_specs(block_kv, (d, dv), lambda b, j, i: (b, j, 0))
     row_spec_kv = pl.BlockSpec((1, 1, 1, block_q),
                               lambda b, j, i: (b, i, 0, 0))
     seg_specs_kv = []
@@ -400,13 +400,13 @@ def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
                           block_q=block_q, block_kv=block_kv, sk=sk,
                           segmented=segmented),
         grid=(bh, n_k, n_q),
-        in_specs=[q_spec_kv, kv_spec, kv_spec, q_spec_kv, row_spec_kv,
+        in_specs=[q_kv, k_spec, v_spec, do_kv, row_spec_kv,
                   row_spec_kv, *seg_specs_kv],
-        out_specs=[kv_spec, kv_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[_sds((bh, sk_p, d), k.dtype, q, k, v, do),
-                   _sds((bh, sk_p, d), v.dtype, q, k, v, do)],
+                   _sds((bh, sk_p, dv), v.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
-                        pltpu.VMEM((block_kv, d), jnp.float32)],
+                        pltpu.VMEM((block_kv, dv), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, do, lse3, delta3, *seg_args)
@@ -500,7 +500,7 @@ def pallas_flash_attention(q, k, v, *, causal=True, scale=None,
                     f"pallas flash kernel: target platform {platform!r}")
             interpret = False
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[3]
     if sq < 128 or sk < 128:
         raise NotImplementedError("pallas flash kernel needs seq >= 128")
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -510,9 +510,9 @@ def pallas_flash_attention(q, k, v, *, causal=True, scale=None,
     block_q = min(block_q, _round_up(sq, 128))
     block_kv = min(block_kv, _round_up(sk, 128))
 
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    qf = _heads_first(q, lanes=128)
+    kf = _heads_first(k, lanes=128)
+    vf = _heads_first(v)
 
     seg_q = seg_k = None
     if segment_ids is not None:
@@ -532,4 +532,26 @@ def pallas_flash_attention(q, k, v, *, causal=True, scale=None,
         of, _ = _fwd(qf, kf, vf, seg_q, seg_k, causal, scale, q_offset,
                      interpret, block_q, block_kv)
         of = jax.lax.stop_gradient(of)
-    return of.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return of.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
+
+
+# helpers of the entry points above; kept below them so that a change here
+# moves no line of the kernels (Mosaic's serialized module carries them)
+
+def _io_bytes(bh, sq, sk, d, dv, itemsize):
+    return 2 * bh * (sq * d + sk * (d + dv)) * itemsize
+
+
+def _row_specs(block, sizes, index_map):
+    return [pl.BlockSpec((1, block, size), index_map) for size in sizes]
+
+
+def _heads_first(x, lanes=None):
+    """[B, S, H, D] -> [B * H, S, D]. With `lanes`, a head size off that
+    grid (q and k of 192 beside values of 128: latent attention) is padded
+    with zeros to the next multiple: they add nothing to q k^T, and the
+    scale stays that of the published size."""
+    b, s, h, d = x.shape
+    if lanes and d % lanes:
+        x = jnp.pad(x, ((0, 0),) * 3 + ((0, _round_up(d, lanes) - d),))
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[3])

@@ -1,0 +1,452 @@
+"""Chunked gated delta rule with a per-channel decay (Kimi Delta Attention).
+
+Per head, with a state S in R^{dk x dv}, S_0 = 0:
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+(g_t <= 0 is the log-decay of every key channel, beta_t in (0, 1)). Nothing
+here walks the positions one by one. A chunk of C = 64 positions with the
+state S at its start and G_r = sum_{i<=r} g_i inside it gives
+
+    A_ri = sum_c k_r[c] k_i[c] exp(G_r[c] - G_i[c])     (i <  r)
+    B_ri = sum_c q_r[c] k_i[c] exp(G_r[c] - G_i[c])     (i <= r)
+    M    = (I + Diag(beta) A)^-1 Diag(beta)             (the UT transform)
+    U    = M V - M (K * exp(G)) S                       (beta_t times the "new values")
+    O    = (Q * exp(G)) S + B U
+    S'   = Diag(exp(G_C)) S + (K * exp(G_C - G))^T U
+
+`exp(-G_i)` overflows float32 inside a chunk once the decay is strong, so A
+and B are never computed as (K exp(G)) (K exp(-G))^T: the chunk is cut into
+sub-blocks of 16 rows, a pair of different sub-blocks takes the first row of
+the later one as its reference point (both factors are then <= 1), and the
+pairs inside one sub-block are summed channel by channel with the exponent
+taken of the difference itself. No exponent anywhere is positive.
+
+Three stages, forward:
+  1. `_intra_pallas`: A and B of every chunk (Pallas).
+  2. `_ut_transform`: M by block-recursive inversion of the unit lower
+     triangle (six steps of two 64 x 64 matmuls, float32, XLA).
+  3. `_state_pallas`: chunks in order, the state carried in VMEM (Pallas);
+     on the way it can write the state at every chunk's start.
+Stage 2 runs under the named scope `kda_solve` and the backward under
+`kda_backward`: a capture's device operations carry them, and the benchmark
+reads their shares of the device's time (benchmark/lib/xscopes.py).
+Backward (`custom_vjp`) is chunked jax.numpy: it keeps ONE state per chunk
+(written by stage 3), walks the chunks backwards with hand-written
+cotangents of stage 3, and differentiates stages 1-2 (`_prepare`, the same
+mathematics in jax.numpy) in groups of chunks so that the channel-by-channel
+sums never exist for the whole sequence at once. A Pallas backward is queued
+in ROADMAP.md.
+
+The kernels run where they compile (a TPU target) and, for the tests, under
+the interpreter (FORCE_INTERPRET, as in ops/flash_pallas.py). Elsewhere the
+forward is the backward's own jax.numpy (`_prepare`, `_states_xla`): the CPU
+path, which nothing selects by hand.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from kubeflow_tpu.ops import pallas_compat
+from kubeflow_tpu.ops.pallas_compat import sds_with_vma as _sds
+
+CHUNK = 64
+SUB = 16          # rows of a sub-block: one reference point each
+BACKWARD_GROUP = 8   # chunks of every head that the backward prepares at once
+HIGHEST = jax.lax.Precision.HIGHEST
+
+# Tests on the CPU set this to run the kernels under the Pallas interpreter.
+FORCE_INTERPRET = False
+
+
+# ---------------------------------------------------------------------------
+# stage 1: A and B inside every chunk
+# ---------------------------------------------------------------------------
+
+def _intra_kernel(q_ref, k_ref, g_ref, a_ref, b_ref, *, chunk, sub, mm_dtype):
+    q = q_ref[0].astype(jnp.float32)          # [C, dk]
+    k = k_ref[0].astype(jnp.float32)
+    g = g_ref[0]                              # cumulative log-decay, f32
+    ns = chunk // sub
+    dk = q.shape[-1]
+
+    # pairs of different sub-blocks: rows of sub-block I against every
+    # column, reference point the first row of I; columns that are not
+    # before I are masked below (their exponent is clamped, not used)
+    a_rows, b_rows = [], []
+    for i in range(ns):
+        ref = g[i * sub:i * sub + 1, :]                       # [1, dk]
+        decay = jnp.exp(g[i * sub:(i + 1) * sub, :] - ref)    # <= 1
+        rows = jnp.concatenate(
+            [k[i * sub:(i + 1) * sub, :] * decay,
+             q[i * sub:(i + 1) * sub, :] * decay], axis=0)    # [2 sub, dk]
+        cols = k * jnp.exp(jnp.minimum(ref - g, 0.0))         # [C, dk]
+        ab = jax.lax.dot_general(
+            rows.astype(mm_dtype), cols.astype(mm_dtype),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [2 sub, C]
+        a_rows.append(ab[:sub])
+        b_rows.append(ab[sub:])
+    a_off = jnp.concatenate(a_rows, axis=0)                   # [C, C]
+    b_off = jnp.concatenate(b_rows, axis=0)
+
+    # pairs inside one sub-block: column i of every sub-block at once,
+    # the exponent of the difference itself
+    q3 = q.reshape(ns, sub, dk)
+    k3 = k.reshape(ns, sub, dk)
+    g3 = g.reshape(ns, sub, dk)
+    col = jax.lax.broadcasted_iota(jnp.int32, (ns, sub, chunk), 2)
+    blk = jax.lax.broadcasted_iota(jnp.int32, (ns, sub, chunk), 0)
+    a_dg = jnp.zeros((ns, sub, chunk), jnp.float32)
+    b_dg = jnp.zeros((ns, sub, chunk), jnp.float32)
+    for i in range(sub):
+        ki = k3[:, i:i + 1, :]                                # [ns, 1, dk]
+        e = jnp.exp(jnp.minimum(g3 - g3[:, i:i + 1, :], 0.0)) * ki
+        a_col = jnp.sum(k3 * e, axis=-1, keepdims=True)       # [ns, sub, 1]
+        b_col = jnp.sum(q3 * e, axis=-1, keepdims=True)
+        here = col == blk * sub + i
+        a_dg = jnp.where(here, a_col, a_dg)
+        b_dg = jnp.where(here, b_col, b_dg)
+    a_dg = a_dg.reshape(chunk, chunk)
+    b_dg = b_dg.reshape(chunk, chunk)
+
+    r = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    same = (r // sub) == (c // sub)
+    before = (c // sub) < (r // sub)
+    a_ref[0, 0] = jnp.where(same & (c < r), a_dg,
+                            jnp.where(before, a_off, 0.0))
+    b_ref[0, 0] = jnp.where(same & (c <= r), b_dg,
+                            jnp.where(before, b_off, 0.0))
+
+
+def _intra_pallas(q, k, gc, *, interpret, mm_dtype):
+    """q, k [BH, S, dk], gc [BH, S, dk] (cumulative inside each chunk) ->
+    A, B [BH, S / C, C, C] float32."""
+    bh, s, dk = q.shape
+    nc = s // CHUNK
+    row = pl.BlockSpec((1, CHUNK, dk), lambda b, c: (b, c, 0))
+    sq = pl.BlockSpec((1, 1, CHUNK, CHUNK), lambda b, c: (b, c, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_intra_kernel, chunk=CHUNK, sub=SUB,
+                          mm_dtype=mm_dtype),
+        grid=(bh, nc),
+        in_specs=[row, row, row],
+        out_specs=[sq, sq],
+        out_shape=[_sds((bh, nc, CHUNK, CHUNK), jnp.float32, q, k, gc),
+                   _sds((bh, nc, CHUNK, CHUNK), jnp.float32, q, k, gc)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(q, k, gc)
+
+
+def _intra_xla(q, k, gc, mm_dtype):
+    """The same sums in jax.numpy: q, k, gc [n, C, dk] -> A, B [n, C, C]."""
+    n, chunk, dk = q.shape
+    ns = chunk // SUB
+    q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+    q4, k4, g4 = (x.reshape(n, ns, SUB, dk) for x in (q, k, gc))
+    ref = g4[:, :, :1, :]                                     # [n, ns, 1, dk]
+    decay = jnp.exp(g4 - ref)
+    cols = k[:, None] * jnp.exp(
+        jnp.minimum(ref - gc[:, None], 0.0))                  # [n, ns, C, dk]
+
+    def off(rows):
+        return jnp.einsum("nisd,nicd->nisc", (rows * decay).astype(mm_dtype),
+                          cols.astype(mm_dtype), precision=HIGHEST,
+                          preferred_element_type=jnp.float32
+                          ).reshape(n, chunk, chunk)
+
+    diff = g4[:, :, :, None, :] - g4[:, :, None, :, :]        # [n,ns,r,i,dk]
+    e = jnp.exp(jnp.minimum(diff, 0.0)) * k4[:, :, None, :, :]
+
+    def diag(rows):
+        d = jnp.sum(rows[:, :, :, None, :] * e, axis=-1)      # [n,ns,r,i]
+        eye = jnp.eye(ns, dtype=d.dtype)[None, :, None, :, None]
+        return (d[:, :, :, None, :] * eye).reshape(n, chunk, chunk)
+
+    r = jnp.arange(chunk)[:, None]
+    c = jnp.arange(chunk)[None, :]
+    same, before = (r // SUB) == (c // SUB), (c // SUB) < (r // SUB)
+    a = jnp.where(same & (c < r), diag(k4), jnp.where(before, off(k4), 0.0))
+    b = jnp.where(same & (c <= r), diag(q4), jnp.where(before, off(q4), 0.0))
+    return a, b
+
+
+# ---------------------------------------------------------------------------
+# stage 2: the UT transform
+# ---------------------------------------------------------------------------
+
+def _ut_transform(a, beta):
+    """M = (I + Diag(beta) A)^-1 Diag(beta) for A strictly lower, [.., C, C].
+    The inverse of a unit lower triangle by halves: with the diagonal blocks
+    of size b inverted (X), those of size 2b are X - X L21 X."""
+    chunk = a.shape[-1]
+    r = jnp.arange(chunk)[:, None]
+    c = jnp.arange(chunk)[None, :]
+    with jax.named_scope("kda_solve"):
+        low = beta[..., :, None] * a
+        x = jnp.broadcast_to(jnp.eye(chunk, dtype=jnp.float32), a.shape)
+        b = 1
+        while b < chunk:
+            l21 = ((r // (2 * b) == c // (2 * b)) & (r % (2 * b) >= b)
+                   & (c % (2 * b) < b))
+            mid = jnp.matmul(jnp.where(l21, low, 0.0), x, precision=HIGHEST)
+            x = x - jnp.matmul(x, mid, precision=HIGHEST)
+            b *= 2
+        return x * beta[..., None, :]
+
+
+# ---------------------------------------------------------------------------
+# stage 3: the chunks in order
+# ---------------------------------------------------------------------------
+
+def _state_kernel(q_ref, k_ref, v_ref, g_ref, m_ref, b_ref, *rest, chunk,
+                  mm_dtype, emit_states):
+    if emit_states:
+        o_ref, h_ref, st_ref = rest
+    else:
+        (o_ref, st_ref), h_ref = rest, None
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        st_ref[:] = jnp.zeros_like(st_ref)
+
+    st = st_ref[:]                              # S^T [dv, dk], f32
+    if emit_states:
+        h_ref[0, 0] = st
+    g = g_ref[0]                                # [C, dk] f32
+    gam = jnp.exp(g)
+    g_last = g[chunk - 1:chunk, :]              # [1, dk]
+    kf = k_ref[0].astype(jnp.float32)
+    kg = (kf * gam).astype(mm_dtype)
+    qg = (q_ref[0].astype(jnp.float32) * gam).astype(mm_dtype)
+    kd = (kf * jnp.exp(g_last - g)).astype(mm_dtype)
+    m = m_ref[0, 0].astype(mm_dtype)
+    sb = st.astype(mm_dtype)
+
+    def mm(x, y, dims):
+        return jax.lax.dot_general(x, y, (dims, ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    w = mm(m, kg, ((1,), (0,)))                 # [C, dk]
+    uv = mm(m, v_ref[0].astype(mm_dtype), ((1,), (0,)))
+    u = uv - mm(w.astype(mm_dtype), sb, ((1,), (1,)))        # [C, dv]
+    ub = u.astype(mm_dtype)
+    o = mm(qg, sb, ((1,), (1,))) + mm(b_ref[0, 0].astype(mm_dtype), ub,
+                                      ((1,), (0,)))
+    o_ref[0] = o.astype(o_ref.dtype)
+    st_ref[:] = st * jnp.exp(g_last) + mm(ub, kd, ((0,), (0,)))
+
+
+def _state_pallas(q, k, v, gc, m, b, *, emit_states, interpret, mm_dtype):
+    """-> o [BH, S, dv] (and the state S^T at every chunk's start,
+    [BH, S / C, dv, dk] float32)."""
+    bh, s, dk = q.shape
+    dv = v.shape[-1]
+    nc = s // CHUNK
+    row = lambda d: pl.BlockSpec((1, CHUNK, d), lambda i, c: (i, c, 0))
+    sq = pl.BlockSpec((1, 1, CHUNK, CHUNK), lambda i, c: (i, c, 0, 0))
+    out_specs = [row(dv)]
+    out_shape = [_sds((bh, s, dv), v.dtype, q, k, v, gc)]
+    if emit_states:
+        out_specs.append(pl.BlockSpec((1, 1, dv, dk),
+                                      lambda i, c: (i, c, 0, 0)))
+        out_shape.append(_sds((bh, nc, dv, dk), jnp.float32, q, k, v, gc))
+    out = pl.pallas_call(
+        functools.partial(_state_kernel, chunk=CHUNK, mm_dtype=mm_dtype,
+                          emit_states=emit_states),
+        grid=(bh, nc),
+        in_specs=[row(dk), row(dk), row(dv), row(dk), sq, sq],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(q, k, v, gc, m, b)
+    return (out[0], out[1]) if emit_states else (out[0], None)
+
+
+def _prepare(q, k, v, g, beta, mm_dtype):
+    """Stages 1-2 and the operands of stage 3 in jax.numpy, for chunks
+    [n, C, d]: (Qg, W, Uv, B, Kd, gamma). Differentiated by the backward."""
+    gc = jnp.cumsum(g, axis=1)
+    a, b = _intra_xla(q, k, gc, mm_dtype)
+    m = _ut_transform(a, beta).astype(mm_dtype)
+    gam = jnp.exp(gc)
+    kf = k.astype(jnp.float32)
+    mm = functools.partial(jnp.matmul, precision=HIGHEST,
+                           preferred_element_type=jnp.float32)
+    w = mm(m, (kf * gam).astype(mm_dtype))
+    uv = mm(m, v.astype(mm_dtype))
+    qg = q.astype(jnp.float32) * gam
+    kd = kf * jnp.exp(gc[:, -1:, :] - gc)
+    return qg, w, uv, b, kd, jnp.exp(gc[:, -1, :])
+
+
+def _states_xla(ops, mm_dtype):
+    """Stage 3 in jax.numpy over [BH, NC, ...] operands: o, and S at every
+    chunk's start."""
+    qg, w, uv, b, kd, gam = ops
+    bh, _, _, dk = qg.shape
+    dv = uv.shape[-1]
+    lo = lambda x: x.astype(mm_dtype)
+    mm = functools.partial(jnp.einsum, precision=HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+    def step(s, x):
+        qg, w, uv, b, kd, gam = x
+        sb = lo(s)
+        u = uv - mm("ncd,nde->nce", lo(w), sb)
+        o = mm("ncd,nde->nce", lo(qg), sb) + mm("ncj,nje->nce", lo(b), lo(u))
+        return s * gam[:, :, None] + mm("ncd,nce->nde", lo(kd), lo(u)), (o, s)
+
+    _, (o, h) = jax.lax.scan(step, jnp.zeros((bh, dk, dv), jnp.float32),
+                             jax.tree.map(lambda x: jnp.moveaxis(x, 1, 0),
+                                          (qg, w, uv, b, kd, gam)))
+    return jnp.moveaxis(o, 0, 1), jnp.moveaxis(h, 0, 1)
+
+
+# ---------------------------------------------------------------------------
+# the op
+# ---------------------------------------------------------------------------
+
+def _chunks(x):
+    bh, s = x.shape[:2]
+    return x.reshape(bh * (s // CHUNK), CHUNK, *x.shape[2:])
+
+
+def _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, emit_states):
+    bh, s, dk = q.shape
+    nc = s // CHUNK
+    if not pallas:
+        ops = _prepare(*(_chunks(x) for x in (q, k, v, g, beta)), mm_dtype)
+        ops = jax.tree.map(lambda x: x.reshape(bh, nc, *x.shape[1:]), ops)
+        o, h = _states_xla(ops, mm_dtype)
+        return (o.reshape(bh, s, -1).astype(v.dtype),
+                jnp.swapaxes(h, -1, -2))
+    gc = jnp.cumsum(g.reshape(bh, nc, CHUNK, dk), axis=2).reshape(bh, s, dk)
+    a, b = _intra_pallas(q, k, gc, interpret=interpret, mm_dtype=mm_dtype)
+    m = _ut_transform(a, beta.reshape(bh, nc, CHUNK))
+    return _state_pallas(q, k, v, gc, m, b, emit_states=emit_states,
+                         interpret=interpret, mm_dtype=mm_dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda(q, k, v, g, beta, pallas, interpret, mm_dtype):
+    return _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, False)[0]
+
+
+def _kda_fwd(q, k, v, g, beta, pallas, interpret, mm_dtype):
+    o, h = _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, True)
+    return o, (q, k, v, g, beta, h)
+
+
+def _kda_bwd(pallas, interpret, mm_dtype, res, do):
+    with jax.named_scope("kda_backward"):
+        return _backward(mm_dtype, res, do)
+
+
+def _backward(mm_dtype, res, do):
+    """One state per chunk (h, written by the forward); the cotangents of
+    stage 3 by hand, chunks in reverse; stages 1-2 differentiated by JAX,
+    BACKWARD_GROUP chunks of every head at a time."""
+    q, k, v, g, beta, h = res
+    bh, s, dk = q.shape
+    dv = v.shape[-1]
+    nc = s // CHUNK
+    group = min(BACKWARD_GROUP, nc)
+    while nc % group:
+        group -= 1
+    ng = nc // group
+    lo = lambda x: x.astype(mm_dtype)
+    mm = functools.partial(jnp.einsum, precision=HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+    def to_groups(x):     # [BH, NC, ..] -> [ng, BH * group, ..]
+        x = x.reshape(bh, ng, group, *x.shape[2:])
+        return jnp.moveaxis(x, 1, 0).reshape(ng, bh * group, *x.shape[3:])
+
+    def from_groups(x):   # [ng, BH * group, ..] -> [BH, NC, ..]
+        x = x.reshape(ng, bh, group, *x.shape[2:])
+        return jnp.moveaxis(x, 0, 1).reshape(bh, nc, *x.shape[3:])
+
+    def grouped(x):       # [BH, S, ..] -> [ng, BH * group, C, ..]
+        return to_groups(x.reshape(bh, nc, CHUNK, *x.shape[2:]))
+
+    xs = tuple(grouped(x) for x in (q, k, v, g, beta))
+    prepare = lambda *x: _prepare(*x, mm_dtype)
+    ops = jax.lax.map(lambda x: prepare(*x), xs)
+    qg, w, uv, b, kd, gam = jax.tree.map(from_groups, ops)
+    st = jnp.swapaxes(h, -1, -2)                 # S [BH, NC, dk, dv]
+    do = do.astype(jnp.float32).reshape(bh, nc, CHUNK, dv)
+
+    def step(ds, x):    # ds: cotangent of the state AFTER this chunk
+        qg, w, uv, b, kd, gam, s, do = x
+        sb = lo(s)
+        u = lo(uv - mm("ncd,nde->nce", lo(w), sb))
+        du = mm("ncj,nce->nje", lo(b), lo(do)) + mm("ncd,nde->nce", lo(kd),
+                                                    lo(ds))
+        d_ops = (mm("nce,nde->ncd", lo(do), sb),            # d Qg
+                 -mm("nce,nde->ncd", lo(du), sb),           # d W
+                 du,                                        # d Uv
+                 mm("nce,nje->ncj", lo(do), u),             # d B
+                 mm("nce,nde->ncd", u, lo(ds)),             # d Kd
+                 jnp.sum(ds * s, axis=-1))                  # d gamma
+        ds = (mm("ncd,nce->nde", lo(qg), lo(do)) + ds * gam[:, :, None]
+              - mm("ncd,nce->nde", lo(w), lo(du)))
+        return ds, d_ops
+
+    chunks_first = lambda t: jax.tree.map(lambda x: jnp.moveaxis(x, 1, 0), t)
+    _, d_ops = jax.lax.scan(step, jnp.zeros((bh, dk, dv), jnp.float32),
+                            chunks_first((qg, w, uv, b, kd, gam, st, do)),
+                            reverse=True)
+    d_ops = jax.tree.map(lambda x: to_groups(jnp.moveaxis(x, 0, 1)), d_ops)
+
+    def back(x):
+        ins, cts = x
+        return jax.vjp(prepare, *ins)[1](cts)
+
+    grads = jax.lax.map(back, (xs, d_ops))
+
+    return tuple(from_groups(x).reshape(like.shape).astype(like.dtype)
+                 for x, like in zip(grads, (q, k, v, g, beta)))
+
+
+_kda.defvjp(_kda_fwd, _kda_bwd)
+
+
+def _kernels() -> tuple[bool, bool]:
+    """(pallas, interpret): the kernels where they compile (a TPU target) or
+    where the interpreter was asked for, jax.numpy elsewhere."""
+    if FORCE_INTERPRET:
+        return True, True
+    return pallas_compat.target_platform() == "tpu", False
+
+
+def chunk_kda(q, k, v, g, beta, *, mm_dtype=jnp.bfloat16):
+    """q, k [B, S, H, dk] (q scaled, both l2-normalised by the caller),
+    v [B, S, H, dv], g [B, S, H, dk] float32 log-decay (<= 0), beta
+    [B, S, H] -> o [B, S, H, dv]. S is padded to a multiple of 64 with
+    positions that change nothing (g = 0, beta = 0, k = 0)."""
+    b, s, h, dk = q.shape
+    pad = -s % CHUNK
+    heads_first = lambda x: jnp.moveaxis(x, 2, 1).reshape(
+        b * h, s, *x.shape[3:])
+    args = [heads_first(x) for x in (q, k, v, g.astype(jnp.float32),
+                                     beta.astype(jnp.float32))]
+    if pad:
+        args = [jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                for x in args]
+    o = _kda(*args, *_kernels(), jnp.dtype(mm_dtype))
+    o = o[:, :s].reshape(b, h, s, -1)
+    return jnp.moveaxis(o, 1, 2)

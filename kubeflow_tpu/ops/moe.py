@@ -18,15 +18,35 @@ equivalent, in the GShard/Switch formulation that XLA shards well:
     z-loss for logit stability.
 
 Everything is jit/scan/remat-safe (pure functions, static shapes).
+
+A second formulation beside it, `moe_share_mlp`, drops nothing and knows its
+share: a sigmoid router over ALL the experts of the layer (top-k of score +
+bias, weights renormalised over the chosen and scaled), the assignments
+sorted by expert, the rows of the experts HELD HERE gathered, one grouped
+matmul per projection over the experts held (megablox `gmm` on a TPU target,
+which visits only tiles that hold rows; `jax.lax.ragged_dot` elsewhere), and
+the results scattered back with their weights. What the experts held
+elsewhere would add is left out: that partial sum is the layer's output on
+one expert-parallel rank before the exchange, and no code stands in for the
+exchange. Shapes stay static at any imbalance because the row buffer is sized
+for the worst case (every assignment held here); a step whose rows fit the
+eighth of it that balanced routing needs takes the small buffer instead
+(`lax.cond`), so nothing is dropped by construction and little is moved.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+
+from kubeflow_tpu.ops import pallas_compat
+
+# Tests on the CPU set this to run megablox under the Pallas interpreter.
+FORCE_INTERPRET = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,3 +136,148 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
     # combine back: [T,E,C] x [E,C,D] -> [T,D]
     out = jnp.einsum("tec,ecd->td", combine.astype(dtype), expert_out)
     return out.reshape(b, s, d), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless routing over a share of the experts
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class ShareArgs:
+    n_router_experts: int          # the router's width: all experts
+    top_k: int
+    n_held: int                    # experts whose weights are here
+    first_expert: int = 0          # the first of them, in the router's order
+    scale: float = 1.0             # routed_scaling_factor
+    renormalize: bool = True       # weights / their sum over the chosen
+
+
+ROW_TILE = 256    # the grouped matmul's tile of rows
+
+
+def sigmoid_route(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
+                  args: ShareArgs):
+    """x [T, D] -> (expert ids [T, k], weights [T, k] float32): top-k of
+    sigmoid(x Wr) + bias over all experts; the weight is the score without
+    the bias, renormalised over the chosen, times the scale. Float32 at the
+    highest precision: the choice is discontinuous, so rounding must not
+    make it."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(
+        router_bias.astype(jnp.float32)), args.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    if args.renormalize:
+        w = w / jnp.sum(w, axis=1, keepdims=True)
+    return idx, w * args.scale
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    def tile(size, most):
+        return max(t for t in range(128, most + 1, 128) if size % t == 0)
+    return ROW_TILE, tile(k, 768), tile(n, 1024)
+
+
+def _grouped_matmul(rows, w, group_sizes, dtype):
+    """rows [M, K] sorted by group, w [G, K, N], group_sizes [G + 1] (the
+    last group is the rows no expert here takes: they come out zero)."""
+    interpret = FORCE_INTERPRET
+    if interpret or pallas_compat.target_platform() == "tpu":
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        return megablox.gmm(rows, w.astype(dtype), group_sizes, dtype,
+                            _gmm_tiling, None, None, False, interpret)
+    sizes = group_sizes[:-1]
+    out = jax.lax.ragged_dot(rows, w.astype(dtype), sizes,
+                             preferred_element_type=dtype)
+    live = jnp.arange(rows.shape[0]) < jnp.sum(sizes)
+    return jnp.where(live[:, None], out, 0)
+
+
+def moe_share_mlp(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
+                  w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                  args: ShareArgs, dtype: Any = jnp.bfloat16):
+    """The routed experts' part of a layer, as the rank that holds experts
+    [first_expert, first_expert + n_held) computes it.
+
+    x [B, S, D]; router_w [D, E_all]; router_bias [E_all] (a buffer: no
+    gradient); w_gate / w_up [n_held, D, F]; w_down [n_held, F, D]. Returns
+    (out [B, S, D], counters): `rows_here` the assignments this rank took,
+    `rows_dropped` those it took and did not compute (0, or the step is
+    wrong), `load_max_over_mean` over the experts held,
+    `top1_share_max` the largest share of tokens whose first choice is one
+    expert, over all experts."""
+    b, s, d = x.shape
+    t, k, held = b * s, args.top_k, args.n_held
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe_route"):
+        idx, w = sigmoid_route(xt, router_w, router_bias, args)
+        local = idx - args.first_expert
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        total = -(-t * k // ROW_TILE) * ROW_TILE
+        key = jnp.pad(key.reshape(t * k), (0, total - t * k),
+                      constant_values=held)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(key, held + 1, dtype=jnp.int32),
+                        axis=0)
+        rows_here = jnp.sum(sizes[:held])
+        w_flat = jnp.pad(w.reshape(t * k), (0, total - t * k))
+
+    # the sorted rows go through the experts a slice at a time: the first
+    # slice holds what balanced routing sends here eight times over; the
+    # others run only when the rows reach them, under a rematerialised scan,
+    # so the worst case costs no memory until it happens
+    m = -(-max(total // 8, 1) // ROW_TILE) * ROW_TILE
+    n_slices = -(-total // m)
+    order = jnp.pad(order, (0, n_slices * m - total),
+                    constant_values=t * k)      # past every row: no expert
+    starts = jnp.cumsum(sizes[:held]) - sizes[:held]
+
+    def slice_out(lo, xt, w_flat, w_gate, w_up, w_down):
+        """Rows [lo, lo + m) of the sorted order -> their part of [T, D]
+        (float32) and how many of them an expert here computed."""
+        sel = jax.lax.dynamic_slice_in_dim(order, lo, m)
+        tok = jnp.minimum(sel // k, t - 1)
+        rows = xt[tok].astype(dtype)
+        here = (jnp.clip(starts + sizes[:held], lo, lo + m)
+                - jnp.clip(starts, lo, lo + m))
+        # the order is sorted, so a slice is experts' rows (its first one
+        # possibly the tail of an expert's) and then rows no expert here
+        # takes: the groups start at the slice's first row
+        groups = jnp.concatenate([here, (m - jnp.sum(here))[None]])
+        mm = functools.partial(_grouped_matmul, group_sizes=groups,
+                               dtype=dtype)
+        gate, up = mm(rows, w_gate), mm(rows, w_up)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(dtype)
+        wt = jnp.take(w_flat, jnp.minimum(sel, total - 1))
+        y = mm(act, w_down).astype(jnp.float32) * wt[:, None]
+        return (jnp.zeros((t, d), jnp.float32).at[tok].add(y),
+                jnp.sum(here))
+
+    operands = (xt, w_flat, w_gate, w_up, w_down)
+    with jax.named_scope("moe_experts"):
+        out, done = slice_out(0, *operands)
+        if n_slices > 1:
+            def rest(out, done, *operands):
+                def body(carry, lo):
+                    o, n = jax.checkpoint(slice_out)(lo, *operands)
+                    return (carry[0] + o, carry[1] + n), None
+                return jax.lax.scan(body, (out, done),
+                                    m * jnp.arange(1, n_slices))[0]
+            out, done = jax.lax.cond(
+                rows_here > m, rest, lambda out, done, *_: (out, done),
+                out, done, *operands)
+        out, dropped = out.astype(dtype), rows_here - done
+    load = sizes[:held].astype(jnp.float32)
+    first = jnp.sum(jax.nn.one_hot(idx[:, 0], args.n_router_experts,
+                                   dtype=jnp.int32), axis=0)
+    counters = {
+        "rows_here": rows_here.astype(jnp.float32),
+        "rows_dropped": dropped.astype(jnp.float32),
+        "load_max_over_mean": jnp.max(load) / jnp.maximum(jnp.mean(load),
+                                                          1e-9),
+        "top1_share_max": jnp.max(first).astype(jnp.float32) / t,
+    }
+    return out.reshape(b, s, d), counters

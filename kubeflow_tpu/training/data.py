@@ -106,7 +106,7 @@ def array_dataset(arrays: dict[str, np.ndarray], batch_size: int,
 def for_model(model: str, model_cfg, batch_size: int, seq_len: int = 128,
               seed: int = 0) -> Iterator[dict[str, Any]]:
     """Default synthetic stream for a registered model (bench/HPO/test path)."""
-    if model in ("llama", "llama_lora", "mixtral"):
+    if model in ("llama", "llama_lora", "mixtral", "kimi_linear"):
         return synthetic_tokens(batch_size, seq_len, model_cfg.vocab_size, seed)
     if model == "bert":
         return synthetic_classification_text(

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 
 from kubeflow_tpu.models import llama
-from kubeflow_tpu.ops import (flash_decode, flash_pallas, flash_prefill,
+from kubeflow_tpu.ops import (flash_decode, flash_pallas, flash_prefill, kda,
                               pallas_compat, quant_matmul)
 
 #: pallas_call sites per ops module that this file lowers for TPU.
@@ -30,6 +30,7 @@ PALLAS_CALL_SITES = {
     "flash_prefill": 1,
     "quant_matmul": 1,   # one call site, two entries: both lowered below
     "flash_pallas": 3,
+    "kda": 2,
 }
 
 # chip_smoke.py's serving shapes: Llama-3-8B heads, 16 slots x 2048
@@ -144,6 +145,35 @@ def test_flash_pallas_forward_and_backward_lower():
     assert mosaic_calls(fwd, q, q, q) == 1
     # value_and_grad: the forward kernel, then the dq and the dk/dv kernels
     assert mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 3
+
+
+def test_flash_pallas_lowers_with_qk_192_beside_v_128():
+    # latent attention at the Kimi-Linear cut: 2 rows of 8192, 32 heads
+    q = sds((2, 8192, 32, 192), jnp.bfloat16)
+    v = sds((2, 8192, 32, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_pallas.pallas_flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32))
+
+    assert mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, v) == 3
+
+
+@pytest.mark.parametrize("emit_states", [False, True])
+def test_kda_kernels_lower(emit_states):
+    # the Kimi-Linear cut: 2 rows x 32 heads of 8192 positions, dk = dv = 128
+    rows = sds((64, 8192, 128), jnp.bfloat16)
+    decay = sds((64, 8192, 128), jnp.float32)
+    square = sds((64, 128, 64, 64), jnp.float32)
+    assert mosaic_calls(
+        lambda q, k, gc: kda._intra_pallas(
+            q, k, gc, interpret=False, mm_dtype=jnp.bfloat16),
+        rows, rows, decay) == 1
+    assert mosaic_calls(
+        lambda q, k, v, gc, m, b: kda._state_pallas(
+            q, k, v, gc, m, b, emit_states=emit_states, interpret=False,
+            mm_dtype=jnp.bfloat16),
+        rows, rows, rows, decay, square, square) == 1
 
 
 def test_unsupported_head_dim_is_refused_at_engine_construction(
