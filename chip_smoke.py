@@ -354,16 +354,20 @@ def kernel_parity(jax) -> dict:
         return float(np.abs(got - want).max() / np.abs(want).max())
 
     errs = {}
-    span = 512
-    kq, ks = llama.quantize_kv(normal(4, span, nkv, hd))
-    vq, vs = llama.quantize_kv(normal(4, span, nkv, hd))
+    span = 1024
+    # the cache as the layer scan carries it: 3 layers of 4 slots, the
+    # int8 payloads with their scale planes lane-major; layer 1 is read
+    kq, ks = llama.quantize_kv(normal(3, 4, span, nkv, hd))
+    vq, vs = llama.quantize_kv(normal(3, 4, span, nkv, hd))
+    cache = {"k": kq, "v": vq, "k_s": jnp.swapaxes(ks, 2, 3),
+             "v_s": jnp.swapaxes(vs, 2, 3)}
     for s_v in (1, 7):   # plain decode; verify of `speculative: 6`
-        lengths = jnp.asarray([span - s_v, 0, 131, 300], jnp.int32)
+        lengths = jnp.asarray([span - s_v, 0, 131, 700], jnp.int32)
         positions = lengths[:, None] + jnp.arange(s_v)[None]
         q = normal(4, s_v, nh, hd).astype(dt)
         errs[f"decode_int8_sv{s_v}"] = rel_err(*(
             jax.jit(lambda *a, impl=impl: llama.decode_attention(
-                cfg, *a, impl=impl))(q, kq, vq, ks, vs, positions)
+                cfg, *a, impl=impl))(q, cache, jnp.int32(1), positions)
             for impl in ("xla", "flash")))
     for s, t, q_offset in ((256, 256, 0), (128, 384, 256)):
         q, k, v = (normal(2, n, h, hd).astype(dt)

@@ -45,13 +45,14 @@ def make_block_pool_buffers(n_layers: int, n_blocks: int, block_tokens: int,
                             n_kv_heads: int, head_dim: int, dtype: Any,
                             kv_quantize: str | None = None) -> dict:
     """Mint the pool's device arrays: k/v `[L, N, bt, kv, hd]` (+ f32
-    per-token scales `[L, N, bt, kv]` when int8). kvcache-internal —
-    everything else goes through `BlockPool.device_buffers()`."""
+    per-token scales, lane-major `[L, N, kv, bt]` as in llama.init_cache,
+    when int8). kvcache-internal — everything else goes through
+    `BlockPool.device_buffers()`."""
     import jax.numpy as jnp
 
     shape = (n_layers, n_blocks, block_tokens, n_kv_heads, head_dim)
     if kv_quantize == "int8":
-        sshape = shape[:-1]
+        sshape = (n_layers, n_blocks, n_kv_heads, block_tokens)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
                 "k_s": jnp.zeros(sshape, jnp.float32),

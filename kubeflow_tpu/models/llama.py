@@ -405,14 +405,31 @@ def _chunked_ce_loss(params: Params, batch: dict[str, jax.Array],
 
 def init_cache(cfg: LlamaConfig, n_slots: int, max_len: int,
                kv_quantize: str | None = None) -> Params:
+    """The slab KV cache: payload `[L, slots, max_len, kv_heads, hd]`;
+    int8 payloads come with their per-token-per-head f32 scales stored
+    LANE-MAJOR, `[L, slots, kv_heads, max_len]`: the layout the decode
+    kernel reads in place (ops/flash_decode.py), dense under the TPU's
+    (8, 128) tiling where `[..., max_len, kv_heads]` pads 8 lanes to 128."""
     shape = (cfg.n_layers, n_slots, max_len, cfg.n_kv_heads, cfg.head_dim)
     if kv_quantize == "int8":
-        sshape = shape[:-1]
+        sshape = (cfg.n_layers, n_slots, cfg.n_kv_heads, max_len)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
                 "k_s": jnp.zeros(sshape, jnp.float32),
                 "v_s": jnp.zeros(sshape, jnp.float32)}
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def cache_kv_spec(name: str, axis: str = "tensor"):
+    """PartitionSpec that shards cache array `name` ("k", "v", "k_s",
+    "v_s") over its kv-head dimension: dim 3 of the 5-D payloads, dim 2
+    of the lane-major scale planes. No trailing None: GSPMD emits the
+    trimmed spec on program outputs and the jit cache compares specs
+    structurally."""
+    from jax.sharding import PartitionSpec as P
+
+    return (P(None, None, axis) if name.endswith("_s")
+            else P(None, None, None, axis))
 
 
 def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -632,34 +649,36 @@ def prefill_continue_inner(layers: Params, x: jax.Array,
 def decode_step(params: Params, last_tokens: jax.Array, cache: Params,
                 lengths: jax.Array, cfg: LlamaConfig,
                 span: int | None = None, lora: Params | None = None,
-                ids: jax.Array | None = None):
+                ids: jax.Array | None = None,
+                active: jax.Array | None = None):
     """One continuous-batching decode step over all cache slots.
 
     last_tokens: [B] token per slot; lengths: [B] current KV lengths
     (position where this step's KV is written). Returns
     (logits [B, vocab] fp32, updated cache). Inactive slots just produce
-    garbage logits the engine ignores — shapes stay static.
+    garbage logits the engine ignores — shapes stay static; with `active`
+    [B] bool they also attend nothing (verify_inner).
 
     `span` (static) bounds the attention to the cache's first `span` rows —
-    the length-aware decode menu (serving/llm.py): when every active length
-    is < span, attending over max_len would read/compute against rows the
-    mask discards anyway. Decode is HBM-bound on those KV reads at long
-    max_len, so the slice is the throughput lever. Caller guarantees
-    lengths < span; writes still land in the full cache.
+    the length-aware decode menu (serving/llm.py). The einsum path reads
+    and computes the whole span; the flash kernel only the blocks a
+    slot's length reaches, so there it sets the grid's length alone.
+    Caller guarantees lengths < span; writes still land in the full cache.
 
     This IS verify_step at S_v=1 — one attention body, so a masking or
     quantization change can never diverge the plain and speculative paths.
     """
     logits, new_cache = verify_step(params, last_tokens[:, None], cache,
                                     lengths, cfg, span=span, lora=lora,
-                                    ids=ids)
+                                    ids=ids, active=active)
     return logits[:, 0], new_cache
 
 
 def verify_step(params: Params, tokens: jax.Array, cache: Params,
                 lengths: jax.Array, cfg: LlamaConfig,
                 span: int | None = None, lora: Params | None = None,
-                ids: jax.Array | None = None):
+                ids: jax.Array | None = None,
+                active: jax.Array | None = None):
     """Speculative-verify step: forward S_v tokens per slot in ONE pass.
 
     tokens: [B, S_v] — row b holds the slot's pending last token followed by
@@ -686,7 +705,8 @@ def verify_step(params: Params, tokens: jax.Array, cache: Params,
         cache_keys = cache_keys + ("tbl",)
     cache_in = {name: cache[name] for name in cache_keys}
     x, new_cache = verify_inner(params["layers"], x, cache_in, lengths,
-                                cfg, span=span, lora=lora, ids=ids)
+                                cfg, span=span, lora=lora, ids=ids,
+                                active=active)
     return lm_head(params, x, cfg), new_cache
 
 
@@ -774,46 +794,87 @@ def prefill_attention(cfg: LlamaConfig, q: jax.Array, k: jax.Array,
                q_offset=q_offset)
 
 
-def decode_attention(cfg: LlamaConfig, q: jax.Array, ck: jax.Array,
-                     cv: jax.Array, cks, cvs, positions: jax.Array,
+def decode_attention(cfg: LlamaConfig, q: jax.Array, cache: Params,
+                     layer, positions: jax.Array, *,
+                     span: int | None = None, slot_start: int = 0,
                      impl: str | None = None,
-                     tables: jax.Array | None = None) -> jax.Array:
-    """Grouped-query decode/verify attention over a span-sliced KV cache
-    slab — THE pluggable seam of the serving hot loop (ISSUE 15).
+                     tables: jax.Array | None = None, new_scales=None):
+    """Grouped-query decode/verify attention of ONE layer over the KV
+    cache as the layer scan carries it — THE pluggable seam of the
+    serving hot loop (ISSUE 15; in place since ISSUE 28).
 
-    q: [B, S_v, nh, hd] (post-RoPE, cfg.dtype); ck/cv: [B, span, kv, hd]
-    in cache dtype (int8 or cfg.dtype) with cks/cvs [B, span, kv] f32
-    per-token scales when int8 (None otherwise); positions: [B, S_v]
-    absolute key positions of the query rows — row i MUST sit at
-    positions[:, 0] + i (the decode/verify contract; the flash kernel
-    exploits it). Key t is visible to row i iff t <= positions[:, i].
-    Returns [B, S_v, nh*hd] attention output in cfg.dtype.
+    q: [B, S_v, nh, hd] (post-RoPE, cfg.dtype); cache: {"k", "v"} the
+    whole payloads `[L, slots, max_len, kv, hd]` in cache dtype (int8 or
+    cfg.dtype), with {"k_s", "v_s"} `[L, slots, kv, max_len]` f32
+    per-token scales when int8; `layer`: which of the L (the scan's
+    index, traced); the B rows are cache slots `slot_start ..
+    slot_start + B - 1` and attend the first `span` rows (static; the
+    whole of max_len by default); positions: [B, S_v] absolute key
+    positions of the query rows — row i MUST sit at positions[:, 0] + i
+    (the decode/verify contract; the flash kernel exploits it). Key t is
+    visible to row i iff t <= positions[:, i]. Returns [B, S_v, nh*hd]
+    attention output in cfg.dtype.
 
-    impl: "xla" — the reference einsum path (dequant fused into the
-    einsum operands, f32 softmax); "flash" — the fused Pallas kernel
-    (ops/flash_decode.py; interpret-mode off-TPU, so the differential
-    tests run on CPU); None resolves cfg.decode_attention_impl.
+    impl: "flash" — the fused Pallas kernel, which takes the arrays
+    whole: its index maps pick layer, slot window and the blocks a
+    slot's context reaches (ops/flash_decode.py; interpret-mode off-TPU,
+    so the differential tests run on CPU); "xla" — the reference einsum
+    path (dequant fused into the einsum operands, f32 softmax), which
+    slices its layer and span out here; None resolves
+    cfg.decode_attention_impl.
 
-    PAGED mode (ISSUE 19): with `tables` [B, span//bt] int32, ck/cv are
-    the POOL layer `[N_blocks, bt, kv, hd]` (cks/cvs `[N_blocks, bt,
-    kv]`) and row b's logical span is the concatenation of its table's
-    blocks. The flash kernel indirects its kv-block grid axis through
-    the scalar-prefetched table; the XLA path gathers the same blocks
-    into the contiguous slab view and falls into the identical einsum —
-    the parity anchor that makes slab vs paged byte-comparable.
+    PAGED mode (ISSUE 19): with `tables` [B, span//bt] int32 (this
+    batch's rows, clipped to the span), the payloads are the POOL
+    `[L, N_blocks, bt, kv, hd]` (scales `[L, N_blocks, kv, bt]`) and row
+    b's logical span is the concatenation of its table's blocks. The
+    flash kernel indirects its kv-block grid axis through the
+    scalar-prefetched table; the XLA path gathers the same blocks into
+    the contiguous slab view and falls into the identical einsum — the
+    parity anchor that makes slab vs paged byte-comparable.
+
+    `new_scales` (flash, int8): the (k, v) `[B, S_v, kv]` scales of the
+    rows this step wrote at `positions`, which the planes do not hold
+    yet: the kernel attends with them and stores them, and the return is
+    `(out, k_s, v_s)` (ops/flash_decode.py says why it is the kernel's
+    to do).
     """
     if impl is None:
         impl = resolve_decode_attn(cfg)
     b, s_v = q.shape[:2]
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    quantized = "k_s" in cache
     if impl == "flash":
         from kubeflow_tpu.ops.flash_decode import flash_decode_attention
 
-        out = flash_decode_attention(q, ck, cv, positions[:, 0],
-                                     k_scale=cks, v_scale=cvs,
-                                     scale=1.0 / (hd ** 0.5),
-                                     tables=tables)
-        return out.reshape(b, s_v, nh * hd)
+        out = flash_decode_attention(
+            q, cache["k"], cache["v"], positions[:, 0], layer=layer,
+            span=span, slot_start=slot_start, k_scale=cache.get("k_s"),
+            v_scale=cache.get("v_s"), new_scales=new_scales,
+            scale=1.0 / (hd ** 0.5), tables=tables)
+        if new_scales is None:
+            return out.reshape(b, s_v, nh * hd)
+        return (out[0].reshape(b, s_v, nh * hd), *out[1:])
+    if new_scales is not None:
+        raise ValueError("new_scales are the flash kernel's to store")
+
+    def layer_rows(name):
+        # index the layer FIRST, then slice: the other order would stage
+        # an [L, B, span, ...] temp of the whole cache
+        rows = jax.lax.dynamic_index_in_dim(cache[name], layer, axis=0,
+                                            keepdims=False)
+        if tables is not None:   # pool layer: the TABLE does the slicing
+            return rows
+        if slot_start or rows.shape[0] != b:   # a microbatch's window
+            rows = jax.lax.slice_in_dim(rows, slot_start, slot_start + b,
+                                        axis=0)
+        t_axis = 2 if name.endswith("_s") else 1
+        return jax.lax.slice_in_dim(
+            rows, 0, rows.shape[t_axis] if span is None else span,
+            axis=t_axis)
+
+    ck, cv = layer_rows("k"), layer_rows("v")
+    cks, cvs = ((layer_rows("k_s"), layer_rows("v_s")) if quantized
+                else (None, None))
     if tables is not None:
         # XLA gather twin: jnp.take stages the table's blocks as the
         # [B, span, kv, hd] slab view (the transient copy the
@@ -823,38 +884,36 @@ def decode_attention(cfg: LlamaConfig, q: jax.Array, ck: jax.Array,
         bt, nb = ck.shape[1], tables.shape[1]
         ck = jnp.take(ck, tables, axis=0).reshape(b, nb * bt, nkv, hd)
         cv = jnp.take(cv, tables, axis=0).reshape(b, nb * bt, nkv, hd)
-        if cks is not None:
-            cks = jnp.take(cks, tables, axis=0).reshape(b, nb * bt, nkv)
-            cvs = jnp.take(cvs, tables, axis=0).reshape(b, nb * bt, nkv)
+        if quantized:   # [b, nb, kv, bt] -> [b, kv, span]
+            cks, cvs = (jnp.swapaxes(jnp.take(sc, tables, axis=0), 1, 2)
+                        .reshape(b, nkv, nb * bt) for sc in (cks, cvs))
     # XLA reference: grouped-query attention WITHOUT repeat_kv — q
     # regroups to [B, kv, g, Sv, hd] and both einsums contract against
     # the [B, span, kv, hd] cache directly; materializing the 4x
     # head-expanded K/V (and, when quantized, a dequantized copy) would
     # add GiB-scale HBM traffic per step at 8B dims. The int8 cache
     # dequant stays INSIDE the einsum operand (convert + scale fuse into
-    # the dot read); scales apply to the score/output instead of the
-    # payload where the algebra allows.
+    # the dot read); scales ([B, kv, span]) apply to the score/output
+    # instead of the payload where the algebra allows.
     g = nh // nkv
-    span = ck.shape[1]
-    k_pos = jnp.arange(span)
+    k_pos = jnp.arange(ck.shape[1])
     mask = (k_pos[None, None, None, :]
             <= positions[:, None, :, None])  # [B, 1, Sv, span]
     qg = jnp.moveaxis(q.reshape(b, s_v, nkv, g, hd), 1, 3)
-    if cks is not None:
+    if quantized:
         att = jnp.einsum("bhgqd,bkhd->bhgqk", qg, ck.astype(cfg.dtype),
                          preferred_element_type=jnp.float32)
-        att = att * jnp.moveaxis(cks, -1, 1)[:, :, None, None, :]
+        att = att * cks[:, :, None, None, :]
     else:
         att = jnp.einsum("bhgqd,bkhd->bhgqk", qg, ck,
                          preferred_element_type=jnp.float32)
     att = att * (1.0 / (hd ** 0.5))
     att = jnp.where(mask[:, :, None], att, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
-    if cvs is not None:
+    if quantized:
         # v = vq * vs[..., None]: fold vs into probs' k axis so the
         # int8 payload feeds the dot un-materialized
-        probs_s = probs * jnp.moveaxis(cvs, -1, 1)[
-            :, :, None, None, :].astype(probs.dtype)
+        probs_s = probs * cvs[:, :, None, None, :].astype(probs.dtype)
         out = jnp.einsum("bhgqk,bkhd->bqhgd", probs_s,
                          cv.astype(cfg.dtype))
     else:
@@ -865,16 +924,20 @@ def decode_attention(cfg: LlamaConfig, q: jax.Array, ck: jax.Array,
 def verify_inner(layers: Params, x: jax.Array, cache: Params,
                  lengths: jax.Array, cfg: LlamaConfig,
                  span: int | None = None, lora: Params | None = None,
-                 ids: jax.Array | None = None, slot_start: int = 0):
+                 ids: jax.Array | None = None, slot_start: int = 0,
+                 active: jax.Array | None = None):
     """Layer-slab half of verify_step: x [B, S_v, D] activations through
     a contiguous slab of layers against that slab's KV cache →
     (x, new_cache). The cache may hold MORE slots than x carries rows:
     `slot_start` names the first cache slot this batch occupies (the
     pipeline stage runner decodes one microbatch of slots at a time
     against the stage's full-slot cache slab; the single-program path
-    always passes the full batch at slot_start 0, where the slicing is
-    the identity). `lengths` is per-ROW of x (already sliced to the
-    microbatch)."""
+    always passes the full batch at slot_start 0). `lengths` is per-ROW
+    of x (already sliced to the microbatch). `active` [B] bool, where
+    the caller has it: a row that is not attends NOTHING (a finished
+    slot keeps a stale length, and its old context is not worth
+    fetching); its KV write and RoPE positions stay as `lengths` has
+    them, so junk writes still vanish (mode="drop")."""
     b, s_v = x.shape[:2]
     paged = "tbl" in cache
     if paged:
@@ -893,10 +956,8 @@ def verify_inner(layers: Params, x: jax.Array, cache: Params,
     quantized = "k_s" in cache
     rows = slot_start + jnp.arange(b)
     positions = lengths[:, None] + jnp.arange(s_v)[None]  # [B, S_v]
-    # drop mode: inactive slots can carry lengths near max_len — their junk
-    # writes must vanish, not clamp onto the last live row
-    idx = (rows[:, None], positions)
-    full_batch = slot_start == 0 and cache["k"].shape[1] == b
+    attn_positions = positions if active is None else jnp.where(
+        active[:, None], positions, jnp.arange(s_v)[None] - s_v)
     if paged:
         if span % bt:
             raise ValueError(
@@ -911,13 +972,16 @@ def verify_inner(layers: Params, x: jax.Array, cache: Params,
         pos_c = jnp.minimum(positions, max_len - 1)
         blk = jnp.where(positions < max_len,
                         tbl[rows[:, None], pos_c // bt], 0)
-        w_idx = (blk, positions % bt)
+        w_row, w_pos = blk, positions % bt
     else:
-        w_idx = idx
+        # drop mode: inactive slots can carry lengths near max_len —
+        # their junk writes must vanish, not clamp onto the last live row
+        w_row, w_pos = rows[:, None], positions
     # resolved ONCE per trace (static): the whole compiled menu of an
     # engine runs one decode-attention impl — xla einsum or the fused
     # Pallas flash-decode kernel (cfg.decode_attention_impl)
     attn_impl = resolve_decode_attn(cfg)
+    kernel_stores = quantized and attn_impl == "flash"
 
     # The KV cache rides the scan as CARRY (not xs/ys): a per-layer
     # dynamic-update-slice on the carried buffer updates S_v rows in
@@ -937,32 +1001,31 @@ def verify_inner(layers: Params, x: jax.Array, cache: Params,
         else:
             writes = {"k": k_new.astype(cache_c["k"].dtype),
                       "v": v_new.astype(cache_c["v"].dtype)}
-        cache_c = {
-            name: buf.at[(li,) + w_idx].set(writes[name], mode="drop")
-            for name, buf in cache_c.items()}
-        def layer_span(name):
-            # index the layer FIRST, then slice the span: the other order
-            # would stage an [L, B, span, ...] temp of the whole cache
-            rows_all = jax.lax.dynamic_index_in_dim(
-                cache_c[name], li, axis=0, keepdims=False)
-            if paged:            # pool layer [N, bt, ...]: the TABLE does
-                return rows_all  # the span slicing (tbl_b is span-clipped)
-            if not full_batch:   # microbatch: this batch's slot window
-                rows_all = jax.lax.slice_in_dim(
-                    rows_all, slot_start, slot_start + b, axis=0)
-            return jax.lax.slice_in_dim(rows_all, 0, span, axis=1)
-
-        # attention over the slab rides the pluggable decode_attention
-        # seam: the xla einsum reference or the fused Pallas flash-decode
-        # kernel, per cfg.decode_attention_impl — ONE body for plain
+        # payload rows are [.., token, kv, hd], scale rows [.., kv, token]:
+        # the einsum path scatters both here, the flash kernel stores the
+        # step's scales itself (it holds the block they land in)
+        cache_c = dict(cache_c)
+        for name in ("k", "v"):
+            cache_c[name] = cache_c[name].at[li, w_row, w_pos].set(
+                writes[name], mode="drop")
+        if quantized and not kernel_stores:
+            for name in ("k_s", "v_s"):
+                cache_c[name] = cache_c[name].at[li, w_row, :, w_pos].set(
+                    writes[name], mode="drop")
+        # attention rides the pluggable decode_attention seam over the
+        # carry itself: the xla einsum reference slices its layer and
+        # span out, the fused Pallas flash-decode kernel reads them in
+        # place, per cfg.decode_attention_impl — ONE body for plain
         # decode (S_v=1) and speculative verify, so the impls can never
         # diverge the two paths
         out = decode_attention(
-            cfg, q, layer_span("k"), layer_span("v"),
-            layer_span("k_s") if quantized else None,
-            layer_span("v_s") if quantized else None,
-            positions, impl=attn_impl,
-            tables=tbl_b if paged else None)
+            cfg, q, cache_c, li, attn_positions, span=span,
+            slot_start=slot_start, impl=attn_impl,
+            tables=tbl_b if paged else None,
+            new_scales=(ksc, vsc) if kernel_stores else None)
+        if kernel_stores:
+            out, k_s, v_s = out
+            cache_c = dict(cache_c, k_s=k_s, v_s=v_s)
         x = x + _wo(cfg, out, layer, ll, ids)
         x = _serving_mlp(cfg, x, layer, ll, ids)
         return (x, cache_c), None

@@ -135,6 +135,14 @@ ENGINE_DEVICE_EMPTY_SECONDS = REGISTRY.counter(
     "serving_engine_device_empty_seconds_total",
     "Wall with nothing dispatched and unfetched: a floor under the "
     "device's idle time", ["engine"])
+ENGINE_KV_BLOCKS_FETCHED = REGISTRY.counter(
+    "serving_engine_kv_blocks_fetched_total",
+    "KV blocks the decode dispatches' slot lengths let the attention "
+    "kernel copy", ["engine"])
+ENGINE_KV_BLOCKS_SPANNED = REGISTRY.counter(
+    "serving_engine_kv_blocks_spanned_total",
+    "KV blocks the decode dispatches' attention grids covered (slots x "
+    "span / block)", ["engine"])
 ENGINE_STALLS = REGISTRY.counter(
     "serving_engine_stalls_total",
     "Single non-idle phase occurrences of 500 ms or more",

@@ -292,6 +292,7 @@ class PhaseMark(NamedTuple):
     device_empty_ns: int
     steps: int
     tokens: int
+    kv_blocks: tuple[int, int] = (0, 0)   # fetched, spanned
 
 
 class PhaseClock(StepAggregator):
@@ -314,7 +315,8 @@ class PhaseClock(StepAggregator):
     ``step()`` calls, so a caller's own time is nobody's phase."""
 
     __slots__ = ("engine", "context", "hold_open", "ns", "counts",
-                 "device_empty_ns", "_cur", "_t0", "_ann", "_empty_since",
+                 "device_empty_ns", "kv_blocks_fetched", "kv_blocks_spanned",
+                 "_cur", "_t0", "_ann", "_empty_since",
                  "_longest", "_published", "_publish_lock", "_annotate")
 
     def __init__(self, engine: str = "engine",
@@ -328,6 +330,9 @@ class PhaseClock(StepAggregator):
         self.ns = dict.fromkeys(PHASES, 0)
         self.counts = dict.fromkeys(PHASES, 0)
         self.device_empty_ns = 0
+        #: decode attention's KV blocks, per dispatch (note_kv_blocks)
+        self.kv_blocks_fetched = 0
+        self.kv_blocks_spanned = 0
         self._cur: str | None = None
         self._t0 = 0
         self._ann = None
@@ -336,7 +341,8 @@ class PhaseClock(StepAggregator):
         # an occurrence shorter than a later one can never again be the
         # longest of a window that ends after both
         self._longest: deque[tuple[int, int, str]] = deque(maxlen=64)
-        self._published = dict(self.ns, device_empty=0)
+        self._published = dict(self.ns, device_empty=0, kv_fetched=0,
+                               kv_spanned=0)
         self._publish_lock = threading.Lock()
         try:
             from jax.profiler import TraceAnnotation
@@ -368,6 +374,14 @@ class PhaseClock(StepAggregator):
         if self._cur is not None and not self.hold_open:
             self._close(time.monotonic_ns())
             self._cur = None
+
+    def note_kv_blocks(self, fetched: int, spanned: int) -> None:
+        """One decode dispatch: of the `spanned` KV blocks its attention
+        grid covers (slots x span / block), the lengths handed to the
+        kernel let `fetched` through (ops/flash_decode.py: a block past
+        a slot's context is not copied)."""
+        self.kv_blocks_fetched += int(fetched)
+        self.kv_blocks_spanned += int(spanned)
 
     def fetched(self, outstanding: bool) -> None:
         """A device fetch returned; ``outstanding``: some program is
@@ -423,7 +437,8 @@ class PhaseClock(StepAggregator):
         return PhaseMark(
             now, tuple(ns), tuple(self.counts[p] for p in PHASES),
             self.device_empty_ns + (now - since if since is not None else 0),
-            self.steps, self.tokens)
+            self.steps, self.tokens,
+            (self.kv_blocks_fetched, self.kv_blocks_spanned))
 
     def longest_since(self, start_s: float,
                       now_ns: int) -> tuple[int, str] | None:
@@ -446,7 +461,8 @@ class PhaseClock(StepAggregator):
               submit_s: float | None) -> dict[str, Any]:
         """The ``engine`` object of a request's ``usage``: what the
         engine thread did from ``first`` (its first token) to now (its
-        finish), and the longest occurrence since ``submit_s``."""
+        finish), the decode dispatches' ``kv_blocks`` [fetched, spanned]
+        in that window, and the longest occurrence since ``submit_s``."""
         end = self.mark()
         out: dict[str, Any] = {
             "phases": {
@@ -456,6 +472,9 @@ class PhaseClock(StepAggregator):
                 if e > s or ce > cs},
             "device_empty_ms": round(
                 (end.device_empty_ns - first.device_empty_ns) / 1e6, 3)}
+        if end.kv_blocks[1] > first.kv_blocks[1]:
+            out["kv_blocks"] = [e - s for s, e in zip(first.kv_blocks,
+                                                      end.kv_blocks)]
         longest = (self.longest_since(submit_s, end.at_ns)
                    if submit_s is not None else None)
         if longest is not None:
@@ -470,7 +489,9 @@ class PhaseClock(StepAggregator):
 
         with self._publish_lock:    # two scrapes must not add one delta twice
             last = self._published
-            now = dict(self.ns, device_empty=self.device_empty_ns)
+            now = dict(self.ns, device_empty=self.device_empty_ns,
+                       kv_fetched=self.kv_blocks_fetched,
+                       kv_spanned=self.kv_blocks_spanned)
             self._published = now
         for p in PHASES:
             obs_metrics.ENGINE_PHASE_SECONDS.inc(
@@ -478,6 +499,10 @@ class PhaseClock(StepAggregator):
         obs_metrics.ENGINE_DEVICE_EMPTY_SECONDS.inc(
             (now["device_empty"] - last["device_empty"]) / 1e9,
             engine=self.engine)
+        obs_metrics.ENGINE_KV_BLOCKS_FETCHED.inc(
+            now["kv_fetched"] - last["kv_fetched"], engine=self.engine)
+        obs_metrics.ENGINE_KV_BLOCKS_SPANNED.inc(
+            now["kv_spanned"] - last["kv_spanned"], engine=self.engine)
 
 
 #: the process tracer every layer shares (tests may swap the sink).

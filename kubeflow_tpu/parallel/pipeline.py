@@ -489,14 +489,17 @@ class InferenceStagePlan:
             logical_tree, sm, rules={"layers": None})
         return shard_tree(slab, shardings)
 
-    def cache_sharding(self, stage: int):
-        """KV-slab sharding on the stage sub-mesh: kv-heads over
-        `tensor` (dim 3 for both 5D payloads and 4D scale planes), the
-        single-program engine's layout per stage."""
+    def cache_sharding(self, stage: int, name: str):
+        """Sharding of KV-slab array `name` on the stage sub-mesh:
+        kv-heads over `tensor` (dim 3 of the 5D payloads, dim 2 of the
+        lane-major scale planes), the single-program engine's layout
+        per stage."""
+        from kubeflow_tpu.models.llama import cache_kv_spec
+
         sm = self.submeshes[stage]
         if sm is None:
             return None
-        return NamedSharding(sm, P(None, None, None, "tensor"))
+        return NamedSharding(sm, cache_kv_spec(name))
 
     def describe(self) -> dict:
         """The /healthz `mesh` section's geometry half."""

@@ -181,7 +181,9 @@ def aot_serving_report(
     # cache schema from the ONE source of truth (llama.init_cache) so the
     # proof can't drift from the layout the live engine allocates
     cache = {
-        name: jax.ShapeDtypeStruct(sds.shape, sds.dtype, sharding=cache_sh)
+        name: jax.ShapeDtypeStruct(
+            sds.shape, sds.dtype,
+            sharding=NamedSharding(mesh, llama.cache_kv_spec(name)))
         for name, sds in jax.eval_shape(
             lambda: llama.init_cache(cfg, n_slots, max_len,
                                      kv_quantize=kv_quantize)).items()}

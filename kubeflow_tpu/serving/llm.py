@@ -556,10 +556,10 @@ class LLMEngine:
             self.params,
             tree_logical_to_sharding(
                 llama.logical_axes_for(self.params, self.cfg), mesh))
-        # no trailing None: GSPMD emits the trimmed spec on program outputs
-        # and the jit cache compares specs structurally — a 5-element spec
-        # here would retrace every program on its first post-warmup call
-        self._cache_sh = NamedSharding(mesh, P(None, None, None, "tensor"))
+        # kv heads over `tensor` (llama.cache_kv_spec: no trailing None, or
+        # every program would retrace on its first post-warmup call)
+        self._cache_sh = {name: NamedSharding(mesh, llama.cache_kv_spec(name))
+                          for name in ("k", "v", "k_s", "v_s")}
         self._repl = NamedSharding(mesh, P())
         # penalty counts shard over the vocab axis like the lm_head logits
         # they edit; every program pins this layout (a free-floating GSPMD
@@ -597,11 +597,9 @@ class LLMEngine:
                 return np.zeros(shard, sds.dtype)
             return cb
 
-        # the 4-element spec shards dim 3 (kv heads) for both the 5D int8
-        # payloads and the 4D scale planes
         cache = {
-            name: jax.make_array_from_callback(sds.shape, self._cache_sh,
-                                               zeros_shard(sds))
+            name: jax.make_array_from_callback(
+                sds.shape, self._cache_sh[name], zeros_shard(sds))
             for name, sds in leaves.items()}
         cache["cnt"] = jax.device_put(
             np.zeros((self.n_slots, self.cfg.vocab_size), np.int32),
@@ -891,10 +889,11 @@ class LLMEngine:
             vq, vsc = llama.quantize_kv(vs)
             out["k"] = cache["k"].at[:, slot, start:start + count].set(kq)
             out["v"] = cache["v"].at[:, slot, start:start + count].set(vq)
-            out["k_s"] = cache["k_s"].at[:, slot,
-                                         start:start + count].set(ksc)
-            out["v_s"] = cache["v_s"].at[:, slot,
-                                         start:start + count].set(vsc)
+            # scales are stored lane-major: [L, slots, kv, max_len]
+            out["k_s"] = cache["k_s"].at[:, slot, :, start:start + count
+                                         ].set(jnp.swapaxes(ksc, 1, 2))
+            out["v_s"] = cache["v_s"].at[:, slot, :, start:start + count
+                                         ].set(jnp.swapaxes(vsc, 1, 2))
         else:
             out["k"] = cache["k"].at[:, slot, start:start + count].set(
                 ks.astype(cache["k"].dtype))
@@ -965,13 +964,19 @@ class LLMEngine:
         v = jax.lax.dynamic_index_in_dim(cache["v"], slot, axis=1,
                                          keepdims=False)[:, :p][:, None]
         if self.kv_quantize == "int8":
-            ksc = jax.lax.dynamic_index_in_dim(
-                cache["k_s"], slot, axis=1, keepdims=False)[:, :p][:, None]
-            vsc = jax.lax.dynamic_index_in_dim(
-                cache["v_s"], slot, axis=1, keepdims=False)[:, :p][:, None]
+            ksc, vsc = (self._slot_scales(cache[n], slot, p)
+                        for n in ("k_s", "v_s"))
             k = llama.dequantize_kv(k, ksc, self.cfg.dtype)
             v = llama.dequantize_kv(v, vsc, self.cfg.dtype)
         return k, v
+
+    @staticmethod
+    def _slot_scales(scales, slot, p: int):
+        """A slot's first `p` per-token scales out of the lane-major
+        cache plane `[L, slots, kv, max_len]`, store-shaped [L, 1, p, kv]."""
+        rows = jax.lax.dynamic_index_in_dim(scales, slot, axis=1,
+                                            keepdims=False)[:, :, :p]
+        return jnp.swapaxes(rows, 1, 2)[:, None]
 
     def _extract_prefix_raw(self, cache, slot, *, p: int):
         """Raw-layout twin of _extract_prefix for the radix block store:
@@ -985,7 +990,8 @@ class LLMEngine:
                 cache[name], slot, axis=1, keepdims=False)[:, :p][:, None]
 
         if self.kv_quantize == "int8":
-            return take("k"), take("k_s"), take("v"), take("v_s")
+            return (take("k"), self._slot_scales(cache["k_s"], slot, p),
+                    take("v"), self._slot_scales(cache["v_s"], slot, p))
         return take("k"), take("v")
 
     @_under_engine_mesh
@@ -1012,7 +1018,8 @@ class LLMEngine:
             cnt = cache["cnt"]
             logits, kv = llama.decode_step(params, last_tokens, cache,
                                            lengths, self.cfg, span=span,
-                                           lora=lora, ids=aids)
+                                           lora=lora, ids=aids,
+                                           active=active)
             if aids is not None:
                 kv["aids"] = aids  # decode never re-assigns slots
             if sample:
@@ -1093,7 +1100,7 @@ class LLMEngine:
             kv = {k: v for k, v in cache.items() if k != "hist"}
             logits, kv = llama.verify_step(params, tokens_in, kv, lengths,
                                            self.cfg, span=span, lora=lora,
-                                           ids=aids)
+                                           ids=aids, active=active)
             preds = jnp.argmax(logits, -1).astype(jnp.int32)  # [B, k+1]
             match = ((preds[:, :k_spec] == drafts)
                      & (jnp.arange(k_spec)[None] < count[:, None]))
@@ -1318,6 +1325,12 @@ class LLMEngine:
             s *= 2
         spans.append(self.max_len)
         return spans
+
+    def _kv_block_tokens(self) -> int:
+        """Tokens to a KV block of the decode-attention kernel's grid."""
+        from kubeflow_tpu.ops.flash_decode import DEFAULT_BLOCK_KV
+
+        return min(DEFAULT_BLOCK_KV, self.max_len)
 
     def _pick_span(self, needed: int) -> int:
         for s in self._span_menu():
@@ -2544,6 +2557,14 @@ class LLMEngine:
         # obs: phases and aggregate counters only on this path (no span
         # objects — scripts/check_observability.py lints that invariant)
         clock.note_step(int(active.sum()) * k * per_tok, steps=k)
+        # decode attention copies a slot's KV blocks up to its length and
+        # none of a dead slot's: of the blocks the span covers, how many
+        # the chunk's last step moves
+        blk = self._kv_block_tokens()
+        n_k = -(-span // blk)
+        clock.note_kv_blocks(
+            np.minimum(n_k, (planned[active] + k * per_tok - 1) // blk
+                       + 1).sum(), self.n_slots * n_k)
         rows_added = np.where(active, k * per_tok, 0)
         self._inflight += rows_added
         prev = self._pending

@@ -203,9 +203,10 @@ class StageShardedEngine(LLMEngine):
             scfg = dataclasses.replace(self.cfg, n_layers=hi - lo)
             slab = llama.init_cache(scfg, self.n_slots, self.max_len,
                                     kv_quantize=self.kv_quantize)
-            sh = self._plan.cache_sharding(s)
-            if sh is not None:
-                slab = {k: jax.device_put(v, sh) for k, v in slab.items()}
+            if self._plan.submeshes[s] is not None:
+                slab = {k: jax.device_put(
+                    v, self._plan.cache_sharding(s, k))
+                    for k, v in slab.items()}
             stages.append(slab)
         cnt = jnp.zeros((self.n_slots, self.cfg.vocab_size), jnp.int32)
         if self._cnt_sh_stage is not None:

@@ -155,6 +155,9 @@ class PagedLLMEngine(LLMEngine):
             cache["aids"] = jnp.zeros((self.n_slots,), jnp.int32)
         return cache
 
+    def _kv_block_tokens(self) -> int:
+        return self._bt   # the kernel's KV block IS the pool's
+
     def _tbl_sync(self) -> None:
         """Re-upload the host table mirror. The table is tiny
         ([n_slots, max_len/bt] int32), so every mutation batch eagerly
@@ -185,13 +188,18 @@ class PagedLLMEngine(LLMEngine):
             v = vals.reshape(vals.shape[0], nb, bt, *vals.shape[2:])
             return buf.at[:, blks].set(v, mode="drop")
 
+        def scatter_scales(buf, vals):   # [L, count, kv] -> [L, nb, kv, bt]
+            v = vals.reshape(vals.shape[0], nb, bt, vals.shape[2])
+            return buf.at[:, blks].set(jnp.swapaxes(v, 2, 3), mode="drop")
+
         if self.kv_quantize == "int8":
             kq, ksc = llama.quantize_kv(ks)
             vq, vsc = llama.quantize_kv(vs)
             out["k"] = scatter(cache["k"], kq)
             out["v"] = scatter(cache["v"], vq)
-            out["k_s"] = scatter(cache["k_s"], ksc)
-            out["v_s"] = scatter(cache["v_s"], vsc)
+            # pool scales are lane-major: [L, N, kv, bt]
+            out["k_s"] = scatter_scales(cache["k_s"], ksc)
+            out["v_s"] = scatter_scales(cache["v_s"], vsc)
         else:
             out["k"] = scatter(cache["k"], ks.astype(cache["k"].dtype))
             out["v"] = scatter(cache["v"], vs.astype(cache["v"].dtype))
@@ -206,10 +214,14 @@ class PagedLLMEngine(LLMEngine):
             g = jnp.take(cache[name], blks, axis=1)   # [L, nb, bt, ...]
             return g.reshape(g.shape[0], n_tokens, *g.shape[3:])[:, None]
 
+        def gather_scales(name):   # [L, nb, kv, bt] -> [L, 1, n_tokens, kv]
+            g = jnp.swapaxes(jnp.take(cache[name], blks, axis=1), 2, 3)
+            return g.reshape(g.shape[0], n_tokens, g.shape[3])[:, None]
+
         k, v = gather("k"), gather("v")
         if self.kv_quantize == "int8":
-            k = llama.dequantize_kv(k, gather("k_s"), self.cfg.dtype)
-            v = llama.dequantize_kv(v, gather("v_s"), self.cfg.dtype)
+            k = llama.dequantize_kv(k, gather_scales("k_s"), self.cfg.dtype)
+            v = llama.dequantize_kv(v, gather_scales("v_s"), self.cfg.dtype)
         return k, v
 
     def _extract_prefix(self, cache, slot, *, p: int):

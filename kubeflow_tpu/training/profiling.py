@@ -345,25 +345,31 @@ def serving_decode_breakdown(engine, *, steps: int | None = None,
             (n_slots, 1, cfg.n_heads, cfg.head_dim)).astype(cfg.dtype)
 
         def _layer_span(cache, name, li):
+            """One layer's rows as the probes below take them: payload
+            [slots, span, kv, hd], scales [slots, span, kv] (the cache
+            keeps scales lane-major, [.., kv, max_len])."""
             rows_all = jax.lax.dynamic_index_in_dim(
                 cache[name], li, axis=0, keepdims=False)
+            if name.endswith("_s"):
+                rows_all = jnp.swapaxes(rows_all, 1, 2)
             if paged:
                 return rows_all   # whole pool layer; the table slices
             return jax.lax.slice_in_dim(rows_all, 0, span, axis=1)
+
+        kv_names = ("k", "v", "k_s", "v_s") if quantized else ("k", "v")
 
         @jax.jit
         def attn_probe(cache, lengths):
             positions = lengths[:, None]   # S_v=1: one decode step
             tbl_b = cache["tbl"][:, :nb] if paged else None
+            kv = {name: cache[name] for name in kv_names}
 
             def body(acc, li):
+                # the decode program's own call: the cache whole, the
+                # layer by its index
                 out = _llama.decode_attention(
-                    cfg, q_probe,
-                    _layer_span(cache, "k", li),
-                    _layer_span(cache, "v", li),
-                    _layer_span(cache, "k_s", li) if quantized else None,
-                    _layer_span(cache, "v_s", li) if quantized else None,
-                    positions, tables=tbl_b)
+                    cfg, q_probe, kv, li, positions, span=span,
+                    tables=tbl_b)
                 return acc + jnp.sum(out.astype(jnp.float32)), None
 
             acc, _ = jax.lax.scan(body, jnp.float32(0.0),
@@ -423,6 +429,8 @@ def serving_decode_breakdown(engine, *, steps: int | None = None,
             pool = jax.lax.dynamic_index_in_dim(
                 cache[name], li, axis=0, keepdims=False)
             g = jnp.take(pool, cache["tbl"][:, :nb], axis=0)
+            if name.endswith("_s"):   # pool scales: [N, kv, bt]
+                g = jnp.swapaxes(g, 2, 3)
             return g.reshape((g.shape[0], nb * bt_blk) + g.shape[3:])
 
         if quantized:

@@ -17,6 +17,7 @@ claim here is byte-level testable without hardware:
 """
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -55,14 +56,23 @@ def _inputs(nh, nkv, s_v, t, hd, quantized, lengths, *, seed=0,
     return q, kf.astype(dtype), vf.astype(dtype), None, None
 
 
+def _layer_cache(ck, cv, cks, cvs):
+    """One layer's [B, T, kv, hd] rows (scales [B, T, kv]) as the cache
+    the serving scan carries: a leading layer axis, scales lane-major."""
+    cache = {"k": ck[None], "v": cv[None]}
+    if cks is not None:
+        cache["k_s"] = jnp.swapaxes(cks, 1, 2)[None]
+        cache["v_s"] = jnp.swapaxes(cvs, 1, 2)[None]
+    return cache
+
+
 def _both(cfg, q, ck, cv, cks, cvs, lengths):
     s_v = q.shape[1]
     positions = jnp.asarray(lengths, jnp.int32)[:, None] \
         + jnp.arange(s_v)[None]
-    want = llama.decode_attention(cfg, q, ck, cv, cks, cvs, positions,
-                                  impl="xla")
-    got = llama.decode_attention(cfg, q, ck, cv, cks, cvs, positions,
-                                 impl="flash")
+    cache = _layer_cache(ck, cv, cks, cvs)
+    want = llama.decode_attention(cfg, q, cache, 0, positions, impl="xla")
+    got = llama.decode_attention(cfg, q, cache, 0, positions, impl="flash")
     return np.asarray(want, np.float32), np.asarray(got, np.float32)
 
 
@@ -132,6 +142,231 @@ def test_rows_mask_independent_slots():
     ck3 = ck.at[1, 10:].set(99.0)
     _, got3 = _both(cfg, q, ck3, cv, cks, cvs, lengths)
     assert np.any(got3[1] != base[1])
+
+
+# -- the in-place entry (ISSUE 28): the kernel takes the WHOLE cache -----------
+# 8 kv heads of 128 is the serving layout: int8 rows unpack from 32-bit
+# words (4 heads to a word), bf16 rows from half-words; 2 kv heads of 16
+# with int8 is a layout the word view cannot express (the value path).
+
+LAYERS, SLOTS_ALL, T_CACHE = 3, 5, 320
+
+
+def _whole_cache(nkv, hd, kv_dtype, *, seed=0, t=T_CACHE):
+    """A filled slab cache [L, slots, T, kv, hd] (+ lane-major scales)."""
+    rng = np.random.default_rng(seed)
+    shape = (LAYERS, SLOTS_ALL, t, nkv, hd)
+    kf = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    if kv_dtype == jnp.int8:
+        (kq, ks), (vq, vs) = llama.quantize_kv(kf), llama.quantize_kv(vf)
+        return {"k": kq, "v": vq, "k_s": jnp.swapaxes(ks, 2, 3),
+                "v_s": jnp.swapaxes(vs, 2, 3)}
+    return {"k": kf.astype(kv_dtype), "v": vf.astype(kv_dtype)}
+
+
+def _paged(cache, bt):
+    """The same rows as a block pool [L, N, bt, kv, hd] (+ [L, N, kv, bt])
+    behind shuffled tables [slots, T // bt]; block 0 is the trash block."""
+    n_layers, slots, t = cache["k"].shape[:3]
+    per_slot = t // bt
+    order = np.random.default_rng(9).permutation(slots * per_slot)
+    tables = jnp.asarray(1 + order.reshape(slots, per_slot), jnp.int32)
+    inverse = np.argsort(order)
+    pool = {}
+    for name, buf in cache.items():
+        if name.endswith("_s"):   # [L, slots, kv, T] -> [L, N, kv, bt]
+            blocks = buf.reshape(n_layers, slots, -1, per_slot, bt)
+            blocks = jnp.moveaxis(blocks, 3, 2).reshape(
+                n_layers, slots * per_slot, -1, bt)
+        else:
+            blocks = buf.reshape(n_layers, slots * per_slot, bt,
+                                 *buf.shape[3:])
+        blocks = blocks[:, inverse]
+        pool[name] = jnp.concatenate(
+            [jnp.zeros_like(blocks[:, :1]), blocks], axis=1)
+    return pool, tables
+
+
+def _ragged_lengths(b, span, s_v):
+    """Ragged, with two slots at 0 and one at the span's last window."""
+    lengths = np.random.default_rng(4).integers(1, span - s_v, size=(b,))
+    lengths[0], lengths[-1] = 0, span - s_v
+    if b > 2:
+        lengths[1] = 0
+    return jnp.asarray(lengths, jnp.int32)
+
+
+def _garbage_elsewhere(cache, layer, slot_start, lengths, s_v, tables=None):
+    """Every row no query may see — past each slot's window, in every
+    other slot and every other layer — overwritten with large finite
+    junk: what is not fetched cannot matter."""
+    junk = {name: (jnp.full_like(buf, 113) if buf.dtype == jnp.int8
+                   else jnp.full_like(buf, 3.0e4))
+            for name, buf in cache.items()}
+    out = dict(junk)
+    lengths = np.asarray(lengths)
+    for i, n in enumerate(lengths):
+        seen = int(n) + s_v
+        for name, buf in cache.items():
+            if tables is None:
+                rows = (layer, slot_start + i)
+                keep = ((rows + (slice(None), slice(0, seen)))
+                        if name.endswith("_s")
+                        else (rows + (slice(0, seen),)))
+                out[name] = out[name].at[keep].set(buf[keep])
+                continue
+            bt = cache["k"].shape[2]
+            for j in range(-(-seen // bt)):
+                blk = int(tables[i, j])
+                live = min(bt, seen - j * bt)
+                keep = ((layer, blk, slice(None), slice(0, live))
+                        if name.endswith("_s")
+                        else (layer, blk, slice(0, live)))
+                out[name] = out[name].at[keep].set(buf[keep])
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "impl", "span",
+                                             "slot_start"))
+def _attend(cfg, q, cache, layer, positions, tables, *, impl, span=None,
+            slot_start=0):
+    # jitted so that the three layers of a case share one compile: the
+    # layer is a traced scalar, exactly as in the serving scan
+    return llama.decode_attention(cfg, q, cache, layer, positions,
+                                  impl=impl, span=span,
+                                  slot_start=slot_start, tables=tables)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "mid", "last"])
+@pytest.mark.parametrize("s_v", [1, 4])
+@pytest.mark.parametrize("slot_start,b", [(0, SLOTS_ALL), (1, 3)],
+                         ids=["full_batch", "microbatch"])
+@pytest.mark.parametrize("nkv,hd,kv_dtype,paged", [
+    (8, 128, jnp.int8, False), (8, 128, jnp.int8, True),
+    (8, 128, jnp.bfloat16, False), (2, 16, jnp.int8, False),
+    (2, 16, jnp.float32, True)],
+    ids=["int8-slab", "int8-paged", "bf16-slab", "int8-toy-slab",
+         "f32-toy-paged"])
+def test_in_place_entry(monkeypatch, nkv, hd, kv_dtype, paged, slot_start,
+                        b, s_v, layer):
+    """The kernel handed the whole cache, a layer index and a slot
+    window: equal to the einsum reference; bit-identical to the kernel
+    handed that layer and window sliced out; and blind to anything a
+    query may not see. Four KV blocks to a span, five to the cache."""
+    monkeypatch.setattr(flash_decode, "DEFAULT_BLOCK_KV", 64)
+    span = 256
+    nh = nkv * 2
+    q_dtype = jnp.bfloat16 if kv_dtype == jnp.bfloat16 else jnp.float32
+    cfg = _cfg(nh, nkv, hd, dtype=q_dtype)
+    cache = _whole_cache(nkv, hd, kv_dtype)
+    lengths = _ragged_lengths(b, span, s_v)
+    positions = lengths[:, None] + jnp.arange(s_v)[None]
+    q = jnp.asarray(np.random.default_rng(2).normal(
+        size=(b, s_v, nh, hd)), q_dtype)
+    tables = None
+    kw = dict(span=span, slot_start=slot_start)
+    if paged:
+        cache, tables = _paged(cache, bt=64)
+        tables = tables[slot_start:slot_start + b, :span // 64]
+        kw = dict(tables=tables)
+
+    def attend(c, li, impl, tables=None, **kw):
+        return np.asarray(_attend(cfg, q, c, li, positions, tables,
+                                  impl=impl, **kw), np.float32)
+
+    got = attend(cache, jnp.int32(layer), "flash", **kw)
+    want = attend(cache, jnp.int32(layer), "xla", **kw)
+    tol = 0.05 if q_dtype == jnp.bfloat16 else 1e-5 * (
+        float(np.max(np.abs(want))) or 1.0)
+    assert float(np.max(np.abs(got - want))) < tol
+    # the layer (and, for a slab, the slot window) sliced out by XLA: the
+    # index maps must have picked exactly those blocks
+    sliced = {name: buf[layer][None] for name, buf in cache.items()}
+    if not paged:
+        sliced = {name: buf[:, slot_start:slot_start + b]
+                  for name, buf in sliced.items()}
+        kw = dict(span=span)
+    np.testing.assert_array_equal(
+        got, attend(sliced, jnp.int32(0), "flash", **kw))
+    # what no query may see is not read
+    kw = dict(tables=tables) if paged else dict(span=span,
+                                                slot_start=slot_start)
+    junked = _garbage_elsewhere(cache, layer, slot_start, lengths, s_v,
+                                tables)
+    np.testing.assert_array_equal(
+        got, attend(junked, jnp.int32(layer), "flash", **kw))
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+@pytest.mark.parametrize("s_v", [1, 3])
+@pytest.mark.parametrize("pattern", [(None, 63, None, 130, 0),
+                                     (None, None, 130, 63, None)],
+                         ids=["dead_between", "dead_ahead_and_after"])
+def test_kernel_stores_the_steps_scales(monkeypatch, pattern, s_v, paged):
+    """The rows a step wrote come with scales the planes do not hold yet:
+    the kernel attends with them and stores them in place — exactly what
+    the einsum path's scatter leaves, for live rows; a row that attends
+    nothing (before, between and after live ones) stores nothing, and
+    nothing else in the planes moves. One window straddles two blocks."""
+    monkeypatch.setattr(flash_decode, "DEFAULT_BLOCK_KV", 64)
+    cfg = _cfg(16, 8, 128)
+    cache = _whole_cache(8, 128, jnp.int8)
+    layer, span = 1, 256
+    lengths = np.asarray([-s_v if n is None else n for n in pattern])
+    live = np.flatnonzero(lengths >= 0)
+    rng = np.random.default_rng(6)
+    new = tuple(jnp.asarray(rng.uniform(0.5, 1.5, size=(SLOTS_ALL, s_v, 8)),
+                            jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(SLOTS_ALL, s_v, 16, 128)), jnp.float32)
+    positions = jnp.asarray(lengths, jnp.int32)[:, None] + jnp.arange(s_v)
+    tables, kw = None, dict(span=span)
+    if paged:
+        cache, tables = _paged(cache, bt=64)
+        tables = tables[:, :span // 64]
+        kw = dict(tables=tables)
+    want = {}
+    for name, sc in zip(("k_s", "v_s"), new):
+        plane = cache[name]
+        for b in live:
+            for i in range(s_v):
+                p = int(lengths[b]) + i
+                at = ((layer, int(tables[b, p // 64]), slice(None), p % 64)
+                      if paged else (layer, int(b), slice(None), p))
+                plane = plane.at[at].set(sc[b, i])
+        want[name] = plane
+    out, k_s, v_s = llama.decode_attention(
+        cfg, q, cache, jnp.int32(layer), positions, impl="flash",
+        new_scales=new, **kw)
+    np.testing.assert_array_equal(np.asarray(k_s), np.asarray(want["k_s"]))
+    np.testing.assert_array_equal(np.asarray(v_s), np.asarray(want["v_s"]))
+    ref = llama.decode_attention(cfg, q, dict(cache, **want),
+                                 jnp.int32(layer), positions, impl="xla",
+                                 **kw)
+    out, ref = np.asarray(out), np.asarray(ref)
+    assert float(np.max(np.abs(out[live] - ref[live]))) < 1e-5 * float(
+        np.max(np.abs(ref)))
+    assert not out[lengths < 0].any()
+
+
+def test_inactive_rows_attend_nothing():
+    """verify_inner hands the attention position -S_v for a row that is
+    not active: every key is masked, no block computes, the output is 0
+    (flash) or a finite average (einsum) — and a live row is untouched."""
+    cfg = _cfg(16, 8, 128)
+    cache = _whole_cache(8, 128, jnp.int8)
+    q = jnp.asarray(np.random.default_rng(2).normal(
+        size=(SLOTS_ALL, 1, 16, 128)), jnp.float32)
+    live = jnp.asarray([[300], [7], [0], [255], [90]], jnp.int32)
+    dead = live.at[1].set(-1).at[4].set(-1)
+    base, got = (np.asarray(llama.decode_attention(
+        cfg, q, cache, jnp.int32(1), pos, span=T_CACHE, impl="flash"))
+        for pos in (live, dead))
+    np.testing.assert_array_equal(got[[0, 2, 3]], base[[0, 2, 3]])
+    assert not got[[1, 4]].any() and base[[1, 4]].any()
+    ref = np.asarray(llama.decode_attention(
+        cfg, q, cache, jnp.int32(1), dead, span=T_CACHE, impl="xla"))
+    assert np.isfinite(ref).all()
 
 
 def test_selection_policy(monkeypatch):

@@ -49,7 +49,8 @@ def mosaic_calls(fn, *args) -> int:
 
 
 def kv_operands(batch, tokens, quantized, paged):
-    """(k, scale, tables) abstract operands in the slab or pool layout."""
+    """(k, scale, tables) abstract operands in the slab or pool layout,
+    as flash_prefill takes them: ONE layer's rows."""
     dtype = jnp.int8 if quantized else jnp.bfloat16
     if paged:
         n_pool = batch * tokens // BLOCK_TOKENS + 1
@@ -63,20 +64,64 @@ def kv_operands(batch, tokens, quantized, paged):
     return k, (scale if quantized else None), tables
 
 
+LAYERS = 8   # the served cut's depth: the cache the layer scan carries
+
+
+def cache_operands(quantized, paged, span):
+    """(k, scale, tables) as flash_decode takes them: the WHOLE cache,
+    `[L, slots, max_len, kv, hd]` with lane-major scales `[L, slots, kv,
+    max_len]`, or the pool `[L, N, bt, kv, hd]` / `[L, N, kv, bt]` behind
+    tables clipped to the span."""
+    dtype = jnp.int8 if quantized else jnp.bfloat16
+    if paged:
+        n_pool = SLOTS * SPAN // BLOCK_TOKENS + 1
+        k = sds((LAYERS, n_pool, BLOCK_TOKENS, KV_HEADS, HEAD_DIM), dtype)
+        scale = sds((LAYERS, n_pool, KV_HEADS, BLOCK_TOKENS), jnp.float32)
+        tables = sds((SLOTS, span // BLOCK_TOKENS), jnp.int32)
+    else:
+        k = sds((LAYERS, SLOTS, SPAN, KV_HEADS, HEAD_DIM), dtype)
+        scale = sds((LAYERS, SLOTS, KV_HEADS, SPAN), jnp.float32)
+        tables = None
+    return k, (scale if quantized else None), tables
+
+
 @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("span", [1024, 2048])
 @pytest.mark.parametrize("s_v", [1, 4, 7])   # decode; verify k=3; k=6
-def test_flash_decode_lowers(s_v, quantized, paged):
-    k, scale, tables = kv_operands(SLOTS, SPAN, quantized, paged)
+def test_flash_decode_lowers(s_v, span, quantized, paged):
+    """The in-place entry at the serving shapes: 8 layers of 16 x 2048,
+    8 KV heads of 128; the layer a traced index, the span 1024 or the
+    whole 2048; int8 with the step's scales handed in and the planes
+    coming back aliased (decode and verify both store them)."""
+    k, scale, tables = cache_operands(quantized, paged, span)
+    new = sds((SLOTS, s_v, KV_HEADS), jnp.float32) if quantized else None
 
-    def fn(q, k, v, lengths, ks, vs, tables):
+    def fn(q, k, v, lengths, layer, ks, vs, new, tables):
         return flash_decode.flash_decode_attention(
-            q, k, v, lengths, k_scale=ks, v_scale=vs, tables=tables,
-            interpret=False)
+            q, k, v, lengths, layer=layer, span=span, k_scale=ks,
+            v_scale=vs, new_scales=(new, new) if quantized else None,
+            tables=tables, interpret=False)
 
     assert mosaic_calls(
         fn, sds((SLOTS, s_v, HEADS, HEAD_DIM), jnp.bfloat16), k, k,
-        sds((SLOTS,), jnp.int32), scale, scale, tables) == 1
+        sds((SLOTS,), jnp.int32), sds((), jnp.int32), scale, scale, new,
+        tables) == 1
+
+
+def test_flash_decode_lowers_for_a_microbatch_of_slots():
+    """StageShardedEngine's call: 4 rows of q against slots 8..11 of the
+    stage's full-slot slab; the planes read, not stored (the probe's)."""
+    k, scale, _ = cache_operands(True, False, 1024)
+
+    def fn(q, k, v, lengths, layer, ks, vs):
+        return flash_decode.flash_decode_attention(
+            q, k, v, lengths, layer=layer, span=1024, slot_start=8,
+            k_scale=ks, v_scale=vs, interpret=False)
+
+    assert mosaic_calls(
+        fn, sds((4, 1, HEADS, HEAD_DIM), jnp.bfloat16), k, k,
+        sds((4,), jnp.int32), sds((), jnp.int32), scale, scale) == 1
 
 
 @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])

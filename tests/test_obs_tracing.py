@@ -241,7 +241,7 @@ def test_engine_usage_phases_sum_to_decode_ms(toy_engine):
     tm = eng.request_timing(a)
     engine = tm["engine"]
     assert set(engine) == {"phases", "device_empty_ms", "phase_max_ms",
-                           "phase_max"}
+                           "phase_max", "kv_blocks"}
     assert set(engine["phases"]) <= set(PHASES)
     assert "idle" not in engine["phases"]
     total = sum(ms for ms, _ in engine["phases"].values())
@@ -332,6 +332,43 @@ def test_stall_is_logged_counted_spanned_and_named_in_usage(
     assert engine["phases"]["sched"][0] >= 600
     eng.release(a)
     eng.release(b)
+
+
+def test_kv_block_counts_reach_usage_and_metrics(toy_engine):
+    """Each decode dispatch counts the KV blocks its attention grid
+    spans (slots x span / block) and those the slots' lengths let the
+    kernel copy (none for a dead slot): `usage.engine.kv_blocks` carries
+    the request's window of both, /metrics the two cumulative series."""
+    eng = toy_engine
+    clock = eng.phase_clock
+    assert eng._kv_block_tokens() == 64     # a cache shorter than a block
+    before = clock.mark().kv_blocks
+    chunks = clock.counts["decode_dispatch"]
+    a = eng.submit([1, 2, 3, 4, 5], 12)     # the other slot stays dead
+    _run(eng, a)
+    chunks = clock.counts["decode_dispatch"] - chunks
+    fetched, spanned = (e - s for s, e in zip(before,
+                                              clock.mark().kv_blocks))
+    # one block to a span here: 2 slots spanned, the live one fetched
+    assert (fetched, spanned) == (chunks, 2 * chunks) and chunks >= 5
+    # the request's window opens at its first token: every decode
+    # dispatch but those before it
+    got = eng.request_timing(a)["engine"]["kv_blocks"]
+    assert got[1] == 2 * got[0] and 0 < got[0] <= fetched
+    eng.release(a)
+    text = render_metrics()
+    for name, at_least in (("fetched", fetched), ("spanned", spanned)):
+        series = f"serving_engine_kv_blocks_{name}_total"
+        assert f"# TYPE {series} counter" in text
+        assert _metric_value(text, series + '{engine="engine"}') >= at_least
+    # a clock that saw no decode dispatch reports no such key
+    c = PhaseClock("unit")
+    c.enter("sched")
+    first = c.mark()
+    c.enter("replay")
+    assert "kv_blocks" not in c.usage(first, None)
+    c.note_kv_blocks(3, 8)
+    assert c.usage(first, None)["kv_blocks"] == [3, 8]
 
 
 def test_perf_counters_is_a_view_of_the_clock(toy_engine):

@@ -149,8 +149,14 @@ def _cache(paged: bool):
     if not paged:
         return cache
     per_slot = MAX_LEN // BLOCK
-    pool = {k: v.reshape(CFG.n_layers, SLOTS * per_slot, BLOCK,
-                         *v.shape[3:]) for k, v in cache.items()}
+    # payloads [L, slots, T, kv, hd] -> [L, N, bt, kv, hd]; the lane-major
+    # scale planes [L, slots, kv, T] -> [L, N, kv, bt]
+    pool = {k: (jnp.moveaxis(v.reshape(CFG.n_layers, SLOTS, -1, per_slot,
+                                       BLOCK), 3, 2).reshape(
+                    CFG.n_layers, SLOTS * per_slot, -1, BLOCK)
+                if k.endswith("_s") else
+                v.reshape(CFG.n_layers, SLOTS * per_slot, BLOCK,
+                          *v.shape[3:])) for k, v in cache.items()}
     # one spare block 0 (the pool's trash sentinel) ahead of the slots'
     pool = {k: jnp.concatenate([v[:, :1], v], axis=1)
             for k, v in pool.items()}
@@ -247,3 +253,71 @@ def test_engine_reports_the_census_of_its_warmed_menu():
         "xla": 7 * (programs - narrow)}
     assert len(eng.generate(list(range(1, 7)), 4)) == 4
     eng.close()
+
+
+# -- and the KV cache: flash decode reads it where it lies (ISSUE 28) ---------
+
+#: what XLA would have to stage a copy for ahead of a Mosaic custom call
+_VIEWS = ("dynamic_slice", "gather", "slice", "reshape", "transpose",
+          "convert_element_type", "squeeze", "pad", "copy", "copy_p")
+
+
+@pytest.mark.parametrize("kind,paged", [
+    ("decode_step", False), ("decode_step", True),
+    ("verify_step", False), ("verify_step", True)],
+    ids=["decode-slab", "decode-paged", "verify-slab", "verify-paged"])
+def test_flash_decode_program_never_views_a_cache_array(served, kind, paged):
+    """With the flash impl the decode and verify programs hand the cache
+    arrays to the kernel as the layer scan carries them: outside the
+    pallas_call nothing slices, reshapes, transposes or converts `k`, `v`,
+    `k_s` or `v_s` (the payloads' per-step scatter and the carry itself are
+    all that touch them), the kernel's operands are the benchmark reader's
+    list (ONE s32 vector, the query, two payloads, two scale planes), and
+    the planes come back aliased."""
+    import dataclasses
+
+    params, _ = served
+    cfg = dataclasses.replace(CFG, decode_attention_impl="flash",
+                              dtype=jnp.bfloat16)
+    cache = _cache(paged)
+    s_v = 1 if kind == "decode_step" else 3
+    toks = jnp.zeros((SLOTS,) if s_v == 1 else (SLOTS, s_v), jnp.int32)
+    step = getattr(llama, kind)
+    jaxpr = jax.make_jaxpr(lambda p, t, c, n: step(p, t, c, n, cfg))(
+        params, toks, cache, jnp.zeros((SLOTS,), jnp.int32))
+    shapes = {(v.shape, v.dtype) for k, v in cache.items() if k != "tbl"}
+
+    def is_cache(var):
+        aval = getattr(var, "aval", None)
+        return (getattr(aval, "shape", None), getattr(aval, "dtype",
+                                                      None)) in shapes
+
+    # the einsum reference DOES slice its layer out: the walk sees it
+    ref = jax.make_jaxpr(lambda p, t, c, n: step(
+        p, t, c, n, dataclasses.replace(cfg, decode_attention_impl="xla")))(
+        params, toks, cache, jnp.zeros((SLOTS,), jnp.int32))
+    assert any(eqn.primitive.name in _VIEWS and any(map(is_cache,
+                                                        eqn.invars))
+               for eqn in _walk(ref.jaxpr))
+    kernels = []
+    for eqn in _walk(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        if name in _VIEWS:
+            assert not any(map(is_cache, eqn.invars)), eqn
+        if name == "scan":   # a scanned operand is a slice per iteration
+            skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+            assert not any(map(is_cache, eqn.invars[skip:])), eqn
+        if name == "pallas_call" and any(map(is_cache, eqn.invars)):
+            kernels.append(eqn)
+    assert len(kernels) == 1      # one call site, inside the layer scan
+    avals = [v.aval for v in kernels[0].invars]
+    if paged:                     # the tables ride a second s32 operand
+        assert avals[1].dtype == jnp.int32 and avals[1].ndim == 2
+        del avals[1]
+    assert [(a.dtype, a.ndim) for a in avals] == [
+        (jnp.int32, 1), (jnp.bfloat16, 4), (jnp.int8, 5), (jnp.int8, 5),
+        (jnp.float32, 4), (jnp.float32, 4)]
+    assert all(map(is_cache, kernels[0].invars[-4:]))
+    aliases = dict(kernels[0].params["input_output_aliases"])
+    n_in = len(kernels[0].invars)
+    assert aliases == {n_in - 2: 1, n_in - 1: 2}

@@ -135,8 +135,11 @@ def test_kv_int8_decode_logits_close(tiny):
     cache_q = llama.init_cache(cfg, b, 32, kv_quantize="int8")
     cache_q = {"k": cache_q["k"].at[:, :, :s].set(kq),
                "v": cache_q["v"].at[:, :, :s].set(vq),
-               "k_s": cache_q["k_s"].at[:, :, :s].set(ksc),
-               "v_s": cache_q["v_s"].at[:, :, :s].set(vsc)}
+               # scale planes are lane-major: [L, slots, kv, max_len]
+               "k_s": cache_q["k_s"].at[:, :, :, :s].set(
+                   jnp.swapaxes(ksc, 2, 3)),
+               "v_s": cache_q["v_s"].at[:, :, :, :s].set(
+                   jnp.swapaxes(vsc, 2, 3))}
     lo_q, new_cache = llama.decode_step(params, last, cache_q, lengths, cfg)
     assert new_cache["k"].dtype == jnp.int8
     a, bq = np.asarray(lo_f), np.asarray(lo_q)
