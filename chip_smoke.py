@@ -590,7 +590,7 @@ def phase_serve(chips: int) -> dict:
 
 def trainer_config(chips: int, batch: int, corpus: str) -> dict:
     if chips == 1:
-        # the r01-r04 proxy (bench.py): unrolled, no remat, bf16 first moment
+        # the one-chip proxy: unrolled, no remat, bf16 first moment
         model = dict(vocab_size=32000, d_model=2048, n_layers=8, n_heads=16,
                      n_kv_heads=8, d_ff=7168, max_seq_len=2048, remat=False,
                      scan_layers=False)

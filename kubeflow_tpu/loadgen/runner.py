@@ -6,7 +6,7 @@ tenant/adapter routing, and client cancellations. The runner is the only
 loadgen piece that touches wall clocks; everything it produces reduces
 through `loadgen.slo` (pure math) into the committed summary.
 
-Conventions (shared with bench._poisson_run): arrivals coming due while a
+Conventions: arrivals coming due while a
 blocking engine.step() runs are submitted late but keep their SCHEDULED
 arrival as the TTFT epoch — dropping that wait would bias the percentiles
 low. Cancellation fires `cancel_after_s` after the scheduled arrival; a

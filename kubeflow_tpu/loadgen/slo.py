@@ -6,8 +6,7 @@ hand-computed miniature trace (tests/test_loadgen_runner.py does exactly
 that). Definitions, chosen to be computable by hand:
 
 - TTFT = first_token_s - arrival_s: measured from the SCHEDULED arrival
-  (an arrival submitted late because the engine was busy still waited —
-  same convention as bench._poisson_run).
+  (an arrival submitted late because the engine was busy still waited).
 - TPOT = (finish_s - first_token_s) / (n_tokens - 1) for n_tokens >= 2.
 - A request MEETS SLO iff it completed normally ("stop"/"length"),
   TTFT <= ttft_slo_ms, and (n_tokens < 2 or TPOT <= tpot_slo_ms).

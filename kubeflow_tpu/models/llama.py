@@ -73,18 +73,18 @@ class LlamaConfig:
     # kernel (ops/flash_decode.py — online softmax over KV blocks, int8
     # dequant fused at the block load, GQA regrouped in-kernel), "auto"
     # the selection policy (flash where it compiles — a TPU target and a
-    # head_dim the kernel tiles — xla elsewhere; KTPU_DECODE_ATTN env
-    # overrides the default). Orthogonal to attention_impl, which
-    # governs the TRAINING/prefill full-sequence attention.
+    # head_dim the kernel tiles — xla elsewhere:
+    # ops/pallas_compat.resolve_flash_impl). Orthogonal to attention_impl,
+    # which governs the TRAINING/prefill full-sequence attention.
     decode_attention_impl: str = "auto"
     # serving PREFILL chunk attention (ISSUE 20): "xla" the reference
     # mha einsum, "flash" the fused Pallas chunked-prefill kernel
     # (ops/flash_prefill.py — online softmax over KV blocks, q_offset
     # causal masking, int8 dequant fused at the block load), "auto" the
-    # selection policy (the decode rule; KTPU_PREFILL_ATTN env overrides
-    # the default). Governs the serving prefill_inner/
-    # prefill_continue_inner bodies — TRAINING attention stays on
-    # attention_impl.
+    # selection policy (the decode rule:
+    # ops/pallas_compat.resolve_flash_impl). Governs the serving
+    # prefill_inner/prefill_continue_inner bodies — TRAINING attention
+    # stays on attention_impl.
     prefill_attention_impl: str = "auto"
 
     def __post_init__(self):
@@ -1186,18 +1186,3 @@ def load_hf(path: str, cfg: LlamaConfig | None = None, *,
         params = jax.tree.map(jnp.asarray, params)
     return params, cfg
 
-
-def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
-    """Training FLOPs/token (fwd+bwd ~ 6*N params + attention quadratic term)
-    for MFU accounting. Matches the standard 6N + 12*L*H*S approximation
-    (PaLM-appendix convention: the causal attention term is NOT halved,
-    even though the Pallas kernel skips fully-masked KV blocks — at the
-    bench shape attention is ~11% of the total, so the convention flatters
-    causal MFU by a few percent of that share; kept because every public
-    MFU number this is compared against uses the same convention)."""
-    d, f, hd = cfg.d_model, cfg.d_ff, cfg.head_dim
-    nh, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
-    matmul_params = L * (d * nh * hd + 2 * d * nkv * hd + nh * hd * d + 3 * d * f)
-    embed_params = cfg.vocab_size * d  # lm_head matmul counts; embed gather ~free
-    attn_flops = 12 * L * nh * hd * seq_len  # 2 matmuls * 2 (fwd) * 3 (bwd) * S
-    return 6.0 * (matmul_params + embed_params) + attn_flops

@@ -149,13 +149,3 @@ def loss_fn(params: Params, batch: dict[str, jax.Array], cfg: MoELlamaConfig):
     ce = total / denom
     return ce + aux, {"loss": ce, "aux_loss": aux, "tokens": jnp.sum(mask)}
 
-
-def flops_per_token(cfg: MoELlamaConfig, seq_len: int) -> float:
-    """Training FLOPs/token counting only ACTIVE experts (top_k of E)."""
-    d, f, hd = cfg.d_model, cfg.d_ff, cfg.head_dim
-    nh, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
-    attn_params = L * (d * nh * hd + 2 * d * nkv * hd + nh * hd * d)
-    moe_params = L * (cfg.top_k * 3 * d * f + d * cfg.n_experts)
-    embed_params = cfg.vocab_size * d
-    attn_flops = 12 * L * nh * hd * seq_len
-    return 6.0 * (attn_params + moe_params + embed_params) + attn_flops

@@ -1,10 +1,7 @@
 """Build/runtime stamp for /healthz (ISSUE 17 satellite): the
 kubeflow_tpu version plus the jax/jaxlib pair and the live device view,
 so fleet tooling can detect restarts and version skew from one GET.
-
-This is the bench runtime-stamp helper promoted into the package —
-bench._runtime_stamp delegates here so a committed record and a live
-/healthz can never disagree on what "the runtime" means."""
+"""
 
 from __future__ import annotations
 

@@ -2,8 +2,8 @@
 that reads the serving engine's KV cache WHERE IT LIES (ISSUE 15, ROADMAP
 #5; in place since ISSUE 28).
 
-Decode re-reads the KV span every step, so at serving dims the attention
-bucket of `serving_decode_breakdown` is HBM traffic the XLA einsum path
+Decode re-reads the KV span every step, so at serving dims a decode
+step's attention is HBM traffic the XLA einsum path
 (separate score/softmax/weighted-sum programs) cannot tile optimally.
 This kernel streams each live KV block HBM→VMEM exactly once and runs the
 whole attention — scores, per-token int8 dequant, online softmax,
@@ -60,7 +60,6 @@ path fork other than `interpret=`.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -84,23 +83,24 @@ FORCE_INTERPRET = False
 #: bytes past the context cost more than the steps saved.
 DEFAULT_BLOCK_KV = 512
 
-#: env override for the auto impl selection (`LlamaConfig
-#: .decode_attention_impl == "auto"`): "flash" | "xla". An EXPLICIT
-#: config value wins over the env (tests and the bench A/B pin impls per
-#: engine); the env wins over the platform default (the operational
-#: kill-switch for a fleet without config pushes).
-IMPL_ENV = "KTPU_DECODE_ATTN"
+#: the impl selection (`LlamaConfig.decode_attention_impl`): an
+#: EXPLICIT "flash" | "xla" wins (tests pin impls per engine); "auto"
+#: is decided from what the process can observe, the target platform
+#: and the KV layout, and from nothing a user sets on the machine: a
+#: trace or a /metrics reading names the impl, and no one has to ask
+#: what the environment held where it was made.
 
 
 def resolve_impl(configured: str = "auto", *, head_dim: int,
                  n_kv_heads: int) -> str:
-    """Selection policy: explicit config ("xla"/"flash") >
-    KTPU_DECODE_ATTN env > flash where it compiles (TPU target, KV
-    layout the kernel tiles), xla elsewhere — see
-    pallas_compat.resolve_flash_impl. Static — resolved at trace time,
-    so each engine's compiled menu covers exactly one impl."""
+    """Selection policy: explicit config ("xla"/"flash") > flash
+    where it compiles (TPU target, KV layout the kernel tiles), xla
+    elsewhere — see pallas_compat.resolve_flash_impl (it raises on an
+    explicit "flash" at a layout the TPU compiler would refuse).
+    Static — resolved at trace time, so each engine's compiled menu
+    covers exactly one impl."""
     return pallas_compat.resolve_flash_impl(
-        configured, os.environ.get(IMPL_ENV), head_dim=head_dim,
+        configured, head_dim=head_dim,
         n_kv_heads=n_kv_heads)
 
 
@@ -109,8 +109,8 @@ def _resolve_interpret(interpret):
         return interpret
     if FORCE_INTERPRET:
         return True
-    # non-TPU target: interpreter mode — the differential tests' CPU
-    # fast lane (and the bench's CPU A/B smoke) run the SAME kernel body
+    # non-TPU target: interpreter mode: the differential tests' CPU
+    # fast lane runs the SAME kernel body the chip compiles
     return pallas_compat.target_platform() != "tpu"
 
 

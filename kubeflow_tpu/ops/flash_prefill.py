@@ -56,7 +56,6 @@ path fork other than `interpret=`.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -78,24 +77,25 @@ FORCE_INTERPRET = False
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_KV = 256
 
-#: env override for the auto impl selection (`LlamaConfig
-#: .prefill_attention_impl == "auto"`): "flash" | "xla". An EXPLICIT
-#: config value wins over the env (tests and the bench A/B pin impls per
-#: engine); the env wins over the platform default (the operational
-#: kill-switch for a fleet without config pushes) — the KTPU_DECODE_ATTN
-#: pattern.
-IMPL_ENV = "KTPU_PREFILL_ATTN"
+#: the impl selection (`LlamaConfig.prefill_attention_impl`): an
+#: EXPLICIT "flash" | "xla" wins (tests pin impls per engine); "auto"
+#: is decided from what the process can observe, the target platform
+#: and the KV layout, and from nothing a user sets on the machine
+#: (flash_decode.resolve_impl's rule: both kernels tile the same KV
+#: layout, so one policy in pallas_compat serves both, and an engine
+#: never runs one as a kernel and the other as XLA by accident).
 
 
 def resolve_impl(configured: str = "auto", *, head_dim: int,
                  n_kv_heads: int) -> str:
-    """Selection policy: explicit config ("xla"/"flash") >
-    KTPU_PREFILL_ATTN env > flash where it compiles (TPU target, KV
-    layout the kernel tiles), xla elsewhere — see
-    pallas_compat.resolve_flash_impl. Static — resolved at trace time,
-    so each engine's compiled prefill menu covers exactly one impl."""
+    """Selection policy: explicit config ("xla"/"flash") > flash
+    where it compiles (TPU target, KV layout the kernel tiles), xla
+    elsewhere — see pallas_compat.resolve_flash_impl (it raises on an
+    explicit "flash" at a layout the TPU compiler would refuse).
+    Static — resolved at trace time, so each engine's compiled
+    prefill menu covers exactly one impl."""
     return pallas_compat.resolve_flash_impl(
-        configured, os.environ.get(IMPL_ENV), head_dim=head_dim,
+        configured, head_dim=head_dim,
         n_kv_heads=n_kv_heads)
 
 
@@ -104,8 +104,8 @@ def _resolve_interpret(interpret):
         return interpret
     if FORCE_INTERPRET:
         return True
-    # non-TPU target: interpreter mode — the differential tests' CPU
-    # fast lane (and the bench's CPU A/B smoke) run the SAME kernel body
+    # non-TPU target: interpreter mode: the differential tests' CPU
+    # fast lane runs the SAME kernel body the chip compiles
     return pallas_compat.target_platform() != "tpu"
 
 

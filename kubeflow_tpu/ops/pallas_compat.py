@@ -63,23 +63,19 @@ def flash_kv_refusal(head_dim: int, n_kv_heads: int) -> str | None:
             "per-head KV block cannot be tiled by Mosaic)")
 
 
-def resolve_flash_impl(configured: str, env_value: str | None, *,
-                       head_dim: int, n_kv_heads: int) -> str:
+def resolve_flash_impl(configured: str, *, head_dim: int,
+                       n_kv_heads: int) -> str:
     """The one selection policy of both serving attention kernels:
-    explicit config ("xla"/"flash") > env override > "flash" exactly
-    where it compiles (a TPU target and a KV layout the kernel tiles),
-    "xla" elsewhere. An explicit "flash" (config or env) at a layout the
-    TPU compiler refuses raises with the reason instead of failing at
-    the first prefill. Static: engines resolve once at construction."""
-    explicit = configured if configured in ("xla", "flash") else None
-    if explicit is None:
-        env_value = (env_value or "").strip().lower()
-        explicit = env_value if env_value in ("xla", "flash") else None
-    if explicit == "xla":
+    explicit config ("xla"/"flash"), else "flash" exactly where it
+    compiles (a TPU target and a KV layout the kernel tiles), "xla"
+    elsewhere. An explicit "flash" at a layout the TPU compiler refuses
+    raises with the reason instead of failing at the first prefill.
+    Static: engines resolve once at construction."""
+    if configured == "xla":
         return "xla"
     on_tpu = target_platform() == "tpu"
     refusal = flash_kv_refusal(head_dim, n_kv_heads) if on_tpu else None
-    if explicit == "flash":
+    if configured == "flash":
         if refusal:
             raise ValueError(f"flash attention kernel refused: {refusal}")
         return "flash"

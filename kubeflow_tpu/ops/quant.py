@@ -19,7 +19,6 @@ import collections
 import contextlib
 import contextvars
 import math
-import os
 from typing import Any
 
 import jax
@@ -44,34 +43,27 @@ def is_quantized(wt: Any) -> bool:
 # activation rows against a whole 2D weight). PROMOTED to the default
 # TPU weight-read path in ISSUE 15 (ROADMAP #5: "kernels on by default
 # where they win"), behind the same impl-selection mechanism as the
-# flash-decode kernel: default "pallas" on TPU, "xla" elsewhere, env
-# override KTPU_QUANT_MATMUL=xla|pallas (the fleet kill-switch), and
-# USE_PALLAS_DEQUANT=True as the programmatic force-on the older tests
-# use. Inside the serving scans the kernel is handed the whole stacked
-# leaf and the layer's index (_fused_or_leaf): a layer sliced out by the
-# scan reached the custom call as a copy made on every decode step, which
-# is what the r2 record's "-17% on scan-of-steps chunk programs" was
-# (ops/quant_matmul.py has the measured numbers).
+# flash-decode kernel: "pallas" on TPU, "xla" elsewhere and under a
+# GSPMD mesh, and USE_PALLAS_DEQUANT=True as the programmatic force-on
+# the older tests use. Inside the serving scans the kernel is handed the
+# whole stacked leaf and the layer's index (_fused_or_leaf): a layer
+# sliced out by the scan reached the custom call as a copy made on every
+# decode step, which is what the r2 record's "-17% on scan-of-steps chunk
+# programs" was (ops/quant_matmul.py has the measured numbers).
 USE_PALLAS_DEQUANT: bool = False
-
-#: env override for the quant-matmul impl selection: "pallas" | "xla".
-QUANT_MATMUL_ENV = "KTPU_QUANT_MATMUL"
 
 
 def resolve_quant_matmul_impl() -> str:
     """"pallas" | "xla" — which lowering decode-shaped int8 matmuls take:
-    USE_PALLAS_DEQUANT (programmatic force-on) > KTPU_QUANT_MATMUL env >
-    xla wherever XLA will partition the program (an active GSPMD mesh:
-    the Mosaic custom call has no partitioning rule, the attention
-    kernels' boundary) > platform default (pallas on TPU, xla
-    elsewhere). The probes are the mesh-aware ones in ops/pallas_compat
-    that the flash kernels use, so the kernel defaults cannot diverge on
-    the AOT-for-TPU-from-CPU scenario."""
+    USE_PALLAS_DEQUANT (programmatic force-on) > xla wherever XLA will
+    partition the program (an active GSPMD mesh: the Mosaic custom call
+    has no partitioning rule, the attention kernels' boundary) >
+    platform default (pallas on TPU, xla elsewhere). The probes are the
+    mesh-aware ones in ops/pallas_compat that the flash kernels use, so
+    the kernel defaults cannot diverge on the AOT-for-TPU-from-CPU
+    scenario."""
     if USE_PALLAS_DEQUANT:
         return "pallas"
-    env = os.environ.get(QUANT_MATMUL_ENV, "").strip().lower()
-    if env in ("xla", "pallas"):
-        return env
     if pallas_compat.gspmd_partitioned():
         return "xla"
     return "pallas" if pallas_compat.target_platform() == "tpu" else "xla"
@@ -85,8 +77,8 @@ def _pallas_dequant_wanted(x, q) -> bool:
         return False
     if quant_matmul.FORCE_INTERPRET:
         return True
-    # selected but the compile TARGET isn't a TPU (the env set on a CPU
-    # box): compiled Mosaic cannot lower there — the XLA expression
+    # forced on but the compile TARGET isn't a TPU: compiled Mosaic
+    # cannot lower there — the XLA expression
     return (resolve_quant_matmul_impl() == "pallas"
             and pallas_compat.target_platform() == "tpu")
 

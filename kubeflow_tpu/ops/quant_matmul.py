@@ -1,6 +1,6 @@
 """Pallas (Mosaic) fused int8-dequant matmul — the default TPU
-weight-read path since ISSUE 15 (env kill-switch KTPU_QUANT_MATMUL=xla;
-see ops/quant.py resolve_quant_matmul_impl for the selection policy).
+weight-read path since ISSUE 15 (XLA's expression elsewhere and under a
+mesh; ops/quant.py resolve_quant_matmul_impl is the selection policy).
 
 Decode/verify matmuls are pure bandwidth: a handful of activation rows
 (m = slots × verify-positions, 4..~100) against every int8 weight in the
@@ -28,9 +28,9 @@ matmuls 2.45 ms for 1.75 GB (2.13 ms at 819 GB/s: 88 % of the HBM roof),
 and the p95 gap between tokens fell from 118.3 to 60.5 ms end to end.
 That copy is the cause of what an earlier toolchain's record here called
 "-17% on the engine's scan-of-steps chunk programs ... the custom call
-blocked XLA's cross-iteration weight prefetch". KTPU_QUANT_MATMUL=xla
-still flips the fleet back without a code push; quant.matmul gates on
-resolve_quant_matmul_impl() (or FORCE_INTERPRET in tests); see
+blocked XLA's cross-iteration weight prefetch". quant.matmul gates on
+resolve_quant_matmul_impl(), which reads the target platform and the
+ambient mesh and nothing a user sets (or FORCE_INTERPRET in tests); see
 ops/quant.py for the policy.
 
 Gating (quant.matmul decides): m ≤ MAX_ROWS (decode/verify shapes; big

@@ -32,7 +32,6 @@ is automatically the reverse pipeline (activations rotate back up the ring).
 
 from __future__ import annotations
 
-import os
 import time
 from typing import Any, Callable, Sequence
 
@@ -42,29 +41,18 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AXIS = "stage"
 
-#: env override for the serving stage-pipeline schedule (ISSUE 20):
-#: "overlapped"/"1" or "sync"/"0". An EXPLICIT engine arg wins over the
-#: env; the env wins over the default ("sync": overlap stays opt-in until
-#: a chip run has timed it).
-SCHEDULE_ENV = "KTPU_STAGE_OVERLAP"
-
-
 def resolve_schedule(configured: str | None = None) -> str:
-    """Stage-schedule selection policy: explicit config ("sync"/
-    "overlapped") > KTPU_STAGE_OVERLAP env > "sync". Static per engine —
-    the decode drivers bake the schedule into their dispatch loop."""
-    if configured is not None:
-        if configured not in ("sync", "overlapped"):
-            raise ValueError(
-                f"unknown stage schedule {configured!r} "
-                "(want 'sync' or 'overlapped')")
-        return configured
-    env = os.environ.get(SCHEDULE_ENV, "").strip().lower()
-    if env in ("overlapped", "1", "on"):
-        return "overlapped"
-    if env in ("sync", "0", "off", ""):
+    """Stage-schedule selection policy (ISSUE 20): the configured value
+    ("sync"/"overlapped"), else "sync": overlap stays opt-in until a chip
+    run has timed it. Static per engine — the decode drivers bake the
+    schedule into their dispatch loop."""
+    if configured is None:
         return "sync"
-    return "sync"
+    if configured not in ("sync", "overlapped"):
+        raise ValueError(
+            f"unknown stage schedule {configured!r} "
+            "(want 'sync' or 'overlapped')")
+    return configured
 
 
 def gpipe(

@@ -93,6 +93,13 @@ def _fold_seed24(seed: int) -> int:
 #: of rounds after a workload shift while still smoothing round noise.
 SPEC_EMA_ALPHA = 0.25
 
+#: COLD-START COST: the continuation-program menu is (block-multiple
+#: prefix) × (tail bucket) × log2(n_slots) full-model programs. warmup()
+#: pre-compiles only the first WARM_CONT_PAIRS (prefix, tail) pairs;
+#: colder pairs compile lazily on their first hit (that one wave pays
+#: ~seconds of XLA compile, subsequent hits are warm).
+WARM_CONT_PAIRS = 4
+
 
 class AdaptiveDraftLen:
     """Per-slot EMA of accepted drafts per verify round → the NEXT round's
@@ -246,7 +253,6 @@ class LLMEngine:
                  prefix_cache: bool = False, max_prefixes: int = 4,
                  prefix_cache_blocks: int | None = None,
                  quantize: str | None = None,
-                 warm_cont_pairs: int | None = 4,
                  kv_quantize: str | None = None,
                  decode_attention_impl: str | None = None,
                  prefill_attention_impl: str | None = None,
@@ -339,7 +345,7 @@ class LLMEngine:
         # -- serving attention impls: "xla" (the mha/einsum reference) vs
         # the fused Pallas "flash" kernels over the KV layout
         # (ops/flash_decode.py, ops/flash_prefill.py). The ctor args are
-        # convenience overrides of the LlamaConfig fields, so bench A/B
+        # convenience overrides of the LlamaConfig fields, so A/B
         # pairs and runtime configs need not rebuild the config.
         overrides = {}
         if decode_attention_impl is not None:
@@ -405,10 +411,6 @@ class LLMEngine:
         self.pipeline_decode = pipeline_decode
         self._pending: tuple | None = None
         self._inflight = np.zeros((n_slots,), np.int64)
-        # active-mask re-uploads (perf_counters() reports them beside
-        # the phase clock's decode buckets)
-        self._active_uploads = 0
-        self._perf_base: dict[str, Any] = {}
         # device-resident copy of the decode active mask: the mask only
         # changes at prefill/finish boundaries, so re-uploading it every
         # chunk paid a host->device transfer per chunk for identical
@@ -490,13 +492,6 @@ class LLMEngine:
         # a block pinned by an in-flight admission.
         self.prefix_cache_enabled = prefix_cache
         self.max_prefixes = max_prefixes
-        # COLD-START COST: the continuation-program menu is (block-
-        # multiple prefix) × (tail bucket) × log2(n_slots) full-model
-        # programs. warmup() pre-compiles only the first `warm_cont_pairs`
-        # (prefix, tail) pairs (None = all); colder pairs compile lazily
-        # on their first hit (that one wave pays ~seconds of XLA compile,
-        # subsequent hits are warm).
-        self.warm_cont_pairs = warm_cont_pairs
         self.prefix_block_tokens = 0
         self.kvcache: RadixKVCache | None = None
         if prefix_cache:
@@ -996,20 +991,14 @@ class LLMEngine:
 
     @_under_engine_mesh
     def _decode(self, params, cache, lengths, last_tokens, samp, key,
-                active, lora=None, *, steps: int, span: int | None = None,
-                sample: bool = True):
+                active, lora=None, *, steps: int, span: int | None = None):
         """`steps` chained decode iterations inside ONE program (lax.scan):
         a K-token chunk costs one dispatch round-trip instead of K. Slots
         that finish (EOS) mid-chunk keep decoding on device; the host drops
         their surplus tokens, and the slot's next prefill resets its
         state. `span` statically bounds the attention window (length-aware
         decode — see llama.decode_step). Emits packed [steps, n_slots,
-        out_cols] rows (_pack_out).
-
-        `sample=False` is the PROFILER's variant (serving_decode_breakdown):
-        raw argmax, no sampling pipeline, no penalty-count touch — timing
-        it against the full program isolates the sampling+penalties bucket
-        of a decode step. Never dispatched by live traffic."""
+        out_cols] rows (_pack_out)."""
         slots = jnp.arange(self.n_slots)
 
         def body(carry, _):
@@ -1022,26 +1011,21 @@ class LLMEngine:
                                            active=active)
             if aids is not None:
                 kv["aids"] = aids  # decode never re-assigns slots
-            if sample:
-                # seeded-key position: this step samples generated token
-                # #(lengths - prompt_len + 2) at absolute position
-                # lengths + 1 (prefill sampled token #1 AT position
-                # prompt_len == lengths, so passing bare `lengths` would
-                # reuse prefill's key)
-                key, toks = self._choose(logits, samp, key, slots, cnt,
-                                         lengths + 1)
-                # the generated-token counts only feed the penalty logit
-                # edits, and every prefill resets its slot's counts — so
-                # an all-unpenalized batch skips the [slots, vocab]
-                # scatter (read+write of the whole count buffer) entirely
-                kv["cnt"] = self._constrain_cnt(jax.lax.cond(
-                    jnp.any((samp[:, 3] != 0) | (samp[:, 4] != 0)),
-                    lambda c: c.at[slots, toks].add(
-                        active.astype(c.dtype)),
-                    lambda c: c, cnt))
-            else:
-                toks = jnp.argmax(logits, -1).astype(jnp.int32)
-                kv["cnt"] = cnt
+            # seeded-key position: this step samples generated token
+            # #(lengths - prompt_len + 2) at absolute position
+            # lengths + 1 (prefill sampled token #1 AT position
+            # prompt_len == lengths, so passing bare `lengths` would
+            # reuse prefill's key)
+            key, toks = self._choose(logits, samp, key, slots, cnt,
+                                     lengths + 1)
+            # the generated-token counts only feed the penalty logit
+            # edits, and every prefill resets its slot's counts — so
+            # an all-unpenalized batch skips the [slots, vocab]
+            # scatter (read+write of the whole count buffer) entirely
+            kv["cnt"] = self._constrain_cnt(jax.lax.cond(
+                jnp.any((samp[:, 3] != 0) | (samp[:, 4] != 0)),
+                lambda c: c.at[slots, toks].add(active.astype(c.dtype)),
+                lambda c: c, cnt))
             cache = kv
             lengths = lengths + active.astype(jnp.int32)
             last_tokens = jnp.where(active, toks, last_tokens)
@@ -1301,19 +1285,6 @@ class LLMEngine:
                 named_program("decode", self._decode, steps=steps, span=span),
                 donate_argnums=(1, 2, 3, 4, 5))
         return self._decode_fns[steps, span]
-
-    def _decode_nosample_fn(self, steps: int, span: int | None = None):
-        """The PROFILER's sampling-stripped decode variant (same call
-        signature as _decode_fn's programs): raw argmax, no sampling
-        pipeline, no penalty-count touch — timing it against the full
-        program isolates the sampling bucket of the decode breakdown.
-        A method (not an inline jit in the profiler) so the
-        stage-sharded engine can supply its pipelined twin."""
-        span = self.max_len if span is None else span
-        return jax.jit(
-            named_program("decode_nosample", self._decode, steps=steps,
-                          span=span, sample=False),
-            donate_argnums=(1, 2, 3, 4, 5))
 
     def _span_menu(self) -> list[int]:
         """Attention-span buckets: powers of two from 128 up to (and always
@@ -1881,15 +1852,12 @@ class LLMEngine:
             # width) combos, plus the per-prefix extract programs. Radix
             # hits reuse ANY block multiple up to the largest bucket
             # (longer reused prefixes belong to the chunked chain and
-            # compile lazily like the rest of it). Only the first
-            # `warm_cont_pairs` pairs are pre-compiled (the menu grows
-            # with buckets[-1]/block — see __init__); colder pairs
-            # compile lazily on their first hit.
+            # compile lazily like the rest of it), the first
+            # WARM_CONT_PAIRS pairs of it.
             bt = self.prefix_block_tokens
             pairs = [(p, t) for p in range(bt, self.buckets[-1] + 1, bt)
-                     for t in self.buckets if p + t <= self.max_len]
-            if self.warm_cont_pairs is not None:
-                pairs = pairs[:self.warm_cont_pairs]
+                     for t in self.buckets
+                     if p + t <= self.max_len][:WARM_CONT_PAIRS]
             # the banking path's raw-extract programs are cheap slice
             # jits, but a cold one still stalls the engine thread
             # mid-replay — warm every block multiple the banker can ask
@@ -2208,7 +2176,7 @@ class LLMEngine:
                "completed": s.completed, "rejected": s.rejected,
                "cancelled": self._cancelled_count,
                "decode_chunk": self.decode_chunk,
-               # the RESOLVED decode-attention impl (the A/B bench and
+               # the RESOLVED decode-attention impl (the benchmark and
                # /healthz read this, so a record can never misreport
                # which kernel path produced its numbers)
                "decode_attention_impl": self.cfg.decode_attention_impl,
@@ -2604,26 +2572,7 @@ class LLMEngine:
                 or not np.array_equal(active, self._active_host)):
             self._active_host = active.copy()
             self._active_dev = self._put(active)
-            self._active_uploads += 1
         return self._active_dev
-
-    def perf_counters(self, reset: bool = False) -> dict[str, Any]:
-        """Decode host-side attribution counters (dispatch wall, fetch+
-        replay wall, chunk/step counts, active-mask uploads): a view of
-        the phase clock since the last reset. The serving profiler
-        (training/profiling.serving_decode_breakdown) reads these to fill
-        the host buckets of the decode-step breakdown."""
-        c = self.phase_clock
-        now = {"dispatch_s": c.ns["decode_dispatch"] / 1e9,
-               "fetch_replay_s": (c.ns["decode_fetch"]
-                                  + c.ns["replay"]) / 1e9,
-               "decode_chunks": c.counts["decode_dispatch"],
-               "decode_steps": c.steps,
-               "active_uploads": self._active_uploads}
-        out = {k: v - self._perf_base.get(k, 0) for k, v in now.items()}
-        if reset:
-            self._perf_base = now
-        return out
 
     def _observe_round_tokens(self, n: int) -> None:
         """Fold one verify round's delivered-token count into the EMA the

@@ -103,14 +103,12 @@ class LLMModel(Model):
         # It is a LlamaConfig field, so `model: {decode_attention_impl:
         # ...}` works too; this top-level key is the ergonomic spelling
         # (and wins over the model dict when both are given). "auto"
-        # (the default) resolves flash on TPU / xla elsewhere, with the
-        # KTPU_DECODE_ATTN env as the fleet kill-switch.
+        # (the default) resolves flash on TPU / xla elsewhere.
         if decode_attention_impl is not None:
             self._cfg_overrides["decode_attention_impl"] = \
                 decode_attention_impl
         # config.prefill_attention_impl (ISSUE 20): the chunked-prefill
-        # twin — same spelling rules and env kill-switch
-        # (KTPU_PREFILL_ATTN) as decode_attention_impl.
+        # twin — same spelling rules as decode_attention_impl.
         if prefill_attention_impl is not None:
             self._cfg_overrides["prefill_attention_impl"] = \
                 prefill_attention_impl
@@ -186,13 +184,10 @@ class LLMModel(Model):
         # config.kv_layout (ISSUE 19): "slab" (the preallocated
         # [n_slots, max_len] rows — the default) or "paged"
         # (block-granular pool + per-slot block tables with
-        # oversubscribed admission, serving/paged.py). Explicit config
-        # wins over the KTPU_KV_LAYOUT env (the fleet-wide rollout
-        # lever); unset resolves slab. config.pool_blocks sizes the
-        # paged pool (None = the slab's exact HBM footprint).
-        import os
-
-        resolved = kv_layout or os.environ.get("KTPU_KV_LAYOUT") or "slab"
+        # oversubscribed admission, serving/paged.py); unset resolves
+        # slab. config.pool_blocks sizes the paged pool (None = the
+        # slab's exact HBM footprint).
+        resolved = kv_layout or "slab"
         if resolved not in ("slab", "paged"):
             raise ValueError(
                 f"kv_layout must be 'slab' or 'paged', got {resolved!r}")
@@ -334,8 +329,7 @@ class LLMModel(Model):
                     StageShardedEngine
 
                 # config.parallel.stage_schedule (ISSUE 20): "sync" |
-                # "overlapped" wavefront dispatch; None defers to the
-                # KTPU_STAGE_OVERLAP env, then the sync default
+                # "overlapped" wavefront dispatch; None is sync
                 eng = StageShardedEngine(
                     params, cfg, stage=self._pp, tensor=self._tp,
                     stage_schedule=self._parallel.get("stage_schedule"),

@@ -24,7 +24,7 @@ training schedule to a first-class serving configuration:
   - sampling/penalties/stop/cancel/radix logic is NOT duplicated: the
     drivers reuse every host-side engine mechanism and the models/llama
     `*_inner` bodies, so greedy/seeded output is byte-exact against the
-    single-program engine (the bench.py serving_multichip floor);
+    single-program engine (tests/test_multichip_serving.py);
   - prefix-KV reuse stays correct under pp: blocks bank per-stage with
     the stage id IN the radix block key (kvcache.StagePartitionedKVCache
     — namespace (ns, stage)), so a cached chain always materializes the
@@ -122,8 +122,7 @@ class StageShardedEngine(LLMEngine):
         # "overlapped" keeps every dispatch async — stage s's program
         # for microbatch m+1 enters the queue while m's outputs are
         # still in flight — and times per-stage dispatch→drain windows
-        # instead. Resolution: explicit ctor arg > KTPU_STAGE_OVERLAP
-        # env > sync (default off — the KTPU_DECODE_ATTN seam pattern).
+        # instead. Resolution: the explicit ctor arg, else sync.
         self.stage_schedule = resolve_schedule(stage_schedule)
         # geometry + placement first: _alloc_cache/_put run inside the
         # base __init__ and need the plan
@@ -360,21 +359,18 @@ class StageShardedEngine(LLMEngine):
                 run, donate_argnums=(2, 3, 4, 5, 6))
         return self._tail_progs[key]
 
-    def _tail_dec_prog(self, sample: bool = True):
-        key = ("tail_dec", sample)
+    def _tail_dec_prog(self):
+        key = "tail_dec"
         if key not in self._tail_progs:
             def run(logits, lengths, last_tokens, samp, key_, cnt, active):
                 slots = jnp.arange(self.n_slots)
-                if sample:
-                    key_, toks = self._choose(logits, samp, key_, slots,
-                                              cnt, lengths + 1)
-                    cnt = self._constrain_cnt(jax.lax.cond(
-                        jnp.any((samp[:, 3] != 0) | (samp[:, 4] != 0)),
-                        lambda c: c.at[slots, toks].add(
-                            active.astype(c.dtype)),
-                        lambda c: c, cnt))
-                else:
-                    toks = jnp.argmax(logits, -1).astype(jnp.int32)
+                key_, toks = self._choose(logits, samp, key_, slots,
+                                          cnt, lengths + 1)
+                cnt = self._constrain_cnt(jax.lax.cond(
+                    jnp.any((samp[:, 3] != 0) | (samp[:, 4] != 0)),
+                    lambda c: c.at[slots, toks].add(
+                        active.astype(c.dtype)),
+                    lambda c: c, cnt))
                 lengths = lengths + active.astype(jnp.int32)
                 last_tokens = jnp.where(active, toks, last_tokens)
                 return (lengths, last_tokens, key_, cnt,
@@ -386,7 +382,7 @@ class StageShardedEngine(LLMEngine):
 
     # -- drivers (the engine menu's stage-sharded twins) ----------------------
     # Same call signatures as the single jitted programs, so step()/
-    # warmup()/_do_decode()/profiling drive them unchanged. Dispatches
+    # warmup()/_do_decode() drive them unchanged. Dispatches
     # are async (the host never fetches inside a driver), so stage
     # programs of successive waves/microbatches overlap on disjoint
     # device groups; StageClock only blocks when stage_timing is armed.
@@ -455,7 +451,7 @@ class StageShardedEngine(LLMEngine):
             self._cont_fns[p, t, width] = driver
         return self._cont_fns[p, t, width]
 
-    def _decode_driver(self, steps: int, span: int, sample: bool):
+    def _decode_driver(self, steps: int, span: int):
         S, M = self.n_stages, self._plan.n_microbatches
         overlapped = self.stage_schedule == "overlapped"
 
@@ -509,7 +505,7 @@ class StageShardedEngine(LLMEngine):
                 logits = (acts[0] if M == 1
                           else jnp.concatenate(acts, axis=0))
                 (lengths, last_tokens, key_, cache["cnt"], out) = \
-                    self._tail_dec_prog(sample)(
+                    self._tail_dec_prog()(
                         logits, lengths, last_tokens, samp, key_,
                         cache["cnt"], active)
                 outs.append(out)
@@ -523,12 +519,8 @@ class StageShardedEngine(LLMEngine):
         span = self.max_len if span is None else span
         if (steps, span) not in self._decode_fns:
             self._decode_fns[steps, span] = self._decode_driver(
-                steps, span, sample=True)
+                steps, span)
         return self._decode_fns[steps, span]
-
-    def _decode_nosample_fn(self, steps: int, span: int | None = None):
-        span = self.max_len if span is None else span
-        return self._decode_driver(steps, span, sample=False)
 
     # -- prefix-KV plumbing (per-stage payloads) ------------------------------
 

@@ -50,8 +50,8 @@ cont path never re-quantizes a dequantized prefix (the spliced blocks
 already hold the bytes the slab path would recompute) — greedy AND
 seeded sampling outputs match the slab engine byte-for-byte.
 
-Selection: `kv_layout: slab|paged` via serving/llm_runtime.py (env
-`KTPU_KV_LAYOUT`), default slab. Like LLMEngine, this class may only
+Selection: `kv_layout: slab|paged` via serving/llm_runtime.py,
+default slab. Like LLMEngine, this class may only
 be constructed inside supervisor factories (scripts/check_dataplane.py
 lints the name).
 """

@@ -21,10 +21,9 @@ Rules (AST, no imports of the checked code):
    be called from inside `kubeflow_tpu/kvcache/`. Everyone else
    (PagedLLMEngine included) takes buffers from a `BlockPool`, so the
    pool's free-list/refcounts are the ONLY owner of KV memory.
-4. `bench.py` may build bare engines for raw-engine perf points, but its
-   chaos/HTTP dataplane sections must go through `EngineSupervisor` /
-   `LLMModel`; the repo-root bench is therefore out of scope here by
-   path, not by oversight (rule 1's scope is the library package).
+4. Rule 1's scope is the library package: `benchmark/` and
+   `chip_smoke.py` reach engines through `Platform` / `LLMModel` and are
+   out of scope here by path.
 
 Run: `python scripts/check_dataplane.py` — exit 0 clean, 1 with findings
 (one per line). The fast lane runs it via tests/test_dataplane_lint.py.

@@ -22,6 +22,18 @@ module under `kubeflow_tpu/ops/` that calls `pallas_call`:
    interpreter accepts block shapes Mosaic refuses, so every call site
    is also lowered for TPU (compiled, from the CPU fast lane) there.
 
+And one rule for the whole compiled path (ISSUE 29), whatever calls
+`pallas_call`:
+
+5. No module under `kubeflow_tpu/ops`, `kubeflow_tpu/parallel`,
+   `kubeflow_tpu/models` or `kubeflow_tpu/serving` reads the process
+   environment (`os.environ`, `os.getenv`), `serving/storage.py` apart
+   (paths and credentials: deployment settings). Which kernel, schedule
+   or KV layout runs is the configuration's explicit value or a rule over
+   what the code can observe (the target platform, the head layout, an
+   active mesh), so a trace never raises the question of what was set on
+   the machine that made it.
+
 Run: `python scripts/check_kernels.py` — exit 0 clean, 1 with findings
 (one per line). The fast lane runs it via tests/test_dataplane_lint.py.
 """
@@ -36,6 +48,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OPS = os.path.join(REPO, "kubeflow_tpu", "ops")
 TESTS = os.path.join(REPO, "tests")
 LOWERING_TEST = "test_kernels_lower_tpu.py"
+#: rule 5's scope, and the one module in it that reads deployment settings
+ENV_FREE_PACKAGES = ("ops", "parallel", "models", "serving")
+ENV_READ_ALLOWED = (os.path.join("serving", "storage.py"),)
 
 
 class _PallasCallVisitor(ast.NodeVisitor):
@@ -87,8 +102,57 @@ def _lowered_call_sites(tests_root: str) -> dict[str, int]:
     return {}
 
 
-def check(ops_root: str = OPS, tests_root: str = TESTS) -> list[str]:
+def _env_reads(tree: ast.AST) -> list[int]:
+    """Line numbers where the module touches the process environment:
+    `os.environ` / `os.getenv` (any use: a read is what the rule is
+    about, and these packages have no reason to write it either), or
+    either name imported from `os`."""
+    lines = []
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Attribute)
+                and node.attr in ("environ", "getenv")
+                and isinstance(node.value, ast.Name)
+                and node.value.id == "os"):
+            lines.append(node.lineno)
+        elif isinstance(node, ast.ImportFrom) and node.module == "os":
+            lines.extend(node.lineno for a in node.names
+                         if a.name in ("environ", "getenv"))
+    return lines
+
+
+def check_env_free(pkg_root: str) -> list[str]:
+    """Rule 5 over `<pkg_root>/{ops,parallel,models,serving}`."""
     findings: list[str] = []
+    for package in ENV_FREE_PACKAGES:
+        for dirpath, dirnames, filenames in os.walk(
+                os.path.join(pkg_root, package)):
+            dirnames[:] = sorted(d for d in dirnames if d != "__pycache__")
+            for fn in sorted(filenames):
+                path = os.path.join(dirpath, fn)
+                inside = os.path.relpath(path, pkg_root)
+                if not fn.endswith(".py") or inside in ENV_READ_ALLOWED:
+                    continue
+                rel = os.path.join(os.path.basename(pkg_root), inside)
+                with open(path, encoding="utf-8") as f:
+                    src = f.read()
+                if "environ" not in src and "getenv" not in src:
+                    continue
+                try:
+                    tree = ast.parse(src, filename=rel)
+                except SyntaxError as e:
+                    findings.append(f"{rel}: unparseable ({e})")
+                    continue
+                findings.extend(
+                    f"{rel}:{lineno}: reads the process environment — a "
+                    "selection on the compiled path is the configuration's "
+                    "explicit value or a rule over what the code can "
+                    "observe, never a variable set on the machine"
+                    for lineno in _env_reads(tree))
+    return findings
+
+
+def check(ops_root: str = OPS, tests_root: str = TESTS) -> list[str]:
+    findings: list[str] = check_env_free(os.path.dirname(ops_root))
     test_src = _test_references(tests_root)
     lowered = _lowered_call_sites(tests_root)
     for fn in sorted(os.listdir(ops_root)):

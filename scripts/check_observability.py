@@ -54,11 +54,11 @@ _INSTRUMENT_METHODS = ("counter", "gauge", "histogram")
 HOT_PATHS = {
     os.path.join("kubeflow_tpu", "serving", "llm.py"):
         ("step", "_step", "_do_decode", "_replay", "_run_prefill_actions",
-         "_decode", "_decode_fn", "_decode_nosample_fn", "_prefill",
-         "_prefill_cont", "_prefill_fn"),
+         "_decode", "_decode_fn", "_prefill", "_prefill_cont",
+         "_prefill_fn"),
     os.path.join("kubeflow_tpu", "serving", "multichip.py"):
         ("step", "_do_decode", "_decode_driver", "_decode_fn",
-         "_decode_nosample_fn", "_prefill_fn"),
+         "_prefill_fn"),
     os.path.join("kubeflow_tpu", "serving", "disagg.py"):
         ("step", "_prefill_loop"),
 }

@@ -6,8 +6,8 @@ the real sharded programs on 8 virtual CPU devices so multi-chip semantics
 (collectives, shardings, gang sizes) are exercised for real — just not fast.
 
 Env vars must be set before jax initializes its backends, hence the top of
-conftest. Tests marked `tpu` are skipped here and run on real hardware via
-bench.py / examples.
+conftest. Tests marked `tpu` are skipped here; the chip is reached through
+chip_smoke.py and benchmark/run.py.
 """
 
 import os

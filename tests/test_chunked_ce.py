@@ -1,6 +1,6 @@
 """Sequence-chunked cross-entropy parity (llama.ce_chunk): the 32k-context
 loss path must produce the same loss/grads as the whole-sequence CE.
-Anchor: bench.longctx seq32768 point; SURVEY §5.7 long-context scale."""
+Anchor: SURVEY §5.7 long-context scale."""
 
 import jax
 import jax.numpy as jnp

@@ -360,3 +360,37 @@ def test_kernel_lint_ignores_pallas_free_modules(tmp_path):
     ops, tests = _kernel_tree(
         tmp_path, "def op(x):\n    return x\n")
     assert lint.check(ops_root=ops, tests_root=tests) == []
+
+
+#: rule 5: (file inside kubeflow_tpu/, its source, the line it is flagged at
+#: or None)
+ENV_READ_CASES = [
+    ("ops/rogue.py", "import os\nX = os.environ.get('A_SWITCH')\n", 2),
+    ("parallel/rogue.py",
+     "import os\n\ndef f():\n    return os.getenv('A_SWITCH', '')\n", 4),
+    ("models/rogue.py", "from os import environ\n", 1),
+    ("serving/deep/rogue.py", "import os\nX = 'A' in os.environ\n", 2),
+    # deployment settings: the one module of the four packages that may
+    ("serving/storage.py", "import os\nX = os.environ.get('HOME')\n", None),
+    # outside the compiled path: not this rule's business
+    ("runtime/compile_cache.py",
+     "import os\nX = os.environ.get('JAX_COMPILATION_CACHE_DIR')\n", None),
+    # `environ` of something that is not `os`, and a mention in a comment
+    ("ops/fine.py", "# os.environ is not read here\nX = cfg.environ\n", None),
+]
+
+
+@pytest.mark.parametrize("inside,src,line", ENV_READ_CASES)
+def test_kernel_lint_flags_environment_reads(tmp_path, inside, src, line):
+    lint = _load_kernel_lint()
+    ops, tests = _kernel_tree(tmp_path, "def op(x):\n    return x\n")
+    path = tmp_path / "kubeflow_tpu" / inside
+    path.parent.mkdir(parents=True, exist_ok=True)
+    path.write_text(src)
+    findings = lint.check(ops_root=ops, tests_root=tests)
+    if line is None:
+        assert findings == []
+    else:
+        assert len(findings) == 1
+        assert findings[0].startswith(f"kubeflow_tpu/{inside}:{line}: ")
+        assert "process environment" in findings[0]

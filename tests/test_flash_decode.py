@@ -7,13 +7,13 @@ claim here is byte-level testable without hardware:
   int8 + f32 KV, span edge cases (span=1, span=max_len, ragged spans
   across slots), and S_v ∈ {1, 4} verify windows — all against
   llama.decode_attention's XLA reference on identical inputs;
-- selection policy: explicit config > KTPU_DECODE_ATTN env > platform
-  default (xla on this CPU box);
+- selection policy: explicit config, else the rule (target platform x
+  head_dim x kv heads; xla on this CPU box);
 - engine level: a full warmed xla-vs-flash engine pair (int8 KV, f32
   model) produces byte-identical greedy AND seeded outputs — the
   fast-lane core at toy dims; heavy combos (prefix-cache + chunked
-  prompts, speculative verify, bf16) ride the slow lane. The committed
-  A/B with per-bucket attribution is bench.py serving_kernels.
+  prompts, speculative verify, bf16) ride the slow lane. On the chip the
+  kernel is measured by the benchmark's `flash_decode_roofline`.
 """
 
 import dataclasses
@@ -369,56 +369,72 @@ def test_inactive_rows_attend_nothing():
     assert np.isfinite(ref).all()
 
 
-def test_selection_policy(monkeypatch):
+#: (configured, target platform, head_dim, kv heads) -> the impl, or the
+#: reason an explicit "flash" is refused. On a TPU target the policy
+#: follows what Mosaic can tile: auto takes the kernel where
+#: kv heads x head_dim fills whole 128-lane tiles.
+SELECTION_CASES = [
+    ("auto", "cpu", 8, 4, "xla"),        # auto off the chip
+    ("auto", "cpu", 128, 8, "xla"),      # ... at any layout
+    ("xla", "cpu", 8, 4, "xla"),         # an explicit value wins
+    ("flash", "cpu", 8, 4, "flash"),     # ... (interpret mode here)
+    ("auto", "tpu", 128, 8, "flash"),    # the serving cell's layout
+    ("auto", "tpu", 64, 8, "xla"),       # a head the kernel cannot tile
+    ("auto", "tpu", 64, 1, "flash"),     # lane dim == head_dim
+    ("auto", "tpu", 32, 4, "xla"),
+    ("xla", "tpu", 128, 8, "xla"),
+    ("flash", "tpu", 128, 8, "flash"),
+    ("flash", "tpu", 64, 8, ValueError("head_dim 64")),
+    ("flash", "tpu", 32, 4, ValueError("head_dim 32")),
+]
+
+
+def _check_selection(monkeypatch, resolve, configured, platform, head_dim,
+                     n_kv_heads, want):
     from kubeflow_tpu.ops import pallas_compat
 
-    toy = dict(head_dim=8, n_kv_heads=4)
-    monkeypatch.delenv(flash_decode.IMPL_ENV, raising=False)
-    # auto on this CPU box resolves xla
-    assert flash_decode.resolve_impl("auto", **toy) == "xla"
-    # env overrides the platform default...
-    monkeypatch.setenv(flash_decode.IMPL_ENV, "flash")
-    assert flash_decode.resolve_impl("auto", **toy) == "flash"
-    # ...but an explicit config wins over the env (bench A/B pins impls)
-    assert flash_decode.resolve_impl("xla", **toy) == "xla"
-    assert flash_decode.resolve_impl("flash", **toy) == "flash"
-    monkeypatch.setenv(flash_decode.IMPL_ENV, "xla")
-    assert flash_decode.resolve_impl("flash", **toy) == "flash"
+    monkeypatch.setattr(pallas_compat, "target_platform", lambda: platform)
+    if isinstance(want, Exception):
+        with pytest.raises(type(want), match=str(want)):
+            resolve(configured, head_dim=head_dim, n_kv_heads=n_kv_heads)
+    else:
+        assert resolve(configured, head_dim=head_dim,
+                       n_kv_heads=n_kv_heads) == want
+
+
+@pytest.mark.parametrize(
+    "configured,platform,head_dim,n_kv_heads,want", SELECTION_CASES)
+def test_selection_policy(monkeypatch, configured, platform, head_dim,
+                          n_kv_heads, want):
+    _check_selection(monkeypatch, flash_decode.resolve_impl, configured,
+                     platform, head_dim, n_kv_heads, want)
+
+
+def test_config_refuses_an_unknown_decode_impl():
     with pytest.raises(ValueError):
-        llama.LlamaConfig.tiny().__class__(
-            **{**dataclasses.asdict(llama.LlamaConfig.tiny()),
-               "decode_attention_impl": "mosaic"})
-    # on a TPU target the policy follows what Mosaic can tile: auto
-    # takes the kernel at head_dim 128 only, and an explicit flash at a
-    # head_dim the compiler refuses raises with the reason
-    monkeypatch.delenv(flash_decode.IMPL_ENV)
-    monkeypatch.setattr(pallas_compat, "target_platform", lambda: "tpu")
-    assert flash_decode.resolve_impl(
-        "auto", head_dim=128, n_kv_heads=8) == "flash"
-    assert flash_decode.resolve_impl(
-        "auto", head_dim=64, n_kv_heads=8) == "xla"
-    assert flash_decode.resolve_impl(
-        "auto", head_dim=64, n_kv_heads=1) == "flash"   # lane dim == hd
-    with pytest.raises(ValueError, match="head_dim 64"):
-        flash_decode.resolve_impl("flash", head_dim=64, n_kv_heads=8)
-    monkeypatch.setenv(flash_decode.IMPL_ENV, "flash")
-    with pytest.raises(ValueError, match="head_dim 32"):
-        flash_decode.resolve_impl("auto", head_dim=32, n_kv_heads=4)
+        dataclasses.replace(llama.LlamaConfig.tiny(),
+                            decode_attention_impl="mosaic")
 
 
-def test_quant_matmul_selection_policy(monkeypatch):
-    """The promoted weight-read path follows the same shape of policy:
-    force-on flag > KTPU_QUANT_MATMUL env > platform default (xla on
-    this CPU box)."""
-    from kubeflow_tpu.ops import quant
+@pytest.mark.parametrize("forced,platform,gspmd,want", [
+    (False, "cpu", False, "xla"),      # this CPU box
+    (False, "tpu", False, "pallas"),   # one chip
+    (False, "tpu", True, "xla"),       # XLA partitions the program
+    (False, "cpu", True, "xla"),
+    (True, "cpu", False, "pallas"),    # the programmatic force-on
+    (True, "tpu", True, "pallas"),
+])
+def test_quant_matmul_selection_policy(monkeypatch, forced, platform,
+                                       gspmd, want):
+    """The weight-read path's policy has the same shape: the force-on
+    flag, else XLA wherever a GSPMD mesh partitions the program, else the
+    target platform."""
+    from kubeflow_tpu.ops import pallas_compat, quant
 
-    monkeypatch.delenv(quant.QUANT_MATMUL_ENV, raising=False)
-    assert quant.resolve_quant_matmul_impl() == "xla"   # CPU default
-    monkeypatch.setenv(quant.QUANT_MATMUL_ENV, "pallas")
-    assert quant.resolve_quant_matmul_impl() == "pallas"
-    monkeypatch.setenv(quant.QUANT_MATMUL_ENV, "xla")
-    monkeypatch.setattr(quant, "USE_PALLAS_DEQUANT", True)
-    assert quant.resolve_quant_matmul_impl() == "pallas"
+    monkeypatch.setattr(quant, "USE_PALLAS_DEQUANT", forced)
+    monkeypatch.setattr(pallas_compat, "target_platform", lambda: platform)
+    monkeypatch.setattr(pallas_compat, "gspmd_partitioned", lambda: gspmd)
+    assert quant.resolve_quant_matmul_impl() == want
 
 
 # -- engine level -------------------------------------------------------------
@@ -487,8 +503,7 @@ def test_engine_penalized_greedy_parity(engine_pair):
 def test_engine_prefix_cache_and_chunked_parity():
     """The heavy engine gauntlet: prefix-cache hits (radix admission →
     continuation programs) and chunked long prompts through a flash
-    engine match the xla engine byte-for-byte, greedy and seeded — the
-    in-engine twin of bench.py serving_kernels' committed parity."""
+    engine match the xla engine byte-for-byte, greedy and seeded."""
     cfg = dataclasses.replace(llama.LlamaConfig.tiny(),
                               dtype=jnp.float32)
     params = llama.init(jax.random.key(0), cfg)
@@ -595,18 +610,3 @@ def test_auto_pins_to_xla_under_gspmd_sharding():
     eng = LLMEngine(params, cfg, **ENG_KW)   # no mesh: same pin
     assert eng.cfg.decode_attention_impl == "xla"
     eng.close()
-
-
-def test_breakdown_attn_subbuckets_on_flash_engine(engine_pair):
-    """serving_decode_breakdown's attn_kernel/attn_dequant probes run
-    the SELECTED impl — on the flash engine the probe exercises the
-    kernel, and the int8 cache yields a real dequant sub-bucket."""
-    from kubeflow_tpu.training.profiling import serving_decode_breakdown
-
-    _, ef = engine_pair
-    bd = serving_decode_breakdown(ef, steps=1, iters=2)
-    b = bd["buckets_ms"]
-    assert b["attn_kernel"] is not None and b["attn_kernel"] >= 0
-    assert b["attn_dequant"] is not None and b["attn_dequant"] >= 0
-    # profiling leaves the engine serviceable (warmup-style reset)
-    assert len(ef.generate([1, 2, 3], 4)) == 4

@@ -9,13 +9,13 @@ byte-level testable without hardware:
   multi-kv-block shapes, and paged block-table indirection with a
   scrambled pool — all against llama.prefill_attention's XLA reference
   on identical inputs;
-- selection policy: explicit config > KTPU_PREFILL_ATTN env > platform
-  default (xla on this CPU box);
+- selection policy: explicit config, else the decode kernel's rule (xla
+  on this CPU box);
 - engine level: a warmed xla-vs-flash engine pair (int8 KV, f32 model,
   radix prefix cache ON) produces byte-identical greedy AND seeded
   outputs across full prefills, prefix-hit continuations, and chunked
   long prompts. Heavy combos (paged engine pair, big offsets) ride the
-  slow lane. The committed TTFT A/B is bench.py serving_prefill_kernels.
+  slow lane. No cell of the benchmark reads this kernel alone yet.
 """
 
 import dataclasses
@@ -179,17 +179,28 @@ def test_q_offset_must_be_static_and_nonnegative():
 
 # -- selection policy ---------------------------------------------------------
 
-def test_resolve_impl_policy(monkeypatch):
-    toy = dict(head_dim=8, n_kv_heads=4)
-    monkeypatch.delenv(flash_prefill.IMPL_ENV, raising=False)
-    assert flash_prefill.resolve_impl("xla", **toy) == "xla"
-    assert flash_prefill.resolve_impl("flash", **toy) == "flash"
-    assert flash_prefill.resolve_impl("auto", **toy) == "xla"  # CPU default
-    monkeypatch.setenv(flash_prefill.IMPL_ENV, "flash")
-    assert flash_prefill.resolve_impl("auto", **toy) == "flash"
-    assert flash_prefill.resolve_impl("xla", **toy) == "xla"  # explicit wins
-    monkeypatch.setenv(flash_prefill.IMPL_ENV, "xla")
-    assert flash_prefill.resolve_impl("auto", **toy) == "xla"
+@pytest.mark.parametrize(
+    "configured,platform,head_dim,n_kv_heads,want", [
+        ("auto", "cpu", 8, 4, "xla"),        # auto off the chip
+        ("xla", "cpu", 8, 4, "xla"),         # an explicit value wins
+        ("flash", "cpu", 8, 4, "flash"),
+        ("auto", "tpu", 128, 8, "flash"),    # the serving cell's layout
+        ("auto", "tpu", 64, 8, "xla"),       # the decode rule: one policy
+        ("xla", "tpu", 128, 8, "xla"),
+        ("flash", "tpu", 64, 8, ValueError("head_dim 64")),
+    ])
+def test_resolve_impl_policy(monkeypatch, configured, platform, head_dim,
+                             n_kv_heads, want):
+    from kubeflow_tpu.ops import pallas_compat
+
+    monkeypatch.setattr(pallas_compat, "target_platform", lambda: platform)
+    if isinstance(want, Exception):
+        with pytest.raises(type(want), match=str(want)):
+            flash_prefill.resolve_impl(configured, head_dim=head_dim,
+                                       n_kv_heads=n_kv_heads)
+    else:
+        assert flash_prefill.resolve_impl(
+            configured, head_dim=head_dim, n_kv_heads=n_kv_heads) == want
 
 
 def test_config_validates_impl():

@@ -350,7 +350,7 @@ def test_stage_sharded_parity_with_flash_decode_impl(params):
     the single-program FLASH engine: tokens AND logprobs, greedy and
     seeded, int8 KV. (Flash-vs-flash: the suite's contract is the
     stage machinery's exactness; the flash-vs-xla contract is
-    tests/test_flash_decode.py and the bench floor.)"""
+    tests/test_flash_decode.py.)"""
     import dataclasses
 
     cfg = dataclasses.replace(CFG, decode_attention_impl="flash")

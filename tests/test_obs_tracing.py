@@ -371,19 +371,26 @@ def test_kv_block_counts_reach_usage_and_metrics(toy_engine):
     assert c.usage(first, None)["kv_blocks"] == [3, 8]
 
 
-def test_perf_counters_is_a_view_of_the_clock(toy_engine):
-    """`self._perf` is gone: the decode host counters the serving
-    profiler reads are the phase clock's, since the last reset."""
+def test_decode_host_counters_are_read_from_the_phase_clock(toy_engine):
+    """`self._perf` and the engine's second view of it are gone: the
+    decode host counters are the phase clock's own, read as differences."""
     eng = toy_engine
     assert not hasattr(eng, "_perf")
-    eng.perf_counters(reset=True)
-    zero = eng.perf_counters()
-    assert set(zero) == {"dispatch_s", "fetch_replay_s", "decode_chunks",
-                         "decode_steps", "active_uploads"}
+    c = eng.phase_clock
+
+    def read():
+        return {"dispatch_s": c.ns["decode_dispatch"] / 1e9,
+                "fetch_replay_s": (c.ns["decode_fetch"]
+                                   + c.ns["replay"]) / 1e9,
+                "decode_chunks": c.counts["decode_dispatch"],
+                "decode_steps": c.steps}
+
+    base = read()
+    zero = {k: v - base[k] for k, v in read().items()}
     assert zero["decode_chunks"] == zero["decode_steps"] == 0
     assert zero["dispatch_s"] == 0
     eng.generate([1, 2, 3], 8)
-    pc = eng.perf_counters()
+    pc = {k: v - base[k] for k, v in read().items()}
     assert pc["decode_chunks"] >= 3 and pc["decode_steps"] >= 7
     assert pc["dispatch_s"] > 0 and pc["fetch_replay_s"] > 0
     assert (pc["decode_chunks"] <= pc["decode_steps"]
@@ -420,7 +427,6 @@ def test_engine_programs_have_one_name_per_kind(toy_engine):
     eng = toy_engine
     assert eng._decode_fn(2).__name__ == "decode"
     assert eng._decode_fn(1, 32).__name__ == "decode"
-    assert eng._decode_nosample_fn(1).__name__ == "decode_nosample"
     assert eng._prefill_fn(16, 1).__name__ == "prefill"
     assert eng._cont_fn(16, 16, 1).__name__ == "prefill_cont"
     assert eng._extract_fn(16).__name__ == "extract_prefix"

@@ -101,26 +101,25 @@ def test_paged_ctor_validation(tiny):
                        pool_blocks=3)
 
 
-def test_runtime_kv_layout_seam(monkeypatch):
+@pytest.mark.parametrize("config,want", [
+    ({}, "slab"),                                   # unset resolves slab
+    ({"kv_layout": "slab"}, "slab"),
+    ({"kv_layout": "paged"}, "paged"),
+    ({"kv_layout": "bogus"}, "kv_layout"),
+    ({"kv_layout": "paged", "parallel": {"stage": 2}}, "stage"),
+    ({"kv_layout": "paged", "mesh": {"tensor": 2}}, "mesh"),
+    ({"kv_layout": "paged", "disaggregated": True}, "disaggregated"),
+])
+def test_runtime_kv_layout_seam(config, want):
+    """The layout is the configuration's `kv_layout` and nothing else; a
+    combination the paged engine cannot serve is refused by name."""
     from kubeflow_tpu.serving.llm_runtime import LLMModel
 
-    monkeypatch.delenv("KTPU_KV_LAYOUT", raising=False)
-    assert LLMModel("m")._kv_layout == "slab"
-    assert LLMModel("m", kv_layout="paged")._kv_layout == "paged"
-    # env is the fleet lever; explicit config still wins
-    monkeypatch.setenv("KTPU_KV_LAYOUT", "paged")
-    assert LLMModel("m")._kv_layout == "paged"
-    assert LLMModel("m", kv_layout="slab")._kv_layout == "slab"
-    monkeypatch.setenv("KTPU_KV_LAYOUT", "bogus")
-    with pytest.raises(ValueError, match="kv_layout"):
-        LLMModel("m")
-    monkeypatch.delenv("KTPU_KV_LAYOUT")
-    with pytest.raises(ValueError, match="stage"):
-        LLMModel("m", kv_layout="paged", parallel={"stage": 2})
-    with pytest.raises(ValueError, match="mesh"):
-        LLMModel("m", kv_layout="paged", mesh={"tensor": 2})
-    with pytest.raises(ValueError, match="disaggregated"):
-        LLMModel("m", kv_layout="paged", disaggregated=True)
+    if want in ("slab", "paged"):
+        assert LLMModel("m", **config)._kv_layout == want
+    else:
+        with pytest.raises(ValueError, match=want):
+            LLMModel("m", **config)
 
 
 def test_stage_sharded_rejects_paged(tiny):

@@ -5,8 +5,8 @@ serving/multichip.py):
   BIT-exact against the monolithic matmul (row/column slicing only, no
   float-sum reassociation) for every rank, via the injectable shift —
   no shard_map needed in a single process;
-- resolve_schedule: explicit config > KTPU_STAGE_OVERLAP env > sync
-  default, invalid explicit raises;
+- resolve_schedule: the configured value, else the sync default; an
+  invalid value raises;
 - StagePerf carries the schedule kind into snapshot()/pipeline_perf();
 - engine level: the overlapped wavefront dispatch is byte-identical to
   the sync schedule on a virtual pp2 staging (the schedule changes WHEN
@@ -85,20 +85,19 @@ def test_collective_matmul_under_shard_map():
 
 # -- schedule seam ------------------------------------------------------------
 
-def test_resolve_schedule_policy(monkeypatch):
-    monkeypatch.delenv(pipeline.SCHEDULE_ENV, raising=False)
-    assert pipeline.resolve_schedule() == "sync"
-    assert pipeline.resolve_schedule("overlapped") == "overlapped"
-    assert pipeline.resolve_schedule("sync") == "sync"
-    monkeypatch.setenv(pipeline.SCHEDULE_ENV, "1")
-    assert pipeline.resolve_schedule() == "overlapped"
-    monkeypatch.setenv(pipeline.SCHEDULE_ENV, "overlapped")
-    assert pipeline.resolve_schedule() == "overlapped"
-    assert pipeline.resolve_schedule("sync") == "sync"   # explicit wins
-    monkeypatch.setenv(pipeline.SCHEDULE_ENV, "0")
-    assert pipeline.resolve_schedule() == "sync"
-    with pytest.raises(ValueError):
-        pipeline.resolve_schedule("bogus")
+@pytest.mark.parametrize("configured,want", [
+    (None, "sync"),                  # unset: overlap stays opt-in
+    ("sync", "sync"),
+    ("overlapped", "overlapped"),
+    ("bogus", ValueError),
+    ("1", ValueError),               # the two names are the only values
+])
+def test_resolve_schedule_policy(configured, want):
+    if want is ValueError:
+        with pytest.raises(ValueError):
+            pipeline.resolve_schedule(configured)
+    else:
+        assert pipeline.resolve_schedule(configured) == want
 
 
 def test_stageperf_snapshot_reports_schedule():
@@ -158,19 +157,16 @@ def test_overlapped_schedule_byte_parity():
         assert 0.0 <= v <= 1.0
 
 
-def test_schedule_env_seam_on_engine(monkeypatch):
-    monkeypatch.setenv(pipeline.SCHEDULE_ENV, "overlapped")
+@pytest.mark.parametrize("configured,want", [
+    (None, "sync"), ("overlapped", "overlapped")])
+def test_schedule_seam_on_engine(configured, want):
+    """The engine's schedule is its constructor's value, else sync, and
+    the stage clock reports the one that runs."""
     params = llama.init(jax.random.key(7), CFG)
-    eng = StageShardedEngine(params, CFG, stage=2, **KW)
+    eng = StageShardedEngine(params, CFG, stage=2,
+                             stage_schedule=configured, **KW)
     try:
-        assert eng.stage_schedule == "overlapped"
-        assert eng.pipeline_perf()["schedule"] == "overlapped"
-    finally:
-        eng.close()
-    # explicit arg beats the env
-    eng = StageShardedEngine(params, CFG, stage=2, stage_schedule="sync",
-                             **KW)
-    try:
-        assert eng.stage_schedule == "sync"
+        assert eng.stage_schedule == want
+        assert eng.pipeline_perf()["schedule"] == want
     finally:
         eng.close()

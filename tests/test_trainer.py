@@ -138,22 +138,3 @@ def test_llama_scan_vs_unrolled_layers_identical():
     # fp32: identical math; fusion reassociation may flip last ulps only
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-5, atol=1e-5)
-
-
-def test_device_peak_flops_is_an_exact_lookup_that_raises():
-    """A utilization needs the peak of the device it ran on: an exact
-    device_kind match, and an error — not a nominal CPU number or a
-    near-miss of the name — for anything else."""
-    import types
-
-    from kubeflow_tpu.training import mfu
-
-    v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
-    assert mfu.device_peak_flops(v5e) == 197e12
-    for kind in ("cpu", "TPU v5", "TPU v5 lite pod", "tpu v5 lite"):
-        with pytest.raises(ValueError, match="no peak"):
-            mfu.device_peak_flops(types.SimpleNamespace(device_kind=kind))
-    with pytest.raises(ValueError, match="no peak"):
-        mfu.device_peak_flops()      # this process's CPU device
-    with pytest.raises(ValueError, match="no peak"):
-        mfu.mfu(1e12, 1.0, 1)
