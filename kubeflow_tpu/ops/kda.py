@@ -32,17 +32,23 @@ Three stages, forward:
 Stage 2 runs under the named scope `kda_solve` and the backward under
 `kda_backward`: a capture's device operations carry them, and the benchmark
 reads their shares of the device's time (benchmark/lib/xscopes.py).
-Backward (`custom_vjp`) is chunked jax.numpy: it keeps ONE state per chunk
-(written by stage 3), walks the chunks backwards with hand-written
-cotangents of stage 3, and differentiates stages 1-2 (`_prepare`, the same
-mathematics in jax.numpy) in groups of chunks so that the channel-by-channel
-sums never exist for the whole sequence at once. A Pallas backward is queued
-in ROADMAP.md.
+
+Backward (`custom_vjp`), two parts. Stage 3's cotangents by hand, chunks in
+reverse: `_state_bwd_pallas` takes what `_state_pallas` took (q, k, v, the
+cumulative decay, and the forward's own M and B, kept as residuals) plus the
+state at every chunk's start and the output's cotangent, carries the state's
+cotangent in VMEM and writes the cotangents of `_prepare`'s six results in
+the grouped layout the second part reads. Stages 1-2 are then
+differentiated by JAX (`jax.vjp` of `_prepare`, the same mathematics in
+jax.numpy) BACKWARD_GROUP chunks of every head at a time, so that the
+channel-by-channel sums never exist for the whole sequence at once. A kernel
+for that transpose is queued in ROADMAP.md.
 
 The kernels run where they compile (a TPU target) and, for the tests, under
 the interpreter (FORCE_INTERPRET, as in ops/flash_pallas.py). Elsewhere the
-forward is the backward's own jax.numpy (`_prepare`, `_states_xla`): the CPU
-path, which nothing selects by hand.
+forward is `_prepare` with `_states_xla` and the backward's first part
+`_prepare` again with `_state_bwd_xla`, a reverse `lax.scan`: the CPU path,
+which nothing selects by hand, and the kernels' oracle in the tests.
 """
 
 from __future__ import annotations
@@ -59,7 +65,9 @@ from kubeflow_tpu.ops.pallas_compat import sds_with_vma as _sds
 
 CHUNK = 64
 SUB = 16          # rows of a sub-block: one reference point each
-BACKWARD_GROUP = 8   # chunks of every head that the backward prepares at once
+# chunks of every head that the backward differentiates `_prepare` for at
+# once (`back`; off the TPU also the `_prepare` ahead of the reverse scan)
+BACKWARD_GROUP = 8
 HIGHEST = jax.lax.Precision.HIGHEST
 
 # Tests on the CPU set this to run the kernels under the Pallas interpreter.
@@ -209,6 +217,31 @@ def _ut_transform(a, beta):
 # stage 3: the chunks in order
 # ---------------------------------------------------------------------------
 
+def _mm(x, y, dims):
+    return jax.lax.dot_general(x, y, (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _walk_operands(q_ref, k_ref, v_ref, g_ref, m_ref, st, chunk, mm_dtype):
+    """What either walk over the chunks forms in VMEM from a chunk's blocks
+    and the state S^T [dv, dk] at its start: Q exp(G), K exp(G_C - G),
+    W = M (K exp G), S^T and U, each rounded to mm_dtype where a matmul takes
+    it (sums in float32), and exp(G_C) [1, dk]."""
+    g = g_ref[0]                                # [C, dk] f32
+    gam = jnp.exp(g)
+    g_last = g[chunk - 1:chunk, :]
+    kf = k_ref[0].astype(jnp.float32)
+    kg = (kf * gam).astype(mm_dtype)
+    qg = (q_ref[0].astype(jnp.float32) * gam).astype(mm_dtype)
+    kd = (kf * jnp.exp(g_last - g)).astype(mm_dtype)
+    m = m_ref[0, 0].astype(mm_dtype)
+    sb = st.astype(mm_dtype)
+    w = _mm(m, kg, ((1,), (0,))).astype(mm_dtype)            # [C, dk]
+    uv = _mm(m, v_ref[0].astype(mm_dtype), ((1,), (0,)))
+    ub = (uv - _mm(w, sb, ((1,), (1,)))).astype(mm_dtype)    # [C, dv]
+    return qg, kd, w, sb, ub, jnp.exp(g_last)
+
+
 def _state_kernel(q_ref, k_ref, v_ref, g_ref, m_ref, b_ref, *rest, chunk,
                   mm_dtype, emit_states):
     if emit_states:
@@ -223,28 +256,12 @@ def _state_kernel(q_ref, k_ref, v_ref, g_ref, m_ref, b_ref, *rest, chunk,
     st = st_ref[:]                              # S^T [dv, dk], f32
     if emit_states:
         h_ref[0, 0] = st
-    g = g_ref[0]                                # [C, dk] f32
-    gam = jnp.exp(g)
-    g_last = g[chunk - 1:chunk, :]              # [1, dk]
-    kf = k_ref[0].astype(jnp.float32)
-    kg = (kf * gam).astype(mm_dtype)
-    qg = (q_ref[0].astype(jnp.float32) * gam).astype(mm_dtype)
-    kd = (kf * jnp.exp(g_last - g)).astype(mm_dtype)
-    m = m_ref[0, 0].astype(mm_dtype)
-    sb = st.astype(mm_dtype)
-
-    def mm(x, y, dims):
-        return jax.lax.dot_general(x, y, (dims, ((), ())),
-                                   preferred_element_type=jnp.float32)
-
-    w = mm(m, kg, ((1,), (0,)))                 # [C, dk]
-    uv = mm(m, v_ref[0].astype(mm_dtype), ((1,), (0,)))
-    u = uv - mm(w.astype(mm_dtype), sb, ((1,), (1,)))        # [C, dv]
-    ub = u.astype(mm_dtype)
-    o = mm(qg, sb, ((1,), (1,))) + mm(b_ref[0, 0].astype(mm_dtype), ub,
-                                      ((1,), (0,)))
+    qg, kd, _, sb, ub, decay = _walk_operands(q_ref, k_ref, v_ref, g_ref,
+                                              m_ref, st, chunk, mm_dtype)
+    o = _mm(qg, sb, ((1,), (1,))) + _mm(b_ref[0, 0].astype(mm_dtype), ub,
+                                        ((1,), (0,)))
     o_ref[0] = o.astype(o_ref.dtype)
-    st_ref[:] = st * jnp.exp(g_last) + mm(ub, kd, ((0,), (0,)))
+    st_ref[:] = st * decay + _mm(ub, kd, ((0,), (0,)))
 
 
 def _state_pallas(q, k, v, gc, m, b, *, emit_states, interpret, mm_dtype):
@@ -274,6 +291,75 @@ def _state_pallas(q, k, v, gc, m, b, *, emit_states, interpret, mm_dtype):
         interpret=interpret,
     )(q, k, v, gc, m, b)
     return (out[0], out[1]) if emit_states else (out[0], None)
+
+
+def _state_bwd_kernel(q_ref, k_ref, v_ref, g_ref, m_ref, b_ref, h_ref, do_ref,
+                      dqg_ref, dw_ref, duv_ref, db_ref, dkd_ref, dgam_ref,
+                      ds_ref, *, chunk, mm_dtype):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        ds_ref[:] = jnp.zeros_like(ds_ref)
+
+    st = h_ref[0, 0]                            # S^T at the chunk's start, f32
+    ds = ds_ref[:]                              # cotangent of S^T AFTER it
+    qg, kd, w, sb, ub, decay = _walk_operands(q_ref, k_ref, v_ref, g_ref,
+                                              m_ref, st, chunk, mm_dtype)
+    dsb = ds.astype(mm_dtype)
+    do = do_ref[0].astype(mm_dtype)
+    du = (_mm(b_ref[0, 0].astype(mm_dtype), do, ((0,), (0,)))
+          + _mm(kd, dsb, ((1,), (1,))))                      # [C, dv]
+    dub = du.astype(mm_dtype)
+    dqg_ref[0, 0] = _mm(do, sb, ((1,), (0,)))
+    dw_ref[0, 0] = -_mm(dub, sb, ((1,), (0,)))
+    duv_ref[0, 0] = du
+    db_ref[0, 0] = _mm(do, ub, ((1,), (1,)))
+    dkd_ref[0, 0] = _mm(ub, dsb, ((1,), (0,)))
+    dgam_ref[0, 0] = jnp.sum(ds * st, axis=0, keepdims=True)
+    ds_ref[:] = (_mm(do, qg, ((0,), (0,))) + ds * decay
+                 - _mm(dub, w, ((0,), (0,))))
+
+
+def _state_bwd_pallas(q, k, v, gc, m, b, h, do, *, group, interpret,
+                      mm_dtype):
+    """Stage 3's cotangents, chunks in reverse: the operands of
+    `_state_pallas`, the state S^T at every chunk's start (h) and the
+    cotangent of o -> those of `_prepare`'s (Qg, W, Uv, B, Kd, gamma),
+    float32, written where `_backward`'s groups of chunks read them:
+    [NC / group, BH * group, C, ..] (gamma [.., dk])."""
+    bh, s, dk = q.shape
+    dv = v.shape[-1]
+    nc = s // CHUNK
+    ins = (q, k, v, gc, m, b, h, do)
+
+    def at(i, c):         # grid step c of head i is chunk nc - 1 - c
+        return i, nc - 1 - c
+
+    def grouped(i, c):
+        i, c = at(i, c)
+        return c // group, i * group + c % group, 0, 0
+
+    row = lambda d: pl.BlockSpec((1, CHUNK, d), lambda i, c: (*at(i, c), 0))
+    per_chunk = lambda r, d: pl.BlockSpec((1, 1, r, d),
+                                          lambda i, c: (*at(i, c), 0, 0))
+    out = lambda r, d: (pl.BlockSpec((1, 1, r, d), grouped),
+                        _sds((nc // group, bh * group, r, d), jnp.float32,
+                             *ins))
+    out_specs, out_shape = zip(out(CHUNK, dk), out(CHUNK, dk), out(CHUNK, dv),
+                               out(CHUNK, CHUNK), out(CHUNK, dk), out(1, dk))
+    *d_ops, dgam = pl.pallas_call(
+        functools.partial(_state_bwd_kernel, chunk=CHUNK, mm_dtype=mm_dtype),
+        grid=(bh, nc),
+        in_specs=[row(dk), row(dk), row(dv), row(dk),
+                  per_chunk(CHUNK, CHUNK), per_chunk(CHUNK, CHUNK),
+                  per_chunk(dv, dk), row(dv)],
+        out_specs=list(out_specs),
+        out_shape=list(out_shape),
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*ins)
+    return (*d_ops, dgam[:, :, 0])
 
 
 def _prepare(q, k, v, g, beta, mm_dtype):
@@ -316,79 +402,14 @@ def _states_xla(ops, mm_dtype):
     return jnp.moveaxis(o, 0, 1), jnp.moveaxis(h, 0, 1)
 
 
-# ---------------------------------------------------------------------------
-# the op
-# ---------------------------------------------------------------------------
-
-def _chunks(x):
-    bh, s = x.shape[:2]
-    return x.reshape(bh * (s // CHUNK), CHUNK, *x.shape[2:])
-
-
-def _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, emit_states):
-    bh, s, dk = q.shape
-    nc = s // CHUNK
-    if not pallas:
-        ops = _prepare(*(_chunks(x) for x in (q, k, v, g, beta)), mm_dtype)
-        ops = jax.tree.map(lambda x: x.reshape(bh, nc, *x.shape[1:]), ops)
-        o, h = _states_xla(ops, mm_dtype)
-        return (o.reshape(bh, s, -1).astype(v.dtype),
-                jnp.swapaxes(h, -1, -2))
-    gc = jnp.cumsum(g.reshape(bh, nc, CHUNK, dk), axis=2).reshape(bh, s, dk)
-    a, b = _intra_pallas(q, k, gc, interpret=interpret, mm_dtype=mm_dtype)
-    m = _ut_transform(a, beta.reshape(bh, nc, CHUNK))
-    return _state_pallas(q, k, v, gc, m, b, emit_states=emit_states,
-                         interpret=interpret, mm_dtype=mm_dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _kda(q, k, v, g, beta, pallas, interpret, mm_dtype):
-    return _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, False)[0]
-
-
-def _kda_fwd(q, k, v, g, beta, pallas, interpret, mm_dtype):
-    o, h = _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, True)
-    return o, (q, k, v, g, beta, h)
-
-
-def _kda_bwd(pallas, interpret, mm_dtype, res, do):
-    with jax.named_scope("kda_backward"):
-        return _backward(mm_dtype, res, do)
-
-
-def _backward(mm_dtype, res, do):
-    """One state per chunk (h, written by the forward); the cotangents of
-    stage 3 by hand, chunks in reverse; stages 1-2 differentiated by JAX,
-    BACKWARD_GROUP chunks of every head at a time."""
-    q, k, v, g, beta, h = res
-    bh, s, dk = q.shape
-    dv = v.shape[-1]
-    nc = s // CHUNK
-    group = min(BACKWARD_GROUP, nc)
-    while nc % group:
-        group -= 1
-    ng = nc // group
+def _state_bwd_xla(ops, st, do, mm_dtype):
+    """Stage 3's cotangents in jax.numpy, a reverse scan over the chunks:
+    `_prepare`'s results, S at every chunk's start and the cotangent of o,
+    all [BH, NC, ..] -> the cotangents of `_prepare`'s results."""
+    bh, _, dk, dv = st.shape
     lo = lambda x: x.astype(mm_dtype)
     mm = functools.partial(jnp.einsum, precision=HIGHEST,
                            preferred_element_type=jnp.float32)
-
-    def to_groups(x):     # [BH, NC, ..] -> [ng, BH * group, ..]
-        x = x.reshape(bh, ng, group, *x.shape[2:])
-        return jnp.moveaxis(x, 1, 0).reshape(ng, bh * group, *x.shape[3:])
-
-    def from_groups(x):   # [ng, BH * group, ..] -> [BH, NC, ..]
-        x = x.reshape(ng, bh, group, *x.shape[2:])
-        return jnp.moveaxis(x, 0, 1).reshape(bh, nc, *x.shape[3:])
-
-    def grouped(x):       # [BH, S, ..] -> [ng, BH * group, C, ..]
-        return to_groups(x.reshape(bh, nc, CHUNK, *x.shape[2:]))
-
-    xs = tuple(grouped(x) for x in (q, k, v, g, beta))
-    prepare = lambda *x: _prepare(*x, mm_dtype)
-    ops = jax.lax.map(lambda x: prepare(*x), xs)
-    qg, w, uv, b, kd, gam = jax.tree.map(from_groups, ops)
-    st = jnp.swapaxes(h, -1, -2)                 # S [BH, NC, dk, dv]
-    do = do.astype(jnp.float32).reshape(bh, nc, CHUNK, dv)
 
     def step(ds, x):    # ds: cotangent of the state AFTER this chunk
         qg, w, uv, b, kd, gam, s, do = x
@@ -408,9 +429,98 @@ def _backward(mm_dtype, res, do):
 
     chunks_first = lambda t: jax.tree.map(lambda x: jnp.moveaxis(x, 1, 0), t)
     _, d_ops = jax.lax.scan(step, jnp.zeros((bh, dk, dv), jnp.float32),
-                            chunks_first((qg, w, uv, b, kd, gam, st, do)),
-                            reverse=True)
-    d_ops = jax.tree.map(lambda x: to_groups(jnp.moveaxis(x, 0, 1)), d_ops)
+                            chunks_first((*ops, st, do)), reverse=True)
+    return jax.tree.map(lambda x: jnp.moveaxis(x, 0, 1), d_ops)
+
+
+# ---------------------------------------------------------------------------
+# the op
+# ---------------------------------------------------------------------------
+
+def _chunks(x):
+    bh, s = x.shape[:2]
+    return x.reshape(bh * (s // CHUNK), CHUNK, *x.shape[2:])
+
+
+def _cumulative(g):
+    """The log-decay summed inside each chunk: [BH, S, dk] -> the same."""
+    bh, s, dk = g.shape
+    return jnp.cumsum(g.reshape(bh, s // CHUNK, CHUNK, dk),
+                      axis=2).reshape(bh, s, dk)
+
+
+def _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, emit_states):
+    """-> o, the state S^T at every chunk's start (if asked for), and on the
+    kernels' path the M and B that stage 3 read (the backward reads them
+    again)."""
+    bh, s, dk = q.shape
+    nc = s // CHUNK
+    if not pallas:
+        ops = _prepare(*(_chunks(x) for x in (q, k, v, g, beta)), mm_dtype)
+        ops = jax.tree.map(lambda x: x.reshape(bh, nc, *x.shape[1:]), ops)
+        o, h = _states_xla(ops, mm_dtype)
+        return (o.reshape(bh, s, -1).astype(v.dtype),
+                jnp.swapaxes(h, -1, -2), None)
+    gc = _cumulative(g)
+    a, b = _intra_pallas(q, k, gc, interpret=interpret, mm_dtype=mm_dtype)
+    m = _ut_transform(a, beta.reshape(bh, nc, CHUNK))
+    o, h = _state_pallas(q, k, v, gc, m, b, emit_states=emit_states,
+                         interpret=interpret, mm_dtype=mm_dtype)
+    return o, h, (m, b)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda(q, k, v, g, beta, pallas, interpret, mm_dtype):
+    return _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, False)[0]
+
+
+def _kda_fwd(q, k, v, g, beta, pallas, interpret, mm_dtype):
+    o, h, mb = _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, True)
+    return o, (q, k, v, g, beta, h, mb)
+
+
+def _kda_bwd(pallas, interpret, mm_dtype, res, do):
+    with jax.named_scope("kda_backward"):
+        return _backward(pallas, interpret, mm_dtype, res, do)
+
+
+def _backward(pallas, interpret, mm_dtype, res, do):
+    """One state per chunk (h, written by the forward); the cotangents of
+    stage 3 by hand, chunks in reverse (the kernel, fed by the forward's M
+    and B; off the TPU the scan, fed by `_prepare`); stages 1-2
+    differentiated by JAX, BACKWARD_GROUP chunks of every head at a time."""
+    q, k, v, g, beta, h, mb = res
+    bh, s, dk = q.shape
+    nc = s // CHUNK
+    group = min(BACKWARD_GROUP, nc)
+    while nc % group:
+        group -= 1
+    ng = nc // group
+
+    def to_groups(x):     # [BH, NC, ..] -> [ng, BH * group, ..]
+        x = x.reshape(bh, ng, group, *x.shape[2:])
+        return jnp.moveaxis(x, 1, 0).reshape(ng, bh * group, *x.shape[3:])
+
+    def from_groups(x):   # [ng, BH * group, ..] -> [BH, NC, ..]
+        x = x.reshape(ng, bh, group, *x.shape[2:])
+        return jnp.moveaxis(x, 0, 1).reshape(bh, nc, *x.shape[3:])
+
+    def grouped(x):       # [BH, S, ..] -> [ng, BH * group, C, ..]
+        return to_groups(x.reshape(bh, nc, CHUNK, *x.shape[2:]))
+
+    xs = tuple(grouped(x) for x in (q, k, v, g, beta))
+    prepare = lambda *x: _prepare(*x, mm_dtype)
+    if pallas:
+        d_ops = _state_bwd_pallas(q, k, v, _cumulative(g), *mb, h, do,
+                                  group=group, interpret=interpret,
+                                  mm_dtype=mm_dtype)
+    else:
+        ops = jax.tree.map(from_groups,
+                           jax.lax.map(lambda x: prepare(*x), xs))
+        d_ops = _state_bwd_xla(
+            ops, jnp.swapaxes(h, -1, -2),            # S [BH, NC, dk, dv]
+            do.astype(jnp.float32).reshape(bh, nc, CHUNK, -1), mm_dtype)
+        d_ops = jax.tree.map(to_groups, d_ops)
 
     def back(x):
         ins, cts = x

@@ -3,6 +3,8 @@ one position at a time, forward and backward, at decays strong enough that
 `exp(-cumsum(g))` over a chunk overflows float32 (the reason for the
 sub-blocks with a local reference point)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,12 +49,6 @@ def path(request, monkeypatch):
     return request.param
 
 
-@pytest.fixture(params=["xla", pytest.param("pallas", marks=pytest.mark.slow)])
-def path_slow_kernels(request, monkeypatch):
-    monkeypatch.setattr(kda, "FORCE_INTERPRET", request.param == "pallas")
-    return request.param
-
-
 def test_the_decays_would_overflow_a_naive_cumsum():
     g = inputs(0)[3]
     worst = float(jnp.min(jnp.cumsum(g[:, :kda.CHUNK], axis=1)))
@@ -70,17 +66,63 @@ def test_forward_matches_the_recurrence(path):
         jnp.max(jnp.abs(ref)))
 
 
-def test_backward_matches_the_recurrence(path_slow_kernels, monkeypatch):
+# jitted: the interpreter walks the backward kernel's grid in seconds then
+def test_backward_matches_the_recurrence(path, monkeypatch):
     monkeypatch.setattr(kda, "BACKWARD_GROUP", 2)   # 3 chunks: groups of 1
     args = inputs(1)
     w = jax.random.normal(jax.random.key(9), args[2].shape)
     f = lambda *a: jnp.sum(kda.chunk_kda(*a, mm_dtype=jnp.float32) * w)
-    got = jax.grad(f, argnums=(0, 1, 2, 3, 4))(*args)
+    got = jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4)))(*args)
     want = jax.grad(lambda *a: jnp.sum(recurrence(*a) * w),
                     argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip("q k v g beta".split(), got, want):
         assert float(jnp.max(jnp.abs(a - b))) < 5e-5 * float(
             jnp.max(jnp.abs(b))), name
+
+
+def heads_first(x):
+    b, s, h = x.shape[:3]
+    return jnp.moveaxis(x, 2, 1).reshape(b * h, s, *x.shape[3:])
+
+
+# the kernel rounds every operand where the scan does and sums in float32
+# as it does: float32 to 1e-5 of the largest value, bfloat16 to one rounding
+# of an operand (2^-8) over sums of 64 to 128 products of either sign
+@pytest.mark.parametrize("mm_dtype,strength,tol", [
+    (jnp.float32, 4.0, 1e-5), (jnp.bfloat16, 0.5, 4e-3)],
+    ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("group", [1, 3])
+def test_the_backward_kernel_matches_the_scan_it_replaces(
+        mm_dtype, strength, tol, group):
+    """Stage 3's six cotangents from `_state_bwd_pallas` (interpreted, fed
+    the operands of the forward's kernel) against `_state_bwd_xla` fed
+    `_prepare`'s results for the same chunks: 3 chunks, 2 heads, dk = dv =
+    32, the state's cotangent carried across the chunks, in the grouped
+    layout at both group sizes."""
+    q, k, v, g, beta = (heads_first(x) for x in inputs(5, strength=strength))
+    bh, s, _ = q.shape
+    nc = s // kda.CHUNK
+    gc = kda._cumulative(g)
+    chunked = [kda._chunks(x) for x in (q, k, v, g, beta)]
+    a, b = kda._intra_xla(chunked[0], chunked[1], kda._chunks(gc), mm_dtype)
+    m = kda._ut_transform(a, chunked[4])
+    per_head = lambda x: x.reshape(bh, nc, *x.shape[1:])
+    ops = jax.tree.map(per_head, kda._prepare(*chunked, mm_dtype))
+    st = kda._states_xla(ops, mm_dtype)[1]               # S [BH, NC, dk, dv]
+    do = jax.random.normal(jax.random.key(7), v.shape)
+    want = kda._state_bwd_xla(ops, st, per_head(kda._chunks(do)), mm_dtype)
+    got = kda._state_bwd_pallas(
+        q, k, v, gc, per_head(m), per_head(b), jnp.swapaxes(st, -1, -2), do,
+        group=group, interpret=True, mm_dtype=mm_dtype)
+    # dKd = U dS^T: nothing reaches the last chunk's state, the earlier
+    # chunks' states take a cotangent from the chunks after them
+    assert not np.any(np.asarray(want[4][:, -1]))
+    assert float(jnp.max(jnp.abs(want[4][:, 0]))) > 1e-3
+    for name, x, y in zip("Qg W Uv B Kd gamma".split(), got, want):
+        x = x.reshape(nc // group, bh, group, *x.shape[2:])
+        x = jnp.moveaxis(x, 0, 1).reshape(y.shape)
+        assert float(jnp.max(jnp.abs(x - y))) <= tol * float(
+            jnp.max(jnp.abs(y))), name
 
 
 def test_a_length_off_the_chunk_grid_is_padded_with_inert_positions():
@@ -113,8 +155,6 @@ def test_the_kernels_run_off_the_tpu_only_when_interpreted(monkeypatch):
 def test_the_solve_and_the_backward_carry_their_scopes():
     """The benchmark splits the device's time by these names
     (benchmark/lib/xscopes.py reads them from a capture's operations)."""
-    import re
-
     args = inputs(4, s=128)
     f = lambda *a: jnp.sum(kda.chunk_kda(*a, mm_dtype=jnp.float32))
     text = jax.jit(jax.grad(f, argnums=(0, 3))).lower(*args).as_text(
@@ -125,3 +165,42 @@ def test_the_solve_and_the_backward_carry_their_scopes():
     assert any(n.startswith("jit(<lambda>)/jvp(kda_solve)/") for n in names)
     assert any("jvp(kda_backward))/while/body/" in n for n in names)
     assert any(n.startswith("transpose(jvp(kda_solve))/") for n in names)
+
+
+def test_the_backward_kernel_carries_the_backwards_scope(monkeypatch):
+    """On the kernels' path (lowered for a TPU target from here) the chunk
+    walk is ONE Mosaic call under `kda_backward`, so the share the benchmark
+    reads by that scope still holds the whole backward; the only loop left
+    under the scope is the `lax.map` that differentiates `_prepare` in
+    groups of chunks (the solve's transpose runs inside it)."""
+    from kubeflow_tpu.ops import pallas_compat
+
+    monkeypatch.setattr(pallas_compat, "target_platform", lambda: "tpu")
+    assert kda._kernels() == (True, False)
+    args = inputs(4, s=128)
+    f = lambda *a: jnp.sum(kda.chunk_kda(*a, mm_dtype=jnp.float32))
+    grad = jax.grad(f, argnums=(0, 3))
+
+    def under_the_scope(jaxpr, found):
+        """(primitive, operands, reverse) of every loop and kernel whose
+        name stack holds the scope, loops' bodies not entered."""
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if "kda_backward" in str(eqn.source_info.name_stack) and name in (
+                    "scan", "while", "pallas_call"):
+                found.append((name, len(eqn.invars),
+                              eqn.params.get("reverse")))
+            elif name not in ("scan", "while"):
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    under_the_scope(sub, found)
+        return found
+
+    found = under_the_scope(jax.make_jaxpr(grad)(*args).jaxpr, [])
+    # q, k, v, gc, M, B, h, do into the kernel; `back`'s forward map
+    assert sorted(found) == [("pallas_call", 8, None), ("scan", 11, False)]
+    text = jax.jit(grad).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = set(re.findall(r'"([^"]*kda_[^"]*)"', text))
+    assert "jit(<lambda>)/transpose(jvp(kda_backward))/pallas_call" in names
+    assert any(n.startswith("transpose(jvp(kda_solve))/") for n in names)
+    assert text.count("tpu_custom_call") == 3

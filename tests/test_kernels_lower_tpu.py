@@ -14,6 +14,10 @@ itself can refuse is covered by the slow-lane topology compiles in
 test_contract_serving.py.
 """
 
+import importlib.util
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -30,7 +34,7 @@ PALLAS_CALL_SITES = {
     "flash_prefill": 1,
     "quant_matmul": 1,   # one call site, two entries: both lowered below
     "flash_pallas": 3,
-    "kda": 2,
+    "kda": 3,
 }
 
 # chip_smoke.py's serving shapes: Llama-3-8B heads, 16 slots x 2048
@@ -204,12 +208,16 @@ def test_flash_pallas_lowers_with_qk_192_beside_v_128():
     assert mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, v) == 3
 
 
+# the Kimi-Linear cut: 2 rows x 32 heads of 8192 positions, dk = dv = 128
+KDA_ROWS = sds((64, 8192, 128), jnp.bfloat16)
+KDA_DECAY = sds((64, 8192, 128), jnp.float32)
+KDA_SQUARE = sds((64, 128, 64, 64), jnp.float32)     # A, B, M of every chunk
+KDA_STATES = sds((64, 128, 128, 128), jnp.float32)   # S^T at every chunk's start
+
+
 @pytest.mark.parametrize("emit_states", [False, True])
 def test_kda_kernels_lower(emit_states):
-    # the Kimi-Linear cut: 2 rows x 32 heads of 8192 positions, dk = dv = 128
-    rows = sds((64, 8192, 128), jnp.bfloat16)
-    decay = sds((64, 8192, 128), jnp.float32)
-    square = sds((64, 128, 64, 64), jnp.float32)
+    rows, decay, square = KDA_ROWS, KDA_DECAY, KDA_SQUARE
     assert mosaic_calls(
         lambda q, k, gc: kda._intra_pallas(
             q, k, gc, interpret=False, mm_dtype=jnp.bfloat16),
@@ -219,6 +227,59 @@ def test_kda_kernels_lower(emit_states):
             q, k, v, gc, m, b, emit_states=emit_states, interpret=False,
             mm_dtype=jnp.bfloat16),
         rows, rows, rows, decay, square, square) == 1
+
+
+def kda_backward_call(group=8):
+    """The backward's chunk walk at the Kimi-Linear cut, lowered for TPU:
+    the operands of the forward's walk, the state at every chunk's start and
+    the output's cotangent."""
+    return jax.jit(
+        lambda q, k, v, gc, m, b, h, do: kda._state_bwd_pallas(
+            q, k, v, gc, m, b, h, do, group=group, interpret=False,
+            mm_dtype=jnp.bfloat16)).trace(
+        KDA_ROWS, KDA_ROWS, KDA_ROWS, KDA_DECAY, KDA_SQUARE, KDA_SQUARE,
+        KDA_STATES, KDA_ROWS).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("group", [8, 1])
+def test_kda_backward_kernel_lowers(group):
+    # six cotangents in the layout of `group` chunks of every head together
+    lowered = kda_backward_call(group)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    shapes = [tuple(x.shape) for x in jax.tree.leaves(lowered.out_info)]
+    lead = (128 // group, 64 * group)
+    assert shapes == [lead + (64, 128)] * 3 + [lead + (64, 64),
+                                               lead + (64, 128),
+                                               lead + (128,)]
+
+
+def test_kda_backward_kernel_is_not_read_as_a_forward_kernel():
+    """The benchmark tells the two forward kernels by their operands
+    (benchmark/opcount/kda_chunk.py, three and six) and computes a roofline
+    share of the FORWARD from their time: the backward's walk takes eight,
+    so neither pattern may take it for one of them."""
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_opcount_kda_chunk", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", "opcount", "kda_chunk.py"))
+    kc = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(kc)
+    call, = [line for line in kda_backward_call().as_text().splitlines()
+             if "tpu_custom_call" in line]
+    operands, results = re.search(r":\s*\(([^)]*)\)\s*->\s*\((.*)\)",
+                                  call).groups()
+
+    def shapes(types):    # tensor<64x8192x128xbf16>, .. -> bf16[64,8192,128],..
+        return ",".join(f"{t}[{dims.replace('x', ',')}]" for dims, t in
+                        re.findall(r"tensor<([\dx]+)x(\w+)>", types))
+
+    # as lib/tracered.short_name writes a kernel's event
+    name = f"kda_backward.4({shapes(operands)})->{shapes(results)}"
+    assert name.startswith(
+        "kda_backward.4(bf16[64,8192,128],bf16[64,8192,128],"
+        "bf16[64,8192,128],f32[64,8192,128],f32[64,128,64,64],"
+        "f32[64,128,64,64],f32[64,128,128,128],bf16[64,8192,128])->f32[")
+    assert not kc.INTRA.match(name) and not kc.STATE.match(name)
 
 
 def test_unsupported_head_dim_is_refused_at_engine_construction(
