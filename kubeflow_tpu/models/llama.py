@@ -26,11 +26,13 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from kubeflow_tpu.ops import quant
 from kubeflow_tpu.ops.attention import mha, repeat_kv
 from kubeflow_tpu.ops.norms import rms_norm
 from kubeflow_tpu.ops.rope import apply_rope
+from kubeflow_tpu.parallel import overlap
 
 Params = dict[str, Any]
 
@@ -203,6 +205,14 @@ def _attention(cfg: LlamaConfig, x, layer, positions, segment_ids):
     q = quant.matmul(h, layer["wq"], cfg.dtype).reshape(b, s, nh, hd)
     k = quant.matmul(h, layer["wk"], cfg.dtype).reshape(b, s, nkv, hd)
     v = quant.matmul(h, layer["wv"], cfg.dtype).reshape(b, s, nkv, hd)
+    out = _attend(cfg, q, k, v, positions, segment_ids)
+    return x + quant.matmul(out, layer["wo"], cfg.dtype)
+
+
+def _attend(cfg: LlamaConfig, q, k, v, positions, segment_ids):
+    """RoPE and the configured full-sequence attention over projected
+    q [B, S, nh, hd], k, v [B, S, nkv, hd] -> [B, S, nh * hd]."""
+    b, s, nh, hd = q.shape
     q = apply_rope(q, positions, theta=cfg.rope_theta)
     k = apply_rope(k, positions, theta=cfg.rope_theta)
 
@@ -250,8 +260,7 @@ def _attention(cfg: LlamaConfig, x, layer, positions, segment_ids):
                                             segment_ids=segment_ids)
     else:
         out = mha(q, k, v, causal=True, segment_ids=segment_ids)
-    out = out.reshape(b, s, nh * hd)
-    return x + quant.matmul(out, layer["wo"], cfg.dtype)
+    return out.reshape(b, s, nh * hd)
 
 
 def _mlp(cfg: LlamaConfig, x, layer):
@@ -259,8 +268,60 @@ def _mlp(cfg: LlamaConfig, x, layer):
     return _serving_mlp(cfg, x, layer)
 
 
+def _overlapped_layer(cfg: LlamaConfig, mesh, x, layer, positions,
+                      segment_ids):
+    """The training layer on a mesh with `tensor` > 1 (parallel/overlap.py
+    has the mechanism and `mesh_for` the selection): the residual x is
+    [batch, seq / tensor, embed] between projections, and each projection
+    is a region manual over `tensor` that moves its exchange under its
+    own matmuls. The weights' `fsdp` axis stays with the partitioner, and
+    so does everything between the regions: RoPE and the attention (the
+    Pallas island opens its own shard_map) see q, k, v with the whole
+    sequence and heads over `tensor`, as under plain GSPMD."""
+    b, s, _ = x.shape
+    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    dt, ax = cfg.dtype, overlap.AXIS
+    seq, cols, rows = P(None, ax), P(None, ax), P(ax)
+    region = partial(jax.shard_map, mesh=mesh, axis_names=frozenset({ax}))
+
+    def qkv(x, norm, wq, wk, wv):
+        h = rms_norm(x, norm, cfg.norm_eps)
+        return overlap.gather_matmul(
+            h, (wq.astype(dt), wk.astype(dt), wv.astype(dt)), axis=1,
+            site="wq|wk|wv")
+
+    def wo(x, out, w):
+        return x + overlap.matmul_scatter(out, w.astype(dt), axis=1,
+                                          site="wo")
+
+    def mlp(x, norm, w_gate, w_up, w_down):
+        h = rms_norm(x, norm, cfg.norm_eps)
+        act = [jax.nn.silu(gate) * up for gate, up in overlap.gather_matmul(
+            h, (w_gate.astype(dt), w_up.astype(dt)), axis=1, blocks=True,
+            site="w_gate|w_up")]
+        return x + overlap.matmul_scatter(act, w_down.astype(dt), axis=1,
+                                          site="w_down")
+
+    heads = P(None, None, ax)
+    q, k, v = region(qkv, in_specs=(seq, P(), cols, cols, cols),
+                     out_specs=heads)(
+        x, layer["attn_norm"], layer["wq"], layer["wk"], layer["wv"])
+    out = _attend(cfg, q.reshape(b, s, nh, hd), k.reshape(b, s, nkv, hd),
+                  v.reshape(b, s, nkv, hd), positions, segment_ids)
+    x = region(wo, in_specs=(seq, heads, rows), out_specs=seq)(
+        x, out, layer["wo"])
+    return region(mlp, in_specs=(seq, P(), cols, cols, rows),
+                  out_specs=seq)(
+        x, layer["mlp_norm"], layer["w_gate"], layer["w_up"],
+        layer["w_down"])
+
+
 def _layer_body(cfg: LlamaConfig, carry, layer, positions, segment_ids):
     x = carry
+    mesh = overlap.mesh_for(x.shape[1], [layer[t] for t in QUANT_LEAVES])
+    if mesh is not None:
+        return _overlapped_layer(cfg, mesh, x, layer, positions,
+                                 segment_ids), None
     x = _attention(cfg, x, layer, positions, segment_ids)
     x = _mlp(cfg, x, layer)
     return x, None

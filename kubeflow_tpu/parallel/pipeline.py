@@ -520,53 +520,3 @@ class StageClock:
         jax.block_until_ready(out)
         self.perf.record_stage(stage, time.perf_counter() - t0)
         return out
-
-
-# -- collective matmul (overlapped tensor-stage seam, ISSUE 20) ---------------
-
-def collective_matmul(x_shard: jax.Array, w_shard: jax.Array, *,
-                      axis_name: str = AXIS,
-                      shift: Callable[[jax.Array], jax.Array] | None = None,
-                      axis_size: int | None = None,
-                      axis_index=None) -> jax.Array:
-    """All-gather-form collective matmul: overlap the ring transfer of
-    row-sharded activations with per-chunk matmuls against the local
-    weight shard, instead of all-gather-then-matmul.
-
-    Inside shard_map each device holds ``x_shard`` = rows
-    ``[idx*rows_per : (idx+1)*rows_per]`` of the gathered activation and
-    the full (replicated or column-sharded) ``w_shard``. The classic
-    decomposition computes ``allgather(x) @ w`` as ``size`` chunk
-    matmuls, rotating ``x_shard`` around the ring between them so
-    transfer j+1 rides under matmul j. The result is BIT-EXACT with the
-    unoverlapped form — each output row block is one untouched
-    ``chunk @ w`` (row/column slicing only, no float-sum reassociation),
-    so greedy token parity survives the schedule flip.
-
-    ``shift``/``axis_size``/``axis_index`` are injectable so the chunk
-    schedule is unit-testable in a single process (tests feed successive
-    chunks through a closure); production use inside shard_map leaves
-    them None and gets ppermute receive-from-next semantics.
-    """
-    size = (int(axis_size) if axis_size is not None
-            else jax.lax.axis_size(axis_name))
-    idx = axis_index if axis_index is not None else jax.lax.axis_index(
-        axis_name)
-    if shift is None:
-        def shift(cur):
-            # receive from the NEXT device: after j rotations this
-            # device holds chunk (idx + j) % size, matching the output
-            # row-block index below.
-            perm = [(i, (i - 1) % size) for i in range(size)]
-            return jax.lax.ppermute(cur, axis_name, perm)
-    rows = x_shard.shape[0]
-    out = jnp.zeros((rows * size,) + w_shard.shape[1:],
-                    dtype=jnp.result_type(x_shard.dtype, w_shard.dtype))
-    cur = x_shard
-    for j in range(size):
-        part = cur @ w_shard
-        dst = ((idx + j) % size) * rows
-        out = jax.lax.dynamic_update_slice_in_dim(out, part, dst, axis=0)
-        if j != size - 1:
-            cur = shift(cur)
-    return out

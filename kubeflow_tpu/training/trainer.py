@@ -24,6 +24,7 @@ from kubeflow_tpu.parallel import (
     active_mesh,
     make_mesh,
     logical_to_spec,
+    overlap,
     tree_logical_to_sharding,
 )
 from kubeflow_tpu.training.data import DatasetConfig
@@ -147,7 +148,10 @@ class Trainer:
 
         self._jit_init = None
         self._jit_step = None
-        self._step_stats: dict[str, float] = {}
+        # which of a layer's tensor-parallel projections traced in the
+        # form that carries its own exchange (parallel/overlap.py): filled
+        # when the step is traced, empty on a mesh without `tensor`
+        self.overlapped_sites: set[str] = set()
 
     # -- state ---------------------------------------------------------------
 
@@ -231,7 +235,7 @@ class Trainer:
 
     # -- step ----------------------------------------------------------------
 
-    def _build_step(self, example_batch):
+    def _build_step(self, example_batch, state):
         loss_fn = self.model.loss_fn
         model_cfg = self.model_cfg
         optimizer = self.optimizer
@@ -252,19 +256,27 @@ class Trainer:
             return new_state, metrics
 
         # state keeps the sharding it was initialized with (in_shardings=None
-        # = "as given"); batch is forced onto the data (+sequence) axes.
+        # = "as given") and LEAVES with it: the partitioner's own choice for
+        # the new state differs (norm leaves over fsdp, size-1 axes dropped
+        # from every spec), and a state that comes back sharded otherwise
+        # misses the jit cache on the next call: the step lowered and
+        # compiled twice. batch is forced onto the data (+sequence) axes.
         batch_sh = jax.tree.map(self._leaf_sharding, example_batch)
+        state_sh = jax.tree.map(lambda leaf: leaf.sharding, state)
         jitted = self._jitted = jax.jit(
             train_step,
             in_shardings=(None, batch_sh),
+            out_shardings=(state_sh, None),
             donate_argnums=(0,),
         )
 
         def step(state, batch):
             # ambient mesh for shard_map islands (ring/Ulysses attention,
             # MoE all-to-all) traced inside the jitted step
-            with active_mesh(self.mesh):
-                return jitted(state, batch)
+            with active_mesh(self.mesh), overlap.count_sites() as traced:
+                out = jitted(state, batch)
+            self.overlapped_sites |= traced
+            return out
 
         return step
 
@@ -272,13 +284,14 @@ class Trainer:
         """AOT-lower the sharded train step from ShapeDtypeStructs alone —
         no device memory is touched, so an 8B-scale layout can be proven on
         hosts that could never hold the weights (training/contract.py)."""
-        self._build_step(abstract_batch)
+        abstract_state = self.abstract_state()
+        self._build_step(abstract_batch, abstract_state)
         with active_mesh(self.mesh):
-            return self._jitted.lower(self.abstract_state(), abstract_batch)
+            return self._jitted.lower(abstract_state, abstract_batch)
 
     def compiled_step(self, state, example_batch):
         if self._jit_step is None:
-            self._jit_step = self._build_step(example_batch)
+            self._jit_step = self._build_step(example_batch, state)
         return self._jit_step
 
     def _leaf_sharding(self, x) -> NamedSharding:
@@ -388,6 +401,8 @@ class Trainer:
                 data_wait = 0.0
                 if first_interval:
                     scalars["includes_compile"] = 1.0
+                    scalars["overlapped_projections_per_layer"] = float(
+                        len(self.overlapped_sites))
                     first_interval = False
                 self.metrics.write(step, scalars)
                 if step_callback:
