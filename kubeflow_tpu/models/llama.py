@@ -465,12 +465,15 @@ def _chunked_ce_loss(params: Params, batch: dict[str, jax.Array],
 
 
 def init_cache(cfg: LlamaConfig, n_slots: int, max_len: int,
-               kv_quantize: str | None = None) -> Params:
+               kv_quantize: str | None = None,
+               chunk: int | None = None) -> Params:
     """The slab KV cache: payload `[L, slots, max_len, kv_heads, hd]`;
     int8 payloads come with their per-token-per-head f32 scales stored
     LANE-MAJOR, `[L, slots, kv_heads, max_len]`: the layout the decode
     kernel reads in place (ops/flash_decode.py), dense under the TPU's
-    (8, 128) tiling where `[..., max_len, kv_heads]` pads 8 lanes to 128."""
+    (8, 128) tiling where `[..., max_len, kv_heads]` pads 8 lanes to 128.
+    `chunk` (the most rows one prefill program writes) sizes a family's
+    ring; every layer here keeps all of `max_len`, so it is not read."""
     shape = (cfg.n_layers, n_slots, max_len, cfg.n_kv_heads, cfg.head_dim)
     if kv_quantize == "int8":
         sshape = (cfg.n_layers, n_slots, cfg.n_kv_heads, max_len)
@@ -491,6 +494,65 @@ def cache_kv_spec(name: str, axis: str = "tensor"):
 
     return (P(None, None, axis) if name.endswith("_s")
             else P(None, None, None, axis))
+
+
+#: per-step counters a family's decode_step hands back under
+#: new_cache["counters"] (f32 [len]); the engine packs them into the rows
+#: it fetches anyway and sums them into metrics(). This family keeps none.
+STEP_COUNTERS: tuple[str, ...] = ()
+
+
+def cache_stats(cache: Params) -> dict[str, Any]:
+    """What metrics() says of the cache's layout beyond `kv_layout`:
+    nothing here, one slab being all there is."""
+    return {}
+
+
+def cache_write(cache: Params, slot, start: int, count: int, ks, vs, *,
+                kv_quantize: str | None = None) -> Params:
+    """Write one prompt's [L, count, kv, hd] KV rows into a slot's
+    [start, start+count) range, quantizing when the cache is int8.
+    start/count are static."""
+    out = dict(cache)
+    if kv_quantize == "int8":
+        kq, ksc = quantize_kv(ks)
+        vq, vsc = quantize_kv(vs)
+        out["k"] = cache["k"].at[:, slot, start:start + count].set(kq)
+        out["v"] = cache["v"].at[:, slot, start:start + count].set(vq)
+        # scales are stored lane-major: [L, slots, kv, max_len]
+        out["k_s"] = cache["k_s"].at[:, slot, :, start:start + count
+                                     ].set(jnp.swapaxes(ksc, 1, 2))
+        out["v_s"] = cache["v_s"].at[:, slot, :, start:start + count
+                                     ].set(jnp.swapaxes(vsc, 1, 2))
+    else:
+        out["k"] = cache["k"].at[:, slot, start:start + count].set(
+            ks.astype(cache["k"].dtype))
+        out["v"] = cache["v"].at[:, slot, start:start + count].set(
+            vs.astype(cache["v"].dtype))
+    return out
+
+
+def slot_scales(scales: jax.Array, slot, p: int) -> jax.Array:
+    """A slot's first `p` per-token scales out of the lane-major cache
+    plane `[L, slots, kv, max_len]`, store-shaped [L, 1, p, kv]."""
+    rows = jax.lax.dynamic_index_in_dim(scales, slot, axis=1,
+                                        keepdims=False)[:, :, :p]
+    return jnp.swapaxes(rows, 1, 2)[:, None]
+
+
+def extract_prefix(cfg, cache: Params, slot, p: int, *,
+                   kv_quantize: str | None = None, dtype=None):
+    """A freshly prefilled slot's first `p` KV rows as prefill_continue
+    takes its prefix: (k, v) [L, 1, P, kv, hd], dequantized."""
+    k = jax.lax.dynamic_index_in_dim(cache["k"], slot, axis=1,
+                                     keepdims=False)[:, :p][:, None]
+    v = jax.lax.dynamic_index_in_dim(cache["v"], slot, axis=1,
+                                     keepdims=False)[:, :p][:, None]
+    if kv_quantize == "int8":
+        ksc, vsc = (slot_scales(cache[n], slot, p) for n in ("k_s", "v_s"))
+        k = dequantize_kv(k, ksc, dtype)
+        v = dequantize_kv(v, vsc, dtype)
+    return k, v
 
 
 def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -796,7 +858,8 @@ def resolve_prefill_attn(cfg: LlamaConfig) -> str:
 def prefill_attention(cfg: LlamaConfig, q: jax.Array, k: jax.Array,
                       v: jax.Array, cks=None, cvs=None, *,
                       q_offset: int = 0, impl: str | None = None,
-                      tables: jax.Array | None = None) -> jax.Array:
+                      tables: jax.Array | None = None,
+                      window: int | None = None) -> jax.Array:
     """Causal GQA chunk attention for prefill — the prefill twin of
     decode_attention, THE pluggable seam of the TTFT hot path (ISSUE 20).
 
@@ -823,6 +886,9 @@ def prefill_attention(cfg: LlamaConfig, q: jax.Array, k: jax.Array,
     contiguous slab view and falls into the identical mha — the parity
     anchor that keeps slab and paged byte-comparable, exactly like
     decode_attention's twin paths.
+
+    `window` (static; None = causal alone): key t is visible to row i iff
+    `0 <= q_offset + i - t < window`, in both impls.
     """
     if impl is None:
         impl = resolve_prefill_attn(cfg)
@@ -834,7 +900,9 @@ def prefill_attention(cfg: LlamaConfig, q: jax.Array, k: jax.Array,
         return flash_prefill_attention(q, k, v, q_offset=q_offset,
                                        k_scale=cks, v_scale=cvs,
                                        scale=1.0 / (hd ** 0.5),
-                                       tables=tables)
+                                       tables=tables,
+                                       **({} if window is None
+                                          else {"window": window}))
     if tables is not None:
         # XLA gather twin (see decode_attention): stage the table's
         # blocks as the contiguous [B, T, kv, hd] slab view, then the
@@ -852,14 +920,15 @@ def prefill_attention(cfg: LlamaConfig, q: jax.Array, k: jax.Array,
         k = k.astype(cfg.dtype) * cks[..., None].astype(cfg.dtype)
         v = v.astype(cfg.dtype) * cvs[..., None].astype(cfg.dtype)
     return mha(q, k.astype(cfg.dtype), v.astype(cfg.dtype), causal=True,
-               q_offset=q_offset)
+               q_offset=q_offset, window=window)
 
 
 def decode_attention(cfg: LlamaConfig, q: jax.Array, cache: Params,
                      layer, positions: jax.Array, *,
                      span: int | None = None, slot_start: int = 0,
                      impl: str | None = None,
-                     tables: jax.Array | None = None, new_scales=None):
+                     tables: jax.Array | None = None, new_scales=None,
+                     window: int | None = None):
     """Grouped-query decode/verify attention of ONE layer over the KV
     cache as the layer scan carries it — THE pluggable seam of the
     serving hot loop (ISSUE 15; in place since ISSUE 28).
@@ -898,6 +967,12 @@ def decode_attention(cfg: LlamaConfig, q: jax.Array, cache: Params,
     yet: the kernel attends with them and stores them, and the return is
     `(out, k_s, v_s)` (ops/flash_decode.py says why it is the kernel's
     to do).
+
+    `window` (static; None = the whole context): the slab is a RING of
+    its T rows, position p in row `p mod T`, and key p is visible to row
+    i iff `0 <= positions[:, i] - p < window`; `span` is not read. Both
+    impls: the flash kernel folds its blocks onto the ring, the einsum
+    path masks every ring row by the position it holds.
     """
     if impl is None:
         impl = resolve_decode_attn(cfg)
@@ -911,7 +986,8 @@ def decode_attention(cfg: LlamaConfig, q: jax.Array, cache: Params,
             q, cache["k"], cache["v"], positions[:, 0], layer=layer,
             span=span, slot_start=slot_start, k_scale=cache.get("k_s"),
             v_scale=cache.get("v_s"), new_scales=new_scales,
-            scale=1.0 / (hd ** 0.5), tables=tables)
+            scale=1.0 / (hd ** 0.5), tables=tables,
+            **({} if window is None else {"window": window}))
         if new_scales is None:
             return out.reshape(b, s_v, nh * hd)
         return (out[0].reshape(b, s_v, nh * hd), *out[1:])
@@ -930,8 +1006,8 @@ def decode_attention(cfg: LlamaConfig, q: jax.Array, cache: Params,
                                         axis=0)
         t_axis = 2 if name.endswith("_s") else 1
         return jax.lax.slice_in_dim(
-            rows, 0, rows.shape[t_axis] if span is None else span,
-            axis=t_axis)
+            rows, 0, rows.shape[t_axis]
+            if span is None or window is not None else span, axis=t_axis)
 
     ck, cv = layer_rows("k"), layer_rows("v")
     cks, cvs = ((layer_rows("k_s"), layer_rows("v_s")) if quantized
@@ -958,8 +1034,17 @@ def decode_attention(cfg: LlamaConfig, q: jax.Array, cache: Params,
     # instead of the payload where the algebra allows.
     g = nh // nkv
     k_pos = jnp.arange(ck.shape[1])
-    mask = (k_pos[None, None, None, :]
-            <= positions[:, None, :, None])  # [B, 1, Sv, span]
+    if window is None:
+        mask = (k_pos[None, None, None, :]
+                <= positions[:, None, :, None])  # [B, 1, Sv, span]
+    else:
+        # ring row r holds the newest position <= the row's newest that
+        # is r mod T (negative: nothing yet)
+        top = positions[:, -1:]
+        held = (top - jnp.mod(top - k_pos[None], ck.shape[1])
+                )[:, None, None, :]
+        q_pos = positions[:, None, :, None]
+        mask = (held >= 0) & (held <= q_pos) & (q_pos - held < window)
     qg = jnp.moveaxis(q.reshape(b, s_v, nkv, g, hd), 1, 3)
     if quantized:
         att = jnp.einsum("bhgqd,bkhd->bhgqk", qg, ck.astype(cfg.dtype),

@@ -66,7 +66,7 @@ def _populate() -> None:
 
 
 def _do_populate() -> None:
-    from kubeflow_tpu.models import (bert, kimi_linear, llama, lora,
+    from kubeflow_tpu.models import (bert, kimi_linear, laguna, llama, lora,
                                      mnist_cnn, moe_llama, nas_cnn, resnet,
                                      vit)
 
@@ -81,6 +81,9 @@ def _do_populate() -> None:
     register("kimi_linear", ModelDef(
         kimi_linear.KimiLinearConfig, kimi_linear.init, kimi_linear.apply,
         kimi_linear.loss_fn, kimi_linear.logical_axes))
+    register("laguna", ModelDef(
+        laguna.LagunaConfig, laguna.init, laguna.apply, laguna.loss_fn,
+        laguna.logical_axes))
     register("mnist_cnn", ModelDef(mnist_cnn.MnistConfig, mnist_cnn.init,
                                    mnist_cnn.apply, mnist_cnn.loss_fn,
                                    mnist_cnn.logical_axes))

@@ -117,6 +117,14 @@ ATTENTION_IMPL = REGISTRY.gauge(
     "per (engine, phase=prefill|decode, impl=xla|flash), value 1)",
     ["engine", "phase", "impl"])
 
+# -- a model family's decode-step counts (scrape-hook fed) --------------------
+ENGINE_STEP_COUNT = REGISTRY.gauge(
+    "engine_step_counter",
+    "What the served family's decode steps count (its STEP_COUNTERS: the "
+    "routed experts' assignments, expert visits, dropped rows, load), "
+    "folded over the chunks replayed so far",
+    ["engine", "name"])
+
 # -- scheduler (scrape-hook fed) ----------------------------------------------
 SCHED_QUEUED = REGISTRY.gauge(
     "scheduler_queued", "Requests waiting for admission", ["engine"])

@@ -34,12 +34,15 @@ def mha(
     scale: float | None = None,
     segment_ids: jax.Array | None = None,
     q_offset: int | jax.Array = 0,
+    window: int | None = None,
 ) -> jax.Array:
     """Multi-head attention, BSHD layout, fp32 softmax accumulation.
 
     q: [B, Sq, H, D]; k/v: [B, Sk, Hkv, D] (GQA expanded automatically).
     `q_offset` positions the query block within the kv sequence for causal
     masking — used by decode (Sq=1 at position t) and ring attention shards.
+    `window` (causal only): key j is visible to query i iff
+    0 <= i - j < window — sliding-window attention.
     """
     b, sq, h, d = q.shape
     hkv = k.shape[2]
@@ -58,6 +61,8 @@ def mha(
         q_pos = jnp.arange(sq)[:, None] + q_offset
         k_pos = jnp.arange(sk)[None, :]
         mask = q_pos >= k_pos  # [Sq, Sk]
+        if window is not None:
+            mask = mask & (q_pos - k_pos < window)
         mask = mask[None, None, :, :]
     if segment_ids is not None:
         if segment_ids.shape[1] != sq or k.shape[1] != sq:

@@ -154,7 +154,8 @@ def _heads(ref, dtype):
 
 
 def _decode_kernel(meta_ref, *refs, s_v, block_kv, nkv, span, t_cache,
-                   scale, quantized, row_at, new_scales_at, paged=False):
+                   scale, quantized, row_at, new_scales_at, paged=False,
+                   window=None, lo_at=None):
     if paged:
         # block-table mode (ISSUE 19): the table ref is scalar-prefetch
         # arg 2 — it steers the k/v/scale BlockSpec index_maps (the
@@ -181,7 +182,13 @@ def _decode_kernel(meta_ref, *refs, s_v, block_kv, nkv, span, t_cache,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     length = meta_ref[b]
-    k_start = j * block_kv
+    if window is None:
+        k_start = j * block_kv
+    else:
+        # a ring: grid step j stands on the slot's j-th block of
+        # POSITIONS from the window's lower edge (the index maps fold it
+        # onto the ring), so everything below reads positions as ever
+        k_start = (meta_ref[lo_at + b] + j) * block_kv
     # a cache whose length the block does not divide (toy dims): the last
     # block's tail lies past the array and holds anything, NaN included
     ragged = t_cache is not None and t_cache % block_kv != 0
@@ -236,7 +243,12 @@ def _decode_kernel(meta_ref, *refs, s_v, block_kv, nkv, span, t_cache,
         # [group member, S_v]); padded rows compute garbage sliced off
         q_pos = length + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1) % s_v
-        valid = (k_pos < span) & (k_pos <= q_pos)
+        if window is None:
+            valid = (k_pos < span) & (k_pos <= q_pos)
+        else:
+            # the ring row of a position past the slot's newest still
+            # holds the position a ring earlier: unseen as `k_pos` says
+            valid = (k_pos <= q_pos) & (q_pos - k_pos < window)
         s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_ref[:, :, 0:1]
@@ -301,7 +313,7 @@ def _decode_kernel(meta_ref, *refs, s_v, block_kv, nkv, span, t_cache,
 def flash_decode_attention(q, k, v, lengths, *, layer, span=None,
                            slot_start=0, k_scale=None, v_scale=None,
                            new_scales=None, scale=None, block_kv=None,
-                           interpret=None, tables=None):
+                           interpret=None, tables=None, window=None):
     """Fused GQA decode/verify attention over the KV cache, in place.
 
     q: [B, S_v, heads, hd] (model dtype); k/v: the WHOLE cache payload
@@ -337,6 +349,16 @@ def flash_decode_attention(q, k, v, lengths, *, layer, span=None,
     kernel body, its masking, and the online-softmax recurrence are
     byte-identical to slab mode, which is what keeps the layouts
     parity-comparable.
+
+    WINDOW mode (`window`, static; None = today's programs): the slab is
+    a RING of T rows a slot, position p in row `p mod T`; query row i of
+    slot b sees positions p with `0 <= lengths[b] + i - p < window`. T
+    is a whole number of KV blocks and at least `window + S_v - 1`. A
+    slot's grid steps walk its blocks of positions from the one that
+    holds the window's lower edge up to the one its newest row is in
+    (two blocks for a window of one block's length), folded onto the
+    ring by the index maps; blocks below the window are neither fetched
+    nor computed, and `span` is not read.
     """
     b, s_v, nh, hd = q.shape
     paged = tables is not None
@@ -357,11 +379,24 @@ def flash_decode_attention(q, k, v, lengths, *, layer, span=None,
             raise ValueError(f"tables rows {tables.shape[0]} != batch {b}")
         n_k = tables.shape[1]
         span, t_cache = n_k * block_kv, None
-    else:
+    elif window is None:
         span = t_cache if span is None else min(span, t_cache)
         block_kv = DEFAULT_BLOCK_KV if block_kv is None else block_kv
         block_kv = min(block_kv, t_cache)
         n_k = pl.cdiv(span, block_kv)
+    else:
+        block_kv = DEFAULT_BLOCK_KV if block_kv is None else block_kv
+        block_kv = min(block_kv, t_cache)
+        if t_cache % block_kv or t_cache < window + s_v - 1:
+            raise ValueError(
+                f"a ring of {t_cache} rows must be whole blocks of "
+                f"{block_kv} and hold a window of {window} + {s_v - 1}")
+        n_ring = t_cache // block_kv
+        # the blocks a band of window + S_v - 1 positions can touch
+        n_k = min(n_ring, (window + s_v - 2) // block_kv + 2)
+        span = None
+    if window is not None and paged:
+        raise ValueError("window attention has no paged form")
 
     # regroup q heads onto their kv heads: [B, S_v, nh, hd] →
     # [B, kv, g*S_v, hd] (kv-major head split, the verify_inner
@@ -386,7 +421,10 @@ def flash_decode_attention(q, k, v, lengths, *, layer, span=None,
     slot = jnp.arange(b, dtype=jnp.int32)
     live = lengths + (s_v - 1) >= 0
     row = jnp.maximum(jax.lax.cummax(jnp.where(live, slot, -1)), 0)
-    cap = jnp.clip((lengths + (s_v - 1)) // block_kv, 0, n_k - 1)[row]
+    if window is None:
+        cap = jnp.clip((lengths + (s_v - 1)) // block_kv, 0, n_k - 1)[row]
+    else:   # blocks of positions: the index maps fold them onto the ring
+        cap = (jnp.maximum(lengths + (s_v - 1), 0) // block_kv)[row]
     meta = [lengths, jnp.asarray(layer, jnp.int32).reshape(1), row, cap]
     row_at, cap_at = b + 1, 2 * b + 1
     new_scales_at = None
@@ -397,12 +435,23 @@ def flash_decode_attention(q, k, v, lengths, *, layer, span=None,
         meta += [jax.lax.bitcast_convert_type(
             sc.astype(jnp.float32), jnp.int32).reshape(-1)
             for sc in new_scales]
+    lo_at = None
+    if window is not None:
+        lo_at = sum(m.shape[0] for m in meta)
+        meta.append(jnp.maximum(lengths - (window - 1), 0) // block_kv)
     meta = jnp.concatenate(meta)
 
-    def kv_block(b_, j, meta_ref):
-        cap = meta_ref[cap_at + b_]
-        return jnp.where(meta_ref[b_] + (s_v - 1) >= 0,
-                         jnp.minimum(j, cap), cap)
+    if window is None:
+        def kv_block(b_, j, meta_ref):
+            cap = meta_ref[cap_at + b_]
+            return jnp.where(meta_ref[b_] + (s_v - 1) >= 0,
+                             jnp.minimum(j, cap), cap)
+    else:
+        def kv_block(b_, j, meta_ref):
+            cap = meta_ref[cap_at + b_]
+            return jnp.where(
+                meta_ref[b_] + (s_v - 1) >= 0,
+                jnp.minimum(meta_ref[lo_at + b_] + j, cap), cap) % n_ring
 
     if paged:
         def sc_at(b_, j, meta_ref, tbl_ref):
@@ -458,8 +507,11 @@ def flash_decode_attention(q, k, v, lengths, *, layer, span=None,
     kernel = functools.partial(
         _decode_kernel, s_v=s_v, block_kv=block_kv, nkv=nkv, span=span,
         t_cache=t_cache, scale=scale, quantized=quantized, row_at=row_at,
-        new_scales_at=new_scales_at, paged=paged)
+        new_scales_at=new_scales_at, paged=paged,
+        **({} if window is None
+           else {"window": int(window), "lo_at": lo_at}))
     itemsize = jnp.dtype(k.dtype).itemsize
+    reach = span if window is None else n_k * block_kv
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -469,9 +521,9 @@ def flash_decode_attention(q, k, v, lengths, *, layer, span=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * nh * s_v * span * hd,
-            bytes_accessed=2 * b * span * nkv * hd * itemsize,
-            transcendentals=b * nh * s_v * span,
+            flops=4 * b * nh * s_v * reach * hd,
+            bytes_accessed=2 * b * reach * nkv * hd * itemsize,
+            transcendentals=b * nh * s_v * reach,
         ),
         interpret=interpret,
     )(*prefetch, qg, k, v, *extra_args)

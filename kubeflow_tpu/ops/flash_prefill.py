@@ -114,7 +114,7 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _prefill_kernel(*refs, block_q, block_kv, t_real, q_offset, scale,
-                    quantized, paged=False):
+                    quantized, paged=False, window=None):
     if paged:
         # block-table mode: the table ref is the scalar-prefetch arg —
         # it steers the k/v/scale BlockSpec index_maps (the indirection
@@ -164,6 +164,9 @@ def _prefill_kernel(*refs, block_q, block_kv, t_real, q_offset, scale,
                      jnp.int32, (rows, block_kv), 0) % block_q)
         # mha's causal rule: key t visible to row i iff t <= q_offset+i
         valid = (k_pos < t_real) & (k_pos <= q_pos)
+        if window is not None:
+            # the lower edge: key t is visible iff q - t < window
+            valid = valid & (q_pos - k_pos < window)
         s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_ref[:, 0:1]
@@ -189,8 +192,17 @@ def _prefill_kernel(*refs, block_q, block_kv, t_real, q_offset, scale,
     # causal block skip: whole KV block above this q block's deepest
     # position — or entirely in the T pad — contributes nothing (block
     # 0 always computes: every q row sees key position 0)
-    @pl.when((k_start <= q_offset + (iq + 1) * block_q - 1)
-             & (k_start < t_real))
+    live = ((k_start <= q_offset + (iq + 1) * block_q - 1)
+            & (k_start < t_real))
+    if window is not None:
+        # ... nor does a KV block wholly below the window of the q
+        # block's FIRST row (with a window a row may see nothing in a
+        # block that computes: the rows' running max starts at NEG_INF
+        # and masked entries are zeroed, so the order is free)
+        live = live & (k_start + block_kv - 1
+                       >= q_offset + iq * block_q - (window - 1))
+
+    @pl.when(live)
     def _():
         compute()
 
@@ -202,7 +214,8 @@ def _prefill_kernel(*refs, block_q, block_kv, t_real, q_offset, scale,
 
 def flash_prefill_attention(q, k, v, *, q_offset=0, k_scale=None,
                             v_scale=None, scale=None, block_q=None,
-                            block_kv=None, interpret=None, tables=None):
+                            block_kv=None, interpret=None, tables=None,
+                            window=None):
     """Fused causal GQA prefill attention for one chunk.
 
     q: [B, S_chunk, heads, hd] (model dtype) — row i of slot b sits at
@@ -225,6 +238,15 @@ def flash_prefill_attention(q, k, v, *, q_offset=0, k_scale=None,
     scalar-prefetched table exactly like ops/flash_decode; the kernel
     body, its masking, and the online-softmax recurrence are
     byte-identical to slab mode.
+
+    `window` (static; None = causal alone, today's program): key t is
+    visible to row i iff `0 <= q_offset + i - t < window`. A KV block
+    wholly outside a q block's band — above its last row or below its
+    first row's window — is neither computed nor fetched: the index maps
+    hold the block coordinate inside the band, and the pipeline issues
+    no copy for an unchanged index. Positions are relative: a caller
+    that holds only the last P' rows of a prefix passes them as K/V and
+    `q_offset=P'`.
     """
     b, s, nh, hd = q.shape
     paged = tables is not None
@@ -238,6 +260,8 @@ def flash_prefill_attention(q, k, v, *, q_offset=0, k_scale=None,
     quantized = k_scale is not None
     if quantized != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be passed together")
+    if window is not None and paged:
+        raise ValueError("window attention has no paged form")
     interpret = _resolve_interpret(interpret)
     scale = 1.0 / (hd ** 0.5) if scale is None else scale
     if paged:
@@ -301,10 +325,23 @@ def flash_prefill_attention(q, k, v, *, q_offset=0, k_scale=None,
     else:
         k3 = k.reshape(b, t_pad, nkv * hd)
         v3 = v.reshape(b, t_pad, nkv * hd)
-        kv_spec = pl.BlockSpec((1, block_kv, hd),
-                               lambda b_, h, iq, j, *_: (b_, j, h))
-        sc_spec = pl.BlockSpec((1, 1, 1, block_kv),
-                               lambda b_, h, iq, j, *_: (b_, h, 0, j))
+        if window is None:
+            def band(iq, j):
+                return j
+        else:
+            def band(iq, j):
+                # the KV blocks q block iq can see, first to last
+                lo = jnp.maximum(q_offset + iq * block_q - (window - 1),
+                                 0) // block_kv
+                hi = jnp.minimum((q_offset + (iq + 1) * block_q - 1)
+                                 // block_kv, n_k - 1)
+                return jnp.clip(j, lo, hi)
+        kv_spec = pl.BlockSpec(
+            (1, block_kv, hd),
+            lambda b_, h, iq, j, *_: (b_, band(iq, j), h))
+        sc_spec = pl.BlockSpec(
+            (1, 1, 1, block_kv),
+            lambda b_, h, iq, j, *_: (b_, h, 0, band(iq, j)))
 
     extra_specs, extra_args = [], []
     if quantized:
@@ -332,7 +369,8 @@ def flash_prefill_attention(q, k, v, *, q_offset=0, k_scale=None,
     )
     kernel = functools.partial(
         _prefill_kernel, block_q=block_q, block_kv=block_kv, t_real=t,
-        q_offset=q_offset, scale=scale, quantized=quantized, paged=paged)
+        q_offset=q_offset, scale=scale, quantized=quantized, paged=paged,
+        **({} if window is None else {"window": int(window)}))
     itemsize = jnp.dtype(k.dtype).itemsize
     out = pl.pallas_call(
         kernel,

@@ -152,7 +152,23 @@ class ShareArgs:
     renormalize: bool = True       # weights / their sum over the chosen
 
 
-ROW_TILE = 256    # the grouped matmul's tile of rows
+ROW_TILE = 256    # the grouped matmul's tile of rows, at most
+#: where every expert is held, every assignment is a row here: the sorted
+#: rows then go through the experts this many at a time, which bounds the
+#: temporaries of a wide prefill wave
+ALL_HELD_SLICE = 32768
+
+
+def row_tile(assignments: int) -> int:
+    """The grouped matmul's tile of rows for a call of `assignments` rows:
+    ROW_TILE for a training step or a prefill chunk; a decode step's few
+    hundred rows (about one an expert) get a tile an eighth of them at
+    most, 32 at least, so that an expert's visit multiplies its one or two
+    rows by the weights and not a training tile of padding."""
+    tile = ROW_TILE
+    while tile > 32 and tile * 8 > assignments:
+        tile //= 2
+    return tile
 
 
 def sigmoid_route(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
@@ -173,21 +189,25 @@ def sigmoid_route(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
     return idx, w * args.scale
 
 
-def _gmm_tiling(m: int, k: int, n: int):
+def _gmm_tiling(tm: int, k: int, n: int) -> tuple[int, int, int]:
     def tile(size, most):
         return max(t for t in range(128, most + 1, 128) if size % t == 0)
-    return ROW_TILE, tile(k, 768), tile(n, 1024)
+    return tm, tile(k, 768), tile(n, 1024)
 
 
-def _grouped_matmul(rows, w, group_sizes, dtype):
+def _grouped_matmul(rows, w, group_sizes, dtype, tm=ROW_TILE):
     """rows [M, K] sorted by group, w [G, K, N], group_sizes [G + 1] (the
-    last group is the rows no expert here takes: they come out zero)."""
+    last group is the rows no expert here takes: they come out zero); `tm`
+    the tile of rows, which divides M."""
     interpret = FORCE_INTERPRET
     if interpret or pallas_compat.target_platform() == "tpu":
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
+        # the tiles as a tuple: a static argument of megablox's jit, which
+        # has to compare equal from call to call
         return megablox.gmm(rows, w.astype(dtype), group_sizes, dtype,
-                            _gmm_tiling, None, None, False, interpret)
+                            _gmm_tiling(tm, rows.shape[1], w.shape[2]),
+                            None, None, False, interpret)
     sizes = group_sizes[:-1]
     out = jax.lax.ragged_dot(rows, w.astype(dtype), sizes,
                              preferred_element_type=dtype)
@@ -197,25 +217,38 @@ def _grouped_matmul(rows, w, group_sizes, dtype):
 
 def moe_share_mlp(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
                   w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                  args: ShareArgs, dtype: Any = jnp.bfloat16):
+                  args: ShareArgs, dtype: Any = jnp.bfloat16,
+                  layer: int | None = None):
     """The routed experts' part of a layer, as the rank that holds experts
     [first_expert, first_expert + n_held) computes it.
 
     x [B, S, D]; router_w [D, E_all]; router_bias [E_all] (a buffer: no
-    gradient); w_gate / w_up [n_held, D, F]; w_down [n_held, F, D]. Returns
+    gradient); w_gate / w_up [n_held, D, F]; w_down [n_held, F, D]; or,
+    with `layer` (static), the STACKS of every layer's experts
+    `[layers, n_held, ...]`, read in place: the grouped matmul takes the
+    stack as `layers * n_held` groups whose sizes are zero outside this
+    layer's, and visits no empty group (a slice of the stack would reach
+    the kernel, a custom call, as a copy of a layer's experts). Returns
     (out [B, S, D], counters): `rows_here` the assignments this rank took,
     `rows_dropped` those it took and did not compute (0, or the step is
     wrong), `load_max_over_mean` over the experts held,
     `top1_share_max` the largest share of tokens whose first choice is one
-    expert, over all experts."""
+    expert, over all experts, `experts_touched` how many of the experts
+    held took a row (each one's weights are read for them)."""
     b, s, d = x.shape
     t, k, held = b * s, args.top_k, args.n_held
+    before = after = 0
+    if layer is not None:
+        before, after = layer * held, (w_gate.shape[0] - 1 - layer) * held
+        w_gate, w_up, w_down = (w.reshape((-1,) + w.shape[2:])
+                                for w in (w_gate, w_up, w_down))
+    tile = row_tile(t * k)
     xt = x.reshape(t, d)
     with jax.named_scope("moe_route"):
         idx, w = sigmoid_route(xt, router_w, router_bias, args)
         local = idx - args.first_expert
         key = jnp.where((local >= 0) & (local < held), local, held)
-        total = -(-t * k // ROW_TILE) * ROW_TILE
+        total = -(-t * k // tile) * tile
         key = jnp.pad(key.reshape(t * k), (0, total - t * k),
                       constant_values=held)
         order = jnp.argsort(key, stable=True)
@@ -228,7 +261,9 @@ def moe_share_mlp(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
     # slice holds what balanced routing sends here eight times over; the
     # others run only when the rows reach them, under a rematerialised scan,
     # so the worst case costs no memory until it happens
-    m = -(-max(total // 8, 1) // ROW_TILE) * ROW_TILE
+    m = -(-max(total // 8, 1) // tile) * tile
+    if held == args.n_router_experts:   # every row is here, every time
+        m = min(total, ALL_HELD_SLICE)
     n_slices = -(-total // m)
     order = jnp.pad(order, (0, n_slices * m - total),
                     constant_values=t * k)      # past every row: no expert
@@ -245,9 +280,11 @@ def moe_share_mlp(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
         # the order is sorted, so a slice is experts' rows (its first one
         # possibly the tail of an expert's) and then rows no expert here
         # takes: the groups start at the slice's first row
-        groups = jnp.concatenate([here, (m - jnp.sum(here))[None]])
+        groups = jnp.concatenate([
+            jnp.zeros((before,), here.dtype), here,
+            jnp.zeros((after,), here.dtype), (m - jnp.sum(here))[None]])
         mm = functools.partial(_grouped_matmul, group_sizes=groups,
-                               dtype=dtype)
+                               dtype=dtype, tm=tile)
         gate, up = mm(rows, w_gate), mm(rows, w_up)
         act = (jax.nn.silu(gate.astype(jnp.float32))
                * up.astype(jnp.float32)).astype(dtype)
@@ -279,5 +316,6 @@ def moe_share_mlp(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
         "load_max_over_mean": jnp.max(load) / jnp.maximum(jnp.mean(load),
                                                           1e-9),
         "top1_share_max": jnp.max(first).astype(jnp.float32) / t,
+        "experts_touched": jnp.sum(sizes[:held] > 0).astype(jnp.float32),
     }
     return out.reshape(b, s, d), counters

@@ -654,7 +654,8 @@ class EngineSupervisor:
                         tm = self.engine.request_timing(e.engine_rid)
                         e.phases = {k: tm.get(k) for k in
                                     ("queue_wait_ms", "prefill_ms",
-                                     "decode_ms", "engine")}
+                                     "decode_ms", "engine", "counters")
+                                    if k != "counters" or k in tm}
                         e.cached = int(tm.get("cached_prefix_len") or 0)
                     except Exception:
                         pass   # phase detail is best-effort accounting
@@ -813,7 +814,10 @@ class EngineSupervisor:
                     "queue_wait_ms": phases.get("queue_wait_ms"),
                     "prefill_ms": phases.get("prefill_ms"),
                     "decode_ms": phases.get("decode_ms"),
-                    "engine": phases.get("engine")}
+                    "engine": phases.get("engine"),
+                    # a family's decode-step counts, where it keeps any
+                    **({"counters": phases["counters"]}
+                       if "counters" in phases else {})}
 
     def cached_tokens(self, rid: int) -> int:
         """Prefix-KV tokens the CURRENT engine reused for this request.

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kubeflow_tpu.kvcache import RadixKVCache
-from kubeflow_tpu.models import llama
+from kubeflow_tpu.models import llama as DEFAULT_FAMILY
 from kubeflow_tpu.obs import metrics as obs_metrics
 from kubeflow_tpu.obs.trace import TRACER, PhaseClock, StepAggregator
 from kubeflow_tpu.ops import quant
@@ -183,6 +183,12 @@ def _under_engine_mesh(program):
     return traced
 
 
+def _row(kv, i: int):
+    """Batch row `i` of a family's prefill KV: each leaf is
+    [layers, batch, rows, ...]."""
+    return jax.tree.map(lambda a: a[:, i], kv)
+
+
 def named_program(name: str, fn, **static):
     """`fn` with `static` bound, under a function name: jax calls the
     compiled module `jit_<name>` — one name per KIND of engine program,
@@ -194,8 +200,7 @@ def named_program(name: str, fn, **static):
     return prog
 
 
-def pin_attention_impls(cfg: llama.LlamaConfig, *,
-                        sharded: bool) -> llama.LlamaConfig:
+def pin_attention_impls(cfg, *, sharded: bool, family=DEFAULT_FAMILY):
     """The config an engine actually runs: both serving attention impls
     resolved to "xla" or "flash", once, at construction. A program
     compiled lazily after warmup (cold span/chunk combos) re-traces the
@@ -218,19 +223,34 @@ def pin_attention_impls(cfg: llama.LlamaConfig, *,
             if getattr(cfg, f) == "auto"})
     return dataclasses.replace(
         cfg,
-        decode_attention_impl=llama.resolve_decode_attn(cfg),
-        prefill_attention_impl=llama.resolve_prefill_attn(cfg))
+        decode_attention_impl=family.resolve_decode_attn(cfg),
+        prefill_attention_impl=family.resolve_prefill_attn(cfg))
 
 
 class LLMEngine:
-    """Continuous-batching generation over llama-family params: greedy by
-    default, per-request temperature/top-k/top-p sampling, stop sequences,
-    logprobs, and chunk-boundary cancellation."""
+    """Continuous-batching generation over a model family's params:
+    greedy by default, per-request temperature/top-k/top-p sampling, stop
+    sequences, logprobs, and chunk-boundary cancellation.
+
+    THE FAMILY SEAM: `family` is the model family's module (default
+    `models.llama`), and everything the engine knows of a model it asks
+    that module by these names: `init_cache`, `cache_write`,
+    `extract_prefix`, `cache_stats` (the KV layout), `prefill`,
+    `prefill_continue`, `decode_step`, `verify_step` (the bodies),
+    `quantize_params`, `QUANT_LEAVES`, `quantize_kv`, `dequantize_kv`,
+    `cache_kv_spec`, `logical_axes_for` (weights and sharding),
+    `resolve_decode_attn`, `resolve_prefill_attn` (kernel selection) and
+    `STEP_COUNTERS` (what a decode step counts). A family's prefill KV is
+    any pytree of `[layers, batch, rows, ...]` leaves."""
 
     #: obs component label (overridden by role engines: prefill/decode/
     #: stage_sharded) — the `component=` of every engine-side metric and
     #: the role attribute of engine spans
     role = "engine"
+
+    #: the model family's module (THE FAMILY SEAM); an instance may be
+    #: given another
+    family = DEFAULT_FAMILY
 
     #: KV residency: "slab" = preallocated [n_slots, max_len] rows;
     #: serving/paged.py overrides to "paged" (block pool + tables)
@@ -245,7 +265,7 @@ class LLMEngine:
     #: hold those bytes, so it skips the write
     _cont_writes_prefix = True
 
-    def __init__(self, params, cfg: llama.LlamaConfig, *, n_slots: int = 4,
+    def __init__(self, params, cfg, *, n_slots: int = 4,
                  max_len: int = 512, buckets: Sequence[int] = (64, 128, 256),
                  max_queue: int = 1024, eos_id: int | None = None,
                  prefer_native: bool = True, decode_chunk: int = 8,
@@ -262,7 +282,24 @@ class LLMEngine:
                  adapters: dict[str, dict[str, Any]] | None = None,
                  logprobs_topk: int = 0,
                  sample_k_max: int = 64,
-                 pipeline_decode: bool = True):
+                 pipeline_decode: bool = True,
+                 prefill_wave_max: int | None = None,
+                 warm_chain: bool = False,
+                 family=DEFAULT_FAMILY):
+        self.family = family
+        # the most prompts one prefill wave (one batched program) takes;
+        # None = every slot. A cap keeps the menu of (bucket, width)
+        # programs small and a wave short where prompts are long: the
+        # decode steps of the slots that are running wait for it.
+        if prefill_wave_max is not None and prefill_wave_max < 1:
+            raise ValueError("prefill_wave_max must be >= 1")
+        self.prefill_wave_max = min(n_slots, prefill_wave_max or n_slots)
+        # warmup() also compiles the chunked-prefill chain (every
+        # continuation pair a prompt longer than the largest bucket can
+        # meet, and the extracts that feed them): for a deployment whose
+        # traffic holds such prompts, where the first of each length class
+        # would otherwise wait out a compile of its own
+        self.warm_chain = warm_chain
         if max(buckets) >= max_len:
             raise ValueError("largest bucket must leave room to decode")
         if quantize not in (None, "int8"):
@@ -295,8 +332,8 @@ class LLMEngine:
         # device-resident): each "decode" dispatch becomes a scan of verify
         # steps — draft k tokens by matching the context's trailing n-gram
         # against a device-side token-history buffer, verify all k+1
-        # positions in ONE forward (llama.verify_step), accept the longest
-        # argmax-matching prefix. Greedy output is EXACTLY the
+        # positions in ONE forward (the family's verify_step), accept the
+        # longest argmax-matching prefix. Greedy output is EXACTLY the
         # non-speculative output (tested); the win is tokens-per-dispatch
         # on copy-heavy / low-entropy text where drafts accept. Drafting,
         # verification, and acceptance all run inside the compiled program;
@@ -353,17 +390,17 @@ class LLMEngine:
         if prefill_attention_impl is not None:
             overrides["prefill_attention_impl"] = prefill_attention_impl
         cfg = pin_attention_impls(dataclasses.replace(cfg, **overrides),
-                                  sharded=mesh is not None)
+                                  sharded=mesh is not None, family=family)
         # int8 KV cache: decode re-reads the whole (span of the) cache
         # every step, so int8 storage halves that HBM traffic vs bf16 and
         # halves cache residency (2x slots or context at 8B scale);
         # per-token-per-head scales, bf16 attention compute
         self.kv_quantize = kv_quantize
         if quantize == "int8":
-            # weight-only int8 (models/llama.quantize_params): decode is
+            # weight-only int8 (the family's quantize_params): decode is
             # HBM-bound on weight reads, so int8 storage is the serving
             # throughput lever; done BEFORE sharding so the shards are int8
-            params = llama.quantize_params(params)
+            params = family.quantize_params(params)
         self.quantize = quantize
         self.params = params
         self.cfg = cfg
@@ -478,6 +515,9 @@ class LLMEngine:
         self._submit_lock = threading.Lock()
         self._prefill_fns: dict[tuple[int, int], Any] = {}
         self._decode_fns: dict[int, Any] = {}
+        # what the family's decode steps count (STEP_COUNTERS), summed or
+        # last seen, from the packed rows' trailing columns
+        self._step_counts = np.zeros((len(family.STEP_COUNTERS),))
         # -- prefix KV reuse (the kvcache tentpole, vLLM/SGLang-style and
         # TPU-shaped): a radix/block-trie index (kvcache.RadixKVCache)
         # over token sequences maps to ref-counted device KV blocks of
@@ -550,10 +590,11 @@ class LLMEngine:
         self.params = shard_tree(
             self.params,
             tree_logical_to_sharding(
-                llama.logical_axes_for(self.params, self.cfg), mesh))
-        # kv heads over `tensor` (llama.cache_kv_spec: no trailing None, or
-        # every program would retrace on its first post-warmup call)
-        self._cache_sh = {name: NamedSharding(mesh, llama.cache_kv_spec(name))
+                self.family.logical_axes_for(self.params, self.cfg), mesh))
+        # kv heads over `tensor` (the family's cache_kv_spec: no trailing
+        # None, or every program would retrace on its first post-warmup call)
+        self._cache_sh = {name: NamedSharding(
+                              mesh, self.family.cache_kv_spec(name))
                           for name in ("k", "v", "k_s", "v_s")}
         self._repl = NamedSharding(mesh, P())
         # penalty counts shard over the vocab axis like the lm_head logits
@@ -566,8 +607,9 @@ class LLMEngine:
         only ITS shard (make_array_from_callback) — an 8B-scale cache that
         only fits sharded must never be materialized whole on one device."""
         if self.mesh is None:
-            cache = llama.init_cache(self.cfg, self.n_slots, self.max_len,
-                                     kv_quantize=self.kv_quantize)
+            cache = self.family.init_cache(
+                self.cfg, self.n_slots, self.max_len,
+                kv_quantize=self.kv_quantize, chunk=self.buckets[-1])
             # per-slot generated-token counts (int32 over the vocab) back
             # the presence/frequency penalties: ~0.5 MB/slot at 8B vocab,
             # read once per sampled row — noise next to the weight read
@@ -581,7 +623,7 @@ class LLMEngine:
             return cache
         # schema derives from init_cache — ONE source of truth for the
         # cache layout (shared with serving/contract.py)
-        leaves = jax.eval_shape(lambda: llama.init_cache(
+        leaves = jax.eval_shape(lambda: self.family.init_cache(
             self.cfg, self.n_slots, self.max_len,
             kv_quantize=self.kv_quantize))
 
@@ -624,13 +666,13 @@ class LLMEngine:
         names = sorted(adapters)
         first = adapters[names[0]]["lora"]
         targets = sorted(first)
-        bad = set(targets) - set(llama.QUANT_LEAVES)
+        bad = set(targets) - set(self.family.QUANT_LEAVES)
         if bad:
             # mirror LoraLlamaConfig.__post_init__: a typo'd target (e.g.
             # 'Wq') through the direct engine API must fail loudly here —
             # _adapted would otherwise silently serve the base weights
             raise ValueError(f"unknown adapter targets {sorted(bad)}; "
-                             f"known: {sorted(llama.QUANT_LEAVES)}")
+                             f"known: {sorted(self.family.QUANT_LEAVES)}")
         rank = first[targets[0]]["a"].shape[-1]
         stack = {}
         for t in targets:
@@ -777,12 +819,14 @@ class LLMEngine:
                             lambda _: greedy, logits)
         return key, toks
 
-    def _pack_out(self, toks, logits):
+    def _pack_out(self, toks, logits, counters=None):
         """Program output row per sampled token: [tok, logprob(, top-N ids,
         top-N logprobs)] as ONE f32 array — a single packed fetch keeps the
         host loop at one RTT per iteration (token ids are exact in f32 for
         any vocab < 2^24). Logprobs are of the RAW model distribution
-        (temperature-independent), the OpenAI convention."""
+        (temperature-independent), the OpenAI convention. `counters` (a
+        decode step's f32 vector, the family's STEP_COUNTERS) rides every
+        row's trailing columns: no fetch of its own."""
         lse = jax.nn.logsumexp(logits, axis=-1)
         lp = jnp.take_along_axis(logits, toks[..., None],
                                  axis=-1)[..., 0] - lse
@@ -790,6 +834,9 @@ class LLMEngine:
         if self.logprobs_topk:
             tv, tid = jax.lax.top_k(logits, self.logprobs_topk)
             cols += [tid.astype(jnp.float32), tv - lse[..., None]]
+        if counters is not None:
+            cols.append(jnp.broadcast_to(
+                counters.astype(jnp.float32), toks.shape + counters.shape))
         return jnp.concatenate(cols, axis=-1)
 
     @property
@@ -840,14 +887,14 @@ class LLMEngine:
         tokens, slots, prompt_lens, row_samp, aids = self._unpack_wave(wave)
         # only each prompt's last row is sampled: project just those
         # (the all-position logits of a wide wave do not fit a chip)
-        stacked, ks, vs = llama.prefill(params, tokens, self.cfg,
-                                        lora=lora, ids=aids,
-                                        logit_rows=prompt_lens - 1)
+        stacked, ks, vs = self.family.prefill(params, tokens, self.cfg,
+                                              lora=lora, ids=aids,
+                                              logit_rows=prompt_lens - 1)
         bucket = tokens.shape[1]
         cache = dict(cache)
         for i in range(tokens.shape[0]):   # W is static: unrolled updates
             cache = self._cache_write(cache, slots[i], 0, bucket,
-                                      ks[:, i], vs[:, i])
+                                      _row(ks, i), _row(vs, i))
             lengths = lengths.at[slots[i]].set(prompt_lens[i])
             samp = samp.at[slots[i]].set(row_samp[i])
             if aids is not None:
@@ -876,25 +923,11 @@ class LLMEngine:
                 self._pack_out(toks, stacked))
 
     def _cache_write(self, cache, slot, start: int, count: int, ks, vs):
-        """Write [L, count, kv, hd] KV rows into a slot's [start, start+count)
-        range, quantizing when the cache is int8. start/count are static."""
-        out = dict(cache)
-        if self.kv_quantize == "int8":
-            kq, ksc = llama.quantize_kv(ks)
-            vq, vsc = llama.quantize_kv(vs)
-            out["k"] = cache["k"].at[:, slot, start:start + count].set(kq)
-            out["v"] = cache["v"].at[:, slot, start:start + count].set(vq)
-            # scales are stored lane-major: [L, slots, kv, max_len]
-            out["k_s"] = cache["k_s"].at[:, slot, :, start:start + count
-                                         ].set(jnp.swapaxes(ksc, 1, 2))
-            out["v_s"] = cache["v_s"].at[:, slot, :, start:start + count
-                                         ].set(jnp.swapaxes(vsc, 1, 2))
-        else:
-            out["k"] = cache["k"].at[:, slot, start:start + count].set(
-                ks.astype(cache["k"].dtype))
-            out["v"] = cache["v"].at[:, slot, start:start + count].set(
-                vs.astype(cache["v"].dtype))
-        return out
+        """Write one prompt's KV rows (the family's prefill output, its
+        batch row taken) into a slot's [start, start+count) range,
+        quantizing when the cache is int8. start/count are static."""
+        return self.family.cache_write(cache, slot, start, count, ks, vs,
+                                       kv_quantize=self.kv_quantize)
 
     @_under_engine_mesh
     def _prefill_cont(self, params, cache, lengths, last_tokens, samp, key,
@@ -913,19 +946,20 @@ class LLMEngine:
         _prefill. Returns packed [W, out_cols] rows."""
         tokens_all, slots, prompt_lens, row_samp, aids = \
             self._unpack_wave(wave)
-        p = k_prefix.shape[2]
+        p = jax.tree.leaves(k_prefix)[0].shape[2]
         t_bucket = tokens_all.shape[1] - (p if self.spec else 0)
         tokens = tokens_all[:, :t_bucket]
-        stacked, ks, vs = llama.prefill_continue(
+        stacked, ks, vs = self.family.prefill_continue(
             params, tokens, k_prefix, v_prefix, self.cfg, lora=lora,
             ids=aids, logit_rows=prompt_lens - p - 1)
         cache = dict(cache)
         for i in range(tokens.shape[0]):   # W is static: unrolled updates
             if self._cont_writes_prefix:
                 cache = self._cache_write(cache, slots[i], 0, p,
-                                          k_prefix[:, i], v_prefix[:, i])
+                                          _row(k_prefix, i),
+                                          _row(v_prefix, i))
             cache = self._cache_write(cache, slots[i], p, t_bucket,
-                                      ks[:, i], vs[:, i])
+                                      _row(ks, i), _row(vs, i))
             lengths = lengths.at[slots[i]].set(prompt_lens[i])
             samp = samp.at[slots[i]].set(row_samp[i])
             if aids is not None:
@@ -954,24 +988,12 @@ class LLMEngine:
         store-shaped [L, 1, P, kv, hd] entry (stays on device; entries are
         kept dequantized — the store is tiny next to the cache, and cont
         prefill re-quantizes on write)."""
-        k = jax.lax.dynamic_index_in_dim(cache["k"], slot, axis=1,
-                                         keepdims=False)[:, :p][:, None]
-        v = jax.lax.dynamic_index_in_dim(cache["v"], slot, axis=1,
-                                         keepdims=False)[:, :p][:, None]
-        if self.kv_quantize == "int8":
-            ksc, vsc = (self._slot_scales(cache[n], slot, p)
-                        for n in ("k_s", "v_s"))
-            k = llama.dequantize_kv(k, ksc, self.cfg.dtype)
-            v = llama.dequantize_kv(v, vsc, self.cfg.dtype)
-        return k, v
+        return self.family.extract_prefix(self.cfg, cache, slot, p,
+                                          kv_quantize=self.kv_quantize,
+                                          dtype=self.cfg.dtype)
 
-    @staticmethod
-    def _slot_scales(scales, slot, p: int):
-        """A slot's first `p` per-token scales out of the lane-major
-        cache plane `[L, slots, kv, max_len]`, store-shaped [L, 1, p, kv]."""
-        rows = jax.lax.dynamic_index_in_dim(scales, slot, axis=1,
-                                            keepdims=False)[:, :, :p]
-        return jnp.swapaxes(rows, 1, 2)[:, None]
+    #: a slot's first `p` per-token scales, store-shaped [L, 1, p, kv]
+    _slot_scales = staticmethod(DEFAULT_FAMILY.slot_scales)
 
     def _extract_prefix_raw(self, cache, slot, *, p: int):
         """Raw-layout twin of _extract_prefix for the radix block store:
@@ -997,18 +1019,19 @@ class LLMEngine:
         that finish (EOS) mid-chunk keep decoding on device; the host drops
         their surplus tokens, and the slot's next prefill resets its
         state. `span` statically bounds the attention window (length-aware
-        decode — see llama.decode_step). Emits packed [steps, n_slots,
-        out_cols] rows (_pack_out)."""
+        decode — see the family's decode_step). Emits packed
+        [steps, n_slots, out_cols] rows (_pack_out)."""
         slots = jnp.arange(self.n_slots)
 
         def body(carry, _):
             cache, lengths, last_tokens, key = carry
             aids = cache.get("aids")
             cnt = cache["cnt"]
-            logits, kv = llama.decode_step(params, last_tokens, cache,
-                                           lengths, self.cfg, span=span,
-                                           lora=lora, ids=aids,
-                                           active=active)
+            logits, kv = self.family.decode_step(
+                params, last_tokens, cache, lengths, self.cfg, span=span,
+                lora=lora, ids=aids, active=active)
+            counters = (kv.pop("counters") if self.family.STEP_COUNTERS
+                        else None)
             if aids is not None:
                 kv["aids"] = aids  # decode never re-assigns slots
             # seeded-key position: this step samples generated token
@@ -1030,7 +1053,7 @@ class LLMEngine:
             lengths = lengths + active.astype(jnp.int32)
             last_tokens = jnp.where(active, toks, last_tokens)
             return ((cache, lengths, last_tokens, key),
-                    self._pack_out(toks, logits))
+                    self._pack_out(toks, logits, counters))
 
         (cache, lengths, last_tokens, key), out = jax.lax.scan(
             body, (cache, lengths, last_tokens, key), None, length=steps)
@@ -1043,9 +1066,9 @@ class LLMEngine:
         """`steps` speculative verify rounds inside ONE program: each round
         records the pending token into the history buffer, drafts up to
         `k_spec` tokens by n-gram lookup (_ngram_draft), verifies all
-        drafts in one llama.verify_step forward, and accepts the longest
-        argmax-matching prefix plus the model's own bonus token — 1..k+1
-        tokens per round per slot, at ~one decode-step's HBM cost. Greedy
+        drafts in one verify_step forward of the family, and accepts the
+        longest argmax-matching prefix plus the model's own bonus token —
+        1..k+1 tokens per round per slot, at ~one decode-step's HBM cost. Greedy
         slots get EXACT greedy output (verification IS the greedy model);
         sampled slots (temp>0) draft nothing and sample the bonus (through
         the same top-k/top-p filters as plain decode), i.e. degrade to
@@ -1082,9 +1105,9 @@ class LLMEngine:
                                         axis=1)
             aids = cache.get("aids")
             kv = {k: v for k, v in cache.items() if k != "hist"}
-            logits, kv = llama.verify_step(params, tokens_in, kv, lengths,
-                                           self.cfg, span=span, lora=lora,
-                                           ids=aids, active=active)
+            logits, kv = self.family.verify_step(
+                params, tokens_in, kv, lengths, self.cfg, span=span,
+                lora=lora, ids=aids, active=active)
             preds = jnp.argmax(logits, -1).astype(jnp.int32)  # [B, k+1]
             match = ((preds[:, :k_spec] == drafts)
                      & (jnp.arange(k_spec)[None] < count[:, None]))
@@ -1245,8 +1268,8 @@ class LLMEngine:
             ks = jnp.concatenate([b[1] for b in payloads], axis=2)
             vq = jnp.concatenate([b[2] for b in payloads], axis=2)
             vs = jnp.concatenate([b[3] for b in payloads], axis=2)
-            return (llama.dequantize_kv(kq, ks, dtype),
-                    llama.dequantize_kv(vq, vs, dtype))
+            return (DEFAULT_FAMILY.dequantize_kv(kq, ks, dtype),
+                    DEFAULT_FAMILY.dequantize_kv(vq, vs, dtype))
         if len(payloads) == 1:
             return payloads[0]
         return (jnp.concatenate([b[0] for b in payloads], axis=2),
@@ -1263,8 +1286,9 @@ class LLMEngine:
         wave's (k_prefix, v_prefix) program inputs along the batch axis.
         entries: list of `_materialize_prefix` results, one per wave row.
         The stage-sharded engine overrides this to stack per layer slab."""
-        return (jnp.concatenate([e[0] for e in entries], axis=1),
-                jnp.concatenate([e[1] for e in entries], axis=1))
+        return tuple(jax.tree.map(
+            lambda *rows: jnp.concatenate(rows, axis=1),
+            *[e[n] for e in entries]) for n in (0, 1))
 
     @staticmethod
     def _payload_slice(parts, s: int, e: int):
@@ -1594,7 +1618,7 @@ class LLMEngine:
         self._drain_pending()
         self.phase_clock.enter("sched")
         actions = [action]
-        while len(actions) < self.n_slots:
+        while len(actions) < self.prefill_wave_max:
             with self._submit_lock:
                 nxt = self.scheduler.next()
             if not isinstance(nxt, PrefillAction):
@@ -1825,9 +1849,10 @@ class LLMEngine:
         a whole burst waits ~seconds on the compiler. Slot state is junk
         during warmup and reset after; call only while idle.
 
-        NOT pre-warmed: the chunked-prefill chain programs (extract +
-        continuation per chunk boundary) — the first prompt longer than
-        the largest bucket pays their compile, later ones are warm."""
+        NOT pre-warmed unless `warm_chain`: the chunked-prefill chain
+        programs (extract + continuation per chunk boundary) — the first
+        prompt longer than the largest bucket pays their compile, later
+        ones are warm."""
         ex = self._row_extra
         for bucket in self.buckets:
             width = 1
@@ -1844,7 +1869,7 @@ class LLMEngine:
                     self.params, self.cache, self.lengths,
                     self.last_tokens, self.samp, self.rng_key,
                     self._put(packed), *self._extra())
-                if width >= self.n_slots:
+                if width >= self.prefill_wave_max:
                     break
                 width *= 2
         if self.prefix_cache_enabled:
@@ -1886,9 +1911,28 @@ class LLMEngine:
                             self.params, self.cache, self.lengths,
                             self.last_tokens, self.samp, self.rng_key,
                             self._put(packed), kw, vw, *self._extra())
-                    if width >= self.n_slots:
+                    if width >= self.prefill_wave_max:
                         break
                     width *= 2
+        if self.warm_chain:
+            # the chain of a long prompt: full largest-bucket chunks, then
+            # a tail in any bucket; one request at a time (width 1)
+            big = self.buckets[-1]
+            for p in range(big, self.max_len, big):
+                ek, ev = self._extract_fn(p)(self.cache, 0)
+                for t in self.buckets:
+                    if p + t > self.max_len:
+                        continue
+                    packed = np.zeros((1, t + (p if self.spec else 0) + ex),
+                                      np.int32)
+                    packed[:, 0] = 1
+                    packed[:, -ex + 1] = p + 1
+                    packed[:, -ex + 7] = -1   # unseeded sentinel
+                    (self.cache, self.lengths, self.last_tokens,
+                     self.samp, self.rng_key, _) = self._cont_fn(p, t, 1)(
+                        self.params, self.cache, self.lengths,
+                        self.last_tokens, self.samp, self.rng_key,
+                        self._put(packed), ek, ev, *self._extra())
         chunks, k = [], 1
         while k <= self.decode_chunk:
             chunks.append(k)
@@ -1963,6 +2007,12 @@ class LLMEngine:
         self.params = None
         gc.collect()
 
+    def _step_counters(self) -> dict[str, float]:
+        """The family's STEP_COUNTERS as the replayed decode chunks have
+        folded them."""
+        return {name: float(v) for (name, _), v in zip(
+            self.family.STEP_COUNTERS, self._step_counts)}
+
     def _obs_publish(self) -> None:
         """Scrape hook body: refresh this engine's queue-depth gauges
         just before a /metrics render (see obs.metrics.add_scrape_hook;
@@ -1983,6 +2033,8 @@ class LLMEngine:
         obs_metrics.ATTENTION_IMPL.set(
             1, engine=self.role, phase="prefill",
             impl=self.cfg.prefill_attention_impl)
+        for name, v in self._step_counters().items():
+            obs_metrics.ENGINE_STEP_COUNT.set(v, engine=self.role, name=name)
         if self.kvcache is not None:
             st = self.kvcache.stats()
             obs_metrics.KV_FREE_BLOCKS.set(st["free_blocks"],
@@ -2107,6 +2159,10 @@ class LLMEngine:
             "prefill_ms": ms(pstart, first),
             "decode_ms": ms(first, fin),
             "engine": fin_phases[0] if fin_phases else None,
+            # the family's decode-step counts so far, engine-wide (absent
+            # where the family keeps none: the usage shape stays as it is)
+            **({"counters": self._step_counters()}
+               if self.family.STEP_COUNTERS else {}),
         }
 
     def cached_tokens(self, req_id: int) -> int:
@@ -2190,6 +2246,9 @@ class LLMEngine:
         if self._quant_matmul_sites:
             out["quant_matmul_sites"] = self._quant_matmul_sites
         out["prefill_tokens_computed"] = self._prefill_computed_tokens
+        if self.cache is not None:
+            out.update(self.family.cache_stats(self.cache))
+        out.update(self._step_counters())
         if self.prefix_cache_enabled and self.kvcache is not None:
             st = self.kvcache.stats()
             out["prefix_hits"] = self._prefix_hits
@@ -2652,6 +2711,12 @@ class LLMEngine:
                             done_slots.add(slot)
                             break
         else:
+            if len(self._step_counts):
+                steps_counts = out_np[:, 0, -len(self._step_counts):]
+                for n, (_, how) in enumerate(self.family.STEP_COUNTERS):
+                    self._step_counts[n] = (
+                        self._step_counts[n] + steps_counts[:, n].sum()
+                        if how == "sum" else steps_counts[-1, n])
             for row in out_np:   # [steps, n_slots, out_cols]
                 for slot, req in enumerate(slot_req):
                     if req < 0 or slot in done_slots or not alive[slot]:

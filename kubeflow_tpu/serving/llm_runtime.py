@@ -1,4 +1,6 @@
-"""LLM serving runtime — registers modelFormat "llama" so an
+"""LLM serving runtime — registers modelFormat "llama" (and every other
+served model family, FAMILIES below: one LLMModel class, differing in the
+model module and the config class it hands the engine) so an
 InferenceService predictor resolves to the continuous-batching engine
 (SURVEY.md §2.4 runtime table: the huggingfaceserver/Triton-LLM slot).
 
@@ -31,15 +33,49 @@ uncrashed run (the supervisor verifies the replayed prefix).
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 from typing import Any
 
 from kubeflow_tpu.serving.model import Model, serving_runtime
 
-# jax and the llama model module are imported inside load()/_load_params()
-# so that registering this runtime (imported by kubeflow_tpu.serving for
-# its side effect) keeps the serving package import jax-free.
+# jax and the family's model module are imported inside load()/
+# _load_params() so that registering this runtime (imported by
+# kubeflow_tpu.serving for its side effect) keeps the serving package
+# import jax-free.
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """A served model family: the module that carries the engine's seam
+    (serving/llm.py, "THE FAMILY SEAM") and `init`, the config class in
+    it, and the deployment options the family does not serve, each with
+    the reason a load that asks for it is refused with."""
+    module: str
+    config_cls: str
+    refuses: dict[str, str] = dataclasses.field(default_factory=dict)
+
+
+FAMILIES: dict[str, Family] = {
+    "llama": Family("kubeflow_tpu.models.llama", "LlamaConfig"),
+    "laguna": Family("kubeflow_tpu.models.laguna", "LagunaConfig", refuses={
+        "speculative": "a window layer's ring cannot take back the rows "
+                       "of rejected drafts: the family has no verify step",
+        "prefix_cache": "the radix cache holds full-attention blocks; a "
+                        "window layer's ring keeps no prefix's tail",
+        "kv_layout": "the block pool has one layout for every layer, the "
+                     "family two slabs (full, window)",
+        "parallel": "the experts have no layout across chips (no exchange)",
+        "mesh": "the experts have no layout across chips (no exchange)",
+        "adapters": "the family's matmuls take no low-rank bypass",
+        "lora": "the family's matmuls take no low-rank bypass",
+        "quantize": "int8 experts need a grouped matmul that dequantizes "
+                    "its groups",
+        "disaggregated": "the prefill->decode handoff moves one slab's "
+                         "rows, the family has two",
+    }),
+}
 
 
 class LLMModel(Model):
@@ -76,8 +112,22 @@ class LLMModel(Model):
                  parallel: dict[str, Any] | None = None,
                  trace_sample_rate: float | None = None,
                  slo: dict[str, Any] | None = None,
+                 prefill_wave_max: int | None = None,
+                 warm_chain: bool = False,
+                 family: str = "llama",
                  **_ignored: Any):
         super().__init__(name)
+        self._family = FAMILIES[family]
+        asked = dict(speculative=speculative, prefix_cache=prefix_cache,
+                     kv_layout=kv_layout not in (None, "slab"),
+                     parallel=parallel, mesh=mesh, adapters=adapters,
+                     lora=lora, quantize=quantize,
+                     disaggregated=disaggregated)
+        for option, why in self._family.refuses.items():
+            if asked[option]:
+                raise ValueError(
+                    f"modelFormat {family!r} does not serve `{option}`: "
+                    f"{why}")
         self._cfg_overrides = dict(model or {})
         self._mesh = dict(mesh) if mesh else None
         # text endpoints (/openai/v1/completions): byte-level fallback or
@@ -131,6 +181,12 @@ class LLMModel(Model):
         self._logprobs_topk = logprobs_topk
         self._sample_k_max = sample_k_max
         self._pipeline_decode = pipeline_decode
+        # config.prefill_wave_max: the most prompts one batched prefill
+        # program takes (None: every slot); see LLMEngine
+        self._prefill_wave_max = prefill_wave_max
+        # config.warm_chain: warm-up also compiles the chunked-prefill
+        # chain (prompts longer than the largest bucket); see LLMEngine
+        self._warm_chain = bool(warm_chain)
         # config.supervised (default ON — the unified-dataplane contract):
         # the engine sits behind serving/agent.EngineSupervisor, so every
         # HTTP/gRPC/predict submission is journaled and a mid-stream
@@ -253,8 +309,13 @@ class LLMModel(Model):
 
     # -- lifecycle -----------------------------------------------------------
 
+    def _family_module(self):
+        import importlib
+
+        return importlib.import_module(self._family.module)
+
     def load(self) -> None:
-        from kubeflow_tpu.models import llama
+        llama = self._family_module()   # the family's model module
         from kubeflow_tpu.runtime.compile_cache import ensure_compile_cache
         from kubeflow_tpu.serving.llm import LLMEngine
 
@@ -270,7 +331,8 @@ class LLMModel(Model):
             from kubeflow_tpu.parallel.mesh import make_mesh
 
             mesh = make_mesh(MeshConfig(**self._mesh))
-        if self._checkpoint and llama.is_hf_checkpoint(self._checkpoint):
+        if (self._checkpoint and hasattr(llama, "is_hf_checkpoint")
+                and llama.is_hf_checkpoint(self._checkpoint)):
             # HuggingFace-format dir (config.json + safetensors): weights,
             # architecture AND tokenizer come from one storageUri — the
             # huggingfaceserver slot (⊘ kserve python/huggingfaceserver).
@@ -291,7 +353,8 @@ class LLMModel(Model):
             if self._eos_id is None:
                 self._eos_id = getattr(self.tokenizer, "eos_id", None)
         else:
-            cfg = llama.LlamaConfig(**self._cfg_overrides)
+            cfg = getattr(llama, self._family.config_cls)(
+                **self._cfg_overrides)
             params = self._load_params(cfg)
         engine_kw = dict(n_slots=self._n_slots,
                          max_len=self._max_len,
@@ -309,7 +372,10 @@ class LLMModel(Model):
                          adapters=self._load_adapters(cfg),
                          logprobs_topk=self._logprobs_topk,
                          sample_k_max=self._sample_k_max,
-                         pipeline_decode=self._pipeline_decode)
+                         pipeline_decode=self._pipeline_decode,
+                         prefill_wave_max=self._prefill_wave_max,
+                         warm_chain=self._warm_chain,
+                         family=llama)
         # read, never pop: a second load() on this instance (unload →
         # reload is a legal Model lifecycle) must see the same config
         rewarm = bool(self._sup_cfg.get("rewarm", True))
@@ -436,7 +502,7 @@ class LLMModel(Model):
     def _load_params(self, cfg):
         import jax
 
-        from kubeflow_tpu.models import llama
+        llama = self._family_module()
 
         if self._lora is not None:
             # a llama_lora trainer checkpoint: restore {"base","lora"} and
@@ -706,8 +772,9 @@ class LLMModel(Model):
             return {"timing": {}}
         return {"timing": {k: tm.get(k) for k in
                            ("queue_wait_ms", "prefill_ms", "handoff_ms",
-                            "decode_ms", "engine")
-                           if k != "handoff_ms" or "handoff_ms" in tm},
+                            "decode_ms", "engine", "counters")
+                           if k not in ("handoff_ms", "counters")
+                           or k in tm},
                 "submit_s": tm.get("submit_s")}
 
     def _slo_record(self, rid: int, reason: str) -> None:
@@ -857,3 +924,9 @@ class LLMModel(Model):
 def _llama_runtime(name: str, uri: str | None = None,
                    **config: Any) -> Model:
     return LLMModel(name, uri, **config)
+
+
+@serving_runtime("laguna")
+def _laguna_runtime(name: str, uri: str | None = None,
+                    **config: Any) -> Model:
+    return LLMModel(name, uri, **dict(config, family="laguna"))

@@ -3,7 +3,8 @@
 `benchmark/tests/` is collected by nobody (the tier-1 command collects
 `tests/`), so the trace reducer, the operation counts, the traffic
 schedule, `BENCHMARK.json`'s shape and the `usage.engine` readers were
-tested only by hand. This module loads the seven files of it that read
+tested only by hand. This module loads the files of it (LIGHT: seven of ISSUE 29, one of
+ISSUE 34) that read
 recorded traces, counts and JSON and compile nothing (two seconds
 together) and re-exports their tests, one name each, so each counts.
 Nothing under `benchmark/` is edited for it. The three heavy files
@@ -30,7 +31,7 @@ BENCH_TESTS = os.path.join(os.path.dirname(os.path.dirname(
 LIGHT = ("test_tracered", "test_opcount", "test_traffic",
          "test_benchmark_json", "test_engine_readers",
          "test_decode_kv_fetched_block_share",
-         "test_quant_matmul_stacked_roofline")
+         "test_quant_matmul_stacked_roofline", "test_laguna_cell_light")
 
 #: tests known to fail, by name, each with its reason
 XFAIL = {

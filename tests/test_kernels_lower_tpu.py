@@ -128,6 +128,44 @@ def test_flash_decode_lowers_for_a_microbatch_of_slots():
         sds((4,), jnp.int32), sds((), jnp.int32), scale, scale) == 1
 
 
+@pytest.mark.parametrize("heads", [48, 64])   # 6 and 8 to a KV head
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_flash_decode_lowers_over_a_ring_with_a_window(heads, quantized):
+    """The sliding layers' call of the laguna cell: 3 layers of 32 slots x
+    a ring of 1536 rows, a window of 512, the layer a static index; int8
+    with the step's scales stored by the kernel."""
+    dtype = jnp.int8 if quantized else jnp.bfloat16
+    k = sds((3, 32, 1536, KV_HEADS, HEAD_DIM), dtype)
+    scale = sds((3, 32, KV_HEADS, 1536), jnp.float32) if quantized else None
+    new = sds((32, 1, KV_HEADS), jnp.float32) if quantized else None
+
+    def fn(q, k, v, lengths, ks, vs, new):
+        return flash_decode.flash_decode_attention(
+            q, k, v, lengths, layer=2, k_scale=ks, v_scale=vs,
+            new_scales=(new, new) if quantized else None, window=512,
+            interpret=False)
+
+    assert mosaic_calls(
+        fn, sds((32, 1, heads, HEAD_DIM), jnp.bfloat16), k, k,
+        sds((32,), jnp.int32), scale, scale, new) == 1
+
+
+@pytest.mark.parametrize("heads", [48, 64])
+@pytest.mark.parametrize("q_offset", [0, 512])
+def test_flash_prefill_lowers_with_a_window(q_offset, heads):
+    """A chunk of 1024 rows after the last `window` rows of its prefix
+    (the continuation chain's sliding layers), and a first chunk."""
+    width, chunk = 4, 1024
+    k = sds((width, q_offset + chunk, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+
+    def fn(q, k, v):
+        return flash_prefill.flash_prefill_attention(
+            q, k, v, q_offset=q_offset, window=512, interpret=False)
+
+    assert mosaic_calls(
+        fn, sds((width, chunk, heads, HEAD_DIM), jnp.bfloat16), k, k) == 1
+
+
 @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("q_offset", [0, 1024])
