@@ -38,6 +38,10 @@ _log = logging.getLogger(__name__)
 #: "xla" (blockwise). A caller that must know the kernel is what compiled
 #: (chip_smoke.py) reads it after the step has been traced.
 TRACED_BODIES: set[str] = set()
+#: beside it, what each traced call of the Pallas kernels with a static
+#: q_offset found: flash_pallas.block_census's (interior, diagonal, future)
+#: tiles a head. A Trainer reads the slice its own step's trace added.
+TRACED_CENSUS: list[tuple[int, int, int]] = []
 _refusals_logged: set[str] = set()
 
 
@@ -211,16 +215,22 @@ def flash_attention(
 
     if impl in ("auto", "pallas"):
         try:
+            kernel_block_kv = None if block_kv is None else max(block_kv, 128)
             call = functools.partial(
                 flash_pallas.pallas_flash_attention, causal=causal,
-                scale=scale, q_offset=q_offset,
-                block_kv=None if block_kv is None else max(block_kv, 128))
+                scale=scale, q_offset=q_offset, block_kv=kernel_block_kv)
             out = None
             if isinstance(q_offset, int) and q_offset == 0:
                 out = _pallas_island(q, k, v, segment_ids, call)
             if out is None:
                 out = call(q, k, v, segment_ids=segment_ids)
             TRACED_BODIES.add("pallas")
+            if isinstance(q_offset, int):
+                sq, sk = q.shape[1], k.shape[1]
+                TRACED_CENSUS.append(flash_pallas.block_census(
+                    sq, sk, *flash_pallas.resolve_blocks(
+                        sq, sk, None, kernel_block_kv),
+                    causal, q_offset, segmented=segment_ids is not None))
             return out
         except NotImplementedError as e:
             if impl == "pallas":

@@ -10,6 +10,17 @@ Forward + backward are hand-written kernels wired through `jax.custom_vjp`:
     over KV blocks, q sequential) — the standard flash-attention backward
     decomposition with delta = rowsum(dO ⊙ O) precomputed in XLA.
 
+Every grid step tells from its own block indices which of three kinds its
+[block_q, block_kv] tile is (`_visited`, `_interior`; `block_census` counts
+them), and does only that kind's work:
+  - interior (no padded key, no segments, and under a causal mask wholly
+    below the diagonal): nothing in it can be masked, so no mask is built;
+  - diagonal (every other visited tile): the mask and the guard;
+  - future (wholly above the diagonal): not computed, and not fetched: the
+    index maps hold the sequential index on the nearest block the step's
+    row of the grid does visit, so the pipeline sees an unchanged block and
+    issues no copy.
+
 Layout contract: BSHD in, GQA already expanded (flash_attention.py repeats KV
 heads before calling); q and k share a head size, v and o may have another.
 Sequences are padded here to block multiples; padded keys are masked via `k_pos
@@ -50,11 +61,126 @@ def _round_up(x: int, m: int) -> int:
 
 
 def default_blocks(sq: int, sk: int) -> tuple[int, int]:
-    """Seq-adaptive kernel tile defaults (measured fwd+bwd at B2xH16xD128
-    on v5e): at seq 8192 (512, 1024) runs ~36% faster than (256, 512) —
-    bigger tiles amortize the grid; at seq 2048 the small blocks win (the
-    r2 sweep). ONE source of truth — the ring body mirrors these."""
-    return (512 if sq >= 4096 else 256, 1024 if sk >= 4096 else 512)
+    """The kernels' tiles from the two lengths: each length in the fewest
+    equal parts of at most 1024, on the 128-lane grid (2048 and 8192 give
+    1024, 1536 gives 768 and not a padded second 1024). Measured on a v5e
+    with the three kinds of tile in place, 64 heads, forward twice (remat)
+    plus backward, ms: S 2048 hd 128: 256 x 512 7.44, 512 x 512 5.98,
+    512 x 1024 4.84, 1024 x 1024 4.52, 2048 x 2048 (one tile, with 100 MB
+    of VMEM allowed) 5.26; S 8192 q/k 256 v 128: 512 x 1024 73.5,
+    1024 x 1024 66.3, 2048 x 1024 (100 MB) 70.9; S 4096: 512 x 1024 15.4,
+    1024 x 1024 13.9; S 1024: 256 x 512 2.34, 1024 x 1024 1.59; S 512: one
+    tile or two alike. A grid step costs a pass over the [block_q, 128]
+    statistics and accumulator whatever block_kv is, so wide tiles win until
+    the diagonal's wasted half outweighs it. ONE source of truth: the ring
+    body mirrors these."""
+    def tile(s):
+        return _round_up(-(-s // -(-s // 1024)), 128)
+    return tile(sq), tile(sk)
+
+
+def resolve_blocks(sq, sk, block_q=None, block_kv=None) -> tuple[int, int]:
+    """The tiles a call of pallas_flash_attention runs with: the defaults
+    unless given, no larger than the lengths rounded up to the lanes."""
+    dq_blk, dkv_blk = default_blocks(sq, sk)
+    block_q = dq_blk if block_q is None else block_q
+    block_kv = dkv_blk if block_kv is None else block_kv
+    return (min(block_q, _round_up(sq, 128)),
+            min(block_kv, _round_up(sk, 128)))
+
+
+# ---------------------------------------------------------------------------
+# the kind of a tile, from where it lies to the diagonal
+# ---------------------------------------------------------------------------
+# Written once, on scalars: block_census hands them Python ints, the index
+# maps the grid's traced indices, the kernels their program ids. A result is
+# a Python bool where the call's shape alone decides it.
+
+def _visited(q_start, k_start, block_q, causal):
+    """The q block's last row sees some key of the KV block; a tile that
+    fails this is a future tile."""
+    return k_start <= q_start + block_q - 1 if causal else True
+
+
+def _interior(q_start, k_start, block_kv, sk, causal, segmented):
+    """Nothing in the (visited) tile can be masked: no segments, no padded
+    key, and under a causal mask its last key no later than its first row."""
+    if segmented:
+        return False
+    whole = sk % block_kv == 0 or k_start + block_kv <= sk
+    return whole & (k_start + block_kv - 1 <= q_start) if causal else whole
+
+
+def kv_block_index(i, j, block_q, block_kv, causal, q_offset=0):
+    """The KV block grid step (q block i, KV block j) stands on: a future
+    step stays on the last block its q block visits (`_visited` solved for
+    the KV index)."""
+    if not causal:
+        return j
+    return jnp.minimum(j, (i * block_q + q_offset + block_q - 1) // block_kv)
+
+
+def q_block_index(i, j, block_q, block_kv, n_q, causal):
+    """The q block grid step (KV block j, q block i) of the dK/dV kernel
+    stands on: a future step (they come first there) waits on the first
+    block its KV block visits (`_visited` solved for the q index; a KV
+    block no q block visits stays on the last one)."""
+    if not causal:
+        return i
+    return jnp.maximum(i, jnp.minimum(j * block_kv // block_q, n_q - 1))
+
+
+def block_census(sq, sk, block_q, block_kv, causal, q_offset=0,
+                 segmented=False):
+    """(interior, diagonal, future) tiles a head of one call."""
+    interior = diagonal = 0
+    n_q, n_k = -(-sq // block_q), -(-sk // block_kv)
+    for i in range(n_q):
+        for j in range(n_k):
+            q_start, k_start = i * block_q + q_offset, j * block_kv
+            if not _visited(q_start, k_start, block_q, causal):
+                continue
+            if _interior(q_start, k_start, block_kv, sk, causal, segmented):
+                interior += 1
+            else:
+                diagonal += 1
+    return interior, diagonal, n_q * n_k - interior - diagonal
+
+
+def _mask(q_start, k_start, block_q, block_kv, sk, causal, seg_q_ref,
+          seg_k_ref):
+    """[block_q, block_kv] bool: the keys a diagonal tile's rows may see."""
+    k_pos = k_start + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_kv), 1)
+    valid = k_pos < sk
+    if causal:
+        q_pos = q_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_kv), 0)
+        valid = valid & (q_pos >= k_pos)
+    if seg_q_ref is not None:
+        valid = valid & (seg_q_ref[0, 0, 0, :][:, None]
+                         == seg_k_ref[0, 0, 0, :][None, :])
+    return valid
+
+
+def _when(pred, body):
+    """pl.when for a predicate the call's shape may already have decided."""
+    if isinstance(pred, bool):
+        if pred:
+            body()
+    else:
+        pl.when(pred)(body)
+
+
+def _by_kind(visited, interior, compute):
+    """Run `compute(masked)` for the kind of tile this grid step holds:
+    masked False on an interior tile, True on a diagonal one, not at all on
+    a future one."""
+    if isinstance(interior, bool):
+        _when(visited, functools.partial(compute, not interior))
+    else:
+        _when(visited & interior, functools.partial(compute, False))
+        _when(visited & ~interior, functools.partial(compute, True))
 
 
 # ---------------------------------------------------------------------------
@@ -81,30 +207,24 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, *rest, scale, causal, block_q,
     q_start = pl.program_id(1) * block_q + qoff_ref[0]
     k_start = ki * block_kv
 
-    def compute():
+    def compute(masked):
         q = q_ref[0]
         k = k_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_kv), 1)
-        valid = k_pos < sk
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0)
-            valid = valid & (q_pos >= k_pos)
-        if segmented:
-            valid = valid & (seg_q_ref[0, 0, 0, :][:, None]
-                             == seg_k_ref[0, 0, 0, :][None, :])
-        s = jnp.where(valid, s, NEG_INF)
+        if masked:
+            s = jnp.where(_mask(q_start, k_start, block_q, block_kv, sk,
+                                causal, seg_q_ref, seg_k_ref), s, NEG_INF)
 
         m_prev = m_ref[:, 0:1]                         # [bq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        # guard: a fully-masked row keeps m_new == NEG_INF; exp(s - m_new)
-        # would be exp(0)=1 there, so zero masked entries explicitly.
-        p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - m_new)
+        if masked:
+            # guard: a fully-masked row keeps m_new == NEG_INF; exp(s - m_new)
+            # would be exp(0)=1 there, so zero masked entries explicitly.
+            p = jnp.where(s > NEG_INF / 2, p, 0.0)
         l_new = l_ref[:, 0:1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
@@ -112,13 +232,9 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref, *rest, scale, causal, block_q,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    if causal:
-        # whole KV block is in the future of every query row → skip
-        @pl.when(k_start <= q_start + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
+    _by_kind(_visited(q_start, k_start, block_q, causal),
+             _interior(q_start, k_start, block_kv, sk, causal, segmented),
+             compute)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -151,6 +267,9 @@ def _fwd(q, k, v, seg_q, seg_k, causal, scale, q_offset, interpret, block_q,
     n_q, n_k = sq_p // block_q, sk_p // block_kv
     segmented = seg_q is not None
 
+    def kv_at(i, j, qoff):
+        return kv_block_index(i, j, block_q, block_kv, causal, qoff[0])
+
     seg_in_specs, seg_args = [], []
     if segmented:
         # seg arrays stay [B, ...] — grid row b (= batch*heads) maps back to
@@ -160,7 +279,8 @@ def _fwd(q, k, v, seg_q, seg_k, causal, scale, q_offset, interpret, block_q,
             pl.BlockSpec((1, 1, 1, block_q),
                          lambda b, i, j, *_: (b // hpb, i, 0, 0)),
             pl.BlockSpec((1, 1, 1, block_kv),
-                         lambda b, i, j, *_: (b // hpb, j, 0, 0)),
+                         lambda b, i, j, qoff: (b // hpb, kv_at(i, j, qoff),
+                                                0, 0)),
         ]
         seg_args = [_block_rows(seg_q, sq_p, block_q),
                     _block_rows(seg_k, sk_p, block_kv)]
@@ -171,8 +291,10 @@ def _fwd(q, k, v, seg_q, seg_k, causal, scale, q_offset, interpret, block_q,
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, i, j, *_: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, dv), lambda b, i, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, block_kv, d),
+                         lambda b, i, j, qoff: (b, kv_at(i, j, qoff), 0)),
+            pl.BlockSpec((1, block_kv, dv),
+                         lambda b, i, j, qoff: (b, kv_at(i, j, qoff), 0)),
             *seg_in_specs,
         ],
         out_specs=[
@@ -229,42 +351,32 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     q_start = pl.program_id(1) * block_q
     k_start = ki * block_kv
 
-    def compute():
+    def compute(masked):
         q = q_ref[0]
         k = k_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_kv), 1)
-        valid = k_pos < sk
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0)
-            valid = valid & (q_pos >= k_pos)
-        if segmented:
-            valid = valid & (seg_q_ref[0, 0, 0, :][:, None]
-                             == seg_k_ref[0, 0, 0, :][None, :])
-        lse = lse_ref[0, 0, 0, :][:, None]
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
+        p = jnp.exp(s - lse_ref[0, 0, 0, :][:, None])
+        if masked:
+            p = jnp.where(_mask(q_start, k_start, block_q, block_kv, sk,
+                                causal, seg_q_ref, seg_k_ref), p, 0.0)
         dp = jax.lax.dot_general(
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0, 0, :][:, None]) * scale
+        # ds lacks its factor `scale`: _finalize puts it on the accumulator
+        ds = p * (dp - delta_ref[0, 0, 0, :][:, None])
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        @pl.when(k_start <= q_start + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
+    _by_kind(_visited(q_start, k_start, block_q, causal),
+             _interior(q_start, k_start, block_kv, sk, causal, segmented),
+             compute)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
@@ -285,24 +397,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     q_start = qi * block_q
     k_start = pl.program_id(1) * block_kv
 
-    def compute():
+    def compute(masked):
         q = q_ref[0]
         k = k_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_kv), 1)
-        valid = k_pos < sk
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0)
-            valid = valid & (q_pos >= k_pos)
-        if segmented:
-            valid = valid & (seg_q_ref[0, 0, 0, :][:, None]
-                             == seg_k_ref[0, 0, 0, :][None, :])
-        lse = lse_ref[0, 0, 0, :][:, None]
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)      # [bq, bk]
+        p = jnp.exp(s - lse_ref[0, 0, 0, :][:, None])    # [bq, bk]
+        if masked:
+            p = jnp.where(_mask(q_start, k_start, block_q, block_kv, sk,
+                                causal, seg_q_ref, seg_k_ref), p, 0.0)
         do = do_ref[0]
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -310,22 +414,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dp = jax.lax.dot_general(
             do, v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # [bq, bk]
-        ds = p * (dp - delta_ref[0, 0, 0, :][:, None]) * scale
+        # ds lacks its factor `scale`: _finalize puts it on the accumulator
+        ds = p * (dp - delta_ref[0, 0, 0, :][:, None])
         dk_acc[:] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # [bk, D]
 
-    if causal:
-        # KV block entirely after the last query row of this q block → no grad
-        @pl.when(q_start + block_q - 1 >= k_start)
-        def _():
-            compute()
-    else:
-        compute()
+    _by_kind(_visited(q_start, k_start, block_q, causal),
+             _interior(q_start, k_start, block_kv, sk, causal, segmented),
+             compute)
 
     @pl.when(qi == nq - 1)
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
@@ -354,8 +455,12 @@ def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
         seg_q3 = _block_rows(seg_q, sq_p, block_q)
         seg_k3 = _block_rows(seg_k, sk_p, block_kv)
 
+    def kv_at(i, j):
+        return kv_block_index(i, j, block_q, block_kv, causal)
+
     q_spec, do_spec = _row_specs(block_q, (d, dv), lambda b, i, j: (b, i, 0))
-    k_sp, v_sp = _row_specs(block_kv, (d, dv), lambda b, i, j: (b, j, 0))
+    k_sp, v_sp = _row_specs(block_kv, (d, dv),
+                            lambda b, i, j: (b, kv_at(i, j), 0))
     row_spec = pl.BlockSpec((1, 1, 1, block_q),
                            lambda b, i, j: (b, i, 0, 0))
     seg_specs_dq, seg_args = [], []
@@ -365,7 +470,7 @@ def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
             pl.BlockSpec((1, 1, 1, block_q),
                          lambda b, i, j: (b // hpb, i, 0, 0)),
             pl.BlockSpec((1, 1, 1, block_kv),
-                         lambda b, i, j: (b // hpb, j, 0, 0)),
+                         lambda b, i, j: (b // hpb, kv_at(i, j), 0, 0)),
         ]
         seg_args = [seg_q3, seg_k3]
 
@@ -383,15 +488,19 @@ def _bwd(q, k, v, seg_q, seg_k, o, lse, do, causal, scale, interpret,
         interpret=interpret,
     )(q, k, v, do, lse3, delta3, *seg_args)
 
-    q_kv, do_kv = _row_specs(block_q, (d, dv), lambda b, j, i: (b, i, 0))
+    def q_at(j, i):
+        return q_block_index(i, j, block_q, block_kv, n_q, causal)
+
+    q_kv, do_kv = _row_specs(block_q, (d, dv),
+                             lambda b, j, i: (b, q_at(j, i), 0))
     k_spec, v_spec = _row_specs(block_kv, (d, dv), lambda b, j, i: (b, j, 0))
     row_spec_kv = pl.BlockSpec((1, 1, 1, block_q),
-                              lambda b, j, i: (b, i, 0, 0))
+                              lambda b, j, i: (b, q_at(j, i), 0, 0))
     seg_specs_kv = []
     if segmented:
         seg_specs_kv = [
             pl.BlockSpec((1, 1, 1, block_q),
-                         lambda b, j, i: (b // hpb, i, 0, 0)),
+                         lambda b, j, i: (b // hpb, q_at(j, i), 0, 0)),
             pl.BlockSpec((1, 1, 1, block_kv),
                          lambda b, j, i: (b // hpb, j, 0, 0)),
         ]
@@ -504,11 +613,7 @@ def pallas_flash_attention(q, k, v, *, causal=True, scale=None,
     if sq < 128 or sk < 128:
         raise NotImplementedError("pallas flash kernel needs seq >= 128")
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    dq_blk, dkv_blk = default_blocks(sq, sk)  # explicit args override
-    block_q = dq_blk if block_q is None else block_q
-    block_kv = dkv_blk if block_kv is None else block_kv
-    block_q = min(block_q, _round_up(sq, 128))
-    block_kv = min(block_kv, _round_up(sk, 128))
+    block_q, block_kv = resolve_blocks(sq, sk, block_q, block_kv)
 
     qf = _heads_first(q, lanes=128)
     kf = _heads_first(k, lanes=128)

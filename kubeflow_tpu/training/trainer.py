@@ -19,6 +19,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from kubeflow_tpu.models import registry
+from kubeflow_tpu.ops import flash_attention
 from kubeflow_tpu.parallel import (
     MeshConfig,
     active_mesh,
@@ -152,6 +153,10 @@ class Trainer:
         # form that carries its own exchange (parallel/overlap.py): filled
         # when the step is traced, empty on a mesh without `tensor`
         self.overlapped_sites: set[str] = set()
+        # beside it, what the step's calls of the Pallas attention kernels
+        # found: ops/flash_pallas.block_census's (interior, diagonal,
+        # future) tiles a head, one entry a traced call
+        self.attention_census: list[tuple[int, int, int]] = []
 
     # -- state ---------------------------------------------------------------
 
@@ -273,9 +278,11 @@ class Trainer:
         def step(state, batch):
             # ambient mesh for shard_map islands (ring/Ulysses attention,
             # MoE all-to-all) traced inside the jitted step
+            seen = len(flash_attention.TRACED_CENSUS)
             with active_mesh(self.mesh), overlap.count_sites() as traced:
                 out = jitted(state, batch)
             self.overlapped_sites |= traced
+            self.attention_census += flash_attention.TRACED_CENSUS[seen:]
             return out
 
         return step
@@ -403,6 +410,13 @@ class Trainer:
                     scalars["includes_compile"] = 1.0
                     scalars["overlapped_projections_per_layer"] = float(
                         len(self.overlapped_sites))
+                    # of the tiles the attention kernels visit, those that
+                    # run with no mask (0 where no kernel was traced)
+                    interior = sum(c[0] for c in self.attention_census)
+                    visited = interior + sum(
+                        c[1] for c in self.attention_census)
+                    scalars["attention_interior_tile_share"] = (
+                        interior / visited if visited else 0.0)
                     first_interval = False
                 self.metrics.write(step, scalars)
                 if step_callback:

@@ -240,6 +240,159 @@ def test_pallas_flash_prefill_offset(pallas_interpret):
 
 
 # ---------------------------------------------------------------------------
+# the three kinds of tile (interior, diagonal, future): ops/flash_pallas.py
+# ---------------------------------------------------------------------------
+
+def _two_documents(sk):
+    return jnp.concatenate([jnp.zeros((1, sk * 3 // 8), jnp.int32),
+                            jnp.ones((1, sk - sk * 3 // 8), jnp.int32)],
+                           axis=1)
+
+
+# name: (sq, sk, block_q, block_kv, causal, q_offset, traced offset,
+#        segmented, q/k head size, the census a head must show)
+TILE_CASES = {
+    "block_q<block_kv": (512, 512, 128, 256, True, 0, False, False, 32,
+                         (2, 4, 2)),
+    "block_q=block_kv": (512, 512, 128, 128, True, 0, False, False, 32,
+                         (6, 4, 6)),
+    "block_q>block_kv": (512, 512, 256, 128, True, 0, False, False, 32,
+                         (2, 4, 2)),
+    "padded_last_block": (400, 400, 128, 128, True, 0, False, False, 32,
+                          (6, 4, 6)),
+    # rows past the last key: the padded block lies BELOW the diagonal
+    "padded_block_below_the_diagonal": (768, 400, 128, 128, True, 0, False,
+                                        False, 32, (12, 6, 6)),
+    "q_offset_static": (256, 512, 128, 128, True, 256, False, False, 32,
+                        (5, 2, 1)),
+    "q_offset_static_off_the_tiles": (256, 512, 128, 128, True, 200, False,
+                                      False, 32, (3, 4, 1)),
+    "q_offset_traced_0": (256, 512, 128, 128, True, 0, True, False, 32,
+                          (1, 2, 5)),
+    "q_offset_traced": (256, 512, 128, 128, True, 256, True, False, 32,
+                        (5, 2, 1)),
+    "sq!=sk": (256, 512, 128, 128, True, 0, False, False, 32, (1, 2, 5)),
+    "qk192_v128": (512, 512, 256, 256, True, 0, False, False, 192,
+                   (1, 2, 1)),
+    "non_causal": (512, 512, 128, 256, False, 0, False, False, 32,
+                   (8, 0, 0)),
+    "non_causal_padded": (400, 400, 128, 128, False, 0, False, False, 32,
+                          (12, 4, 0)),
+    "segmented": (512, 512, 128, 128, True, 0, False, True, 32, (0, 10, 6)),
+    "segmented_non_causal": (512, 512, 128, 128, False, 0, False, True, 32,
+                             (0, 16, 0)),
+}
+
+
+@pytest.mark.parametrize("case", TILE_CASES)
+def test_pallas_tile_kinds_match_mha(pallas_interpret, case):
+    """Values and the three gradients against ops.attention.mha where each
+    kind of tile, and each boundary between them, is forced by the shapes."""
+    from kubeflow_tpu.ops import flash_pallas
+
+    (sq, sk, block_q, block_kv, causal, q_offset, traced, segmented, d,
+     census) = TILE_CASES[case]
+    assert flash_pallas.block_census(
+        sq, sk, block_q, block_kv, causal, q_offset,
+        segmented=segmented) == census
+    ks = jax.random.split(jax.random.key(21), 3)
+    q = jax.random.normal(ks[0], (1, sq, 2, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, sk, 2, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, sk, 2, 128 if d == 192 else d),
+                          jnp.float32)
+    seg = _two_documents(sk) if segmented else None
+
+    def ref(q, k, v):
+        return mha(q, k, v, causal=causal, q_offset=q_offset,
+                   segment_ids=seg)
+
+    def pallas(q, k, v, q_offset=q_offset):
+        return flash_pallas.pallas_flash_attention(
+            q, k, v, causal=causal, q_offset=q_offset, segment_ids=seg,
+            block_q=block_q, block_kv=block_kv)
+
+    if traced:
+        out = jax.jit(pallas)(q, k, v, jnp.int32(q_offset))
+    else:
+        out = pallas(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                               rtol=2e-4, atol=2e-4)
+    if traced or q_offset:
+        return   # the continuation path is forward-only
+    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    g_pal = jax.grad(lambda *a: jnp.sum(pallas(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b in zip(g_pal, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-4)
+
+
+TILE_GRID = [(sq, sk, bq, bk, causal, off)
+             for sq, sk in [(512, 512), (400, 400), (256, 768), (768, 256),
+                            (2048, 2048)]
+             for bq, bk in [(128, 128), (128, 256), (256, 128), (256, 512),
+                            (384, 128)]
+             for causal in (True, False)
+             for off in ((0, 128, 200) if causal and sq < sk else (0,))]
+
+
+def _kinds_by_the_mask(sq, sk, bq, bk, causal, off):
+    """[n_q, n_k] of 'i', 'd', 'f' from the [sq, sk] mask itself, each
+    length padded to its tile: padded keys masked, padded rows not."""
+    n_q, n_k = -(-sq // bq), -(-sk // bk)
+    q_pos = np.arange(n_q * bq)[:, None] + off
+    k_pos = np.arange(n_k * bk)[None, :]
+    mask = np.broadcast_to(k_pos < sk, (n_q * bq, n_k * bk))
+    if causal:
+        mask = mask & (q_pos >= k_pos)
+        seen = np.broadcast_to(q_pos >= k_pos, mask.shape)
+    else:
+        seen = np.ones_like(mask)
+    tiles = lambda m: m.reshape(n_q, bq, n_k, bk).transpose(0, 2, 1, 3)
+    return np.where(tiles(mask).all((2, 3)), "i",
+                    np.where(tiles(seen).any((2, 3)), "d", "f"))
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,causal,off", TILE_GRID)
+def test_block_census_counts_the_mask(sq, sk, bq, bk, causal, off):
+    from kubeflow_tpu.ops.flash_pallas import block_census
+
+    kinds = _kinds_by_the_mask(sq, sk, bq, bk, causal, off)
+    want = tuple(int((kinds == c).sum()) for c in "idf")
+    assert block_census(sq, sk, bq, bk, causal, off) == want
+    # segments can mask any element: every visited tile keeps the mask
+    assert block_census(sq, sk, bq, bk, causal, off, segmented=True) == (
+        0, want[0] + want[1], want[2])
+    assert sum(want) == kinds.size
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,causal,off", TILE_GRID)
+def test_future_steps_stand_on_a_visited_block(sq, sk, bq, bk, causal, off):
+    """The block index a future grid step maps to is that of the visited
+    step nearest before it (forward, dQ: KV sequential) or after it (dK/dV:
+    q sequential, no offset), so the pipeline issues no copy for it."""
+    from kubeflow_tpu.ops.flash_pallas import kv_block_index, q_block_index
+
+    kinds = _kinds_by_the_mask(sq, sk, bq, bk, causal, off)
+    n_q, n_k = kinds.shape
+    i, j = np.meshgrid(np.arange(n_q), np.arange(n_k), indexing="ij")
+    kv = np.asarray(kv_block_index(i, j, bq, bk, causal, off))
+    for qi in range(n_q):
+        last = max(kj for kj in range(n_k) if kinds[qi, kj] != "f")
+        for kj in range(n_k):
+            assert kv[qi, kj] == (kj if kinds[qi, kj] != "f" else last)
+    if off:
+        return
+    qb = np.asarray(q_block_index(i, j, bq, bk, n_q, causal))
+    for kj in range(n_k):
+        visited = [qi for qi in range(n_q) if kinds[qi, kj] != "f"]
+        first = min(visited) if visited else n_q - 1
+        for qi in range(n_q):
+            assert qb[qi, kj] == (qi if kinds[qi, kj] != "f" else first)
+
+
+# ---------------------------------------------------------------------------
 # ring attention: segment_ids + the Pallas ring body (VERDICT r2 missing #2)
 # ---------------------------------------------------------------------------
 

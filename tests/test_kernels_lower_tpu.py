@@ -246,6 +246,43 @@ def test_flash_pallas_lowers_with_qk_192_beside_v_128():
     assert mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, v) == 3
 
 
+def _flash_grad_lowered(q, v):
+    def loss(q, k, v):
+        return jnp.sum(flash_pallas.pallas_flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32))
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, v).lower(
+        lowering_platforms=("tpu",))
+
+
+def test_flash_pallas_kernels_keep_the_operands_the_benchmark_reads():
+    """`flash_attention_roofline` and `mla_attention_roofline` tell the
+    forward, dQ and dK/dV kernels by their operand lists and count
+    layer-steps by the dK/dV kernel's calls: at both training cells' shapes
+    each lowered call is matched by its own pattern and by no other."""
+    fa, mla = opcount_module("flash_attention"), opcount_module(
+        "mla_attention")
+    # train_fsdp2tp2: a chip's island is 4 rows x 16 heads of 2048 x 128
+    dense = sds((4, 2048, 16, 128), jnp.bfloat16)
+    fwd, dq, dkv = kernel_event_names(
+        _flash_grad_lowered(dense, dense), "shard_map.3601")
+    assert fwd == ("shard_map.3601(s32[1],bf16[64,2048,128],"
+                   "bf16[64,2048,128],bf16[64,2048,128])->"
+                   "bf16[64,2048,128],f32[64,2,1,1024]")
+    assert fa.FORWARD.match(fwd) and not fa.BACKWARD_Q.match(fwd)
+    assert fa.BACKWARD_Q.match(dq) and not fa.BACKWARD_KV.match(dq)
+    assert fa.BACKWARD_KV.match(dkv) and not fa.BACKWARD_Q.match(dkv)
+    assert not any(mla.kernel(name) for name in (fwd, dq, dkv))
+    # train_kimi_linear_ep32_s8k: 2 rows x 32 heads, q/k 192 (padded to
+    # 256 lanes) beside v 128
+    names = kernel_event_names(_flash_grad_lowered(
+        sds((2, 8192, 32, 192), jnp.bfloat16),
+        sds((2, 8192, 32, 128), jnp.bfloat16)), "mla.4")
+    assert [mla.kernel(name).re for name in names] == [
+        mla.FORWARD, mla.BACKWARD_Q, mla.BACKWARD_KV]
+    assert names[2].endswith("->bf16[64,8192,256],bf16[64,8192,128]")
+
+
 # the Kimi-Linear cut: 2 rows x 32 heads of 8192 positions, dk = dv = 128
 KDA_ROWS = sds((64, 8192, 128), jnp.bfloat16)
 KDA_DECAY = sds((64, 8192, 128), jnp.float32)
@@ -291,28 +328,39 @@ def test_kda_backward_kernel_lowers(group):
                                                lead + (128,)]
 
 
+def opcount_module(name):
+    """benchmark/opcount/<name>.py, whose patterns tell a kernel in a
+    capture by its operand list."""
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark_opcount_{name}", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", "opcount", f"{name}.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def kernel_event_names(lowered, name):
+    """Each Mosaic call of a lowered program as lib/tracered.short_name
+    writes a kernel's event: `name(operand shapes)->result shapes`."""
+    def shapes(types):    # tensor<64x8192x128xbf16>, .. -> bf16[64,8192,128],..
+        return ",".join(   # HLO writes MLIR's i32 as s32
+            f"{re.sub('^i', 's', t)}[{dims.replace('x', ',')}]" for dims, t
+            in re.findall(r"tensor<([\dx]+)x(\w+)>", types))
+
+    return [f"{name}({shapes(operands)})->{shapes(results)}"
+            for operands, results in re.findall(
+                r"tpu_custom_call[^\n]*:\s*\(([^)]*)\)\s*->\s*\(?([^)\n]*)",
+                lowered.as_text())]
+
+
 def test_kda_backward_kernel_is_not_read_as_a_forward_kernel():
     """The benchmark tells the two forward kernels by their operands
     (benchmark/opcount/kda_chunk.py, three and six) and computes a roofline
     share of the FORWARD from their time: the backward's walk takes eight,
     so neither pattern may take it for one of them."""
-    spec = importlib.util.spec_from_file_location(
-        "benchmark_opcount_kda_chunk", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "benchmark", "opcount", "kda_chunk.py"))
-    kc = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(kc)
-    call, = [line for line in kda_backward_call().as_text().splitlines()
-             if "tpu_custom_call" in line]
-    operands, results = re.search(r":\s*\(([^)]*)\)\s*->\s*\((.*)\)",
-                                  call).groups()
-
-    def shapes(types):    # tensor<64x8192x128xbf16>, .. -> bf16[64,8192,128],..
-        return ",".join(f"{t}[{dims.replace('x', ',')}]" for dims, t in
-                        re.findall(r"tensor<([\dx]+)x(\w+)>", types))
-
-    # as lib/tracered.short_name writes a kernel's event
-    name = f"kda_backward.4({shapes(operands)})->{shapes(results)}"
+    kc = opcount_module("kda_chunk")
+    name, = kernel_event_names(kda_backward_call(), "kda_backward.4")
     assert name.startswith(
         "kda_backward.4(bf16[64,8192,128],bf16[64,8192,128],"
         "bf16[64,8192,128],f32[64,8192,128],f32[64,128,64,64],"

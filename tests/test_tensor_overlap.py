@@ -181,24 +181,33 @@ def test_compiled_step_moves_activations_by_permute(devices8, monkeypatch,
         assert len(reduced) >= 4 and not permutes
 
 
+# sequence, attention body -> the share of the visited attention tiles that
+# run with no mask: none without the kernels; at 256 the kernels' one tile
+# a head is the diagonal's; at 2048 with the default 1024 x 1024 tiles 1 of 3
+@pytest.mark.parametrize("seq,impl,share", [
+    (S, "xla", 0.0), (256, "flash", 0.0), (2048, "flash", 1 / 3)])
 @pytest.mark.parametrize("mesh_cfg,want", [
     (MeshConfig(fsdp=2, tensor=2), 4.0), (MeshConfig(fsdp=4), 0.0)])
 def test_trainer_first_record_counts_the_overlapped_projections(
-        devices8, mesh_cfg, want):
+        devices8, monkeypatch, mesh_cfg, want, seq, impl, share):
+    from kubeflow_tpu.ops import flash_pallas
     from kubeflow_tpu.training import (OptimizerConfig, Trainer,
                                        TrainerConfig)
     from kubeflow_tpu.training import data as data_lib
 
+    monkeypatch.setattr(flash_pallas, "FORCE_INTERPRET", True)
     tr = Trainer(TrainerConfig(
         model="llama", batch_size=B, mesh=mesh_cfg, log_every=1,
         optimizer=OptimizerConfig(warmup_steps=2, total_steps=10),
         model_overrides={"vocab_size": 256, "d_model": 32, "n_layers": 2,
                          "n_heads": 4, "n_kv_heads": 2, "d_ff": 64,
-                         "max_seq_len": 64, "attention_impl": "xla"}),
+                         "max_seq_len": seq, "attention_impl": impl}),
         devices=devices8[:4])
     tr.metrics.echo = False
     records = []
-    tr.train(data_lib.for_model("llama", tr.model_cfg, B, seq_len=S), 2,
+    tr.train(data_lib.for_model("llama", tr.model_cfg, B, seq_len=seq), 2,
              step_callback=lambda step, m: records.append(m))
     assert records[0]["overlapped_projections_per_layer"] == want
-    assert "overlapped_projections_per_layer" not in records[1]
+    assert records[0]["attention_interior_tile_share"] == share
+    assert not {"overlapped_projections_per_layer",
+                "attention_interior_tile_share"} & set(records[1])
