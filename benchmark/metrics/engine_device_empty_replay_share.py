@@ -1,0 +1,10 @@
+"""Engine step: the share of a request's decode window in which the device
+was empty while the engine thread replayed fetched tokens
+(`usage.engine.device_empty_by_phase_ms.replay` over `usage.decode_ms`),
+median over the requests."""
+
+from metrics._host import device_empty_share
+
+
+def read(run):
+    return device_empty_share(run, "replay")

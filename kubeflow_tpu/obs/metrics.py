@@ -139,10 +139,16 @@ ENGINE_PHASE_SECONDS = REGISTRY.counter(
     "serving_engine_phase_seconds_total",
     "Engine-thread wall per phase (obs.trace.PHASES partitions the "
     "thread's timeline)", ["engine", "phase"])
+ENGINE_PHASE_CPU_SECONDS = REGISTRY.counter(
+    "serving_engine_phase_cpu_seconds_total",
+    "The engine thread's own CPU time per phase: the phase's wall less "
+    "this is what the thread waited (device, runtime queue, interpreter "
+    "or OS, by phase)", ["engine", "phase"])
 ENGINE_DEVICE_EMPTY_SECONDS = REGISTRY.counter(
     "serving_engine_device_empty_seconds_total",
-    "Wall with nothing dispatched and unfetched: a floor under the "
-    "device's idle time", ["engine"])
+    "Wall with nothing dispatched and unfetched, by the phase the engine "
+    "thread was in: summed over phase, a floor under the device's idle "
+    "time", ["engine", "phase"])
 ENGINE_KV_BLOCKS_FETCHED = REGISTRY.counter(
     "serving_engine_kv_blocks_fetched_total",
     "KV blocks the decode dispatches' slot lengths let the attention "
@@ -153,8 +159,18 @@ ENGINE_KV_BLOCKS_SPANNED = REGISTRY.counter(
     "span / block)", ["engine"])
 ENGINE_STALLS = REGISTRY.counter(
     "serving_engine_stalls_total",
-    "Single non-idle phase occurrences of 500 ms or more",
+    "Single phase occurrences of 500 ms or more (idle only when it "
+    "began with work queued or active)",
     ["engine", "phase"])
+
+# -- what stops every thread (obs.trace.GC, one gc.callbacks hook) ------------
+GC_PAUSE_SECONDS = REGISTRY.counter(
+    "process_gc_pause_seconds_total",
+    "Wall the collector held the interpreter, by generation",
+    ["generation"])
+GC_PAUSE_MAX_SECONDS = REGISTRY.gauge(
+    "process_gc_pause_max_seconds",
+    "The longest single collection since the process started", [])
 
 # -- SLO burn (scrape-hook fed from SloBurnTracker) ---------------------------
 SLO_ATTAINMENT = REGISTRY.gauge(
@@ -210,9 +226,10 @@ def run_scrape_hooks() -> None:
 def render_metrics() -> str:
     """THE scrape path: refresh pull-model gauges, then render the one
     process registry as Prometheus text."""
-    from kubeflow_tpu.obs.trace import TRACER
+    from kubeflow_tpu.obs.trace import GC, TRACER
 
     run_scrape_hooks()
+    GC.publish()
     TRACE_BUFFER_SPANS.set(len(TRACER.sink))
     TRACE_SPANS_DROPPED.set(TRACER.sink.dropped)
     return REGISTRY.render()

@@ -13,8 +13,10 @@ Design constraints, in order:
    ONE retrospective span per request per phase via ``record_span``.
    What the engine THREAD does between those instants is the
    ``PhaseClock``'s: a closed set of named phases (``PHASES``) that
-   partitions the thread's timeline, plus the per-step counters
-   (steps, tokens) that annotate the decode span.
+   partitions the thread's timeline and says who had its time (the
+   thread's CPU, the device, the collector), plus the per-step
+   counters (steps, tokens) that annotate the decode span. The
+   Trainer's loop runs on the same class (``TRAINER_PHASES``).
    scripts/check_observability.py enforces this statically.
 3. Spans are plain dict-shaped facts in a bounded deque — an exporter
    crash or an unscraped buffer can only ever cost old spans
@@ -22,10 +24,10 @@ Design constraints, in order:
 
 Span kinds, the taxonomy (docs/ARCHITECTURE.md "Observability"):
 ``http`` (router relay / server handler), ``supervise`` (journal
-lifetime incl. crash-replay chain), ``admit``, ``queue``, ``prefill``,
+lifetime incl. crash-replay chain), ``queue``, ``prefill``,
 ``handoff``, ``decode``, ``stage`` (pp microbatch wave), ``restart``,
-``replay``, ``stall`` (one engine phase occurrence of ``STALL_NS`` or
-more; recorded by the ``PhaseClock`` itself, sampled or not).
+``replay``, ``stall`` (one phase occurrence of ``STALL_NS`` or more on
+a loop thread; recorded by the ``PhaseClock`` itself, sampled or not).
 
 Clocks. Spans and the ``PhaseClock`` are on ``time.monotonic`` (the
 clock ``request_timing`` uses; ``CLOCK_MONOTONIC`` on Linux, where
@@ -38,6 +40,7 @@ is how a JSONL export is laid over a profiler trace.
 
 from __future__ import annotations
 
+import gc
 import hashlib
 import json
 import logging
@@ -269,80 +272,171 @@ class StepAggregator:
                 "decode_tokens": at_end[1] - at_start[1]}
 
 
-# -- the engine thread's phase clock ------------------------------------------
+# -- what stops every thread: the collector's pauses ---------------------------
 
-#: the closed set: every instant of a driven engine thread lies in
-#: exactly one (docs/ARCHITECTURE.md "Observability" says what runs in
-#: each)
+
+class GcPauses:
+    """The collector's pauses on ``time.monotonic_ns``: a collection holds
+    the interpreter, so every thread of the process stands still for it
+    whichever thread set it off. ONE instance (``GC``), fed by one
+    ``gc.callbacks`` hook registered at import; collections do not nest,
+    so the hook is the single writer. Every ``PhaseClock`` reads
+    ``total_ns`` at its transitions; ``/metrics`` gets the seconds by
+    generation and the longest pause."""
+
+    __slots__ = ("total_ns", "ns", "longest_ns", "_t0", "_published",
+                 "_publish_lock")
+
+    def __init__(self):
+        self.total_ns = 0
+        self.ns = [0, 0, 0]          # by generation
+        self.longest_ns = 0
+        self._t0: int | None = None
+        self._published = [0, 0, 0]
+        self._publish_lock = threading.Lock()
+
+    def __call__(self, phase: str, info: dict[str, Any]) -> None:
+        now = time.monotonic_ns()
+        if phase == "start":
+            self._t0 = now
+        elif self._t0 is not None:
+            d = now - self._t0
+            self._t0 = None
+            self.ns[info["generation"]] += d
+            self.total_ns += d
+            if d > self.longest_ns:
+                self.longest_ns = d
+
+    def publish(self) -> None:
+        """Scrape-path body (``obs.metrics.render_metrics``)."""
+        from kubeflow_tpu.obs import metrics as obs_metrics
+
+        with self._publish_lock:    # two scrapes must not add one delta twice
+            last, now = self._published, list(self.ns)
+            self._published = now
+        for gen, (a, b) in enumerate(zip(last, now)):
+            obs_metrics.GC_PAUSE_SECONDS.inc((b - a) / 1e9,
+                                             generation=str(gen))
+        obs_metrics.GC_PAUSE_MAX_SECONDS.set(self.longest_ns / 1e9)
+
+
+GC = GcPauses()
+gc.callbacks.append(GC)
+
+
+# -- a loop thread's phase clock -----------------------------------------------
+
+#: the engine thread's closed set: every instant of a driven engine
+#: thread lies in exactly one (docs/ARCHITECTURE.md "Observability" says
+#: what runs in each, and what wall less CPU means there)
 PHASES = ("idle", "sched", "prefill_pack", "prefill_dispatch",
           "prefix_bank", "prefill_fetch", "decode_plan", "decode_dispatch",
           "decode_fetch", "replay")
 #: phases whose start is a program call: the device stops being empty
 _DISPATCH = frozenset(("prefill_dispatch", "decode_dispatch"))
-_ANNOTATION = {p: f"engine.{p}" for p in PHASES}
-#: one non-idle phase occurrence this long is a stall (no config key)
+#: phases in which the thread is meant to wait
+_WAITS = frozenset(("idle",))
+#: the Trainer's loop (training/trainer.py) on the same clock
+TRAINER_PHASES = ("data_wait", "dispatch", "fetch", "log", "checkpoint",
+                  "profile")
+#: one occurrence this long is a stall (no config key)
 STALL_NS = 500_000_000
 
 
 class PhaseMark(NamedTuple):
-    """The clock read at one instant; the open phase counted up to it."""
+    """The clock read at one instant; the open phase counted up to it.
+    The tuples are per the clock's ``phases``."""
     at_ns: int
-    ns: tuple[int, ...]          # per PHASES
+    ns: tuple[int, ...]
     counts: tuple[int, ...]
     device_empty_ns: int
     steps: int
     tokens: int
     kv_blocks: tuple[int, int] = (0, 0)   # fetched, spanned
+    cpu_ns: tuple[int, ...] = ()
+    device_empty_by_phase: tuple[int, ...] = ()
+    gc_ns: int = 0
 
 
 class PhaseClock(StepAggregator):
-    """Partitions the engine thread's timeline into ``PHASES``.
+    """Partitions one loop thread's timeline into ``phases`` (the engine
+    thread's ``PHASES`` unless told otherwise; the Trainer's loop hands
+    ``TRAINER_PHASES``).
 
-    ``enter(phase)`` ends the phase before: two ``time.monotonic_ns``
-    reads and one ``jax.profiler.TraceAnnotation`` per transition (a
-    flag check when no profiler runs; under a capture the phases lie on
-    the engine thread's host line, on the profiler's clock), nothing per
-    token. Single writer: the engine thread; ``/metrics`` reads what
-    has closed, ``usage`` takes ``mark()``s on the engine thread.
+    ``enter(phase)`` ends the phase before: one read each of
+    ``time.monotonic_ns`` and ``time.thread_time_ns`` and one
+    ``jax.profiler.TraceAnnotation("<engine>.<phase>")`` per transition
+    (a flag check when no profiler runs; under a capture the phases lie
+    on the thread's host line, on the profiler's clock), nothing per
+    token. Single writer: the loop thread; ``/metrics`` reads what has
+    closed, ``usage`` takes ``mark()``s on the loop thread.
+
+    Beside each phase's wall (``ns``) stand the thread's own CPU time
+    in it (``cpu_ns``: wall less CPU is what the thread WAITED, for the
+    device in a fetch, for the runtime's queue in a dispatch, for the
+    interpreter or the OS anywhere else; accumulated as the CPU clock
+    reads, so sums over many occurrences are fair whatever its tick)
+    and the collector's pauses that ended in it (``gc_ns``, from
+    ``GC``).
 
     ``device_empty_ns`` is an overlay, not a phase: from a fetch that
     left nothing dispatched and unfetched (``fetched(False)``) to the
-    next program call. The device is certainly idle then, so the sum is
-    a floor under a trace's idle share that needs no profiler.
+    next program call (a phase of ``dispatch``). The device is certainly
+    idle then, so the sum is a floor under a trace's idle share that
+    needs no profiler. It accrues phase by phase as each closes
+    (``device_empty_by_phase``), so the split sums to it exactly.
 
-    A driver that owns the whole thread (``LLMModel._loop``) sets
-    ``hold_open``; without it ``leave()`` stops the clock between
-    ``step()`` calls, so a caller's own time is nobody's phase."""
+    A phase of ``waits`` (``idle`` here, the Trainer's ``fetch``) is
+    where the thread is meant to wait: an occurrence is neither a stall
+    nor a candidate for the longest one, UNLESS it began with
+    ``context()`` reporting queued or active work.
 
-    __slots__ = ("engine", "context", "hold_open", "ns", "counts",
-                 "device_empty_ns", "kv_blocks_fetched", "kv_blocks_spanned",
-                 "_cur", "_t0", "_ann", "_empty_since",
+    A driver that owns the whole thread (``LLMModel._loop``,
+    ``Trainer.train``) sets ``hold_open``; without it ``leave()`` stops
+    the clock between ``step()`` calls, so a caller's own time is
+    nobody's phase (nor device-empty time)."""
+
+    __slots__ = ("engine", "context", "hold_open", "phases", "ns", "cpu_ns",
+                 "gc_ns", "counts", "device_empty_by_phase",
+                 "kv_blocks_fetched", "kv_blocks_spanned",
+                 "_dispatch", "_waits", "_annotation", "_cur", "_t0", "_c0",
+                 "_g0", "_wait_busy", "_ann", "_empty_since",
                  "_longest", "_published", "_publish_lock", "_annotate")
 
     def __init__(self, engine: str = "engine",
-                 context: Callable[[], dict[str, Any]] | None = None):
+                 context: Callable[[], dict[str, Any]] | None = None,
+                 phases: tuple[str, ...] = PHASES,
+                 dispatch: frozenset[str] = _DISPATCH,
+                 waits: frozenset[str] = _WAITS):
         super().__init__()
         self.engine = engine
         #: what a stall line reports beside the phase (in flight,
-        #: queued, active); called on the engine thread, rarely
+        #: queued, active); called on the loop thread: once per stall
+        #: and once at the start of each waiting phase
         self.context = context
         self.hold_open = False
-        self.ns = dict.fromkeys(PHASES, 0)
-        self.counts = dict.fromkeys(PHASES, 0)
-        self.device_empty_ns = 0
+        self.phases = phases
+        self._dispatch = dispatch
+        self._waits = waits
+        self._annotation = {p: f"{engine}.{p}" for p in phases}
+        self.ns = dict.fromkeys(phases, 0)
+        self.cpu_ns = dict.fromkeys(phases, 0)
+        self.gc_ns = dict.fromkeys(phases, 0)
+        self.counts = dict.fromkeys(phases, 0)
+        self.device_empty_by_phase = dict.fromkeys(phases, 0)
         #: decode attention's KV blocks, per dispatch (note_kv_blocks)
         self.kv_blocks_fetched = 0
         self.kv_blocks_spanned = 0
         self._cur: str | None = None
-        self._t0 = 0
+        self._t0 = self._c0 = self._g0 = 0
+        self._wait_busy = False
         self._ann = None
         self._empty_since: int | None = None
         # (duration_ns, end_ns, phase), durations falling from the left:
         # an occurrence shorter than a later one can never again be the
         # longest of a window that ends after both
         self._longest: deque[tuple[int, int, str]] = deque(maxlen=64)
-        self._published = dict(self.ns, device_empty=0, kv_fetched=0,
-                               kv_spanned=0)
+        self._published = self._closed()
         self._publish_lock = threading.Lock()
         try:
             from jax.profiler import TraceAnnotation
@@ -350,29 +444,43 @@ class PhaseClock(StepAggregator):
             TraceAnnotation = None
         self._annotate = TraceAnnotation
 
-    # -- the engine thread ----------------------------------------------------
+    @property
+    def device_empty_ns(self) -> int:
+        return sum(self.device_empty_by_phase.values())
+
+    # -- the loop thread ------------------------------------------------------
 
     def enter(self, phase: str) -> None:
         if phase == self._cur:
             return
+        # ONE read of the CPU clock a transition: it is a real system
+        # call (5.8 us on the sandboxed hosts the chips hang off, where
+        # monotonic_ns is 77 ns, PERF.md section 6), so it closes the
+        # phase before and opens this one; the two clocks are read
+        # back to back, so a phase that ran all the way through can
+        # read a fraction of a microsecond more CPU than wall
+        cpu = time.thread_time_ns()
         now = time.monotonic_ns()
         if self._cur is not None:
-            self._close(now)
-        if self._empty_since is not None and phase in _DISPATCH:
-            self.device_empty_ns += now - self._empty_since
+            self._close(now, cpu)
+        if phase in self._dispatch:
             self._empty_since = None
+        self._wait_busy = phase in self._waits and self._has_work()
         self._cur = phase
         self._t0 = now
+        self._c0 = cpu
+        self._g0 = GC.total_ns
         self.counts[phase] += 1
         if self._annotate is not None:
-            self._ann = self._annotate(_ANNOTATION[phase])
+            self._ann = self._annotate(self._annotation[phase])
             self._ann.__enter__()
 
     def leave(self) -> None:
         """End of one driven step: stop the clock unless the driver
         holds the thread (``hold_open``)."""
         if self._cur is not None and not self.hold_open:
-            self._close(time.monotonic_ns())
+            cpu = time.thread_time_ns()
+            self._close(time.monotonic_ns(), cpu)
             self._cur = None
 
     def note_kv_blocks(self, fetched: int, spanned: int) -> None:
@@ -389,33 +497,58 @@ class PhaseClock(StepAggregator):
         if not outstanding and self._empty_since is None:
             self._empty_since = time.monotonic_ns()
 
-    def _close(self, now: int) -> None:
+    def _context(self) -> dict[str, Any]:
+        if self.context is None:
+            return {}
+        try:
+            return dict(self.context())
+        except Exception:    # telemetry never takes the loop down
+            return {}
+
+    def _has_work(self) -> bool:
+        ctx = self._context()
+        return bool(ctx.get("queued") or ctx.get("active"))
+
+    def _empty_in_open(self, now: int) -> int:
+        """Device-empty nanoseconds of the open phase up to ``now``."""
+        since = self._empty_since
+        return 0 if since is None else now - max(self._t0, since)
+
+    def _close(self, now: int, cpu_now: int) -> None:
         cur = self._cur
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
         d = now - self._t0
+        # as read, not cut to the wall: where the thread CPU clock ticks
+        # coarsely (10 ms on a sandboxed host) a tick lands whole in
+        # whichever phase is open, which is fair over many occurrences
+        # and more than the wall of a short one
+        cpu = cpu_now - self._c0
+        paused = GC.total_ns - self._g0
         self.ns[cur] += d
-        if cur == "idle":
+        self.cpu_ns[cur] += cpu
+        self.gc_ns[cur] += paused
+        self.device_empty_by_phase[cur] += self._empty_in_open(now)
+        if cur in self._waits and not self._wait_busy:
             return
         longest = self._longest
         while longest and longest[-1][0] <= d:
             longest.pop()
         longest.append((d, now, cur))
         if d >= STALL_NS:
-            self._stall(cur, d, now)
+            self._stall(cur, d, now, cpu, paused)
 
-    def _stall(self, phase: str, d: int, end_ns: int) -> None:
+    def _stall(self, phase: str, d: int, end_ns: int, cpu: int,
+               paused: int) -> None:
         from kubeflow_tpu.obs import metrics as obs_metrics
 
-        ctx = {}
-        if self.context is not None:
-            try:
-                ctx = dict(self.context())
-            except Exception:    # telemetry never takes the engine down
-                pass
         ms = round(d / 1e6, 3)
-        log.warning("engine stall: %s phase %s lasted %.1f ms (%s)",
+        # long, low cpu_ms, no gc_ms: the thread waited for the
+        # interpreter or the OS; gc_ms near the length: the collector
+        ctx = dict(self._context(), cpu_ms=round(cpu / 1e6, 3),
+                   gc_ms=round(paused / 1e6, 3))
+        log.warning("%s stall: phase %s lasted %.1f ms (%s)",
                     self.engine, phase, ms,
                     ", ".join(f"{k}={v}" for k, v in ctx.items()))
         obs_metrics.ENGINE_STALLS.inc(engine=self.engine, phase=phase)
@@ -429,49 +562,79 @@ class PhaseClock(StepAggregator):
     # -- readers --------------------------------------------------------------
 
     def mark(self) -> PhaseMark:
+        """Loop thread only: the open phase's CPU is this thread's."""
+        cpu_now = time.thread_time_ns()
         now = time.monotonic_ns()
-        cur, t0, since = self._cur, self._t0, self._empty_since
-        ns = [self.ns[p] for p in PHASES]
+        cur = self._cur
+        ns, cpu, empty = ([d[p] for p in self.phases] for d in (
+            self.ns, self.cpu_ns, self.device_empty_by_phase))
+        paused = sum(self.gc_ns.values())
         if cur is not None:
-            ns[PHASES.index(cur)] += now - t0
+            i = self.phases.index(cur)
+            ns[i] += now - self._t0
+            # (a mark read from another thread, as tests do, reads that
+            # thread's CPU clock: never below zero)
+            cpu[i] += max(0, cpu_now - self._c0)
+            empty[i] += self._empty_in_open(now)
+            paused += GC.total_ns - self._g0
         return PhaseMark(
-            now, tuple(ns), tuple(self.counts[p] for p in PHASES),
-            self.device_empty_ns + (now - since if since is not None else 0),
-            self.steps, self.tokens,
-            (self.kv_blocks_fetched, self.kv_blocks_spanned))
+            now, tuple(ns), tuple(self.counts[p] for p in self.phases),
+            sum(empty), self.steps, self.tokens,
+            (self.kv_blocks_fetched, self.kv_blocks_spanned),
+            tuple(cpu), tuple(empty), paused)
 
     def longest_since(self, start_s: float,
                       now_ns: int) -> tuple[int, str] | None:
-        """(ns, phase) of the longest single non-idle occurrence that
+        """(ns, phase) of the longest single non-waiting occurrence that
         overlapped [start_s (time.monotonic), now_ns]; the open one
-        counts up to ``now_ns``. Engine thread only."""
+        counts up to ``now_ns``. Loop thread only."""
         start_ns = int(start_s * 1e9)
         best = None
         for d, end_ns, phase in self._longest:    # longest first
             if end_ns > start_ns:
                 best = (d, phase)
                 break
-        if self._cur not in (None, "idle"):
+        cur = self._cur
+        if cur is not None and (cur not in self._waits or self._wait_busy):
             d = now_ns - self._t0
             if best is None or d > best[0]:
-                best = (d, self._cur)
+                best = (d, cur)
         return best
 
     def usage(self, first: PhaseMark,
               submit_s: float | None) -> dict[str, Any]:
         """The ``engine`` object of a request's ``usage``: what the
         engine thread did from ``first`` (its first token) to now (its
-        finish), the decode dispatches' ``kv_blocks`` [fetched, spanned]
+        finish): per phase wall ``[ms, count]`` and CPU ms, the
+        device-empty overlay and its split by phase, the collector's
+        pauses, the decode dispatches' ``kv_blocks`` [fetched, spanned]
         in that window, and the longest occurrence since ``submit_s``."""
         end = self.mark()
+
+        def ms(a: int, b: int) -> float:
+            return round((b - a) / 1e6, 3)
+
+        seen = [e > s or ce > cs for s, e, cs, ce in zip(
+            first.ns, end.ns, first.counts, end.counts)]
         out: dict[str, Any] = {
             "phases": {
-                p: [round((e - s) / 1e6, 3), ce - cs]
-                for p, s, e, cs, ce in zip(PHASES, first.ns, end.ns,
-                                           first.counts, end.counts)
-                if e > s or ce > cs},
-            "device_empty_ms": round(
-                (end.device_empty_ns - first.device_empty_ns) / 1e6, 3)}
+                p: [ms(s, e), ce - cs]
+                for p, on, s, e, cs, ce in zip(
+                    self.phases, seen, first.ns, end.ns, first.counts,
+                    end.counts) if on},
+            # never more than the phase's wall, whatever the CPU clock's
+            # tick (what a coarse tick overshoots is cut here, per window)
+            "cpu_ms": {p: ms(0, min(ce - cs, e - s))
+                       for p, on, cs, ce, s, e in zip(
+                           self.phases, seen, first.cpu_ns, end.cpu_ns,
+                           first.ns, end.ns) if on},
+            "device_empty_ms": ms(first.device_empty_ns,
+                                  end.device_empty_ns),
+            "device_empty_by_phase_ms": {
+                p: ms(s, e) for p, s, e in zip(
+                    self.phases, first.device_empty_by_phase,
+                    end.device_empty_by_phase) if e > s},
+            "gc_ms": ms(first.gc_ns, end.gc_ns)}
         if end.kv_blocks[1] > first.kv_blocks[1]:
             out["kv_blocks"] = [e - s for s, e in zip(first.kv_blocks,
                                                       end.kv_blocks)]
@@ -482,23 +645,27 @@ class PhaseClock(StepAggregator):
             out["phase_max"] = longest[1]
         return out
 
+    def _closed(self) -> dict[str, Any]:
+        return {"ns": dict(self.ns), "cpu": dict(self.cpu_ns),
+                "empty": dict(self.device_empty_by_phase),
+                "kv_fetched": self.kv_blocks_fetched,
+                "kv_spanned": self.kv_blocks_spanned}
+
     def publish(self) -> None:
         """Scrape-hook body: add what closed since the last scrape to
-        the two cumulative series."""
+        the cumulative series."""
         from kubeflow_tpu.obs import metrics as obs_metrics
 
         with self._publish_lock:    # two scrapes must not add one delta twice
-            last = self._published
-            now = dict(self.ns, device_empty=self.device_empty_ns,
-                       kv_fetched=self.kv_blocks_fetched,
-                       kv_spanned=self.kv_blocks_spanned)
+            last, now = self._published, self._closed()
             self._published = now
-        for p in PHASES:
-            obs_metrics.ENGINE_PHASE_SECONDS.inc(
-                (now[p] - last[p]) / 1e9, engine=self.engine, phase=p)
-        obs_metrics.ENGINE_DEVICE_EMPTY_SECONDS.inc(
-            (now["device_empty"] - last["device_empty"]) / 1e9,
-            engine=self.engine)
+        for key, series in (
+                ("ns", obs_metrics.ENGINE_PHASE_SECONDS),
+                ("cpu", obs_metrics.ENGINE_PHASE_CPU_SECONDS),
+                ("empty", obs_metrics.ENGINE_DEVICE_EMPTY_SECONDS)):
+            for p in self.phases:
+                series.inc((now[key][p] - last[key][p]) / 1e9,
+                           engine=self.engine, phase=p)
         obs_metrics.ENGINE_KV_BLOCKS_FETCHED.inc(
             now["kv_fetched"] - last["kv_fetched"], engine=self.engine)
         obs_metrics.ENGINE_KV_BLOCKS_SPANNED.inc(

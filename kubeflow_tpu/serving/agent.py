@@ -271,6 +271,9 @@ class _Journaled:
     #: top-N alternatives captured at completion (None until then; the
     #: resumed-tail positions of an unseeded retry pad with {})
     top_lps: list[dict] | None = None
+    #: (tokens journaled before the newest copy, when it was made): what
+    #: a stream thread times its pick-up against (last_append)
+    append: tuple[int, float] | None = None
     engine_rid: int | None = None
     #: tokens seen from the CURRENT engine generation (watchdog signal:
     #: a replay regenerating its old prefix is progress even though the
@@ -644,6 +647,7 @@ class EngineSupervisor:
                 part_lp = self.engine.partial_logprobs(e.engine_rid)
                 n = min(len(part), len(part_lp))
                 if n > len(e.tokens):
+                    e.append = (len(e.base_tokens) + len(e.tokens), now)
                     e.tokens = list(part[:n])
                     e.lps = list(part_lp[:n])
                     if e.first_token_s is None:
@@ -757,6 +761,12 @@ class EngineSupervisor:
             if e is None:
                 return []
             return list(e.base_tokens) + list(e.tokens)
+
+    def last_append(self, rid: int) -> tuple[int, float] | None:
+        """The engine's `last_append`, of the journal's copy."""
+        with self._lock:
+            e = self._journal.get(rid)
+            return None if e is None else e.append
 
     def partial_logprobs(self, rid: int) -> list[float]:
         """Logprobs of partial_result(rid), journaled alongside the

@@ -498,12 +498,16 @@ class LLMEngine:
         # no span objects are minted on the step/_do_decode paths).
         # _phase_mark reads the clock at a request's first token,
         # _phase_fin holds (usage `engine` object, decode-step window)
-        # from its finish until release().
+        # from its finish until release(). _append_t stamps a request's
+        # newest append (tokens it had before, time.monotonic): one store
+        # per request per chunk, for the stream thread that picks the
+        # tokens up to time itself against (last_append).
         self._req_trace: dict[int, str] = {}
         self.phase_clock = PhaseClock(self.role, self._stall_context)
         self._phase_mark: dict[int, Any] = {}
         self._phase_fin: dict[int, tuple[dict[str, Any], dict[str, int]]] \
             = {}
+        self._append_t: dict[int, tuple[int, float]] = {}
         # queue-depth gauges are pull-model: refreshed from the scheduler
         # at scrape time (weakref-held, so a dropped engine unregisters
         # itself)
@@ -2076,6 +2080,12 @@ class LLMEngine:
         result_logprobs)."""
         return list(self._logprobs.get(req_id, ()))
 
+    def last_append(self, req_id: int) -> tuple[int, float] | None:
+        """(tokens the request had before its newest append, when that
+        append began on time.monotonic): what a stream thread sets its
+        own pick-up instant against."""
+        return self._append_t.get(req_id)
+
     def finish_reason(self, req_id: int) -> str:
         """Why a finished request stopped: "stop" (EOS) or "length"
         (max-new-tokens / cache room). Read before release()."""
@@ -2099,6 +2109,7 @@ class LLMEngine:
         self._req_trace.pop(req_id, None)
         self._phase_mark.pop(req_id, None)
         self._phase_fin.pop(req_id, None)
+        self._append_t.pop(req_id, None)
 
     def generate(self, prompt: Sequence[int],
                  max_new_tokens: int = 32,
@@ -2130,7 +2141,8 @@ class LLMEngine:
         decode_ms (first token → finish), each None until its phase
         boundary lands; and `engine`, what the ENGINE THREAD did over the
         decode_ms window (obs.trace.PhaseClock.usage: per phase [ms,
-        count], device_empty_ms, the longest single occurrence since
+        count] and CPU ms, device_empty_ms and its split by phase, the
+        collector's pauses, the longest single occurrence since
         submit), None until finish. Read BEFORE release() — release
         drops all of it."""
         plen = self._req_plen.get(req_id)
@@ -2684,6 +2696,10 @@ class LLMEngine:
         # neither — the next prefill into the slot resets both)
         alive = [self.scheduler.slot_request(s) == slot_req[s]
                  for s in range(self.n_slots)]
+        now = time.monotonic()
+        for slot, req in enumerate(slot_req):
+            if req >= 0 and alive[slot]:
+                self._append_t[req] = (len(self._results.get(req, ())), now)
         done_slots: set[int] = set()
         if self.spec:
             kp1 = kd + 1
@@ -2745,6 +2761,7 @@ class LLMEngine:
             self._first_token_t[req_id] = now
             self._ttft_window.append(now - self._submit_t[req_id])
             self._phase_mark[req_id] = self.phase_clock.mark()
+            self._append_t[req_id] = (0, now)
         res = self._results[req_id]
         res.append(token)
         self._logprobs[req_id].append(lp)

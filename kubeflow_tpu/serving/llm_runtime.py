@@ -812,8 +812,19 @@ class LLMModel(Model):
         deadline = time.monotonic() + self._timeout_s
         sent = 0
         last_emit = time.monotonic()
+        # usage_timing: the longest a token waited from its append on the
+        # engine thread to this thread picking it up. Set against the
+        # append's own stamp while this thread keeps up (everything
+        # unsent is of the newest append), else against this thread's
+        # previous look: a stream that slept through several chunks must
+        # not read the newest one's age
+        last_append = (getattr(self._engine, "last_append", None)
+                       if self._usage_timing else None)
+        lag_max = 0.0
+        looked = last_emit
         try:
             while True:
+                now = time.monotonic()
                 done = self._engine.is_done(rid)   # BEFORE the drain: a
                 # token landing between drain and check is caught next loop
                 toks = self._engine.partial_result(rid)
@@ -824,6 +835,12 @@ class LLMModel(Model):
                     # snapshot between the two would otherwise emit a
                     # fabricated 0.0 — hold that token one poll instead
                     limit = min(limit, len(lps))
+                if last_append is not None:
+                    stamp = last_append(rid) if sent < limit else None
+                    if stamp is not None:
+                        lag_max = max(lag_max, now - (
+                            stamp[1] if sent >= stamp[0] else looked))
+                    looked = now
                 while sent < limit:
                     yield toks[sent], (lps[sent] if sent < len(lps)
                                        else 0.0)
@@ -867,6 +884,8 @@ class LLMModel(Model):
                 info["cached_tokens"] = cached
             if self._usage_timing:
                 info.update(self._timing_fields(rid))
+                if last_append is not None:
+                    info["write_lag_max_ms"] = round(lag_max * 1e3, 3)
         if on_finish is not None:
             on_finish(reason)
         self._slo_record(rid, reason)

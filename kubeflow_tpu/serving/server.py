@@ -668,13 +668,17 @@ class ModelServer:
         `pre_submit_ms` (handler entry -> the submit instant that
         queue_wait_ms starts from) and, streaming only,
         `first_write_lag_ms` (the engine's first token, i.e. the end of
-        prefill_ms, -> the first SSE chunk written). With the client's
+        prefill_ms, -> the first SSE chunk written), beside the stream's
+        own `stream_write_lag_max_ms` (the longest any token waited from
+        its append to the stream picking it up). With the client's
         send-to-first-token they split the HTTP overhead into the
         server's two halves and, by subtraction, the router's relay."""
         timing = timed["timing"]
         for k, v in timing.items():
             if v is not None:
                 usage[k] = v
+        if "write_lag_max_ms" in timed:
+            usage["stream_write_lag_max_ms"] = timed["write_lag_max_ms"]
         sub = timed.get("submit_s")
         if sub is None:
             return

@@ -10,7 +10,6 @@ path covers 1 chip -> v5e-16 -> multi-slice: only the MeshConfig changes.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Iterator
 
 import jax
@@ -19,6 +18,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from kubeflow_tpu.models import registry
+from kubeflow_tpu.obs.trace import TRAINER_PHASES, PhaseClock, PhaseMark
 from kubeflow_tpu.ops import flash_attention
 from kubeflow_tpu.parallel import (
     MeshConfig,
@@ -73,6 +73,33 @@ class TrainerConfig:
     profile_dir: str | None = None
     profile_start_step: int = 2
     profile_num_steps: int = 3
+
+
+def _host_scalars(clock: PhaseClock, last: PhaseMark, now: PhaseMark,
+                  steps: int) -> dict[str, float]:
+    """What the loop's phase clock read between two marks (the ends of
+    two logged steps' fetches), per step like `step_time_s`: the wall of
+    each phase (`host_<phase>_ms`; they partition the interval, so they
+    sum to `step_time_s`), the thread's CPU over all of them, the
+    device-empty overlay, the collector's pauses, and the longest single
+    occurrence that was not a fetch (NOT per step). `data_wait_s` is
+    `host_data_wait_ms` in seconds: the host wall in next(data) +
+    shard_batch."""
+    def per_step_ms(a: int, b: int) -> float:
+        return (b - a) / 1e6 / steps
+
+    out = {f"host_{p}_ms": per_step_ms(a, b)
+           for p, a, b in zip(clock.phases, last.ns, now.ns)}
+    longest = clock.longest_since(last.at_ns / 1e9, now.at_ns)
+    out.update(
+        step_time_s=(now.at_ns - last.at_ns) / 1e9 / steps,
+        data_wait_s=out["host_data_wait_ms"] / 1e3,
+        host_cpu_ms=per_step_ms(sum(last.cpu_ns), sum(now.cpu_ns)),
+        device_empty_ms=per_step_ms(last.device_empty_ns,
+                                    now.device_empty_ns),
+        gc_pause_ms=per_step_ms(last.gc_ns, now.gc_ns),
+        host_phase_max_ms=longest[0] / 1e6 if longest else 0.0)
+    return out
 
 
 def _path_keys(path) -> tuple[str, ...]:
@@ -341,7 +368,6 @@ class Trainer:
                 max_to_keep=self.config.keep_checkpoints,
                 save_interval_steps=self.config.checkpoint_every)
         step_fn = None
-        t_last = time.perf_counter()
         steps_since_log = 0
         first_interval = True  # includes jit compile; flagged, not averaged in
         start_step = int(state["step"])
@@ -355,26 +381,39 @@ class Trainer:
                                 start_step + self.config.profile_start_step,
                                 self.config.profile_num_steps)
         pending = None
-        data_wait = 0.0   # since the last logged step
+        step = start_step
+        # every instant of the loop lies in one of TRAINER_PHASES, as the
+        # engine thread's does in its own (obs.trace.PhaseClock): wall,
+        # the thread's CPU, the collector's pauses, and the device-empty
+        # overlay from a fetch to the next step_fn call; under a profiler
+        # capture the phases are `trainer.<phase>` on this thread's host
+        # line, beside the steps' `train_step` annotations. `fetch` lasts
+        # the step by design, so it is the phase that may wait.
+        clock = PhaseClock(
+            "trainer",
+            lambda: {"step": step, "includes_compile": first_interval},
+            phases=TRAINER_PHASES, dispatch=frozenset(("dispatch",)),
+            waits=frozenset(("fetch",)))
+        clock.hold_open = True
 
         def next_batch():
-            # under a profiler capture the wait shows on this thread's
-            # host line, beside the steps' `train_step` annotations
-            nonlocal data_wait
-            t = time.perf_counter()
-            with jax.profiler.TraceAnnotation("trainer.data_wait"):
-                out = self.shard_batch(next(data))
-            data_wait += time.perf_counter() - t
-            return out
+            clock.enter("data_wait")
+            return self.shard_batch(next(data))
 
+        clock.enter("data_wait")
+        last = clock.mark()
         for i in range(num_steps):
             batch = pending if pending is not None else next_batch()
             pending = None
             if step_fn is None:
                 step_fn = self.compiled_step(state, batch)
-            step = start_step + i + 1
             if prof is not None:
-                prof.maybe_start(step)
+                clock.enter("profile")
+                prof.maybe_start(start_step + i + 1)
+            clock.enter("dispatch")
+            # after the transition: a stall of the phase just closed (the
+            # log, the save) is reported with the step it belonged to
+            step = start_step + i + 1
             with jax.profiler.StepTraceAnnotation("train_step",
                                                   step_num=step):
                 state, metrics = step_fn(state, batch)
@@ -390,22 +429,22 @@ class Trainer:
                 except BaseException as e:
                     data_err = e
             if prof is not None:
+                clock.enter("profile")
                 # sync by fetching the step's scalars: a value on the
                 # host means the step that produced it has finished
                 prof.maybe_stop(step, sync=lambda: jax.device_get(metrics))
             steps_since_log += 1
             if step % self.config.log_every == 0 or i == num_steps - 1:
+                clock.enter("fetch")
                 metrics = jax.device_get(metrics)
-                now = time.perf_counter()
-                dt = (now - t_last) / steps_since_log
+                clock.fetched(outstanding=False)
+                clock.enter("log")
+                now = clock.mark()
                 scalars = {k: float(v) for k, v in metrics.items()}
-                scalars["step_time_s"] = dt
-                # per step like step_time_s, and part of it: the host
-                # wall in next(data) + shard_batch
-                scalars["data_wait_s"] = data_wait / steps_since_log
-                t_last = now
+                scalars.update(_host_scalars(clock, last, now,
+                                             steps_since_log))
+                last = now
                 steps_since_log = 0
-                data_wait = 0.0
                 if first_interval:
                     scalars["includes_compile"] = 1.0
                     scalars["overlapped_projections_per_layer"] = float(
@@ -422,15 +461,20 @@ class Trainer:
                 if step_callback:
                     step_callback(step, scalars)
             if ckpt is not None:
+                clock.enter("checkpoint")
                 # manager applies save_interval_steps; final step forced below
                 ckpt.save(step, state)
             if data_err is not None:
                 raise data_err
         if prof is not None:
+            clock.enter("profile")
             prof.close()
         if ckpt is not None:
+            clock.enter("checkpoint")
             final = start_step + num_steps
             if ckpt.latest_step() != final:  # interval may have saved it already
                 ckpt.save(final, state, force=True)
             ckpt.close()
+        clock.hold_open = False
+        clock.leave()
         return state

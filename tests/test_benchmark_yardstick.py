@@ -4,7 +4,7 @@
 `tests/`), so the trace reducer, the operation counts, the traffic
 schedule, `BENCHMARK.json`'s shape and the `usage.engine` readers were
 tested only by hand. This module loads the files of it (LIGHT: seven of ISSUE 29, one of
-ISSUE 34) that read
+ISSUE 34, one of ISSUE 36) that read
 recorded traces, counts and JSON and compile nothing (two seconds
 together) and re-exports their tests, one name each, so each counts.
 Nothing under `benchmark/` is edited for it. The three heavy files
@@ -31,7 +31,11 @@ BENCH_TESTS = os.path.join(os.path.dirname(os.path.dirname(
 LIGHT = ("test_tracered", "test_opcount", "test_traffic",
          "test_benchmark_json", "test_engine_readers",
          "test_decode_kv_fetched_block_share",
-         "test_quant_matmul_stacked_roofline", "test_laguna_cell_light")
+         "test_quant_matmul_stacked_roofline", "test_laguna_cell_light",
+         "test_host_readers")
+
+#: tests of a LIGHT file that build and run models: by hand only
+HEAVY = {"test_host_readers__cpu_rehearsal_prints_the_new_metrics"}
 
 #: tests known to fail, by name, each with its reason
 XFAIL = {
@@ -60,6 +64,8 @@ def _collect() -> dict:
             for attr, fn in vars(module).items():
                 if attr.startswith("test_") and callable(fn):
                     name = f"{stem}__{attr[len('test_'):]}"
+                    if name in HEAVY:
+                        continue
                     if name in XFAIL:
                         fn = pytest.mark.xfail(
                             strict=False, reason=XFAIL[name])(fn)

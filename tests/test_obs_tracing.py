@@ -15,6 +15,7 @@ import gc
 import json
 import logging
 import re
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -178,13 +179,281 @@ def test_phase_clock_device_empty_overlay():
     c.fetched(outstanding=False)
     c.enter("replay")
     time.sleep(0.01)
-    assert c.device_empty_ns == 0 and c.mark().device_empty_ns >= 10_000_000
+    # closed so far: the sliver of `decode_fetch` after the fetch returned
+    assert c.device_empty_ns < 1_000_000
+    assert c.mark().device_empty_ns >= 10_000_000
     c.enter("sched")                  # not a program call: still empty
     c.enter("prefill_dispatch")
     closed = c.device_empty_ns
     assert closed >= 10_000_000
     time.sleep(0.002)
     assert c.mark().device_empty_ns == closed
+
+
+def _script_three_phases(c):
+    """One empty interval over `replay`, `sched` and `prefill_pack`."""
+    c.enter("decode_fetch")
+    c.fetched(outstanding=False)
+    for phase in ("replay", "sched", "prefill_pack"):
+        c.enter(phase)
+        time.sleep(0.003)
+    c.enter("prefill_dispatch")
+    return {"replay", "sched", "prefill_pack", "decode_fetch"}
+
+
+def _script_opens_mid_phase(c):
+    """The fetch returns 5 ms into `decode_fetch`: only the rest of that
+    occurrence is empty, and a second fetch while empty restarts nothing."""
+    c.enter("decode_fetch")
+    time.sleep(0.005)
+    c.fetched(outstanding=False)
+    time.sleep(0.003)
+    c.fetched(outstanding=False)
+    c.enter("decode_dispatch")
+    assert 3_000_000 <= c.device_empty_by_phase["decode_fetch"] \
+        < c.ns["decode_fetch"] - 4_000_000
+    return {"decode_fetch"}
+
+
+def _script_in_flight(c):
+    """A fetch that leaves a program in flight empties nothing; the
+    dispatch phases themselves never hold empty time."""
+    c.enter("decode_fetch")
+    c.fetched(outstanding=True)
+    c.enter("replay")
+    time.sleep(0.002)
+    c.enter("decode_dispatch")
+    c.enter("decode_fetch")
+    c.fetched(outstanding=False)
+    c.enter("decode_plan")
+    time.sleep(0.002)
+    c.enter("decode_dispatch")
+    time.sleep(0.002)
+    c.enter("decode_fetch")
+    return {"decode_fetch", "decode_plan"}
+
+
+def _script_idle_and_a_stopped_clock(c):
+    """`idle` takes its share like any phase; a driver that does not
+    hold the thread stops the overlay with the clock."""
+    c.hold_open = True
+    c.enter("prefill_fetch")
+    c.fetched(outstanding=False)
+    c.enter("idle")
+    time.sleep(0.004)
+    c.hold_open = False
+    c.leave()
+    time.sleep(0.06)                  # nobody's phase, nobody's empty time
+    c.enter("sched")
+    time.sleep(0.002)
+    c.enter("prefill_dispatch")
+    assert c.device_empty_ns < 50_000_000
+    assert c.device_empty_by_phase["idle"] >= 4_000_000
+    return {"prefill_fetch", "idle", "sched"}
+
+
+@pytest.mark.parametrize("script", [
+    _script_three_phases, _script_opens_mid_phase, _script_in_flight,
+    _script_idle_and_a_stopped_clock], ids=lambda f: f.__name__[8:])
+def test_device_empty_is_split_by_phase_exactly(script):
+    """Every device-empty interval goes to the phases it fell in: the
+    split sums to `device_empty_ns` to the nanosecond at every mark, is
+    never more than the phase's own wall, and only the scripted phases
+    hold any."""
+    c = PhaseClock("unit")
+    first = c.mark()
+    holders = script(c)
+    time.sleep(0.001)
+    m = c.mark()                      # with a phase open
+    assert sum(m.device_empty_by_phase) == m.device_empty_ns
+    assert sum(c.device_empty_by_phase.values()) == c.device_empty_ns
+    for p, empty, ns in zip(PHASES, m.device_empty_by_phase, m.ns):
+        assert 0 <= empty <= ns
+        assert (empty > 0) <= (p in holders), p
+    assert {p for p, e in zip(PHASES, m.device_empty_by_phase) if e} \
+        >= holders - {"decode_fetch", "prefill_fetch"}
+    u = c.usage(first, None)
+    assert sum(u["device_empty_by_phase_ms"].values()) == pytest.approx(
+        u["device_empty_ms"], abs=0.001 * len(PHASES))
+    assert set(u["device_empty_by_phase_ms"]) <= holders
+
+
+@pytest.mark.parametrize("how", ["sleeps", "spins"])
+def test_phase_cpu_tells_running_from_waiting(how):
+    """Beside each phase's wall stands the thread's own CPU time in it:
+    never more than the wall, far less in a phase that sleeps, within
+    20 % in one that spins (the best of a few tries: the machine is
+    shared)."""
+    ratios = []
+    for _ in range(5):
+        c = PhaseClock("unit")
+        c.enter("sched")
+        first = c.mark()
+        c.enter("replay")
+        if how == "sleeps":
+            time.sleep(0.05)
+        else:
+            end = time.monotonic() + 0.05
+            while time.monotonic() < end:
+                pass
+        c.enter("sched")
+        # (two clocks, read back to back: microseconds of slack an
+        # occurrence)
+        for p in PHASES:
+            assert 0 <= c.cpu_ns[p] <= c.ns[p] + 20_000 * c.counts[p]
+        m = c.mark()
+        assert all(a <= b + 20_000 * n
+                   for a, b, n in zip(m.cpu_ns, m.ns, m.counts))
+        u = c.usage(first, None)
+        assert set(u["cpu_ms"]) == set(u["phases"])
+        assert all(u["cpu_ms"][p] <= u["phases"][p][0] for p in u["cpu_ms"])
+        assert c.ns["replay"] >= 50_000_000
+        ratios.append(c.cpu_ns["replay"] / c.ns["replay"])
+        if how == "sleeps" or ratios[-1] >= 0.8:
+            break
+    if how == "sleeps":
+        assert ratios[-1] < 0.1
+    else:
+        assert max(ratios) >= 0.8, ratios
+
+
+def test_a_coarse_cpu_clock_is_summed_as_read_and_cut_per_window(
+        monkeypatch):
+    """Where the thread CPU clock ticks in 10 ms steps (a sandboxed
+    host), a tick lands whole in whichever phase is open: the clock
+    keeps what it read (fair over many occurrences: every tick is in
+    SOME phase's sum), and a request's usage cuts each phase's CPU to
+    its wall over the window."""
+    real = time.thread_time_ns
+    monkeypatch.setattr(time, "thread_time_ns",
+                        lambda: real() // 10_000_000 * 10_000_000)
+    c = PhaseClock("unit")
+    c.enter("sched")
+    first = c.mark()
+    def spin(seconds):
+        end = time.monotonic() + seconds
+        while time.monotonic() < end:
+            pass
+
+    start = time.thread_time_ns()
+    for _ in range(150):              # ~9 ticks over 300 phases of 0.3 ms
+        c.enter("replay")
+        spin(0.0003)
+        c.enter("decode_plan")
+        spin(0.0003)
+    c.enter("sched")
+    ticks = time.thread_time_ns() - start
+    assert ticks >= 50_000_000
+    # every tick is whole in some phase's sum, none cut to a 0.3 ms wall
+    assert all(v % 10_000_000 == 0 for v in c.cpu_ns.values())
+    assert sum(c.cpu_ns.values()) == ticks
+    assert max(c.cpu_ns["replay"], c.cpu_ns["decode_plan"]) >= 20_000_000
+    u = c.usage(first, None)
+    assert all(u["cpu_ms"][p] <= u["phases"][p][0] for p in u["cpu_ms"])
+
+
+def test_gc_pause_lands_in_its_phase_and_in_metrics():
+    """One `gc.callbacks` hook for the process: a collection forced
+    inside a phase is that phase's `gc_ns` (of every clock with a phase
+    open: a collection stops all threads), the usage's `gc_ms`, and
+    `process_gc_pause_seconds_total{generation}` with the longest pause
+    beside it."""
+    from kubeflow_tpu.obs.trace import GC
+
+    assert gc.callbacks.count(GC) == 1
+    c, other = PhaseClock("unit"), PhaseClock("other")
+    c.enter("sched")
+    other.enter("idle")
+    first = c.mark()
+    c.enter("replay")
+    junk = [[i] for i in range(50_000)]
+    before = GC.ns[2]
+    gc.collect()
+    paused = GC.ns[2] - before        # the full collection alone
+    assert paused > 0 and GC.longest_ns > 0
+    assert c.mark().gc_ns - first.gc_ns >= paused    # the open phase
+    c.enter("sched")
+    del junk
+    assert c.gc_ns["replay"] >= paused and c.gc_ns["idle"] == 0
+    assert c.gc_ns["replay"] <= c.ns["replay"]
+    other.enter("sched")
+    assert other.gc_ns["idle"] >= paused
+    assert c.usage(first, None)["gc_ms"] >= round(paused / 1e6, 3) > 0
+    text = render_metrics()
+    assert "# TYPE process_gc_pause_seconds_total counter" in text
+    assert _metric_value(
+        text, 'process_gc_pause_seconds_total{generation="2"}') \
+        >= paused / 1e9 * 0.99
+    assert _metric_value(text, "process_gc_pause_max_seconds") > 0
+    # two scrapes add no pause twice
+    total = sum(_metric_value(
+        render_metrics(),
+        f'process_gc_pause_seconds_total{{generation="{g}"}}')
+        for g in range(3))
+    assert total <= GC.total_ns / 1e9 * 1.001 + 1e-9
+
+
+@pytest.mark.parametrize("work", ["queued", "active", "none"])
+def test_idle_is_a_stall_only_when_work_was_waiting(work, monkeypatch,
+                                                    caplog):
+    """`idle` is where the engine thread is meant to wait, so an idle
+    occurrence of `STALL_NS` is nothing, UNLESS it began with
+    `context()` reporting queued or active requests: then it is a stall
+    like any other (one line, one count, the longest of its window)."""
+    monkeypatch.setattr("kubeflow_tpu.obs.trace.STALL_NS", 20_000_000)
+    ctx = {"in_flight": "nothing", "queued": 0, "active": 0}
+    if work != "none":
+        ctx[work] = 2
+    c = PhaseClock("unit-idle", lambda: ctx)
+    n_before = obs_metrics.ENGINE_STALLS.value(engine="unit-idle",
+                                               phase="idle")
+    c.enter("sched")
+    start_s = time.monotonic()
+    with caplog.at_level(logging.WARNING, logger="kubeflow_tpu.obs.trace"):
+        c.enter("idle")
+        ctx.update(queued=0, active=0)    # what counts is how it BEGAN
+        time.sleep(0.03)
+        assert (c.longest_since(start_s, time.monotonic_ns())[1]
+                == "idle") is (work != "none")
+        c.enter("sched")
+    lines = [r.getMessage() for r in caplog.records
+             if "unit-idle stall: phase idle" in r.getMessage()]
+    assert len(lines) == (0 if work == "none" else 1)
+    assert obs_metrics.ENGINE_STALLS.value(
+        engine="unit-idle", phase="idle") == n_before + len(lines)
+    if lines:
+        assert "cpu_ms=" in lines[0] and "gc_ms=" in lines[0]
+        span = [s for s in TRACER.sink.spans() if s.kind == "stall"][-1]
+        assert span.attrs["phase"] == "idle"
+        assert span.attrs["cpu_ms"] < span.attrs["duration_ms"]
+        assert span.attrs["gc_ms"] >= 0
+
+
+def test_a_clock_takes_its_own_phase_set():
+    """One class for every loop: the phase tuple, the dispatch phases
+    and the phases that may wait are the constructor's, the annotation's
+    prefix the clock's name; an unknown phase is an error, not a new
+    one."""
+    c = PhaseClock("loop", phases=("wait", "call", "read"),
+                   dispatch=frozenset(("call",)),
+                   waits=frozenset(("read",)))
+    assert c._annotation == {"wait": "loop.wait", "call": "loop.call",
+                             "read": "loop.read"}
+    c.enter("read")
+    c.fetched(outstanding=False)
+    c.enter("wait")
+    time.sleep(0.002)
+    c.enter("call")
+    c.enter("read")
+    m = c.mark()
+    assert len(m.ns) == len(m.cpu_ns) == len(m.device_empty_by_phase) == 3
+    assert c.device_empty_by_phase["wait"] >= 2_000_000
+    assert c.device_empty_by_phase["call"] == 0
+    # `read` may wait: never the longest occurrence
+    assert c.longest_since(0.0, time.monotonic_ns())[1] in ("wait", "call")
+    with pytest.raises(KeyError):
+        c.enter("sched")
+    assert PhaseClock("engine")._annotation["sched"] == "engine.sched"
 
 
 def test_phase_clock_longest_occurrence_of_a_window():
@@ -240,8 +509,20 @@ def test_engine_usage_phases_sum_to_decode_ms(toy_engine):
     _run(eng, a, b)
     tm = eng.request_timing(a)
     engine = tm["engine"]
+    # PR 25's keys, PR 28's `kv_blocks`, and exactly three more (PR 36)
     assert set(engine) == {"phases", "device_empty_ms", "phase_max_ms",
-                           "phase_max", "kv_blocks"}
+                           "phase_max", "kv_blocks"} | {
+        "cpu_ms", "device_empty_by_phase_ms", "gc_ms"}
+    assert all(isinstance(v, list) and len(v) == 2 and isinstance(v[1], int)
+               for v in engine["phases"].values())
+    assert set(engine["cpu_ms"]) == set(engine["phases"])
+    for p, ms in engine["cpu_ms"].items():
+        assert 0 <= ms <= engine["phases"][p][0] + 0.5
+    assert sum(engine["device_empty_by_phase_ms"].values()) == \
+        pytest.approx(engine["device_empty_ms"], abs=0.01)
+    assert set(engine["device_empty_by_phase_ms"]) <= set(PHASES) - {
+        "prefill_dispatch", "decode_dispatch"}
+    assert engine["gc_ms"] >= 0
     assert set(engine["phases"]) <= set(PHASES)
     assert "idle" not in engine["phases"]
     total = sum(ms for ms, _ in engine["phases"].values())
@@ -287,7 +568,10 @@ def test_device_empty_only_when_nothing_is_in_flight(toy_engine, monkeypatch):
     assert clock.mark().device_empty_ns - before >= 50_000_000
     monkeypatch.undo()
     _run(eng, a, b)
-    assert eng.request_timing(a)["engine"]["device_empty_ms"] >= 50
+    engine = eng.request_timing(a)["engine"]
+    assert engine["device_empty_ms"] >= 50
+    # the planted sleep lay in the admission: `sched` holds it
+    assert engine["device_empty_by_phase_ms"]["sched"] >= 50
     eng.release(a)
     eng.release(b)
 
@@ -320,11 +604,16 @@ def test_stall_is_logged_counted_spanned_and_named_in_usage(
     assert len(warned) == 1
     assert "queued=" in warned[0].getMessage()
     assert "active=" in warned[0].getMessage()
+    # a sleep: the line says the thread was off the CPU, and not for GC
+    said = dict(kv.split("=") for kv in warned[0].getMessage()
+                .split("(")[1].rstrip(")").split(", "))
+    assert float(said["cpu_ms"]) < 300 and float(said["gc_ms"]) < 300
     assert obs_metrics.ENGINE_STALLS.value(
         engine="engine", phase="sched") == n_before + 1
     stalls = [s for s in TRACER.sink.spans() if s.kind == "stall"]
     assert len(stalls) == spans_before + 1
     assert stalls[-1].attrs["phase"] == "sched"
+    assert stalls[-1].attrs["cpu_ms"] < 300
     assert stalls[-1].duration_ms() >= STALL_NS / 1e6
     engine = eng.request_timing(a)["engine"]
     assert engine["phase_max"] == "sched" and engine["phase_max_ms"] >= 600
@@ -413,9 +702,23 @@ def test_metrics_render_the_phase_series(toy_engine):
         'phase="decode_dispatch"}'
     first = _metric_value(text, series)
     assert first > 0
-    assert _metric_value(
+    assert "# TYPE serving_engine_phase_cpu_seconds_total counter" in text
+    # the device-empty series carries the phase; its sum over phase is
+    # the series PR 25 had
+    empty = [_metric_value(
         text, 'serving_engine_device_empty_seconds_total'
-        '{engine="engine"}') > 0
+        f'{{engine="engine",phase="{phase}"}}') for phase in PHASES]
+    assert sum(empty) > 0
+    assert sum(empty) <= eng.phase_clock.mark().device_empty_ns / 1e9 + 1e-6
+    for phase in PHASES:
+        cpu = _metric_value(
+            text, 'serving_engine_phase_cpu_seconds_total'
+            f'{{engine="engine",phase="{phase}"}}')
+        wall = _metric_value(
+            text, 'serving_engine_phase_seconds_total'
+            f'{{engine="engine",phase="{phase}"}}')
+        # as the clocks read: microseconds of slack an occurrence
+        assert 0 <= cpu <= wall * 1.05 + 1e-3
     assert _metric_value(render_metrics(), series) == first
     assert "serving_phase_seconds" not in text    # the dead histogram
 
@@ -783,6 +1086,7 @@ def test_usage_timing_carries_engine_and_server_spans(timed_server, stream):
     assert {"queue_wait_ms", "prefill_ms", "decode_ms", "engine",
             "pre_submit_ms"} <= set(usage)
     assert ("first_write_lag_ms" in usage) is stream
+    assert ("stream_write_lag_max_ms" in usage) is stream
     assert "submit_s" not in usage      # an instant, not for the client
     engine = usage["engine"]
     assert set(engine["phases"]) <= set(PHASES)
@@ -793,6 +1097,73 @@ def test_usage_timing_carries_engine_and_server_spans(timed_server, stream):
     if stream:
         # the first chunk cannot be written before the token exists
         assert 0 <= usage["first_write_lag_ms"] < 5_000
+        # picked up within polls of the journal's copy, not chunks later
+        assert 0 <= usage["stream_write_lag_max_ms"] < 5_000
+    assert {"cpu_ms", "device_empty_by_phase_ms", "gc_ms"} <= set(engine)
+
+
+class _ScriptedEngine:
+    """What `_stream_from` asks of an engine, fed by a thread that
+    appends a chunk of two tokens every 20 ms and stamps each append."""
+
+    def __init__(self, chunks: int):
+        self.tokens: list[int] = []
+        self.stamp = None
+        self.done = False
+        self.released = False
+        self._thread = threading.Thread(target=self._run, args=(chunks,),
+                                        daemon=True)
+
+    def _run(self, chunks: int) -> None:
+        for _ in range(chunks):
+            time.sleep(0.02)
+            self.stamp = (len(self.tokens), time.monotonic())
+            self.tokens += [7, 7]
+        self.done = True
+
+    def is_done(self, rid): return self.done
+    def partial_result(self, rid): return list(self.tokens)
+    def partial_logprobs(self, rid): return [0.0] * len(self.tokens)
+    def last_append(self, rid): return self.stamp
+    def finish_reason(self, rid): return "length"
+    def cancel(self, rid): return True
+    def release(self, rid): self.released = True
+
+
+@pytest.mark.parametrize("stream_thread", ["keeps_up", "sleeps_through"])
+def test_stream_write_lag_is_the_longest_wait_of_a_token(stream_thread):
+    """The stream thread keeps the longest a token waited from its
+    append to being picked up: against the append's stamp while it keeps
+    up, and against its own previous look once several appends went by
+    unseen, where the newest stamp would read one chunk's age at most."""
+    eng = _ScriptedEngine(chunks=10)
+    m = LLMModel("scripted", usage_timing=True)
+    m._engine = eng
+    m._check_alive = lambda deadline: None
+    info: dict = {}
+    gen = m._stream_from(0, info=info)
+    eng._thread.start()
+    got = []
+    for tok, _ in gen:
+        got.append(tok)
+        if stream_thread == "sleeps_through" and len(got) == 2:
+            time.sleep(0.15)          # a blocked write, a starved thread
+    eng._thread.join(timeout=10)
+    assert len(got) == 20 and eng.released
+    lag = info["write_lag_max_ms"]
+    if stream_thread == "keeps_up":
+        assert 0 <= lag < 15          # under one chunk period
+    else:
+        assert 120 <= lag < 400       # the sleep, not the newest chunk's age
+    # without usage_timing nothing is asked of the engine or reported
+    quiet = LLMModel("scripted-off")
+    eng2 = _ScriptedEngine(chunks=2)
+    quiet._engine, quiet._check_alive = eng2, lambda deadline: None
+    eng2.last_append = None           # would raise if it were called
+    info2: dict = {}
+    eng2._thread.start()
+    assert len(list(quiet._stream_from(0, info=info2))) == 4
+    assert "write_lag_max_ms" not in info2
 
 
 def test_loop_driven_clock_names_every_instant(timed_server):

@@ -173,3 +173,60 @@ def test_cli_init_scaffold(tmp_path, capsys):
         kd = yaml.safe_load(f)
     assert kd["kind"] == "KfDef" and kd["metadata"]["name"] == "deploy"
     assert main(["init", d]) == 1  # refuses to clobber
+
+
+# -- the serving-plane instrument set: host time's series (ISSUE 36) ----------
+
+@pytest.mark.parametrize("var, series, kind, labels", [
+    ("ENGINE_PHASE_SECONDS", "serving_engine_phase_seconds_total",
+     "counter", ("engine", "phase")),
+    ("ENGINE_PHASE_CPU_SECONDS", "serving_engine_phase_cpu_seconds_total",
+     "counter", ("engine", "phase")),
+    ("ENGINE_DEVICE_EMPTY_SECONDS",
+     "serving_engine_device_empty_seconds_total", "counter",
+     ("engine", "phase")),
+    ("GC_PAUSE_SECONDS", "process_gc_pause_seconds_total", "counter",
+     ("generation",)),
+    ("GC_PAUSE_MAX_SECONDS", "process_gc_pause_max_seconds", "gauge", ()),
+])
+def test_host_time_series_are_declared_once_and_rendered(var, series, kind,
+                                                         labels):
+    """Each series of the phase clock's host-time split is declared in
+    `obs/metrics.py` (where `scripts/check_observability.py` wants every
+    serving-plane name), with the labels the docs give it, and one
+    scrape of a clock that has run carries it with its TYPE line; what a
+    clock publishes under the phase label sums, over `phase`, to the
+    whole it published before the label came."""
+    from kubeflow_tpu.obs import metrics as obs_metrics
+    from kubeflow_tpu.obs.trace import PHASES, PhaseClock
+
+    metric = getattr(obs_metrics, var)
+    assert (metric.name, metric.kind, metric.label_names) == (
+        series, kind, labels)
+    # re-declaring it elsewhere with other labels is refused
+    with pytest.raises(ValueError):
+        getattr(obs_metrics.REGISTRY, kind)(series, "", ["other"])
+    clock = PhaseClock(f"unit-{var}")
+    clock.enter("decode_fetch")
+    clock.fetched(outstanding=False)
+    clock.enter("replay")
+    clock.enter("decode_dispatch")
+    clock.leave()
+    clock.publish()
+    text = obs_metrics.render_metrics()
+    assert f"# TYPE {series} {kind}" in text
+    if "phase" in labels:
+        got = {p: metric.value(engine=f"unit-{var}", phase=p)
+               for p in PHASES}
+        want = {"ENGINE_PHASE_SECONDS": clock.ns,
+                "ENGINE_PHASE_CPU_SECONDS": clock.cpu_ns,
+                "ENGINE_DEVICE_EMPTY_SECONDS":
+                    clock.device_empty_by_phase}[var]
+        assert got == {p: want[p] / 1e9 for p in PHASES}
+        assert sum(got.values()) > 0
+        for p in PHASES:
+            assert f'{series}{{engine="unit-{var}",phase="{p}"}}' in text
+    if var == "ENGINE_DEVICE_EMPTY_SECONDS":
+        assert sum(got.values()) == pytest.approx(
+            clock.device_empty_ns / 1e9)
+        assert got["decode_dispatch"] == 0
