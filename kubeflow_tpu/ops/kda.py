@@ -26,29 +26,35 @@ taken of the difference itself. No exponent anywhere is positive.
 Three stages, forward:
   1. `_intra_pallas`: A and B of every chunk (Pallas).
   2. `_ut_transform`: M by block-recursive inversion of the unit lower
-     triangle (six steps of two 64 x 64 matmuls, float32, XLA).
+     triangle (six steps of two 64 x 64 matmuls, float32, XLA), and the
+     inverse X = (I + Diag(beta) A)^-1 itself, M = X Diag(beta).
   3. `_state_pallas`: chunks in order, the state carried in VMEM (Pallas);
      on the way it can write the state at every chunk's start.
 Stage 2 runs under the named scope `kda_solve` and the backward under
 `kda_backward`: a capture's device operations carry them, and the benchmark
 reads their shares of the device's time (benchmark/lib/xscopes.py).
 
-Backward (`custom_vjp`), two parts. Stage 3's cotangents by hand, chunks in
-reverse: `_state_bwd_pallas` takes what `_state_pallas` took (q, k, v, the
-cumulative decay, and the forward's own M and B, kept as residuals) plus the
-state at every chunk's start and the output's cotangent, carries the state's
-cotangent in VMEM and writes the cotangents of `_prepare`'s six results in
-the grouped layout the second part reads. Stages 1-2 are then
-differentiated by JAX (`jax.vjp` of `_prepare`, the same mathematics in
-jax.numpy) BACKWARD_GROUP chunks of every head at a time, so that the
-channel-by-channel sums never exist for the whole sequence at once. A kernel
-for that transpose is queued in ROADMAP.md.
+Backward (`custom_vjp`), two kernels, fed by residuals of the forward (M, B,
+X and the state at every chunk's start); nothing of stages 1-2 is re-run.
+`_state_bwd_pallas` walks the chunks in reverse with the state's cotangent
+in VMEM and writes the cotangents of `_prepare`'s six results (Qg, W, Uv, B,
+Kd, gamma) heads first. `_prepare_bwd_pallas` then differentiates stages 1-2
+by hand, one chunk a grid step: dM = dW (K exp G)^T + dUv V^T; the UT
+transform in closed form, dL = -X^T dX X^T with dX = dM Diag(beta)
+(`_ut_cotangents`: two matmuls, not the six steps transposed); and the sums
+A and B transposed against the same sub-blocks, reference points and
+clamped exponents as the forward, so that no channel-by-channel tensor
+leaves VMEM. Since A and B do not depend on a reference point, G's cotangent
+is q dq + k dk (rows) - k dk (columns) there, summed in reverse inside the
+chunk for g's.
 
 The kernels run where they compile (a TPU target) and, for the tests, under
 the interpreter (FORCE_INTERPRET, as in ops/flash_pallas.py). Elsewhere the
-forward is `_prepare` with `_states_xla` and the backward's first part
-`_prepare` again with `_state_bwd_xla`, a reverse `lax.scan`: the CPU path,
-which nothing selects by hand, and the kernels' oracle in the tests.
+forward is `_prepare` with `_states_xla`, and the backward the reverse scan
+`_state_bwd_xla` fed by `_prepare`, then `jax.vjp` of `_prepare` (the same
+mathematics in jax.numpy) BACKWARD_GROUP chunks of every head at a time: the
+CPU path, which nothing selects by hand, and the kernels' oracle in the
+tests. Each traced backward appends its path to TRACED_BACKWARD.
 """
 
 from __future__ import annotations
@@ -65,13 +71,22 @@ from kubeflow_tpu.ops.pallas_compat import sds_with_vma as _sds
 
 CHUNK = 64
 SUB = 16          # rows of a sub-block: one reference point each
-# chunks of every head that the backward differentiates `_prepare` for at
-# once (`back`; off the TPU also the `_prepare` ahead of the reverse scan)
+# off the TPU: chunks of every head that the backward differentiates
+# `_prepare` for at once (`back`, and the `_prepare` ahead of the scan)
 BACKWARD_GROUP = 8
 HIGHEST = jax.lax.Precision.HIGHEST
 
 # Tests on the CPU set this to run the kernels under the Pallas interpreter.
 FORCE_INTERPRET = False
+
+#: the path each traced backward took, "kernel" or "xla": a Trainer reads
+#: the slice its own step's trace added (as flash_attention.TRACED_CENSUS)
+TRACED_BACKWARD: list[str] = []
+
+
+def _mm(x, y, dims, precision=None):
+    return jax.lax.dot_general(x, y, (dims, ((), ())), precision=precision,
+                               preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -194,9 +209,10 @@ def _intra_xla(q, k, gc, mm_dtype):
 # ---------------------------------------------------------------------------
 
 def _ut_transform(a, beta):
-    """M = (I + Diag(beta) A)^-1 Diag(beta) for A strictly lower, [.., C, C].
-    The inverse of a unit lower triangle by halves: with the diagonal blocks
-    of size b inverted (X), those of size 2b are X - X L21 X."""
+    """-> M = X Diag(beta) and X = (I + Diag(beta) A)^-1 for A strictly
+    lower, [.., C, C]. The inverse of a unit lower triangle by halves: with
+    the diagonal blocks of size b inverted (X), those of size 2b are
+    X - X L21 X."""
     chunk = a.shape[-1]
     r = jnp.arange(chunk)[:, None]
     c = jnp.arange(chunk)[None, :]
@@ -210,17 +226,28 @@ def _ut_transform(a, beta):
             mid = jnp.matmul(jnp.where(l21, low, 0.0), x, precision=HIGHEST)
             x = x - jnp.matmul(x, mid, precision=HIGHEST)
             b *= 2
-        return x * beta[..., None, :]
+        return x * beta[..., None, :], x
+
+
+def _ut_cotangents(x, beta, dm):
+    """Stage 2 differentiated in closed form, one chunk: X and beta [1, C]
+    as `_ut_transform` took and made them and the cotangent of M = X
+    Diag(beta) -> that of L = Diag(beta) A, dL = -X^T dX X^T with dX = dM
+    Diag(beta), masked to the strict lower triangle, and beta's through M's
+    columns, sum_r dM_rc X_rc [1, C]. A's cotangent is then Diag(beta) dL,
+    and beta's takes sum_c dL_rc A_rc besides. Two matmuls at HIGHEST in
+    place of the six steps re-run and transposed."""
+    chunk = x.shape[-1]
+    r = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    xt_dx = _mm(x, dm * beta, ((0,), (0,)), HIGHEST)          # X^T dX
+    dl = -_mm(xt_dx, x, ((1,), (1,)), HIGHEST)                # .. X^T
+    return jnp.where(c < r, dl, 0.0), jnp.sum(dm * x, axis=0, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
 # stage 3: the chunks in order
 # ---------------------------------------------------------------------------
-
-def _mm(x, y, dims):
-    return jax.lax.dot_general(x, y, (dims, ((), ())),
-                               preferred_element_type=jnp.float32)
-
 
 def _walk_operands(q_ref, k_ref, v_ref, g_ref, m_ref, st, chunk, mm_dtype):
     """What either walk over the chunks forms in VMEM from a chunk's blocks
@@ -319,13 +346,12 @@ def _state_bwd_kernel(q_ref, k_ref, v_ref, g_ref, m_ref, b_ref, h_ref, do_ref,
                  - _mm(dub, w, ((0,), (0,))))
 
 
-def _state_bwd_pallas(q, k, v, gc, m, b, h, do, *, group, interpret,
-                      mm_dtype):
+def _state_bwd_pallas(q, k, v, gc, m, b, h, do, *, interpret, mm_dtype):
     """Stage 3's cotangents, chunks in reverse: the operands of
     `_state_pallas`, the state S^T at every chunk's start (h) and the
     cotangent of o -> those of `_prepare`'s (Qg, W, Uv, B, Kd, gamma),
-    float32, written where `_backward`'s groups of chunks read them:
-    [NC / group, BH * group, C, ..] (gamma [.., dk])."""
+    float32, heads first as `_prepare_bwd_pallas` reads them: [BH, NC, C, ..]
+    (gamma [BH, NC, dk])."""
     bh, s, dk = q.shape
     dv = v.shape[-1]
     nc = s // CHUNK
@@ -334,16 +360,11 @@ def _state_bwd_pallas(q, k, v, gc, m, b, h, do, *, group, interpret,
     def at(i, c):         # grid step c of head i is chunk nc - 1 - c
         return i, nc - 1 - c
 
-    def grouped(i, c):
-        i, c = at(i, c)
-        return c // group, i * group + c % group, 0, 0
-
     row = lambda d: pl.BlockSpec((1, CHUNK, d), lambda i, c: (*at(i, c), 0))
     per_chunk = lambda r, d: pl.BlockSpec((1, 1, r, d),
                                           lambda i, c: (*at(i, c), 0, 0))
-    out = lambda r, d: (pl.BlockSpec((1, 1, r, d), grouped),
-                        _sds((nc // group, bh * group, r, d), jnp.float32,
-                             *ins))
+    out = lambda r, d: (per_chunk(r, d),
+                        _sds((bh, nc, r, d), jnp.float32, *ins))
     out_specs, out_shape = zip(out(CHUNK, dk), out(CHUNK, dk), out(CHUNK, dv),
                                out(CHUNK, CHUNK), out(CHUNK, dk), out(1, dk))
     *d_ops, dgam = pl.pallas_call(
@@ -362,12 +383,183 @@ def _state_bwd_pallas(q, k, v, gc, m, b, h, do, *, group, interpret,
     return (*d_ops, dgam[:, :, 0])
 
 
+# ---------------------------------------------------------------------------
+# stages 1-2, backward: the chunk sums and the UT transform by hand
+# ---------------------------------------------------------------------------
+
+def _mm_cot(x, y, dims, mm_dtype):
+    """A float32 cotangent (x or y) against an operand in mm_dtype (the
+    other): below float32 the cotangent goes to the MXU in two mm_dtype
+    parts, its rounding and what that left, so the product keeps ~16 bits
+    of it, sums in float32."""
+    if mm_dtype == jnp.float32:
+        return _mm(x, y, dims, HIGHEST)
+    f32 = jnp.float32
+
+    def split(t):
+        hi = t.astype(mm_dtype)
+        return hi, (t - hi.astype(f32)).astype(mm_dtype)
+
+    if x.dtype == f32:
+        return sum(_mm(p, y, dims) for p in split(x))
+    return sum(_mm(x, p, dims) for p in split(y))
+
+
+def _prepare_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, x_ref,
+                        dqg_ref, dw_ref, duv_ref, db_ref, dkd_ref, dgam_ref,
+                        dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, *, chunk,
+                        sub, mm_dtype):
+    f32 = jnp.float32
+    lo = lambda t: t.astype(mm_dtype)
+    q = q_ref[0].astype(f32)                  # [C, dk]
+    k = k_ref[0].astype(f32)
+    g = g_ref[0]                              # cumulative log-decay G, f32
+    beta = beta_ref[0, 0]                     # [1, C]
+    x = x_ref[0, 0]                           # X = (I + Diag(beta) A)^-1
+    ns = chunk // sub
+    dk = q.shape[-1]
+    r = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    beta_r = jnp.sum(jnp.where(r == c, beta, 0.0), axis=1,
+                     keepdims=True)           # the same, a column [C, 1]
+
+    # W = M (K exp G) and Uv = M V as the forward's walk formed them, then
+    # the closed form of stage 2
+    gam = jnp.exp(g)
+    m = lo(x * beta)
+    dw, duv = dw_ref[0, 0], duv_ref[0, 0]
+    dm = (_mm_cot(dw, lo(k * gam), ((1,), (1,)), mm_dtype)
+          + _mm_cot(duv, lo(v_ref[0]), ((1,), (1,)), mm_dtype))
+    d_kg = _mm_cot(m, dw, ((0,), (0,)), mm_dtype)             # M^T dW
+    dv_ref[0] = _mm_cot(m, duv, ((0,), (0,)), mm_dtype).astype(dv_ref.dtype)
+    dl, dbeta = _ut_cotangents(x, beta, dm)   # dA = Diag(beta) dL
+    same = (r // sub) == (c // sub)
+    db = jnp.where(c <= r, db_ref[0, 0], 0.0)
+
+    # pairs inside one sub-block: `_intra_kernel`'s column loop transposed,
+    # column i of every sub-block at once; dlk = sum_i dL_ri k_i e_ri, so
+    # that A's row side is Diag(beta) dlk and sum_c dL_rc A_rc = k_r . dlk_r
+    q3 = q.reshape(ns, sub, dk)
+    k3 = k.reshape(ns, sub, dk)
+    g3 = g.reshape(ns, sub, dk)
+    beta3 = beta_r.reshape(ns, sub, 1)
+    dl3 = jnp.where(same, dl, 0.0).reshape(ns, sub, chunk)
+    db3 = jnp.where(same, db, 0.0).reshape(ns, sub, chunk)
+    col = jax.lax.broadcasted_iota(jnp.int32, (ns, sub, chunk), 2)
+    blk = jax.lax.broadcasted_iota(jnp.int32, (ns, sub, chunk), 0)
+    at_row = jax.lax.broadcasted_iota(jnp.int32, (ns, sub, dk), 1)
+    dlk = jnp.zeros((ns, sub, dk), f32)
+    dq_in = jnp.zeros((ns, sub, dk), f32)
+    dk_col = jnp.zeros((ns, sub, dk), f32)
+    for i in range(sub):
+        here = col == blk * sub + i
+        dl_i = jnp.sum(jnp.where(here, dl3, 0.0), axis=-1, keepdims=True)
+        db_i = jnp.sum(jnp.where(here, db3, 0.0), axis=-1, keepdims=True)
+        e = jnp.exp(jnp.minimum(g3 - g3[:, i:i + 1, :], 0.0))
+        ek = e * k3[:, i:i + 1, :]
+        dlk = dlk + dl_i * ek
+        dq_in = dq_in + db_i * ek
+        to_i = jnp.sum((db_i * q3 + beta3 * dl_i * k3) * e, axis=1,
+                       keepdims=True)                         # [ns, 1, dk]
+        dk_col = jnp.where(at_row == i, to_i, dk_col)
+    dlk = dlk.reshape(chunk, dk)
+    dq_in = dq_in.reshape(chunk, dk)
+    dk_col = dk_col.reshape(chunk, dk)
+    dbeta_r = jnp.sum(k * dlk, axis=1, keepdims=True)         # [C, 1]
+
+    # pairs of different sub-blocks: `_intra_kernel`'s matmuls transposed,
+    # against the same rows and columns, mm_dtype operands
+    zeros = jnp.zeros((sub, dk), f32)
+    off_lk, off_q, off_beta = [zeros], [zeros], [jnp.zeros((sub, 1), f32)]
+    for i in range(1, ns):
+        rows = slice(i * sub, (i + 1) * sub)
+        ref = g[i * sub:i * sub + 1, :]
+        decay = jnp.exp(g[rows] - ref)
+        to_cols = jnp.exp(jnp.minimum(ref - g, 0.0))
+        cols = lo(k * to_cols)
+        rows_k, rows_q = lo(k[rows] * decay), lo(q[rows] * decay)
+        # (an iota of its own: Mosaic refuses a slice of one)
+        before = jax.lax.broadcasted_iota(jnp.int32, (sub, chunk), 1) < (
+            i * sub)
+        dl_i = jnp.where(before, dl[rows], 0.0)                # [sub, C]
+        db_i = jnp.where(before, db[rows], 0.0)
+        p = _mm_cot(jnp.concatenate([dl_i, db_i], axis=0), cols,
+                    ((1,), (0,)), mm_dtype)                    # [2 sub, dk]
+        d_cols = _mm_cot(jnp.concatenate([beta_r[rows] * dl_i, db_i], axis=0),
+                         jnp.concatenate([rows_k, rows_q], axis=0),
+                         ((0,), (0,)), mm_dtype)               # [C, dk]
+        off_lk.append(p[:sub] * decay)
+        off_q.append(p[sub:] * decay)
+        off_beta.append(jnp.sum(rows_k.astype(f32) * p[:sub], axis=1,
+                                keepdims=True))
+        dk_col = dk_col + d_cols * to_cols
+    dk_row = beta_r * (dlk + jnp.concatenate(off_lk, axis=0))
+    dq_in = dq_in + jnp.concatenate(off_q, axis=0)
+    dbeta_r = dbeta_r + jnp.concatenate(off_beta, axis=0)
+    dbeta = dbeta + jnp.sum(jnp.where(r == c, dbeta_r, 0.0), axis=0,
+                            keepdims=True)
+
+    # the elementwise terms, and G's cotangent: A and B do not depend on the
+    # reference points, so there G_r takes q_r dq_r + k_r dk_r (k_r as a
+    # row) - k_r dk_r (as a column); then g's, summed in reverse in the chunk
+    dqg, dkd = dqg_ref[0, 0], dkd_ref[0, 0]
+    g_last = g[chunk - 1:chunk, :]
+    to_last = jnp.exp(g_last - g)
+    kd = dkd * k * to_last                    # dKd * Kd
+    dq_ref[0] = (dqg * gam + dq_in).astype(dq_ref.dtype)
+    dk_ref[0] = (d_kg * gam + dkd * to_last + dk_row
+                 + dk_col).astype(dk_ref.dtype)
+    d_gc = (dqg * q * gam + d_kg * k * gam - kd + q * dq_in + k * dk_row
+            - k * dk_col)
+    last = jax.lax.broadcasted_iota(jnp.int32, (chunk, dk), 0) == chunk - 1
+    d_gc = d_gc + jnp.where(last, jnp.sum(kd, axis=0, keepdims=True)
+                            + dgam_ref[0, 0] * jnp.exp(g_last), 0.0)
+    dg_ref[0] = _mm_cot(lo(jnp.where(c >= r, 1.0, 0.0)), d_gc, ((1,), (0,)),
+                        mm_dtype)
+    dbeta_ref[0, 0] = dbeta
+
+
+def _prepare_bwd_pallas(q, k, v, gc, beta, x, d_ops, *, interpret,
+                        mm_dtype):
+    """Stages 1-2 differentiated by hand, chunk by chunk, fed by the forward's
+    X and stage 3's six cotangents (`_state_bwd_pallas`) -> those of q, k, v
+    (in their dtypes), g [BH, S, dk] and beta [BH, S] (float32)."""
+    bh, s, dk = q.shape
+    dv = v.shape[-1]
+    nc = s // CHUNK
+    dqg, dw, duv, db, dkd, dgam = d_ops
+    ins = (q, k, v, gc, beta.reshape(bh, nc, 1, CHUNK), x, dqg, dw, duv, db,
+           dkd, dgam.reshape(bh, nc, 1, dk))
+    row = lambda d: pl.BlockSpec((1, CHUNK, d), lambda i, c: (i, c, 0))
+    per_chunk = lambda r, d: pl.BlockSpec((1, 1, r, d),
+                                          lambda i, c: (i, c, 0, 0))
+    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
+        functools.partial(_prepare_bwd_kernel, chunk=CHUNK, sub=SUB,
+                          mm_dtype=mm_dtype),
+        grid=(bh, nc),
+        in_specs=[row(dk), row(dk), row(dv), row(dk), per_chunk(1, CHUNK),
+                  per_chunk(CHUNK, CHUNK), per_chunk(CHUNK, dk),
+                  per_chunk(CHUNK, dk), per_chunk(CHUNK, dv),
+                  per_chunk(CHUNK, CHUNK), per_chunk(CHUNK, dk),
+                  per_chunk(1, dk)],
+        out_specs=[row(dk), row(dk), row(dv), row(dk), per_chunk(1, CHUNK)],
+        out_shape=[_sds(q.shape, q.dtype, *ins), _sds(k.shape, k.dtype, *ins),
+                   _sds(v.shape, v.dtype, *ins),
+                   _sds(gc.shape, jnp.float32, *ins),
+                   _sds((bh, nc, 1, CHUNK), jnp.float32, *ins)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(*ins)
+    return dq, dk_, dv_, dg, dbeta.reshape(bh, s)
+
+
 def _prepare(q, k, v, g, beta, mm_dtype):
     """Stages 1-2 and the operands of stage 3 in jax.numpy, for chunks
     [n, C, d]: (Qg, W, Uv, B, Kd, gamma). Differentiated by the backward."""
     gc = jnp.cumsum(g, axis=1)
     a, b = _intra_xla(q, k, gc, mm_dtype)
-    m = _ut_transform(a, beta).astype(mm_dtype)
+    m = _ut_transform(a, beta)[0].astype(mm_dtype)
     gam = jnp.exp(gc)
     kf = k.astype(jnp.float32)
     mm = functools.partial(jnp.matmul, precision=HIGHEST,
@@ -451,8 +643,8 @@ def _cumulative(g):
 
 def _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, emit_states):
     """-> o, the state S^T at every chunk's start (if asked for), and on the
-    kernels' path the M and B that stage 3 read (the backward reads them
-    again)."""
+    kernels' path the M and B that stage 3 read and X (the backward reads
+    them again)."""
     bh, s, dk = q.shape
     nc = s // CHUNK
     if not pallas:
@@ -463,10 +655,10 @@ def _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, emit_states):
                 jnp.swapaxes(h, -1, -2), None)
     gc = _cumulative(g)
     a, b = _intra_pallas(q, k, gc, interpret=interpret, mm_dtype=mm_dtype)
-    m = _ut_transform(a, beta.reshape(bh, nc, CHUNK))
+    m, x = _ut_transform(a, beta.reshape(bh, nc, CHUNK))
     o, h = _state_pallas(q, k, v, gc, m, b, emit_states=emit_states,
                          interpret=interpret, mm_dtype=mm_dtype)
-    return o, h, (m, b)
+    return o, h, (m, b, x)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -475,21 +667,30 @@ def _kda(q, k, v, g, beta, pallas, interpret, mm_dtype):
 
 
 def _kda_fwd(q, k, v, g, beta, pallas, interpret, mm_dtype):
-    o, h, mb = _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, True)
-    return o, (q, k, v, g, beta, h, mb)
+    o, h, mbx = _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, True)
+    return o, (q, k, v, g, beta, h, mbx)
 
 
 def _kda_bwd(pallas, interpret, mm_dtype, res, do):
+    TRACED_BACKWARD.append("kernel" if pallas else "xla")
     with jax.named_scope("kda_backward"):
         return _backward(pallas, interpret, mm_dtype, res, do)
 
 
 def _backward(pallas, interpret, mm_dtype, res, do):
-    """One state per chunk (h, written by the forward); the cotangents of
-    stage 3 by hand, chunks in reverse (the kernel, fed by the forward's M
-    and B; off the TPU the scan, fed by `_prepare`); stages 1-2
+    """One state per chunk (h, written by the forward). On the kernels'
+    path two kernels: stage 3's cotangents, chunks in reverse, fed by the
+    forward's M and B, then stages 1-2's, chunk by chunk, fed by its X.
+    Elsewhere the reverse scan, fed by `_prepare`, then stages 1-2
     differentiated by JAX, BACKWARD_GROUP chunks of every head at a time."""
-    q, k, v, g, beta, h, mb = res
+    q, k, v, g, beta, h, mbx = res
+    if pallas:
+        m, b, x = mbx
+        gc = _cumulative(g)
+        d_ops = _state_bwd_pallas(q, k, v, gc, m, b, h, do,
+                                  interpret=interpret, mm_dtype=mm_dtype)
+        return _prepare_bwd_pallas(q, k, v, gc, beta, x, d_ops,
+                                   interpret=interpret, mm_dtype=mm_dtype)
     bh, s, dk = q.shape
     nc = s // CHUNK
     group = min(BACKWARD_GROUP, nc)
@@ -510,23 +711,16 @@ def _backward(pallas, interpret, mm_dtype, res, do):
 
     xs = tuple(grouped(x) for x in (q, k, v, g, beta))
     prepare = lambda *x: _prepare(*x, mm_dtype)
-    if pallas:
-        d_ops = _state_bwd_pallas(q, k, v, _cumulative(g), *mb, h, do,
-                                  group=group, interpret=interpret,
-                                  mm_dtype=mm_dtype)
-    else:
-        ops = jax.tree.map(from_groups,
-                           jax.lax.map(lambda x: prepare(*x), xs))
-        d_ops = _state_bwd_xla(
-            ops, jnp.swapaxes(h, -1, -2),            # S [BH, NC, dk, dv]
-            do.astype(jnp.float32).reshape(bh, nc, CHUNK, -1), mm_dtype)
-        d_ops = jax.tree.map(to_groups, d_ops)
+    ops = jax.tree.map(from_groups, jax.lax.map(lambda x: prepare(*x), xs))
+    d_ops = _state_bwd_xla(
+        ops, jnp.swapaxes(h, -1, -2),                # S [BH, NC, dk, dv]
+        do.astype(jnp.float32).reshape(bh, nc, CHUNK, -1), mm_dtype)
 
     def back(x):
         ins, cts = x
         return jax.vjp(prepare, *ins)[1](cts)
 
-    grads = jax.lax.map(back, (xs, d_ops))
+    grads = jax.lax.map(back, (xs, jax.tree.map(to_groups, d_ops)))
 
     return tuple(from_groups(x).reshape(like.shape).astype(like.dtype)
                  for x, like in zip(grads, (q, k, v, g, beta)))

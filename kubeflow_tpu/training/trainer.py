@@ -19,7 +19,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from kubeflow_tpu.models import registry
 from kubeflow_tpu.obs.trace import TRAINER_PHASES, PhaseClock, PhaseMark
-from kubeflow_tpu.ops import flash_attention
+from kubeflow_tpu.ops import flash_attention, kda
 from kubeflow_tpu.parallel import (
     MeshConfig,
     active_mesh,
@@ -184,6 +184,8 @@ class Trainer:
         # found: ops/flash_pallas.block_census's (interior, diagonal,
         # future) tiles a head, one entry a traced call
         self.attention_census: list[tuple[int, int, int]] = []
+        # and the path each traced KDA backward took ("kernel" or "xla")
+        self.kda_backward_census: list[str] = []
 
     # -- state ---------------------------------------------------------------
 
@@ -306,10 +308,12 @@ class Trainer:
             # ambient mesh for shard_map islands (ring/Ulysses attention,
             # MoE all-to-all) traced inside the jitted step
             seen = len(flash_attention.TRACED_CENSUS)
+            seen_kda = len(kda.TRACED_BACKWARD)
             with active_mesh(self.mesh), overlap.count_sites() as traced:
                 out = jitted(state, batch)
             self.overlapped_sites |= traced
             self.attention_census += flash_attention.TRACED_CENSUS[seen:]
+            self.kda_backward_census += kda.TRACED_BACKWARD[seen_kda:]
             return out
 
         return step
@@ -456,6 +460,12 @@ class Trainer:
                         c[1] for c in self.attention_census)
                     scalars["attention_interior_tile_share"] = (
                         interior / visited if visited else 0.0)
+                    # of the traced KDA backwards, those that ran the
+                    # kernels (0 where none was traced)
+                    census = self.kda_backward_census
+                    scalars["kda_backward_kernel_share"] = (
+                        census.count("kernel") / len(census) if census
+                        else 0.0)
                     first_interval = False
                 self.metrics.write(step, scalars)
                 if step_callback:

@@ -66,10 +66,12 @@ def test_forward_matches_the_recurrence(path):
         jnp.max(jnp.abs(ref)))
 
 
-# jitted: the interpreter walks the backward kernel's grid in seconds then
-def test_backward_matches_the_recurrence(path, monkeypatch):
+# jitted: the interpreter walks the backward kernels' grids in seconds then;
+# 160 positions are padded to three chunks with inert positions
+@pytest.mark.parametrize("s", [192, 160])
+def test_backward_matches_the_recurrence(path, monkeypatch, s):
     monkeypatch.setattr(kda, "BACKWARD_GROUP", 2)   # 3 chunks: groups of 1
-    args = inputs(1)
+    args = inputs(1, s=s)
     w = jax.random.normal(jax.random.key(9), args[2].shape)
     f = lambda *a: jnp.sum(kda.chunk_kda(*a, mm_dtype=jnp.float32) * w)
     got = jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4)))(*args)
@@ -80,48 +82,143 @@ def test_backward_matches_the_recurrence(path, monkeypatch):
             jnp.max(jnp.abs(b))), name
 
 
+def test_each_traced_backward_records_its_path(path):
+    """kda.TRACED_BACKWARD, which the Trainer's first record reads as
+    `kda_backward_kernel_share`: one entry a traced backward."""
+    seen = len(kda.TRACED_BACKWARD)
+    args = inputs(4, s=128)
+    f = lambda *a: jnp.sum(kda.chunk_kda(*a, mm_dtype=jnp.float32))
+    jax.make_jaxpr(jax.grad(f, argnums=(0, 3)))(*args)
+    assert kda.TRACED_BACKWARD[seen:] == [
+        "kernel" if path == "pallas" else "xla"]
+
+
 def heads_first(x):
     b, s, h = x.shape[:3]
     return jnp.moveaxis(x, 2, 1).reshape(b * h, s, *x.shape[3:])
 
 
-# the kernel rounds every operand where the scan does and sums in float32
-# as it does: float32 to 1e-5 of the largest value, bfloat16 to one rounding
-# of an operand (2^-8) over sums of 64 to 128 products of either sign
-@pytest.mark.parametrize("mm_dtype,strength,tol", [
-    (jnp.float32, 4.0, 1e-5), (jnp.bfloat16, 0.5, 4e-3)],
-    ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("group", [1, 3])
-def test_the_backward_kernel_matches_the_scan_it_replaces(
-        mm_dtype, strength, tol, group):
-    """Stage 3's six cotangents from `_state_bwd_pallas` (interpreted, fed
-    the operands of the forward's kernel) against `_state_bwd_xla` fed
-    `_prepare`'s results for the same chunks: 3 chunks, 2 heads, dk = dv =
-    32, the state's cotangent carried across the chunks, in the grouped
-    layout at both group sizes."""
-    q, k, v, g, beta = (heads_first(x) for x in inputs(5, strength=strength))
-    bh, s, _ = q.shape
-    nc = s // kda.CHUNK
-    gc = kda._cumulative(g)
-    chunked = [kda._chunks(x) for x in (q, k, v, g, beta)]
-    a, b = kda._intra_xla(chunked[0], chunked[1], kda._chunks(gc), mm_dtype)
-    m = kda._ut_transform(a, chunked[4])
+def stage3(seed, mm_dtype, strength, s=192, pad=0):
+    """Heads-first inputs (the last `pad` positions inert, as chunk_kda pads
+    them), `_prepare`'s results per chunk, the state at every chunk's start
+    and stage 3's six cotangents from the reverse scan for an output
+    cotangent that is 0 where the output is cut off."""
+    q, k, v, g, beta = (heads_first(x) for x in inputs(seed, s=s,
+                                                       strength=strength))
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    bh, n, _ = q.shape
+    nc = n // kda.CHUNK
     per_head = lambda x: x.reshape(bh, nc, *x.shape[1:])
+    chunked = [kda._chunks(x) for x in (q, k, v, g, beta)]
     ops = jax.tree.map(per_head, kda._prepare(*chunked, mm_dtype))
     st = kda._states_xla(ops, mm_dtype)[1]               # S [BH, NC, dk, dv]
     do = jax.random.normal(jax.random.key(7), v.shape)
-    want = kda._state_bwd_xla(ops, st, per_head(kda._chunks(do)), mm_dtype)
+    do = do.at[:, n - pad:].set(0.0) if pad else do
+    d_ops = kda._state_bwd_xla(ops, st, per_head(kda._chunks(do)), mm_dtype)
+    return (q, k, v, g, beta), chunked, st, do, d_ops
+
+
+# the kernel rounds every operand where the scan does and sums in float32
+# as it does: float32 to 1e-5 of the largest value, bfloat16 to one rounding
+# of an operand (2^-8) over sums of 64 to 128 products of either sign
+TOLERANCES = pytest.mark.parametrize("mm_dtype,strength,tol", [
+    (jnp.float32, 4.0, 1e-5), (jnp.bfloat16, 0.5, 4e-3)],
+    ids=["float32", "bfloat16"])
+
+
+@TOLERANCES
+@pytest.mark.parametrize("chunks", [1, 3])
+def test_the_backward_kernel_matches_the_scan_it_replaces(
+        mm_dtype, strength, tol, chunks):
+    """Stage 3's six cotangents from `_state_bwd_pallas` (interpreted, fed
+    the operands of the forward's kernel) against `_state_bwd_xla` fed
+    `_prepare`'s results for the same chunks: 2 heads, dk = dv = 32, the
+    state's cotangent carried across the chunks, heads first."""
+    (q, k, v, g, beta), chunked, st, do, want = stage3(
+        5, mm_dtype, strength, s=chunks * kda.CHUNK)
+    bh, nc = st.shape[:2]
+    gc = kda._cumulative(g)
+    a, b = kda._intra_xla(chunked[0], chunked[1], kda._chunks(gc), mm_dtype)
+    m = kda._ut_transform(a, chunked[4])[0]
+    per_head = lambda x: x.reshape(bh, nc, *x.shape[1:])
     got = kda._state_bwd_pallas(
         q, k, v, gc, per_head(m), per_head(b), jnp.swapaxes(st, -1, -2), do,
-        group=group, interpret=True, mm_dtype=mm_dtype)
+        interpret=True, mm_dtype=mm_dtype)
     # dKd = U dS^T: nothing reaches the last chunk's state, the earlier
     # chunks' states take a cotangent from the chunks after them
     assert not np.any(np.asarray(want[4][:, -1]))
-    assert float(jnp.max(jnp.abs(want[4][:, 0]))) > 1e-3
+    if chunks > 1:
+        assert float(jnp.max(jnp.abs(want[4][:, 0]))) > 1e-3
     for name, x, y in zip("Qg W Uv B Kd gamma".split(), got, want):
-        x = x.reshape(nc // group, bh, group, *x.shape[2:])
-        x = jnp.moveaxis(x, 0, 1).reshape(y.shape)
+        assert x.shape == y.shape, name
         assert float(jnp.max(jnp.abs(x - y))) <= tol * float(
+            jnp.max(jnp.abs(y))), name
+
+
+def stages12(mm_dtype, strength, pad=0):
+    """The cotangents of q, k, v, g and beta from `_prepare_bwd_pallas`
+    (interpreted, fed X and stage 3's cotangents) and from `jax.vjp` of
+    `_prepare` for the same chunks: 3 chunks, 2 heads, dk = dv = 32."""
+    args, chunked, st, do, d_ops = stage3(5, mm_dtype, strength,
+                                          s=192 - pad, pad=pad)
+    q, k, v, g, beta = args
+    bh, nc = st.shape[:2]
+    a = kda._intra_xla(chunked[0], chunked[1],
+                       kda._chunks(kda._cumulative(g)), mm_dtype)[0]
+    x = kda._ut_transform(a, chunked[4])[1].reshape(bh, nc, *a.shape[1:])
+    got = jax.jit(lambda *a: kda._prepare_bwd_pallas(
+        *a[:6], a[6:], interpret=True, mm_dtype=mm_dtype))(
+        q, k, v, kda._cumulative(g), beta, x, *d_ops)
+    cts = jax.tree.map(lambda t: t.reshape(bh * nc, *t.shape[2:]), d_ops)
+    want = jax.vjp(lambda *a: kda._prepare(*a, mm_dtype), *chunked)[1](cts)
+    return got, [w.reshape(x.shape) for w, x in zip(want, args)]
+
+
+@TOLERANCES
+def test_the_stages_1_2_kernel_matches_the_autodiff_it_replaces(
+        mm_dtype, strength, tol):
+    got, want = stages12(mm_dtype, strength)
+    for name, x, y in zip("q k v g beta".split(), got, want):
+        assert x.shape == y.shape and x.dtype == y.dtype, name
+        assert float(jnp.max(jnp.abs(x - y))) <= tol * float(
+            jnp.max(jnp.abs(y))), name
+
+
+def test_a_padded_tail_takes_finite_cotangents_and_zero_ones_where_inert():
+    """The last 32 positions as chunk_kda pads them (beta = 0, k = 0, the
+    output cut off): every cotangent finite, those of the padded positions
+    0 (nothing depends on them), the rest as JAX's."""
+    got, want = stages12(jnp.float32, 4.0, pad=32)
+    for name, x, y in zip("q k v g beta".split(), got, want):
+        assert np.all(np.isfinite(np.asarray(x))), name
+        assert not np.any(np.asarray(x[:, -32:])), name
+        assert float(jnp.max(jnp.abs(x - y))) <= 1e-5 * float(
+            jnp.max(jnp.abs(y))), name
+
+
+def test_the_closed_form_matches_the_transposed_solve():
+    """dL = -X^T dX X^T (`_ut_cotangents`), turned into the cotangents of A
+    and beta, against JAX's transpose of the six-step `_ut_transform`, in
+    float32, with one column of beta 0 (a padded position)."""
+    c = kda.CHUNK
+    ka, kb, km = jax.random.split(jax.random.key(11), 3)
+    a = jnp.tril(jax.random.normal(ka, (c, c)) * 0.3, -1)
+    beta = jax.nn.sigmoid(jax.random.normal(kb, (c,))).at[40].set(0.0)
+    dm = jax.random.normal(km, (c, c))
+    m, vjp = jax.vjp(lambda a, b: kda._ut_transform(a, b)[0], a, beta)
+    want_a, want_beta = vjp(dm)
+    x = kda._ut_transform(a, beta)[1]
+    dl, dbeta_c = kda._ut_cotangents(x, beta[None, :], dm)
+    got_a = beta[:, None] * dl
+    got_beta = dbeta_c[0] + jnp.sum(dl * a, axis=1)
+    assert float(jnp.max(jnp.abs(jnp.triu(dl)))) == 0.0
+    # beta = 0 still takes a cotangent through M's column (X is not M / beta)
+    assert abs(float(want_beta[40])) > 1e-2
+    for name, x, y in (("A", got_a, want_a), ("beta", got_beta, want_beta)):
+        assert float(jnp.max(jnp.abs(x - y))) <= 1e-5 * float(
             jnp.max(jnp.abs(y))), name
 
 
@@ -167,12 +264,12 @@ def test_the_solve_and_the_backward_carry_their_scopes():
     assert any(n.startswith("transpose(jvp(kda_solve))/") for n in names)
 
 
-def test_the_backward_kernel_carries_the_backwards_scope(monkeypatch):
-    """On the kernels' path (lowered for a TPU target from here) the chunk
-    walk is ONE Mosaic call under `kda_backward`, so the share the benchmark
-    reads by that scope still holds the whole backward; the only loop left
-    under the scope is the `lax.map` that differentiates `_prepare` in
-    groups of chunks (the solve's transpose runs inside it)."""
+def test_the_backward_kernels_carry_the_backwards_scope(monkeypatch):
+    """On the kernels' path (lowered for a TPU target from here) the
+    backward is TWO Mosaic calls under `kda_backward`, the chunk walk and
+    the chunk sums and UT transform differentiated by hand, so the share
+    the benchmark reads by that scope holds the whole backward: no loop is
+    left under the scope, and no solve is re-run or transposed."""
     from kubeflow_tpu.ops import pallas_compat
 
     monkeypatch.setattr(pallas_compat, "target_platform", lambda: "tpu")
@@ -196,11 +293,14 @@ def test_the_backward_kernel_carries_the_backwards_scope(monkeypatch):
         return found
 
     found = under_the_scope(jax.make_jaxpr(grad)(*args).jaxpr, [])
-    # q, k, v, gc, M, B, h, do into the kernel; `back`'s forward map
-    assert sorted(found) == [("pallas_call", 8, None), ("scan", 11, False)]
+    # q, k, v, gc, M, B, h, do into the walk; q, k, v, gc, beta, X and the
+    # walk's six cotangents into the second kernel
+    assert sorted(found) == [("pallas_call", 8, None),
+                             ("pallas_call", 12, None)]
     text = jax.jit(grad).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     names = set(re.findall(r'"([^"]*kda_[^"]*)"', text))
     assert "jit(<lambda>)/transpose(jvp(kda_backward))/pallas_call" in names
-    assert any(n.startswith("transpose(jvp(kda_solve))/") for n in names)
-    assert text.count("tpu_custom_call") == 3
+    assert not any("kda_solve" in n and "kda_backward" in n for n in names)
+    assert not any(n.startswith("transpose(jvp(kda_solve))/") for n in names)
+    assert text.count("tpu_custom_call") == 4

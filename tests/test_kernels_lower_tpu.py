@@ -34,7 +34,7 @@ PALLAS_CALL_SITES = {
     "flash_prefill": 1,
     "quant_matmul": 1,   # one call site, two entries: both lowered below
     "flash_pallas": 3,
-    "kda": 3,
+    "kda": 4,
 }
 
 # chip_smoke.py's serving shapes: Llama-3-8B heads, 16 slots x 2048
@@ -304,28 +304,43 @@ def test_kda_kernels_lower(emit_states):
         rows, rows, rows, decay, square, square) == 1
 
 
-def kda_backward_call(group=8):
-    """The backward's chunk walk at the Kimi-Linear cut, lowered for TPU:
-    the operands of the forward's walk, the state at every chunk's start and
-    the output's cotangent."""
+KDA_COTANGENTS = sds((64, 128, 64, 128), jnp.float32)   # [BH, NC, C, dk]
+
+
+def kda_backward_call(kernel="state"):
+    """One of the backward's two kernels at the Kimi-Linear cut, lowered for
+    TPU: the chunk walk (`state`: the operands of the forward's walk, the
+    state at every chunk's start and the output's cotangent), or the chunk
+    sums and UT transform differentiated (`prepare`: q, k, v, the decay,
+    beta, X and the walk's six cotangents)."""
+    if kernel == "state":
+        return jax.jit(
+            lambda q, k, v, gc, m, b, h, do: kda._state_bwd_pallas(
+                q, k, v, gc, m, b, h, do, interpret=False,
+                mm_dtype=jnp.bfloat16)).trace(
+            KDA_ROWS, KDA_ROWS, KDA_ROWS, KDA_DECAY, KDA_SQUARE, KDA_SQUARE,
+            KDA_STATES, KDA_ROWS).lower(lowering_platforms=("tpu",))
+    d_ops = (KDA_COTANGENTS,) * 3 + (KDA_SQUARE, KDA_COTANGENTS,
+                                     sds((64, 128, 128), jnp.float32))
     return jax.jit(
-        lambda q, k, v, gc, m, b, h, do: kda._state_bwd_pallas(
-            q, k, v, gc, m, b, h, do, group=group, interpret=False,
+        lambda q, k, v, gc, beta, x, *d: kda._prepare_bwd_pallas(
+            q, k, v, gc, beta, x, d, interpret=False,
             mm_dtype=jnp.bfloat16)).trace(
-        KDA_ROWS, KDA_ROWS, KDA_ROWS, KDA_DECAY, KDA_SQUARE, KDA_SQUARE,
-        KDA_STATES, KDA_ROWS).lower(lowering_platforms=("tpu",))
+        KDA_ROWS, KDA_ROWS, KDA_ROWS, KDA_DECAY,
+        sds((64, 8192), jnp.float32), KDA_SQUARE,
+        *d_ops).lower(lowering_platforms=("tpu",))
 
 
-@pytest.mark.parametrize("group", [8, 1])
-def test_kda_backward_kernel_lowers(group):
-    # six cotangents in the layout of `group` chunks of every head together
-    lowered = kda_backward_call(group)
+@pytest.mark.parametrize("kernel,shapes", [
+    ("state", [(64, 128, 64, 128)] * 3 + [(64, 128, 64, 64),
+                                          (64, 128, 64, 128), (64, 128, 128)]),
+    ("prepare", [(64, 8192, 128)] * 4 + [(64, 8192)])])
+def test_kda_backward_kernel_lowers(kernel, shapes):
+    # the walk's six cotangents heads first, then those of q, k, v, g, beta
+    lowered = kda_backward_call(kernel)
     assert lowered.as_text().count("tpu_custom_call") == 1
-    shapes = [tuple(x.shape) for x in jax.tree.leaves(lowered.out_info)]
-    lead = (128 // group, 64 * group)
-    assert shapes == [lead + (64, 128)] * 3 + [lead + (64, 64),
-                                               lead + (64, 128),
-                                               lead + (128,)]
+    assert [tuple(x.shape) for x in jax.tree.leaves(lowered.out_info)] == (
+        shapes)
 
 
 def opcount_module(name):
@@ -354,17 +369,25 @@ def kernel_event_names(lowered, name):
                 lowered.as_text())]
 
 
-def test_kda_backward_kernel_is_not_read_as_a_forward_kernel():
+@pytest.mark.parametrize("kernel,operands", [
+    ("state", "bf16[64,8192,128],bf16[64,8192,128],bf16[64,8192,128],"
+              "f32[64,8192,128],f32[64,128,64,64],f32[64,128,64,64],"
+              "f32[64,128,128,128],bf16[64,8192,128])->f32["),
+    ("prepare", "bf16[64,8192,128],bf16[64,8192,128],bf16[64,8192,128],"
+                "f32[64,8192,128],f32[64,128,1,64],f32[64,128,64,64],"
+                "f32[64,128,64,128],f32[64,128,64,128],f32[64,128,64,128],"
+                "f32[64,128,64,64],f32[64,128,64,128],f32[64,128,1,128])"
+                "->bf16[64,8192,128],bf16[64,8192,128],bf16[64,8192,128],"
+                "f32[64,8192,128],f32[64,128,1,64]")])
+def test_kda_backward_kernels_are_not_read_as_forward_kernels(kernel,
+                                                              operands):
     """The benchmark tells the two forward kernels by their operands
     (benchmark/opcount/kda_chunk.py, three and six) and computes a roofline
-    share of the FORWARD from their time: the backward's walk takes eight,
-    so neither pattern may take it for one of them."""
+    share of the FORWARD from their time: the backward's kernels take eight
+    and twelve, so neither pattern may take either for one of them."""
     kc = opcount_module("kda_chunk")
-    name, = kernel_event_names(kda_backward_call(), "kda_backward.4")
-    assert name.startswith(
-        "kda_backward.4(bf16[64,8192,128],bf16[64,8192,128],"
-        "bf16[64,8192,128],f32[64,8192,128],f32[64,128,64,64],"
-        "f32[64,128,64,64],f32[64,128,128,128],bf16[64,8192,128])->f32[")
+    name, = kernel_event_names(kda_backward_call(kernel), "kda_backward.4")
+    assert name.startswith(f"kda_backward.4({operands}")
     assert not kc.INTRA.match(name) and not kc.STATE.match(name)
 
 

@@ -32,7 +32,12 @@ def test_bfloat16_compute_stays_near_the_float32_model(params, tokens):
     assert abs(float(loss) - float(want)) < 2e-2 * float(want)
 
 
-def test_a_trainer_step_trains_the_family_and_reports_the_counters():
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_a_trainer_step_trains_the_family_and_reports_the_counters(
+        monkeypatch, path):
+    """Two steps on the CPU's jax.numpy KDA or the kernels interpreted; the
+    first record says which backward the step's trace took."""
+    from kubeflow_tpu.ops import kda
     from kubeflow_tpu.parallel import MeshConfig
     from kubeflow_tpu.training.data import synthetic_tokens
     from kubeflow_tpu.training.trainer import Trainer, TrainerConfig
@@ -40,6 +45,7 @@ def test_a_trainer_step_trains_the_family_and_reports_the_counters():
     overrides = {f.name: getattr(CFG, f.name)
                  for f in dataclasses.fields(CFG)
                  if f.name not in ("dtype", "param_dtype")}
+    monkeypatch.setattr(kda, "FORCE_INTERPRET", path == "pallas")
     seen = []
     trainer = Trainer(TrainerConfig(
         model="kimi_linear", model_overrides=overrides, batch_size=2,
@@ -51,3 +57,6 @@ def test_a_trainer_step_trains_the_family_and_reports_the_counters():
                  "moe_expert_load_max_over_mean", "router_top1_share_max"):
         assert name in seen[-1]
     assert seen[-1]["moe_rows_dropped"] == 0
+    assert seen[0]["kda_backward_kernel_share"] == (
+        1.0 if path == "pallas" else 0.0)
+    assert "kda_backward_kernel_share" not in seen[1]
