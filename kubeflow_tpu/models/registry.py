@@ -67,8 +67,8 @@ def _populate() -> None:
 
 def _do_populate() -> None:
     from kubeflow_tpu.models import (bert, kimi_linear, laguna, llama, lora,
-                                     mnist_cnn, moe_llama, nas_cnn, resnet,
-                                     vit)
+                                     mnist_cnn, moe_llama, nas_cnn,
+                                     pangu_ultra_moe, resnet, vit)
 
     register("llama", ModelDef(llama.LlamaConfig, llama.init, llama.apply,
                                llama.loss_fn, llama.logical_axes))
@@ -84,6 +84,10 @@ def _do_populate() -> None:
     register("laguna", ModelDef(
         laguna.LagunaConfig, laguna.init, laguna.apply, laguna.loss_fn,
         laguna.logical_axes))
+    register("pangu_ultra_moe", ModelDef(
+        pangu_ultra_moe.PanguUltraMoEConfig, pangu_ultra_moe.init,
+        pangu_ultra_moe.apply, pangu_ultra_moe.loss_fn,
+        pangu_ultra_moe.logical_axes))
     register("mnist_cnn", ModelDef(mnist_cnn.MnistConfig, mnist_cnn.init,
                                    mnist_cnn.apply, mnist_cnn.loss_fn,
                                    mnist_cnn.logical_axes))

@@ -121,8 +121,9 @@ ATTENTION_IMPL = REGISTRY.gauge(
 ENGINE_STEP_COUNT = REGISTRY.gauge(
     "engine_step_counter",
     "What the served family's decode steps count (its STEP_COUNTERS: the "
-    "routed experts' assignments, expert visits, dropped rows, load), "
-    "folded over the chunks replayed so far",
+    "routed experts' assignments, expert visits, dropped rows, load, the "
+    "latent context rows read), folded over the chunks replayed so far, "
+    "and its cache_stats (the latent slab's bytes)",
     ["engine", "name"])
 
 # -- scheduler (scrape-hook fed) ----------------------------------------------

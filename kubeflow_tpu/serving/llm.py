@@ -2039,6 +2039,12 @@ class LLMEngine:
             impl=self.cfg.prefill_attention_impl)
         for name, v in self._step_counters().items():
             obs_metrics.ENGINE_STEP_COUNT.set(v, engine=self.role, name=name)
+        # ... and what the family says of its cache's layout (the latent
+        # slab's bytes): llama's says nothing
+        if self.cache is not None:
+            for name, v in self.family.cache_stats(self.cache).items():
+                obs_metrics.ENGINE_STEP_COUNT.set(v, engine=self.role,
+                                                  name=name)
         if self.kvcache is not None:
             st = self.kvcache.stats()
             obs_metrics.KV_FREE_BLOCKS.set(st["free_blocks"],

@@ -75,6 +75,30 @@ FAMILIES: dict[str, Family] = {
         "disaggregated": "the prefill->decode handoff moves one slab's "
                          "rows, the family has two",
     }),
+    "pangu_ultra_moe": Family(
+        "kubeflow_tpu.models.pangu_ultra_moe", "PanguUltraMoEConfig",
+        refuses={
+            "speculative": "the drafter would be the MTP module, which is "
+                           "not served: it needs the verified hidden "
+                           "state, a second latent cache and a verify "
+                           "step, and drafts nothing a verifier accepts "
+                           "from seeded weights",
+            "prefix_cache": "the radix cache holds [kv, head_dim] blocks, "
+                            "the family one latent row a token",
+            "kv_layout": "the block pool holds [kv, head_dim] blocks, the "
+                         "family one latent row a token",
+            "parallel": "the experts held here have no exchange with the "
+                        "other ranks' (one chip of an expert-parallel "
+                        "layer)",
+            "mesh": "the experts held here have no exchange with the other "
+                    "ranks' (one chip of an expert-parallel layer)",
+            "adapters": "the family's matmuls take no low-rank bypass",
+            "lora": "the family's matmuls take no low-rank bypass",
+            "quantize": "int8 experts need a grouped matmul that "
+                        "dequantizes its groups",
+            "disaggregated": "the prefill->decode handoff moves [kv, "
+                             "head_dim] rows, the family latent rows",
+        }),
 }
 
 
@@ -949,3 +973,9 @@ def _llama_runtime(name: str, uri: str | None = None,
 def _laguna_runtime(name: str, uri: str | None = None,
                     **config: Any) -> Model:
     return LLMModel(name, uri, **dict(config, family="laguna"))
+
+
+@serving_runtime("pangu_ultra_moe")
+def _pangu_ultra_moe_runtime(name: str, uri: str | None = None,
+                             **config: Any) -> Model:
+    return LLMModel(name, uri, **dict(config, family="pangu_ultra_moe"))

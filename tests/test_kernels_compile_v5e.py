@@ -1,5 +1,6 @@
 """The KDA kernels compiled by the REAL v5e compiler at the Kimi-Linear cut,
-from this CPU process: a v5e topology described, not attached (the
+and the served latent attention's two kernels at the openPangu cell's
+shapes, from this CPU process: a v5e topology described, not attached (the
 on-chip-measurement guide, section 2). tests/test_kernels_lower_tpu.py runs
 JAX's own Pallas->Mosaic lowering, which the interpreter's parity tests do
 not; this file runs Mosaic itself, which refused a slice of an iota that
@@ -10,13 +11,15 @@ process at a time may load the TPU's library, and a worker that cannot
 skips these tests instead of leaving the suite uncollected.
 """
 
+import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from kubeflow_tpu.ops import kda
+from kubeflow_tpu.ops import flash_pallas, kda, mla_decode
 
 
 @pytest.fixture(scope="module")
@@ -74,3 +77,85 @@ def test_kda_kernel_compiles_for_v5e(one_chip, kernel):
     fn, args = kernel_call(kernel, kimi_cut(one_chip))
     compiled = jax.jit(fn).lower(*args).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+# -- the served latent attention at the openPangu cell's shapes ---------------
+# 64 slots, 128 heads, a slab of 5 layers x 11,264 rows of 512 + 64 values
+# padded to 640 lanes
+
+def bench_module(path):
+    """benchmark/<path>.py, loaded by path."""
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_" + path.replace("/", "_"), os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", path + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def kernel_events(compiled):
+    """Each Mosaic call of a compiled program as the trace reader names its
+    event, `name(operand shapes)->result shapes` (lib/tracered.short_name):
+    the compiled text names the operands by reference, and their shapes
+    stand in `operand_layout_constraints`."""
+    shape = re.compile(r"\b([a-z]+\d*\[[\d,]*\])")
+    out = []
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        head, _, rest = line.strip().removeprefix("ROOT ").partition(" = ")
+        result = rest.partition(" custom-call(")[0]
+        operands = re.search(r"operand_layout_constraints=\{(.*?)\}, "
+                             r"(?:frontend_attributes|metadata)", rest)
+        out.append(f"{head.lstrip('%')}("
+                   f"{','.join(shape.findall(operands[1]))})"
+                   f"->{','.join(shape.findall(result))}")
+    return out
+
+
+@pytest.mark.parametrize("span", [128, 4096, 11264])
+def test_mla_decode_kernel_compiles_for_v5e(one_chip, span):
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+
+    def fn(q, slab, lengths):
+        return mla_decode.mla_decode_attention(
+            q, slab, lengths, layer=3, latent=512, scale=192 ** -0.5,
+            span=span, interpret=False)
+
+    compiled = jax.jit(fn).lower(sds((64, 128, 640), jnp.bfloat16),
+                                 sds((5, 64, 11264, 640), jnp.bfloat16),
+                                 sds((64,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    # the slab is the operand: no copy of it round the call (at 576 lanes
+    # the compiler copied all 4.6 GB of it into the layout Mosaic reads)
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "bf16[5,64,11264,640]" in line]
+    # mla_latent_decode_roofline finds the call by its operands
+    told = bench_module("opcount/mla_serve").decode_call
+    assert [told(e) for e in kernel_events(compiled)] == [
+        (64, 128, 640, 512)]
+
+
+@pytest.mark.parametrize("q_offset", [0, 7168])
+def test_mla_prefill_kernel_compiles_for_v5e(one_chip, q_offset):
+    """A 1024-row chunk after a cached prefix of `q_offset` rows: q and k
+    of 192 (padded to 256 lanes inside) beside values of 128."""
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    t = q_offset + 1024
+
+    def fn(q, k, v):
+        return flash_pallas.pallas_flash_attention(
+            q, k, v, causal=True, scale=192 ** -0.5, q_offset=q_offset,
+            interpret=False)
+
+    compiled = jax.jit(fn).lower(sds((1, 1024, 128, 192), jnp.bfloat16),
+                                 sds((1, t, 128, 192), jnp.bfloat16),
+                                 sds((1, t, 128, 128), jnp.bfloat16)
+                                 ).compile()
+    # mla_prefill_roofline finds the call by its operands
+    told = bench_module("opcount/mla_serve").prefill_call
+    assert [told(e) for e in kernel_events(compiled)] == [(128, 1024, t)]

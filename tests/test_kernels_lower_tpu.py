@@ -24,7 +24,7 @@ import pytest
 
 from kubeflow_tpu.models import llama
 from kubeflow_tpu.ops import (flash_decode, flash_pallas, flash_prefill, kda,
-                              pallas_compat, quant_matmul)
+                              mla_decode, pallas_compat, quant_matmul)
 
 #: pallas_call sites per ops module that this file lowers for TPU.
 #: scripts/check_kernels.py requires the counts to match the source, so a
@@ -35,6 +35,7 @@ PALLAS_CALL_SITES = {
     "quant_matmul": 1,   # one call site, two entries: both lowered below
     "flash_pallas": 3,
     "kda": 4,
+    "mla_decode": 1,
 }
 
 # chip_smoke.py's serving shapes: Llama-3-8B heads, 16 slots x 2048
@@ -244,6 +245,34 @@ def test_flash_pallas_lowers_with_qk_192_beside_v_128():
             q, k, v, causal=True, interpret=False).astype(jnp.float32))
 
     assert mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, v) == 3
+
+
+@pytest.mark.parametrize("span", [128, 2048])
+def test_mla_decode_lowers(span):
+    # the openPangu cell's slab at a smaller length: 64 slots, 128 heads,
+    # latent rows of 512 + 64 padded to 640 lanes
+    q = sds((64, 128, 640), jnp.bfloat16)
+    slab = sds((5, 64, 2048, 640), jnp.bfloat16)
+
+    def fn(q, slab, lengths):
+        return mla_decode.mla_decode_attention(
+            q, slab, lengths, layer=2, latent=512, scale=192 ** -0.5,
+            span=span, interpret=False)
+
+    assert mosaic_calls(fn, q, slab, sds((64,), jnp.int32)) == 1
+
+
+def test_flash_pallas_lowers_for_a_latent_continuation_chunk():
+    # the served latent prefill: a 1024-row chunk after 2048 cached rows
+    q = sds((1, 1024, 128, 192), jnp.bfloat16)
+    k = sds((1, 3072, 128, 192), jnp.bfloat16)
+    v = sds((1, 3072, 128, 128), jnp.bfloat16)
+
+    def fn(q, k, v):
+        return flash_pallas.pallas_flash_attention(
+            q, k, v, causal=True, q_offset=2048, interpret=False)
+
+    assert mosaic_calls(fn, q, k, v) == 1
 
 
 def _flash_grad_lowered(q, v):
