@@ -25,9 +25,11 @@ taken of the difference itself. No exponent anywhere is positive.
 
 Three stages, forward:
   1. `_intra_pallas`: A and B of every chunk (Pallas).
-  2. `_ut_transform`: M by block-recursive inversion of the unit lower
-     triangle (six steps of two 64 x 64 matmuls, float32, XLA), and the
-     inverse X = (I + Diag(beta) A)^-1 itself, M = X Diag(beta).
+  2. `_ut_pallas`: the inverse X = (I + Diag(beta) A)^-1 of the unit lower
+     triangle and M = X Diag(beta), UT_CHUNKS chunks a grid step in VMEM
+     (Pallas): the two diagonal blocks of 32 rows by forward substitution
+     on the vector unit, then the last step by halves, two 64 x 64 matmuls
+     on the MXU; float32, matmuls at HIGHEST.
   3. `_state_pallas`: chunks in order, the state carried in VMEM (Pallas);
      on the way it can write the state at every chunk's start.
 Stage 2 runs under the named scope `kda_solve` and the backward under
@@ -50,11 +52,13 @@ chunk for g's.
 
 The kernels run where they compile (a TPU target) and, for the tests, under
 the interpreter (FORCE_INTERPRET, as in ops/flash_pallas.py). Elsewhere the
-forward is `_prepare` with `_states_xla`, and the backward the reverse scan
-`_state_bwd_xla` fed by `_prepare`, then `jax.vjp` of `_prepare` (the same
-mathematics in jax.numpy) BACKWARD_GROUP chunks of every head at a time: the
-CPU path, which nothing selects by hand, and the kernels' oracle in the
-tests. Each traced backward appends its path to TRACED_BACKWARD.
+forward is `_prepare` with `_states_xla`, stage 2 there `_ut_transform` (by
+halves in six steps of two 64 x 64 matmuls, XLA), and the backward the
+reverse scan `_state_bwd_xla` fed by `_prepare`, then `jax.vjp` of
+`_prepare` (the same mathematics in jax.numpy) BACKWARD_GROUP chunks of
+every head at a time: the CPU path, which nothing selects by hand, and the
+kernels' oracle in the tests. Each traced forward appends its solve's path
+to TRACED_SOLVE, each traced backward its own to TRACED_BACKWARD.
 """
 
 from __future__ import annotations
@@ -74,6 +78,7 @@ SUB = 16          # rows of a sub-block: one reference point each
 # off the TPU: chunks of every head that the backward differentiates
 # `_prepare` for at once (`back`, and the `_prepare` ahead of the scan)
 BACKWARD_GROUP = 8
+UT_CHUNKS = 16    # chunks a grid step of `_ut_pallas` inverts
 HIGHEST = jax.lax.Precision.HIGHEST
 
 # Tests on the CPU set this to run the kernels under the Pallas interpreter.
@@ -82,6 +87,8 @@ FORCE_INTERPRET = False
 #: the path each traced backward took, "kernel" or "xla": a Trainer reads
 #: the slice its own step's trace added (as flash_attention.TRACED_CENSUS)
 TRACED_BACKWARD: list[str] = []
+#: the same for each traced forward's solve (stage 2)
+TRACED_SOLVE: list[str] = []
 
 
 def _mm(x, y, dims, precision=None):
@@ -227,6 +234,68 @@ def _ut_transform(a, beta):
             x = x - jnp.matmul(x, mid, precision=HIGHEST)
             b *= 2
         return x * beta[..., None, :], x
+
+
+def _ut_kernel(a_ref, beta_ref, m_ref, x_ref, *, chunk, half):
+    """Stage 2 for the chunks of one grid step, in VMEM: L = Diag(beta) A;
+    its two diagonal blocks of `half` rows inverted by forward substitution
+    on the vector unit (row i of every block is final at step i, and then
+    leaves its multiple of L's column i in the rows below it), then the
+    last step by halves on the MXU, X21 = -X22 L21 X11. Float32 throughout,
+    matmuls at HIGHEST."""
+    n = a_ref.shape[1]
+    beta = beta_ref[0]                                        # [n, C]
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, chunk, chunk), 1)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, chunk, chunk), 2)
+    beta_r = jnp.sum(jnp.where(r == c, beta[:, None, :], 0.0), axis=2,
+                     keepdims=True)                           # [n, C, 1]
+    low = beta_r * a_ref[0]
+
+    shape = (n, chunk // half, half, chunk)                   # [n, block, row, C]
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 3)
+    blk = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    l4 = jnp.where(col // half == blk, low.reshape(shape), 0.0)
+    x = jnp.where(col == blk * half + row, 1.0, 0.0)
+    for i in range(half - 1):
+        li = jnp.sum(jnp.where(col == blk * half + i, l4, 0.0), axis=-1,
+                     keepdims=True)                           # L's column i
+        # rows up to i are final: the update starts at the tile of 8 rows
+        # (float32's sublanes) that holds row i + 1
+        lo = (i + 1) // 8 * 8
+        below = x[:, :, lo:] - li[:, :, lo:] * x[:, :, i:i + 1]
+        x = jnp.concatenate([x[:, :, :lo], below], axis=2) if lo else below
+    x = x.reshape(n, chunk, chunk)
+
+    bmm = functools.partial(jax.lax.dot_general,
+                            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                            precision=HIGHEST,
+                            preferred_element_type=jnp.float32)
+    l21 = (r >= half) & (c < half)
+    x = x - bmm(x, bmm(jnp.where(l21, low, 0.0), x))
+    x_ref[0] = x
+    m_ref[0] = x * beta[:, None, :]
+
+
+def _ut_pallas(a, beta, *, interpret):
+    """Stage 2 as one kernel: A [BH, NC, C, C] as `_intra_pallas` writes it
+    and beta [BH, NC, C] -> M = X Diag(beta) and X = (I + Diag(beta) A)^-1,
+    float32 [BH, NC, C, C]; UT_CHUNKS chunks a grid step (the last step's
+    block may run past NC: its chunks there are never written)."""
+    bh, nc = a.shape[:2]
+    n = min(UT_CHUNKS, nc)
+    sq = pl.BlockSpec((1, n, CHUNK, CHUNK), lambda i, j: (i, j, 0, 0))
+    with jax.named_scope("kda_solve"):
+        return pl.pallas_call(
+            functools.partial(_ut_kernel, chunk=CHUNK, half=CHUNK // 2),
+            grid=(bh, pl.cdiv(nc, n)),
+            in_specs=[sq, pl.BlockSpec((1, n, CHUNK), lambda i, j: (i, j, 0))],
+            out_specs=[sq, sq],
+            out_shape=[_sds(a.shape, jnp.float32, a, beta)] * 2,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            interpret=interpret,
+        )(a, beta)
 
 
 def _ut_cotangents(x, beta, dm):
@@ -647,6 +716,7 @@ def _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, emit_states):
     them again)."""
     bh, s, dk = q.shape
     nc = s // CHUNK
+    TRACED_SOLVE.append("kernel" if pallas else "xla")
     if not pallas:
         ops = _prepare(*(_chunks(x) for x in (q, k, v, g, beta)), mm_dtype)
         ops = jax.tree.map(lambda x: x.reshape(bh, nc, *x.shape[1:]), ops)
@@ -655,7 +725,7 @@ def _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, emit_states):
                 jnp.swapaxes(h, -1, -2), None)
     gc = _cumulative(g)
     a, b = _intra_pallas(q, k, gc, interpret=interpret, mm_dtype=mm_dtype)
-    m, x = _ut_transform(a, beta.reshape(bh, nc, CHUNK))
+    m, x = _ut_pallas(a, beta.reshape(bh, nc, CHUNK), interpret=interpret)
     o, h = _state_pallas(q, k, v, gc, m, b, emit_states=emit_states,
                          interpret=interpret, mm_dtype=mm_dtype)
     return o, h, (m, b, x)

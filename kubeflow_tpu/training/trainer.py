@@ -184,8 +184,10 @@ class Trainer:
         # found: ops/flash_pallas.block_census's (interior, diagonal,
         # future) tiles a head, one entry a traced call
         self.attention_census: list[tuple[int, int, int]] = []
-        # and the path each traced KDA backward took ("kernel" or "xla")
+        # and the path each traced KDA backward and forward solve took
+        # ("kernel" or "xla")
         self.kda_backward_census: list[str] = []
+        self.kda_solve_census: list[str] = []
 
     # -- state ---------------------------------------------------------------
 
@@ -308,12 +310,13 @@ class Trainer:
             # ambient mesh for shard_map islands (ring/Ulysses attention,
             # MoE all-to-all) traced inside the jitted step
             seen = len(flash_attention.TRACED_CENSUS)
-            seen_kda = len(kda.TRACED_BACKWARD)
+            seen_kda = len(kda.TRACED_BACKWARD), len(kda.TRACED_SOLVE)
             with active_mesh(self.mesh), overlap.count_sites() as traced:
                 out = jitted(state, batch)
             self.overlapped_sites |= traced
             self.attention_census += flash_attention.TRACED_CENSUS[seen:]
-            self.kda_backward_census += kda.TRACED_BACKWARD[seen_kda:]
+            self.kda_backward_census += kda.TRACED_BACKWARD[seen_kda[0]:]
+            self.kda_solve_census += kda.TRACED_SOLVE[seen_kda[1]:]
             return out
 
         return step
@@ -460,12 +463,14 @@ class Trainer:
                         c[1] for c in self.attention_census)
                     scalars["attention_interior_tile_share"] = (
                         interior / visited if visited else 0.0)
-                    # of the traced KDA backwards, those that ran the
-                    # kernels (0 where none was traced)
-                    census = self.kda_backward_census
-                    scalars["kda_backward_kernel_share"] = (
-                        census.count("kernel") / len(census) if census
-                        else 0.0)
+                    # of the traced KDA backwards and forward solves,
+                    # those that ran the kernels (0 where none was traced)
+                    for part, census in (
+                            ("backward", self.kda_backward_census),
+                            ("solve", self.kda_solve_census)):
+                        scalars[f"kda_{part}_kernel_share"] = (
+                            census.count("kernel") / len(census) if census
+                            else 0.0)
                     first_interval = False
                 self.metrics.write(step, scalars)
                 if step_callback:

@@ -93,6 +93,86 @@ def test_each_traced_backward_records_its_path(path):
         "kernel" if path == "pallas" else "xla"]
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+def test_each_traced_forward_records_its_solves_path(path, grad):
+    """kda.TRACED_SOLVE, which the Trainer's first record reads as
+    `kda_solve_kernel_share`: one entry a traced forward, differentiated
+    or not."""
+    seen = len(kda.TRACED_SOLVE)
+    args = inputs(4, s=128)
+    f = lambda *a: jnp.sum(kda.chunk_kda(*a, mm_dtype=jnp.float32))
+    jax.make_jaxpr(jax.grad(f, argnums=(0, 3)) if grad else f)(*args)
+    assert kda.TRACED_SOLVE[seen:] == ["kernel" if path == "pallas" else "xla"]
+
+
+def path_output(what, args, interpret, monkeypatch):
+    """chunk_kda's output or its five cotangents (float32 operands, jitted)
+    on the CPU path or the kernels' path under the interpreter."""
+    monkeypatch.setattr(kda, "FORCE_INTERPRET", interpret)
+    w = jax.random.normal(jax.random.key(9), args[2].shape)
+    f = lambda *a: kda.chunk_kda(*a, mm_dtype=jnp.float32)
+    if what == "grad":
+        f = jax.grad(lambda *a: jnp.sum(
+            kda.chunk_kda(*a, mm_dtype=jnp.float32) * w),
+            argnums=(0, 1, 2, 3, 4))
+    return jax.tree.leaves(jax.jit(f)(*args))
+
+
+# the recurrence tests' tolerances: 160 positions, padded to three chunks
+@pytest.mark.parametrize("what,tol", [("forward", 2e-5), ("grad", 5e-5)])
+def test_the_kernels_path_matches_the_cpu_path(monkeypatch, what, tol):
+    args = inputs(6, s=160)
+    want = path_output(what, args, False, monkeypatch)
+    got = path_output(what, args, True, monkeypatch)
+    assert len(got) == len(want) == (5 if what == "grad" else 1)
+    for x, y in zip(got, want):
+        assert x.shape == y.shape
+        assert float(jnp.max(jnp.abs(x - y))) <= tol * float(
+            jnp.max(jnp.abs(y)))
+
+
+def solve_inputs(nc, strength, dk, beta_min):
+    """A as the forward's chunk sums make it from unit keys of dk channels
+    under a decay of the given strength, and beta uniform in [beta_min, 1)
+    but for one position of the last chunk, 0 as a padded one is: [1, nc,
+    C, C] and [1, nc, C]."""
+    c = kda.CHUNK
+    kk, kg, kb = jax.random.split(jax.random.key(12), 3)
+    k = jax.random.normal(kk, (nc, c, dk))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -strength * jax.nn.softplus(jax.random.normal(kg, (nc, c, dk)))
+    a = kda._intra_xla(k, k, jnp.cumsum(g, axis=1), jnp.float32)[0]
+    beta = jax.random.uniform(kb, (nc, c), minval=beta_min, maxval=1.0)
+    return a[None], beta.at[nc - 1, 40].set(0.0)[None]
+
+
+# the substitution and one step by halves against the six steps by halves:
+# the same inverse in float32, to 1e-5 of the largest entry (measured 5e-10
+# to 2.4e-7 over these and stronger cases)
+@pytest.mark.parametrize("nc,strength,dk,beta_min", [
+    (3, 4.0, 32, 0.0),
+    (1, 0.0, 2, 0.999),
+    (20, 0.01, 4, 0.99)],
+    ids=["strong-decay", "one-chunk-no-decay", "a-grid-step-past-the-end"])
+def test_the_solve_kernel_matches_the_six_steps_it_replaces(
+        nc, strength, dk, beta_min):
+    """`_ut_pallas` (interpreted) against `_ut_transform`: a decay that
+    leaves A near 0 across its sub-blocks; one chunk of nearly parallel keys
+    with beta near 1, where X's entries reach 1; 20 chunks, two grid steps
+    of UT_CHUNKS, the second past the last chunk."""
+    assert nc < kda.UT_CHUNKS or nc % kda.UT_CHUNKS
+    a, beta = solve_inputs(nc, strength, dk, beta_min)
+    want = kda._ut_transform(a, beta)
+    got = kda._ut_pallas(a, beta, interpret=True)
+    for name, x, y in zip("MX", got, want):
+        assert x.shape == y.shape and x.dtype == y.dtype == jnp.float32
+        assert float(jnp.max(jnp.abs(x - y))) <= 1e-5 * float(
+            jnp.max(jnp.abs(y))), name
+    # beta = 0 zeroes M's column, not X's
+    assert not np.any(np.asarray(got[0][0, -1, :, 40]))
+    assert float(jnp.max(jnp.abs(got[1][0, -1, 41:, 40]))) > 1e-3
+
+
 def heads_first(x):
     b, s, h = x.shape[:3]
     return jnp.moveaxis(x, 2, 1).reshape(b * h, s, *x.shape[3:])
@@ -269,7 +349,9 @@ def test_the_backward_kernels_carry_the_backwards_scope(monkeypatch):
     backward is TWO Mosaic calls under `kda_backward`, the chunk walk and
     the chunk sums and UT transform differentiated by hand, so the share
     the benchmark reads by that scope holds the whole backward: no loop is
-    left under the scope, and no solve is re-run or transposed."""
+    left under the scope, and no solve is re-run or transposed. The
+    forward's solve is ONE Mosaic call of its own under `kda_solve`, so the
+    share read by that scope holds the whole solve."""
     from kubeflow_tpu.ops import pallas_compat
 
     monkeypatch.setattr(pallas_compat, "target_platform", lambda: "tpu")
@@ -278,29 +360,33 @@ def test_the_backward_kernels_carry_the_backwards_scope(monkeypatch):
     f = lambda *a: jnp.sum(kda.chunk_kda(*a, mm_dtype=jnp.float32))
     grad = jax.grad(f, argnums=(0, 3))
 
-    def under_the_scope(jaxpr, found):
+    def under_the_scope(jaxpr, scope, found):
         """(primitive, operands, reverse) of every loop and kernel whose
         name stack holds the scope, loops' bodies not entered."""
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
-            if "kda_backward" in str(eqn.source_info.name_stack) and name in (
+            if scope in str(eqn.source_info.name_stack) and name in (
                     "scan", "while", "pallas_call"):
                 found.append((name, len(eqn.invars),
                               eqn.params.get("reverse")))
             elif name not in ("scan", "while"):
                 for sub in jax.core.jaxprs_in_params(eqn.params):
-                    under_the_scope(sub, found)
+                    under_the_scope(sub, scope, found)
         return found
 
-    found = under_the_scope(jax.make_jaxpr(grad)(*args).jaxpr, [])
+    jaxpr = jax.make_jaxpr(grad)(*args).jaxpr
     # q, k, v, gc, M, B, h, do into the walk; q, k, v, gc, beta, X and the
-    # walk's six cotangents into the second kernel
-    assert sorted(found) == [("pallas_call", 8, None),
-                             ("pallas_call", 12, None)]
+    # walk's six cotangents into the second kernel; A and beta into the solve
+    assert sorted(under_the_scope(jaxpr, "kda_backward", [])) == [
+        ("pallas_call", 8, None), ("pallas_call", 12, None)]
+    assert under_the_scope(jaxpr, "kda_solve", []) == [
+        ("pallas_call", 2, None)]
     text = jax.jit(grad).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     names = set(re.findall(r'"([^"]*kda_[^"]*)"', text))
     assert "jit(<lambda>)/transpose(jvp(kda_backward))/pallas_call" in names
+    assert "jit(<lambda>)/jvp(kda_solve)/pallas_call" in names
     assert not any("kda_solve" in n and "kda_backward" in n for n in names)
     assert not any(n.startswith("transpose(jvp(kda_solve))/") for n in names)
-    assert text.count("tpu_custom_call") == 4
+    # intra, solve and walk forward, the two backward kernels
+    assert text.count("tpu_custom_call") == 5

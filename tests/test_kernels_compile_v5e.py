@@ -38,12 +38,14 @@ def one_chip():
 
 def kimi_cut(sharding):
     """2 rows x 32 heads of 8192 positions, dk = dv = 128: rows, decay,
-    beta, square ([BH, NC, 64, 64]), per-chunk rows, states, gamma."""
+    beta (also by chunk), square ([BH, NC, 64, 64]), per-chunk rows, states,
+    gamma."""
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                     sharding=sharding)
     return dict(rows=sds((64, 8192, 128), jnp.bfloat16),
                 decay=sds((64, 8192, 128), jnp.float32),
                 beta=sds((64, 8192), jnp.float32),
+                beta_chunks=sds((64, 128, 64), jnp.float32),
                 square=sds((64, 128, 64, 64), jnp.float32),
                 chunk_rows=sds((64, 128, 64, 128), jnp.float32),
                 states=sds((64, 128, 128, 128), jnp.float32),
@@ -51,8 +53,11 @@ def kimi_cut(sharding):
 
 
 def kernel_call(kernel, s):
-    """(function, abstract operands) of one of the four KDA kernels."""
+    """(function, abstract operands) of one of the five KDA kernels."""
     kw = dict(interpret=False, mm_dtype=jnp.bfloat16)
+    if kernel == "solve":
+        return (lambda a, beta: kda._ut_pallas(a, beta, interpret=False),
+                (s["square"], s["beta_chunks"]))
     if kernel == "intra":
         return (lambda q, k, gc: kda._intra_pallas(q, k, gc, **kw),
                 (s["rows"], s["rows"], s["decay"]))
@@ -72,7 +77,7 @@ def kernel_call(kernel, s):
 
 
 @pytest.mark.parametrize("kernel", ["intra", "state", "state_bwd",
-                                    "prepare_bwd"])
+                                    "prepare_bwd", "solve"])
 def test_kda_kernel_compiles_for_v5e(one_chip, kernel):
     fn, args = kernel_call(kernel, kimi_cut(one_chip))
     compiled = jax.jit(fn).lower(*args).compile()

@@ -34,7 +34,7 @@ PALLAS_CALL_SITES = {
     "flash_prefill": 1,
     "quant_matmul": 1,   # one call site, two entries: both lowered below
     "flash_pallas": 3,
-    "kda": 4,
+    "kda": 5,
     "mla_decode": 1,
 }
 
@@ -372,6 +372,22 @@ def test_kda_backward_kernel_lowers(kernel, shapes):
         shapes)
 
 
+def kda_solve_call():
+    """The forward's solve at the Kimi-Linear cut, lowered for TPU: A of
+    every chunk as the chunk-sum kernel writes it and beta [BH, NC, C]."""
+    return jax.jit(lambda a, beta: kda._ut_pallas(a, beta, interpret=False)
+                   ).trace(KDA_SQUARE, sds((64, 128, 64), jnp.float32)
+                           ).lower(lowering_platforms=("tpu",))
+
+
+def test_kda_solve_kernel_lowers():
+    # M and X of every chunk, float32, as the walk and the backward read them
+    lowered = kda_solve_call()
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert [(tuple(x.shape), x.dtype) for x in jax.tree.leaves(
+        lowered.out_info)] == [((64, 128, 64, 64), jnp.float32)] * 2
+
+
 def opcount_module(name):
     """benchmark/opcount/<name>.py, whose patterns tell a kernel in a
     capture by its operand list."""
@@ -417,6 +433,16 @@ def test_kda_backward_kernels_are_not_read_as_forward_kernels(kernel,
     kc = opcount_module("kda_chunk")
     name, = kernel_event_names(kda_backward_call(kernel), "kda_backward.4")
     assert name.startswith(f"kda_backward.4({operands}")
+    assert not kc.INTRA.match(name) and not kc.STATE.match(name)
+
+
+def test_kda_solve_kernel_is_not_read_as_a_forward_kernel():
+    """Nor is the forward's solve (two operands, both float32) one of the
+    two kernels whose time `kda_chunk_roofline` divides its count by."""
+    kc = opcount_module("kda_chunk")
+    name, = kernel_event_names(kda_solve_call(), "kda_solve.4")
+    assert name == ("kda_solve.4(f32[64,128,64,64],f32[64,128,64])"
+                    "->f32[64,128,64,64],f32[64,128,64,64]")
     assert not kc.INTRA.match(name) and not kc.STATE.match(name)
 
 

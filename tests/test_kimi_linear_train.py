@@ -36,7 +36,8 @@ def test_bfloat16_compute_stays_near_the_float32_model(params, tokens):
 def test_a_trainer_step_trains_the_family_and_reports_the_counters(
         monkeypatch, path):
     """Two steps on the CPU's jax.numpy KDA or the kernels interpreted; the
-    first record says which backward the step's trace took."""
+    first record says which backward and forward solve the step's trace
+    took."""
     from kubeflow_tpu.ops import kda
     from kubeflow_tpu.parallel import MeshConfig
     from kubeflow_tpu.training.data import synthetic_tokens
@@ -57,6 +58,7 @@ def test_a_trainer_step_trains_the_family_and_reports_the_counters(
                  "moe_expert_load_max_over_mean", "router_top1_share_max"):
         assert name in seen[-1]
     assert seen[-1]["moe_rows_dropped"] == 0
-    assert seen[0]["kda_backward_kernel_share"] == (
-        1.0 if path == "pallas" else 0.0)
-    assert "kda_backward_kernel_share" not in seen[1]
+    for part in ("backward", "solve"):
+        assert seen[0][f"kda_{part}_kernel_share"] == (
+            1.0 if path == "pallas" else 0.0)
+        assert f"kda_{part}_kernel_share" not in seen[1]
