@@ -68,7 +68,8 @@ def _populate() -> None:
 def _do_populate() -> None:
     from kubeflow_tpu.models import (bert, kimi_linear, laguna, llama, lora,
                                      mnist_cnn, moe_llama, nas_cnn,
-                                     pangu_ultra_moe, resnet, vit)
+                                     nemotron_h, pangu_ultra_moe, resnet,
+                                     vit)
 
     register("llama", ModelDef(llama.LlamaConfig, llama.init, llama.apply,
                                llama.loss_fn, llama.logical_axes))
@@ -88,6 +89,9 @@ def _do_populate() -> None:
         pangu_ultra_moe.PanguUltraMoEConfig, pangu_ultra_moe.init,
         pangu_ultra_moe.apply, pangu_ultra_moe.loss_fn,
         pangu_ultra_moe.logical_axes))
+    register("nemotron_h", ModelDef(
+        nemotron_h.NemotronHConfig, nemotron_h.init, nemotron_h.apply,
+        nemotron_h.loss_fn, nemotron_h.logical_axes))
     register("mnist_cnn", ModelDef(mnist_cnn.MnistConfig, mnist_cnn.init,
                                    mnist_cnn.apply, mnist_cnn.loss_fn,
                                    mnist_cnn.logical_axes))

@@ -122,8 +122,10 @@ ENGINE_STEP_COUNT = REGISTRY.gauge(
     "engine_step_counter",
     "What the served family's decode steps count (its STEP_COUNTERS: the "
     "routed experts' assignments, expert visits, dropped rows, load, the "
-    "latent context rows read), folded over the chunks replayed so far, "
-    "and its cache_stats (the latent slab's bytes)",
+    "latent context rows read, the recurrent states updated), folded over "
+    "the chunks replayed so far, its prompt_counters (the prompt tokens "
+    "through the state-space scans) and its cache_stats (the bytes of "
+    "each kind of cache)",
     ["engine", "name"])
 
 # -- scheduler (scrape-hook fed) ----------------------------------------------

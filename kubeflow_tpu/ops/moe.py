@@ -216,14 +216,20 @@ def _grouped_matmul(rows, w, group_sizes, dtype, tm=ROW_TILE):
 
 
 def moe_share_mlp(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
-                  w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                  w_gate: jax.Array | None, w_up: jax.Array, w_down: jax.Array,
                   args: ShareArgs, dtype: Any = jnp.bfloat16,
-                  layer: int | None = None):
+                  layer: int | None = None,
+                  expert_x: jax.Array | None = None):
     """The routed experts' part of a layer, as the rank that holds experts
     [first_expert, first_expert + n_held) computes it.
 
     x [B, S, D]; router_w [D, E_all]; router_bias [E_all] (a buffer: no
-    gradient); w_gate / w_up [n_held, D, F]; w_down [n_held, F, D]; or,
+    gradient); w_gate / w_up [n_held, D, F]; w_down [n_held, F, D]; an
+    expert is a SwiGLU, silu(x w_gate) * (x w_up) w_down, or with `w_gate`
+    None a squared ReLU, relu(x w_up)^2 w_down. The router reads x; the
+    experts read `expert_x` [B, S, D_e] where it is given (a latent the
+    caller projected x into: w_up is then [n_held, D_e, F] and w_down
+    [n_held, F, D_e], and so is the result), else x. Or,
     with `layer` (static), the STACKS of every layer's experts
     `[layers, n_held, ...]`, read in place: the grouped matmul takes the
     stack as `layers * n_held` groups whose sizes are zero outside this
@@ -235,15 +241,18 @@ def moe_share_mlp(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
     `top1_share_max` the largest share of tokens whose first choice is one
     expert, over all experts, `experts_touched` how many of the experts
     held took a row (each one's weights are read for them)."""
-    b, s, d = x.shape
+    b, s, _ = x.shape
     t, k, held = b * s, args.top_k, args.n_held
     before = after = 0
     if layer is not None:
-        before, after = layer * held, (w_gate.shape[0] - 1 - layer) * held
-        w_gate, w_up, w_down = (w.reshape((-1,) + w.shape[2:])
+        before, after = layer * held, (w_up.shape[0] - 1 - layer) * held
+        w_gate, w_up, w_down = (None if w is None
+                                else w.reshape((-1,) + w.shape[2:])
                                 for w in (w_gate, w_up, w_down))
     tile = row_tile(t * k)
-    xt = x.reshape(t, d)
+    xt = x.reshape(t, x.shape[-1])
+    rows_in = xt if expert_x is None else expert_x.reshape(t, -1)
+    d = w_down.shape[-1]
     with jax.named_scope("moe_route"):
         idx, w = sigmoid_route(xt, router_w, router_bias, args)
         local = idx - args.first_expert
@@ -285,15 +294,19 @@ def moe_share_mlp(x: jax.Array, router_w: jax.Array, router_bias: jax.Array,
             jnp.zeros((after,), here.dtype), (m - jnp.sum(here))[None]])
         mm = functools.partial(_grouped_matmul, group_sizes=groups,
                                dtype=dtype, tm=tile)
-        gate, up = mm(rows, w_gate), mm(rows, w_up)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(dtype)
+        if w_gate is None:
+            act = jnp.square(jax.nn.relu(
+                mm(rows, w_up).astype(jnp.float32))).astype(dtype)
+        else:
+            gate, up = mm(rows, w_gate), mm(rows, w_up)
+            act = (jax.nn.silu(gate.astype(jnp.float32))
+                   * up.astype(jnp.float32)).astype(dtype)
         wt = jnp.take(w_flat, jnp.minimum(sel, total - 1))
         y = mm(act, w_down).astype(jnp.float32) * wt[:, None]
         return (jnp.zeros((t, d), jnp.float32).at[tok].add(y),
                 jnp.sum(here))
 
-    operands = (xt, w_flat, w_gate, w_up, w_down)
+    operands = (rows_in, w_flat, w_gate, w_up, w_down)
     with jax.named_scope("moe_experts"):
         out, done = slice_out(0, *operands)
         if n_slices > 1:

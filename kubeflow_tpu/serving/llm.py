@@ -240,8 +240,10 @@ class LLMEngine:
     `quantize_params`, `QUANT_LEAVES`, `quantize_kv`, `dequantize_kv`,
     `cache_kv_spec`, `logical_axes_for` (weights and sharding),
     `resolve_decode_attn`, `resolve_prefill_attn` (kernel selection) and
-    `STEP_COUNTERS` (what a decode step counts). A family's prefill KV is
-    any pytree of `[layers, batch, rows, ...]` leaves."""
+    `STEP_COUNTERS` (what a decode step counts), and where the family has
+    it `prompt_counters` (what the prompt tokens computed count). A
+    family's prefill KV is any pytree of `[layers, batch, ...]` leaves
+    whose first leaf is `[layers, batch, rows, ...]`."""
 
     #: obs component label (overridden by role engines: prefill/decode/
     #: stage_sharded) — the `component=` of every engine-side metric and
@@ -2013,9 +2015,14 @@ class LLMEngine:
 
     def _step_counters(self) -> dict[str, float]:
         """The family's STEP_COUNTERS as the replayed decode chunks have
-        folded them."""
-        return {name: float(v) for (name, _), v in zip(
+        folded them, and what its `prompt_counters` (where it has one)
+        make of the prompt tokens computed so far."""
+        out = {name: float(v) for (name, _), v in zip(
             self.family.STEP_COUNTERS, self._step_counts)}
+        per_prompt = getattr(self.family, "prompt_counters", None)
+        if per_prompt is not None:
+            out.update(per_prompt(self.cfg, self._prefill_computed_tokens))
+        return out
 
     def _obs_publish(self) -> None:
         """Scrape hook body: refresh this engine's queue-depth gauges

@@ -99,6 +99,28 @@ FAMILIES: dict[str, Family] = {
             "disaggregated": "the prefill->decode handoff moves [kv, "
                              "head_dim] rows, the family latent rows",
         }),
+    "nemotron_h": Family(
+        "kubeflow_tpu.models.nemotron_h", "NemotronHConfig", refuses={
+            "speculative": "a recurrent state cannot take back the "
+                           "positions of rejected drafts without a "
+                           "snapshot of it, and the drafter would be the "
+                           "MTP module, which is not served",
+            "prefix_cache": "the radix cache holds [kv, head_dim] blocks, "
+                            "not the recurrent states a prefix leaves",
+            "kv_layout": "the block pool holds [kv, head_dim] blocks, not "
+                         "recurrent states",
+            "parallel": "the experts held here have no exchange with the "
+                        "other ranks' (one chip of an expert-parallel "
+                        "layer)",
+            "mesh": "the experts held here have no exchange with the other "
+                    "ranks' (one chip of an expert-parallel layer)",
+            "adapters": "the family's matmuls take no low-rank bypass",
+            "lora": "the family's matmuls take no low-rank bypass",
+            "quantize": "int8 experts need a grouped matmul that "
+                        "dequantizes its groups",
+            "disaggregated": "the prefill->decode handoff moves KV rows, "
+                             "not recurrent states",
+        }),
 }
 
 
@@ -979,3 +1001,9 @@ def _laguna_runtime(name: str, uri: str | None = None,
 def _pangu_ultra_moe_runtime(name: str, uri: str | None = None,
                              **config: Any) -> Model:
     return LLMModel(name, uri, **dict(config, family="pangu_ultra_moe"))
+
+
+@serving_runtime("nemotron_h")
+def _nemotron_h_runtime(name: str, uri: str | None = None,
+                        **config: Any) -> Model:
+    return LLMModel(name, uri, **dict(config, family="nemotron_h"))

@@ -32,10 +32,12 @@ LIGHT = ("test_tracered", "test_opcount", "test_traffic",
          "test_benchmark_json", "test_engine_readers",
          "test_decode_kv_fetched_block_share",
          "test_quant_matmul_stacked_roofline", "test_laguna_cell_light",
-         "test_host_readers", "test_pangu_cell_light")
+         "test_host_readers", "test_pangu_cell_light",
+         "test_nemotron_cell_light")
 
 #: tests of a LIGHT file that build and run models: by hand only
-HEAVY = {"test_host_readers__cpu_rehearsal_prints_the_new_metrics"}
+HEAVY = {"test_host_readers__cpu_rehearsal_prints_the_new_metrics",
+         "test_nemotron_cell_light__cpu_rehearsal"}
 
 #: tests known to fail, by name, each with its reason
 XFAIL = {
@@ -93,8 +95,10 @@ globals().update(_collect())
 def test_host_readers_declared_with_the_latent_cell():
     """`test_host_readers__every_reader_is_declared_for_cells_that_exist`
     without its two asserts that ISSUE 38's appends break: the served
-    metrics list the THREE served cells, and PR 36's seven are one run of
-    per_layer, in order, followed only by metrics of ISSUE 38's cell."""
+    metrics list the THREE served cells (a later served cell is added to
+    none of them), and the seven are one run of per_layer, in order,
+    followed only by metrics of one served cell each, neither the chat
+    cell nor the Laguna cell."""
     import json
 
     m = MODULES["test_host_readers"]
@@ -123,5 +127,30 @@ def test_host_readers_declared_with_the_latent_cell():
     order = [d["name"] for d in bench["per_layer"]]
     at = order.index(m.NAMES[0])
     assert order[at:at + len(m.NAMES)] == list(m.NAMES)
+    # each metric after the seven lists exactly one cell: a served cell
+    # added after them, when the chat and Laguna cells were the served ones
+    served_since = set(end_to_end["serve_out_tokens_per_s"]["workloads"]
+                          ) - {"serve_chat_open",
+                               "serve_laguna_xs2_mixed_open"}
     for later in bench["per_layer"][at + len(m.NAMES):]:
-        assert later["workloads"] == ["serve_pangu_ultra_moe_long_open"]
+        assert len(later["workloads"]) == 1, later["name"]
+        assert later["workloads"][0] in served_since, later["name"]
+
+
+@pytest.mark.parametrize("entries,keys", [
+    ("configs", ("why", "source")), ("workloads", ("why",)),
+    ("per_layer", ("layer",))])
+def test_every_text_field_fits_one_line(entries, keys):
+    """Each `why`, `source` and `layer` is 1 to 200 printable characters
+    on one line, a configuration's `why` included, which
+    `test_benchmark_json__shape_and_limits` does not hold to the limit."""
+    import json
+
+    m = MODULES["test_benchmark_json"]
+    with open(os.path.join(m.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for entry in bench[entries]:
+        for key in keys:
+            text = entry[key]
+            assert m.line(text) and text.isprintable(), (
+                entry["name"], key, len(text))

@@ -1,6 +1,6 @@
 """The KDA kernels compiled by the REAL v5e compiler at the Kimi-Linear cut,
-and the served latent attention's two kernels at the openPangu cell's
-shapes, from this CPU process: a v5e topology described, not attached (the
+the served latent attention's two kernels at the openPangu cell's shapes and
+the state-space layer's two at the Nemotron-H cell's, from this CPU process: a v5e topology described, not attached (the
 on-chip-measurement guide, section 2). tests/test_kernels_lower_tpu.py runs
 JAX's own Pallas->Mosaic lowering, which the interpreter's parity tests do
 not; this file runs Mosaic itself, which refused a slice of an iota that
@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from kubeflow_tpu.ops import flash_pallas, kda, mla_decode
+from kubeflow_tpu.ops import flash_pallas, kda, mla_decode, ssd
 
 
 @pytest.fixture(scope="module")
@@ -164,3 +164,54 @@ def test_mla_prefill_kernel_compiles_for_v5e(one_chip, q_offset):
     # mla_prefill_roofline finds the call by its operands
     told = bench_module("opcount/mla_serve").prefill_call
     assert [told(e) for e in kernel_events(compiled)] == [(128, 1024, t)]
+
+
+# -- the state-space layer's two kernels at the Nemotron-H cell's shapes ------
+# a prompt wave of 4 x 1024, 128 heads of 64, state 128, 8 groups; a decode
+# step of 96 slots over the slab of 5 layers. Mosaic refused here, not in
+# the lowering test, a [1, 1] broadcast into both axes and a slice of a
+# column of the chunk's last decay
+
+def test_ssd_scan_kernel_compiles_for_v5e(one_chip):
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+
+    def fn(x, dt, cum, bm, cm, h0):
+        return ssd._scan_pallas(x, dt, cum, bm, cm, h0, interpret=False)
+
+    compiled = jax.jit(fn).lower(
+        sds((4, 1024, 128, 64), jnp.bfloat16), sds((4, 1024, 128), jnp.float32),
+        sds((4, 1024, 128), jnp.float32), sds((4, 1024, 8, 128), jnp.bfloat16),
+        sds((4, 1024, 8, 128), jnp.bfloat16),
+        sds((4, 128, 64, 128), jnp.float32)).compile()
+    told = bench_module("opcount/ssd")
+    events = kernel_events(compiled)
+    assert len(events) == 1 and told.SCAN.match(events[0])
+
+
+@pytest.mark.parametrize("state_dtype", [jnp.float32, jnp.bfloat16])
+def test_ssm_state_step_compiles_for_v5e_in_place(one_chip, state_dtype):
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+
+    def fn(states, x, dt, decay, bm, cm):
+        return ssd._step_pallas(states, 3, x, dt, decay, bm, cm,
+                                interpret=False)
+
+    slab = (5, 96, 128, 64, 128)
+    compiled = jax.jit(fn, donate_argnums=0).lower(
+        sds(slab, state_dtype), sds((96, 128, 64), jnp.bfloat16),
+        sds((96, 128), jnp.float32), sds((96, 128), jnp.float32),
+        sds((96, 8, 128), jnp.bfloat16),
+        sds((96, 8, 128), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    # the slab is the operand and the result: no copy of it round the call
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "[5,96,128,64,128]" in line]
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        5 * 96 * 128 * 64 * 128 * jnp.dtype(state_dtype).itemsize)
+    told = bench_module("opcount/ssd")
+    events = kernel_events(compiled)
+    assert [told.step_call(e) for e in events] == [
+        jnp.dtype(state_dtype).itemsize]
+

@@ -24,7 +24,7 @@ import pytest
 
 from kubeflow_tpu.models import llama
 from kubeflow_tpu.ops import (flash_decode, flash_pallas, flash_prefill, kda,
-                              mla_decode, pallas_compat, quant_matmul)
+                              mla_decode, pallas_compat, quant_matmul, ssd)
 
 #: pallas_call sites per ops module that this file lowers for TPU.
 #: scripts/check_kernels.py requires the counts to match the source, so a
@@ -36,6 +36,7 @@ PALLAS_CALL_SITES = {
     "flash_pallas": 3,
     "kda": 5,
     "mla_decode": 1,
+    "ssd": 2,
 }
 
 # chip_smoke.py's serving shapes: Llama-3-8B heads, 16 slots x 2048
@@ -476,3 +477,56 @@ def test_unsupported_head_dim_is_refused_at_engine_construction(
     eng = LLMEngine(llama.init(jax.random.key(0), wide), wide, **kw)
     assert eng.cfg.decode_attention_impl == "flash"
     assert eng.cfg.prefill_attention_impl == "flash"
+
+
+# -- the state-space layer's two kernels at the Nemotron-H cut ---------------
+# a prompt wave of 4 x 1024 positions, 128 heads of 64, 8 groups of B and C
+# of 128; a decode step of 96 slots over the 5 layers' state slab
+
+def ssd_scan_lowered():
+    def scan(x, dt, cum, bm, cm, h0):
+        return ssd._scan_pallas(x, dt, cum, bm, cm, h0, interpret=False)
+    return jax.jit(scan).trace(
+        sds((4, 1024, 128, 64), jnp.bfloat16), sds((4, 1024, 128), jnp.float32),
+        sds((4, 1024, 128), jnp.float32), sds((4, 1024, 8, 128), jnp.bfloat16),
+        sds((4, 1024, 8, 128), jnp.bfloat16),
+        sds((4, 128, 64, 128), jnp.float32)).lower(lowering_platforms=("tpu",))
+
+
+def ssm_step_lowered(state_dtype):
+    def step(states, x, dt, decay, bm, cm):
+        return ssd._step_pallas(states, 3, x, dt, decay, bm, cm,
+                                interpret=False)
+    return jax.jit(step).trace(
+        sds((5, 96, 128, 64, 128), state_dtype),
+        sds((96, 128, 64), jnp.bfloat16), sds((96, 128), jnp.float32),
+        sds((96, 128), jnp.float32), sds((96, 8, 128), jnp.bfloat16),
+        sds((96, 8, 128), jnp.bfloat16)).lower(lowering_platforms=("tpu",))
+
+
+#: every kernel pattern an accepted reader tells its kernel by
+def _accepted_patterns():
+    fa, ma, ms, kc = (opcount_module(n) for n in (
+        "flash_attention", "mla_attention", "mla_serve", "kda_chunk"))
+    gm, mo = opcount_module("grouped_matmul"), opcount_module("moe_serve")
+    return [fa.FORWARD, fa.BACKWARD_KV, fa.BACKWARD_Q, ma.FORWARD,
+            ma.BACKWARD_KV, ma.BACKWARD_Q, ms.DECODE, ms.PREFILL, kc.INTRA,
+            kc.STATE, gm.GMM, gm.TGMM, mo.GMM]
+
+
+@pytest.mark.parametrize("state_dtype", [jnp.float32, jnp.bfloat16])
+def test_ssd_kernels_lower_and_are_told_by_their_names(state_dtype):
+    """Both kernels lower at the cut; the benchmark's readers find each by
+    its own name, and no accepted reader's pattern takes either."""
+    o = opcount_module("ssd")
+    scan = kernel_event_names(ssd_scan_lowered(),
+                              ssd.SCAN_KERNEL + ".1")
+    step = kernel_event_names(ssm_step_lowered(state_dtype),
+                              ssd.STEP_KERNEL + ".1")
+    assert len(scan) == len(step) == 1
+    assert o.SCAN.match(scan[0]) and not o.step_call(scan[0])
+    assert o.step_call(step[0]) == jnp.dtype(state_dtype).itemsize
+    assert not o.SCAN.match(step[0])
+    for name in scan + step:
+        assert not any(p.match(name) for p in _accepted_patterns()), name
+
