@@ -24,14 +24,23 @@ pairs inside one sub-block are summed channel by channel with the exponent
 taken of the difference itself. No exponent anywhere is positive.
 
 Three stages, forward:
-  1. `_intra_pallas`: A and B of every chunk (Pallas).
+  1. `_intra_pallas`: A and B of every chunk (Pallas), G chunks of one head
+     a grid step (grid (BH, NC / G)): the sub-block products as matmuls
+     batched over the G chunks, the sums inside sub-blocks over all of
+     their sub-blocks at once.
   2. `_ut_pallas`: the inverse X = (I + Diag(beta) A)^-1 of the unit lower
      triangle and M = X Diag(beta), UT_CHUNKS chunks a grid step in VMEM
      (Pallas): the two diagonal blocks of 32 rows by forward substitution
      on the vector unit, then the last step by halves, two 64 x 64 matmuls
      on the MXU; float32, matmuls at HIGHEST.
   3. `_state_pallas`: chunks in order, the state carried in VMEM (Pallas);
-     on the way it can write the state at every chunk's start.
+     on the way it can write the state at every chunk's start. H heads a
+     grid step (grid (BH / H, NC), their states [H, dv, dk] in VMEM), the
+     heads unrolled, so that their independent chains of matmuls fill each
+     other's gaps.
+G and H follow the shapes: the largest divisor of NC (of BH) not above
+PER_STEP (`_per_step`); each traced forward on the kernels' path appends
+(G, H) to TRACED_BLOCKING.
 Stage 2 runs under the named scope `kda_solve` and the backward under
 `kda_backward`: a capture's device operations carry them, and the benchmark
 reads their shares of the device's time (benchmark/lib/xscopes.py).
@@ -79,6 +88,9 @@ SUB = 16          # rows of a sub-block: one reference point each
 # `_prepare` for at once (`back`, and the `_prepare` ahead of the scan)
 BACKWARD_GROUP = 8
 UT_CHUNKS = 16    # chunks a grid step of `_ut_pallas` inverts
+# at most: the chunks a grid step of `_intra_pallas` takes, the heads one of
+# `_state_pallas` walks
+PER_STEP = 8
 HIGHEST = jax.lax.Precision.HIGHEST
 
 # Tests on the CPU set this to run the kernels under the Pallas interpreter.
@@ -89,6 +101,15 @@ FORCE_INTERPRET = False
 TRACED_BACKWARD: list[str] = []
 #: the same for each traced forward's solve (stage 2)
 TRACED_SOLVE: list[str] = []
+#: each traced forward on the kernels' path: (chunks a grid step of stage 1,
+#: heads a grid step of stage 3)
+TRACED_BLOCKING: list[tuple[int, int]] = []
+
+
+def _per_step(n: int) -> int:
+    """The largest divisor of n (chunks of a head, or heads) not above
+    PER_STEP: what one grid step of a forward kernel takes."""
+    return max(d for d in range(1, min(n, PER_STEP) + 1) if n % d == 0)
 
 
 def _mm(x, y, dims, precision=None):
@@ -101,73 +122,75 @@ def _mm(x, y, dims, precision=None):
 # ---------------------------------------------------------------------------
 
 def _intra_kernel(q_ref, k_ref, g_ref, a_ref, b_ref, *, chunk, sub, mm_dtype):
-    q = q_ref[0].astype(jnp.float32)          # [C, dk]
-    k = k_ref[0].astype(jnp.float32)
-    g = g_ref[0]                              # cumulative log-decay, f32
+    n = a_ref.shape[1]                        # chunks of this grid step
+    dk = q_ref.shape[-1]
+    q = q_ref[0].astype(jnp.float32).reshape(n, chunk, dk)
+    k = k_ref[0].astype(jnp.float32).reshape(n, chunk, dk)
+    g = g_ref[0].reshape(n, chunk, dk)        # cumulative log-decay, f32
     ns = chunk // sub
-    dk = q.shape[-1]
 
     # pairs of different sub-blocks: rows of sub-block I against every
     # column, reference point the first row of I; columns that are not
     # before I are masked below (their exponent is clamped, not used)
     a_rows, b_rows = [], []
     for i in range(ns):
-        ref = g[i * sub:i * sub + 1, :]                       # [1, dk]
-        decay = jnp.exp(g[i * sub:(i + 1) * sub, :] - ref)    # <= 1
+        ref = g[:, i * sub:i * sub + 1, :]                    # [n, 1, dk]
+        decay = jnp.exp(g[:, i * sub:(i + 1) * sub, :] - ref)  # <= 1
         rows = jnp.concatenate(
-            [k[i * sub:(i + 1) * sub, :] * decay,
-             q[i * sub:(i + 1) * sub, :] * decay], axis=0)    # [2 sub, dk]
-        cols = k * jnp.exp(jnp.minimum(ref - g, 0.0))         # [C, dk]
+            [k[:, i * sub:(i + 1) * sub, :] * decay,
+             q[:, i * sub:(i + 1) * sub, :] * decay], axis=1)  # [n, 2 sub, dk]
+        cols = k * jnp.exp(jnp.minimum(ref - g, 0.0))         # [n, C, dk]
         ab = jax.lax.dot_general(
             rows.astype(mm_dtype), cols.astype(mm_dtype),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [2 sub, C]
-        a_rows.append(ab[:sub])
-        b_rows.append(ab[sub:])
-    a_off = jnp.concatenate(a_rows, axis=0)                   # [C, C]
-    b_off = jnp.concatenate(b_rows, axis=0)
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)               # [n, 2 sub, C]
+        a_rows.append(ab[:, :sub])
+        b_rows.append(ab[:, sub:])
+    a_off = jnp.concatenate(a_rows, axis=1)                   # [n, C, C]
+    b_off = jnp.concatenate(b_rows, axis=1)
 
-    # pairs inside one sub-block: column i of every sub-block at once,
-    # the exponent of the difference itself
-    q3 = q.reshape(ns, sub, dk)
-    k3 = k.reshape(ns, sub, dk)
-    g3 = g.reshape(ns, sub, dk)
-    col = jax.lax.broadcasted_iota(jnp.int32, (ns, sub, chunk), 2)
-    blk = jax.lax.broadcasted_iota(jnp.int32, (ns, sub, chunk), 0)
-    a_dg = jnp.zeros((ns, sub, chunk), jnp.float32)
-    b_dg = jnp.zeros((ns, sub, chunk), jnp.float32)
+    # pairs inside one sub-block: column i of every sub-block of every
+    # chunk at once, the exponent of the difference itself
+    shape = (n, ns, sub, chunk)
+    q4 = q.reshape(n, ns, sub, dk)
+    k4 = k.reshape(n, ns, sub, dk)
+    g4 = g.reshape(n, ns, sub, dk)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 3)
+    blk = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    a_dg = jnp.zeros(shape, jnp.float32)
+    b_dg = jnp.zeros(shape, jnp.float32)
     for i in range(sub):
-        ki = k3[:, i:i + 1, :]                                # [ns, 1, dk]
-        e = jnp.exp(jnp.minimum(g3 - g3[:, i:i + 1, :], 0.0)) * ki
-        a_col = jnp.sum(k3 * e, axis=-1, keepdims=True)       # [ns, sub, 1]
-        b_col = jnp.sum(q3 * e, axis=-1, keepdims=True)
+        ki = k4[:, :, i:i + 1, :]                             # [n, ns, 1, dk]
+        e = jnp.exp(jnp.minimum(g4 - g4[:, :, i:i + 1, :], 0.0)) * ki
+        a_col = jnp.sum(k4 * e, axis=-1, keepdims=True)       # [n, ns, sub, 1]
+        b_col = jnp.sum(q4 * e, axis=-1, keepdims=True)
         here = col == blk * sub + i
         a_dg = jnp.where(here, a_col, a_dg)
         b_dg = jnp.where(here, b_col, b_dg)
-    a_dg = a_dg.reshape(chunk, chunk)
-    b_dg = b_dg.reshape(chunk, chunk)
+    a_dg = a_dg.reshape(n, chunk, chunk)
+    b_dg = b_dg.reshape(n, chunk, chunk)
 
-    r = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    c = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, chunk, chunk), 1)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, chunk, chunk), 2)
     same = (r // sub) == (c // sub)
     before = (c // sub) < (r // sub)
-    a_ref[0, 0] = jnp.where(same & (c < r), a_dg,
-                            jnp.where(before, a_off, 0.0))
-    b_ref[0, 0] = jnp.where(same & (c <= r), b_dg,
-                            jnp.where(before, b_off, 0.0))
+    a_ref[0] = jnp.where(same & (c < r), a_dg, jnp.where(before, a_off, 0.0))
+    b_ref[0] = jnp.where(same & (c <= r), b_dg, jnp.where(before, b_off, 0.0))
 
 
 def _intra_pallas(q, k, gc, *, interpret, mm_dtype):
     """q, k [BH, S, dk], gc [BH, S, dk] (cumulative inside each chunk) ->
-    A, B [BH, S / C, C, C] float32."""
+    A, B [BH, S / C, C, C] float32; `_per_step(S / C)` chunks of one head a
+    grid step."""
     bh, s, dk = q.shape
     nc = s // CHUNK
-    row = pl.BlockSpec((1, CHUNK, dk), lambda b, c: (b, c, 0))
-    sq = pl.BlockSpec((1, 1, CHUNK, CHUNK), lambda b, c: (b, c, 0, 0))
+    n = _per_step(nc)
+    row = pl.BlockSpec((1, n * CHUNK, dk), lambda b, c: (b, c, 0))
+    sq = pl.BlockSpec((1, n, CHUNK, CHUNK), lambda b, c: (b, c, 0, 0))
     return pl.pallas_call(
         functools.partial(_intra_kernel, chunk=CHUNK, sub=SUB,
                           mm_dtype=mm_dtype),
-        grid=(bh, nc),
+        grid=(bh, nc // n),
         in_specs=[row, row, row],
         out_specs=[sq, sq],
         out_shape=[_sds((bh, nc, CHUNK, CHUNK), jnp.float32, q, k, gc),
@@ -318,22 +341,24 @@ def _ut_cotangents(x, beta, dm):
 # stage 3: the chunks in order
 # ---------------------------------------------------------------------------
 
-def _walk_operands(q_ref, k_ref, v_ref, g_ref, m_ref, st, chunk, mm_dtype):
-    """What either walk over the chunks forms in VMEM from a chunk's blocks
-    and the state S^T [dv, dk] at its start: Q exp(G), K exp(G_C - G),
-    W = M (K exp G), S^T and U, each rounded to mm_dtype where a matmul takes
-    it (sums in float32), and exp(G_C) [1, dk]."""
-    g = g_ref[0]                                # [C, dk] f32
+def _walk_operands(q_ref, k_ref, v_ref, g_ref, m_ref, st, chunk, mm_dtype,
+                   head=0):
+    """What either walk over the chunks forms in VMEM from the blocks of one
+    head's chunk (`head` of the step's heads) and the state S^T [dv, dk] at
+    its start: Q exp(G), K exp(G_C - G), W = M (K exp G), S^T and U, each
+    rounded to mm_dtype where a matmul takes it (sums in float32), and
+    exp(G_C) [1, dk]."""
+    g = g_ref[head]                             # [C, dk] f32
     gam = jnp.exp(g)
     g_last = g[chunk - 1:chunk, :]
-    kf = k_ref[0].astype(jnp.float32)
+    kf = k_ref[head].astype(jnp.float32)
     kg = (kf * gam).astype(mm_dtype)
-    qg = (q_ref[0].astype(jnp.float32) * gam).astype(mm_dtype)
+    qg = (q_ref[head].astype(jnp.float32) * gam).astype(mm_dtype)
     kd = (kf * jnp.exp(g_last - g)).astype(mm_dtype)
-    m = m_ref[0, 0].astype(mm_dtype)
+    m = m_ref[head, 0].astype(mm_dtype)
     sb = st.astype(mm_dtype)
     w = _mm(m, kg, ((1,), (0,))).astype(mm_dtype)            # [C, dk]
-    uv = _mm(m, v_ref[0].astype(mm_dtype), ((1,), (0,)))
+    uv = _mm(m, v_ref[head].astype(mm_dtype), ((1,), (0,)))
     ub = (uv - _mm(w, sb, ((1,), (1,)))).astype(mm_dtype)    # [C, dv]
     return qg, kd, w, sb, ub, jnp.exp(g_last)
 
@@ -349,39 +374,43 @@ def _state_kernel(q_ref, k_ref, v_ref, g_ref, m_ref, b_ref, *rest, chunk,
     def _init():
         st_ref[:] = jnp.zeros_like(st_ref)
 
-    st = st_ref[:]                              # S^T [dv, dk], f32
-    if emit_states:
-        h_ref[0, 0] = st
-    qg, kd, _, sb, ub, decay = _walk_operands(q_ref, k_ref, v_ref, g_ref,
-                                              m_ref, st, chunk, mm_dtype)
-    o = _mm(qg, sb, ((1,), (1,))) + _mm(b_ref[0, 0].astype(mm_dtype), ub,
-                                        ((1,), (0,)))
-    o_ref[0] = o.astype(o_ref.dtype)
-    st_ref[:] = st * decay + _mm(ub, kd, ((0,), (0,)))
+    # the heads of this grid step, unrolled: independent chains that fill
+    # each other's gaps between dependent matmuls
+    for i in range(st_ref.shape[0]):
+        st = st_ref[i]                          # S^T [dv, dk], f32
+        if emit_states:
+            h_ref[i, 0] = st
+        qg, kd, _, sb, ub, decay = _walk_operands(
+            q_ref, k_ref, v_ref, g_ref, m_ref, st, chunk, mm_dtype, head=i)
+        o = _mm(qg, sb, ((1,), (1,))) + _mm(b_ref[i, 0].astype(mm_dtype), ub,
+                                            ((1,), (0,)))
+        o_ref[i] = o.astype(o_ref.dtype)
+        st_ref[i] = st * decay + _mm(ub, kd, ((0,), (0,)))
 
 
 def _state_pallas(q, k, v, gc, m, b, *, emit_states, interpret, mm_dtype):
     """-> o [BH, S, dv] (and the state S^T at every chunk's start,
-    [BH, S / C, dv, dk] float32)."""
+    [BH, S / C, dv, dk] float32); `_per_step(BH)` heads a grid step."""
     bh, s, dk = q.shape
     dv = v.shape[-1]
     nc = s // CHUNK
-    row = lambda d: pl.BlockSpec((1, CHUNK, d), lambda i, c: (i, c, 0))
-    sq = pl.BlockSpec((1, 1, CHUNK, CHUNK), lambda i, c: (i, c, 0, 0))
+    n = _per_step(bh)
+    row = lambda d: pl.BlockSpec((n, CHUNK, d), lambda i, c: (i, c, 0))
+    sq = pl.BlockSpec((n, 1, CHUNK, CHUNK), lambda i, c: (i, c, 0, 0))
     out_specs = [row(dv)]
     out_shape = [_sds((bh, s, dv), v.dtype, q, k, v, gc)]
     if emit_states:
-        out_specs.append(pl.BlockSpec((1, 1, dv, dk),
+        out_specs.append(pl.BlockSpec((n, 1, dv, dk),
                                       lambda i, c: (i, c, 0, 0)))
         out_shape.append(_sds((bh, nc, dv, dk), jnp.float32, q, k, v, gc))
     out = pl.pallas_call(
         functools.partial(_state_kernel, chunk=CHUNK, mm_dtype=mm_dtype,
                           emit_states=emit_states),
-        grid=(bh, nc),
+        grid=(bh // n, nc),
         in_specs=[row(dk), row(dk), row(dv), row(dk), sq, sq],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, dv, dk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
@@ -723,6 +752,7 @@ def _forward(q, k, v, g, beta, pallas, interpret, mm_dtype, emit_states):
         o, h = _states_xla(ops, mm_dtype)
         return (o.reshape(bh, s, -1).astype(v.dtype),
                 jnp.swapaxes(h, -1, -2), None)
+    TRACED_BLOCKING.append((_per_step(nc), _per_step(bh)))
     gc = _cumulative(g)
     a, b = _intra_pallas(q, k, gc, interpret=interpret, mm_dtype=mm_dtype)
     m, x = _ut_pallas(a, beta.reshape(bh, nc, CHUNK), interpret=interpret)

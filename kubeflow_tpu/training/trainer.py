@@ -188,6 +188,9 @@ class Trainer:
         # ("kernel" or "xla")
         self.kda_backward_census: list[str] = []
         self.kda_solve_census: list[str] = []
+        # and each traced KDA forward's kernels' blocking: (chunks, heads)
+        # a grid step
+        self.kda_blocking_census: list[tuple[int, int]] = []
 
     # -- state ---------------------------------------------------------------
 
@@ -310,13 +313,15 @@ class Trainer:
             # ambient mesh for shard_map islands (ring/Ulysses attention,
             # MoE all-to-all) traced inside the jitted step
             seen = len(flash_attention.TRACED_CENSUS)
-            seen_kda = len(kda.TRACED_BACKWARD), len(kda.TRACED_SOLVE)
+            seen_kda = (len(kda.TRACED_BACKWARD), len(kda.TRACED_SOLVE),
+                        len(kda.TRACED_BLOCKING))
             with active_mesh(self.mesh), overlap.count_sites() as traced:
                 out = jitted(state, batch)
             self.overlapped_sites |= traced
             self.attention_census += flash_attention.TRACED_CENSUS[seen:]
             self.kda_backward_census += kda.TRACED_BACKWARD[seen_kda[0]:]
             self.kda_solve_census += kda.TRACED_SOLVE[seen_kda[1]:]
+            self.kda_blocking_census += kda.TRACED_BLOCKING[seen_kda[2]:]
             return out
 
         return step
@@ -471,6 +476,12 @@ class Trainer:
                         scalars[f"kda_{part}_kernel_share"] = (
                             census.count("kernel") / len(census) if census
                             else 0.0)
+                    # the least the KDA forward kernels took a grid step
+                    # (0 where no kernel was traced)
+                    for i, name in enumerate(("intra_chunks", "walk_heads")):
+                        scalars[f"kda_{name}_per_step"] = float(min(
+                            (b[i] for b in self.kda_blocking_census),
+                            default=0))
                     first_interval = False
                 self.metrics.write(step, scalars)
                 if step_callback:

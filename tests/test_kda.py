@@ -131,6 +131,96 @@ def test_the_kernels_path_matches_the_cpu_path(monkeypatch, what, tol):
             jnp.max(jnp.abs(y)))
 
 
+# the grid-step rule of the two forward kernels: the largest divisor of the
+# chunks of a head (stage 1) or of the heads (stage 3) not above PER_STEP
+@pytest.mark.parametrize("n,per_step", [(1, 1), (7, 7), (12, 6), (16, 8),
+                                        (128, 8), (64, 8), (13, 1), (20, 5)])
+def test_a_forward_kernel_takes_the_largest_divisor_up_to_eight(n, per_step):
+    assert kda.PER_STEP == 8
+    assert kda._per_step(n) == per_step
+
+
+# rows x heads = BH, positions -> (chunks, heads) a grid step: BH 12 and 7
+# chunks (6 heads, 7 chunks), BH 16 and 16 chunks (8 and 8), and 420
+# positions padded to 7 chunks (12 heads: 6)
+BLOCKED = pytest.mark.parametrize("b,h,s,blocking", [
+    (2, 6, 448, (7, 6)), (2, 8, 1024, (8, 8)), (1, 12, 420, (7, 6))],
+    ids=["bh12-nc7", "bh16-nc16", "padded"])
+
+
+@BLOCKED
+@pytest.mark.parametrize("what,tol", [("forward", 2e-5), ("grad", 5e-5)])
+def test_the_blocked_kernels_match_the_cpu_path(monkeypatch, b, h, s,
+                                                blocking, what, tol):
+    """Several chunks (stage 1) and several heads (stage 3) a grid step,
+    interpreted, against the CPU path: the output and the five cotangents
+    at the recurrence tests' tolerances."""
+    args = inputs(6, b=b, s=s, h=h)
+    seen = len(kda.TRACED_BLOCKING)
+    want = path_output(what, args, False, monkeypatch)
+    got = path_output(what, args, True, monkeypatch)
+    assert set(kda.TRACED_BLOCKING[seen:]) == {blocking}
+    for x, y in zip(got, want, strict=True):
+        assert x.shape == y.shape
+        assert float(jnp.max(jnp.abs(x - y))) <= tol * float(
+            jnp.max(jnp.abs(y)))
+
+
+@pytest.mark.parametrize("emit_states", [False, True])
+@pytest.mark.parametrize("mm_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_blocked_forward_kernels_match_their_xla_stages(emit_states,
+                                                            mm_dtype):
+    """`_intra_pallas` (8 chunks a grid step) against `_intra_xla` and
+    `_state_pallas` (8 heads a grid step, every head's state carried apart)
+    against `_states_xla`, fed the same M and B: 16 heads of 16 chunks,
+    the output, and the state at every chunk's start where it is written.
+    The same roundings on both sides: float32 to 1e-5, bfloat16 to 1e-3."""
+    q, k, v, g, beta = (heads_first(x) for x in inputs(8, b=2, s=1024, h=8,
+                                                       strength=0.5))
+    bh, s = q.shape[:2]
+    nc = s // kda.CHUNK
+    assert (kda._per_step(nc), kda._per_step(bh)) == (8, 8)
+    per_head = lambda x: x.reshape(bh, nc, *x.shape[1:])
+    gc = kda._cumulative(g)
+    a, b = kda._intra_pallas(q, k, gc, interpret=True, mm_dtype=mm_dtype)
+    want_a, want_b = (per_head(x) for x in kda._intra_xla(
+        kda._chunks(q), kda._chunks(k), kda._chunks(gc), mm_dtype))
+    m = per_head(kda._ut_transform(a.reshape(bh * nc, *a.shape[2:]),
+                                   kda._chunks(beta))[0])
+    o, h = kda._state_pallas(q, k, v, gc, m, b, emit_states=emit_states,
+                             interpret=True, mm_dtype=mm_dtype)
+    ops = jax.tree.map(per_head, kda._prepare(
+        *(kda._chunks(x) for x in (q, k, v, g, beta)), mm_dtype))
+    want_o, want_h = kda._states_xla(ops, mm_dtype)
+    tol = 1e-5 if mm_dtype == jnp.float32 else 1e-3
+    pairs = [("A", a, want_a), ("B", b, want_b),
+             ("o", o, want_o.reshape(o.shape))]
+    if emit_states:
+        pairs.append(("h", h, jnp.swapaxes(want_h, -1, -2)))
+        # every head's states its own: the walk's last chunk starts from
+        # a state that its earlier chunks made
+        assert float(jnp.min(jnp.max(jnp.abs(h[:, -1]), axis=(1, 2)))) > 0.0
+    else:
+        assert h is None
+    for name, x, y in pairs:
+        assert x.shape == y.shape, name
+        assert float(jnp.max(jnp.abs(x - y))) <= tol * float(
+            jnp.max(jnp.abs(y))), name
+
+
+def test_only_a_forward_on_the_kernels_records_its_blocking(path):
+    """kda.TRACED_BLOCKING, which the Trainer's first record reads as
+    `kda_intra_chunks_per_step` and `kda_walk_heads_per_step`: one entry a
+    traced forward on the kernels' path (4 chunks, 2 heads), none on the
+    CPU path."""
+    seen = len(kda.TRACED_BLOCKING)
+    args = inputs(4, s=256)
+    jax.make_jaxpr(lambda *a: kda.chunk_kda(*a, mm_dtype=jnp.float32))(*args)
+    assert kda.TRACED_BLOCKING[seen:] == ([(4, 2)] if path == "pallas"
+                                          else [])
+
+
 def solve_inputs(nc, strength, dk, beta_min):
     """A as the forward's chunk sums make it from unit keys of dk channels
     under a decay of the given strength, and beta uniform in [beta_min, 1)

@@ -84,6 +84,29 @@ def test_kda_kernel_compiles_for_v5e(one_chip, kernel):
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
 
 
+@pytest.mark.parametrize("emit_states", [False, True])
+def test_kda_forward_kernels_compile_blocked_and_stay_readable(one_chip,
+                                                               emit_states):
+    """The chunk sums at 8 chunks a grid step and the walk at 8 heads (its
+    states [8, 128, 128] float32 in VMEM) fit the v5e's scoped VMEM, and
+    the compiled calls carry the operands and results `kda_chunk_roofline`
+    tells them by (benchmark/opcount/kda_chunk.py)."""
+    s = kimi_cut(one_chip)
+    kw = dict(interpret=False, mm_dtype=jnp.bfloat16)
+    assert (kda._per_step(128), kda._per_step(64)) == (8, 8)
+    kc = bench_module("opcount/kda_chunk")
+    intra = jax.jit(lambda q, k, gc: kda._intra_pallas(q, k, gc, **kw)).lower(
+        s["rows"], s["rows"], s["decay"]).compile()
+    state = jax.jit(lambda q, k, v, gc, m, b: kda._state_pallas(
+        q, k, v, gc, m, b, emit_states=emit_states, **kw)).lower(
+        *(s["rows"],) * 3, s["decay"], s["square"], s["square"]).compile()
+    event, = kernel_events(intra)
+    assert kc.INTRA.match(event) and not kc.STATE.match(event)
+    event, = kernel_events(state)
+    assert kc.STATE.match(event) and not kc.INTRA.match(event)
+    assert event.endswith(",f32[64,128,128,128]") == emit_states
+
+
 # -- the served latent attention at the openPangu cell's shapes ---------------
 # 64 slots, 128 heads, a slab of 5 layers x 11,264 rows of 512 + 64 values
 # padded to 640 lanes

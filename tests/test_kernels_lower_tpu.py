@@ -447,6 +447,68 @@ def test_kda_solve_kernel_is_not_read_as_a_forward_kernel():
     assert not kc.INTRA.match(name) and not kc.STATE.match(name)
 
 
+def pallas_grids(fn, *args):
+    """The grid of each pallas_call that fn traces to, in order."""
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    return [eqn.params["grid_mapping"].grid for eqn in jaxpr.eqns
+            if eqn.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("emit_states", [False, True])
+def test_kda_forward_kernels_take_eight_chunks_or_heads_a_step(emit_states):
+    """At the cut the chunk sums take 8 chunks of a head a grid step and the
+    walk 8 heads: 64 x 16 and 8 x 128 steps, not 64 x 128 each. Their
+    operands and results are those of one chunk (one head) a step, so
+    `kda_chunk_roofline` still tells each by its own pattern and not by the
+    other's (benchmark/opcount/kda_chunk.py)."""
+    kc = opcount_module("kda_chunk")
+    rows, decay, square = KDA_ROWS, KDA_DECAY, KDA_SQUARE
+    intra = lambda q, k, gc: kda._intra_pallas(
+        q, k, gc, interpret=False, mm_dtype=jnp.bfloat16)
+    state = lambda q, k, v, gc, m, b: kda._state_pallas(
+        q, k, v, gc, m, b, emit_states=emit_states, interpret=False,
+        mm_dtype=jnp.bfloat16)
+    assert pallas_grids(intra, rows, rows, decay) == [(64, 16)]
+    assert pallas_grids(state, rows, rows, rows, decay, square, square) == [
+        (8, 128)]
+    lower = lambda fn, *a: jax.jit(fn).trace(*a).lower(
+        lowering_platforms=("tpu",))
+    name, = kernel_event_names(lower(intra, rows, rows, decay), "kda.1")
+    assert name == ("kda.1(bf16[64,8192,128],bf16[64,8192,128],"
+                    "f32[64,8192,128])->f32[64,128,64,64],f32[64,128,64,64]")
+    assert kc.INTRA.match(name) and not kc.STATE.match(name)
+    name, = kernel_event_names(
+        lower(state, rows, rows, rows, decay, square, square), "kda.3")
+    assert name.endswith("->bf16[64,8192,128]" + (
+        ",f32[64,128,128,128]" if emit_states else ""))
+    assert kc.STATE.match(name) and not kc.INTRA.match(name)
+
+
+def test_kda_training_step_at_the_cut_is_five_kernels_two_of_them_read(
+        monkeypatch):
+    """A KDA layer's forward and backward at the cut, lowered for a TPU
+    target: five Mosaic calls (chunk sums, solve, walk; the backward's
+    two), of which `kda_chunk_roofline` reads exactly the chunk sums and
+    the walk (the walk writing the states, as `custom_vjp`'s forward rule
+    has it)."""
+    monkeypatch.setattr(pallas_compat, "target_platform", lambda: "tpu")
+    kc = opcount_module("kda_chunk")
+    heads = sds((2, 8192, 32, 128), jnp.bfloat16)
+    decay = sds((2, 8192, 32, 128), jnp.float32)
+    f = lambda q, k, v, g, beta: jnp.sum(kda.chunk_kda(q, k, v, g, beta))
+    lowered = jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4))).trace(
+        heads, heads, heads, decay, sds((2, 8192, 32), jnp.float32)).lower(
+        lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") == 5
+    names = kernel_event_names(lowered, "kda.4")
+    assert len(names) == 5
+    assert [n for n in names if kc.INTRA.match(n)] == [
+        "kda.4(bf16[64,8192,128],bf16[64,8192,128],f32[64,8192,128])"
+        "->f32[64,128,64,64],f32[64,128,64,64]"]
+    assert [n.partition("->")[2] for n in names if kc.STATE.match(n)] == [
+        "bf16[64,8192,128],f32[64,128,128,128]"]
+
+
 def test_unsupported_head_dim_is_refused_at_engine_construction(
         monkeypatch):
     """head_dim 32 (examples/llm-inference-service.yaml) cannot be tiled

@@ -37,7 +37,9 @@ def test_a_trainer_step_trains_the_family_and_reports_the_counters(
         monkeypatch, path):
     """Two steps on the CPU's jax.numpy KDA or the kernels interpreted; the
     first record says which backward and forward solve the step's trace
-    took."""
+    took, and how many chunks and heads the forward kernels took a grid
+    step (one chunk of 64 positions, 2 rows x 2 heads; 0 off the
+    kernels)."""
     from kubeflow_tpu.ops import kda
     from kubeflow_tpu.parallel import MeshConfig
     from kubeflow_tpu.training.data import synthetic_tokens
@@ -62,3 +64,7 @@ def test_a_trainer_step_trains_the_family_and_reports_the_counters(
         assert seen[0][f"kda_{part}_kernel_share"] == (
             1.0 if path == "pallas" else 0.0)
         assert f"kda_{part}_kernel_share" not in seen[1]
+    blocking = (1.0, 4.0) if path == "pallas" else (0.0, 0.0)
+    names = ("kda_intra_chunks_per_step", "kda_walk_heads_per_step")
+    assert tuple(seen[0][name] for name in names) == blocking
+    assert not set(names) & set(seen[1])
